@@ -12,13 +12,12 @@
 #    coverage in tier-1 (test_generate fsdp=8 — the 3-axis case shards
 #    fsdp too; test_serve long-stream MoE — family-independent host
 #    logic pinned by gpt2/llama, MoE exactness has its own tests);
-# 3. the CONTAINER-BACKEND-GAP set (see `_container_backend_gap` in
-#    test_pipeline/test_ladder_models/test_llama/test_moe/test_remat/
-#    test_trainer_strategy): composed-mesh and remat parity cases that
-#    cannot pass on this container's legacy shard_map backend
-#    (PartitionId-under-SPMD + old-jax version gaps, the PR 1/PR 2
-#    known-failure set) and burned ~6 min of budget producing no
-#    signal. They run in `make test` and on hardware dryruns.
+# 3. the `_container_backend_gap` set (test_pipeline/
+#    test_ladder_models/test_llama/test_moe/test_remat/
+#    test_trainer_strategy): composed-mesh and remat parity cases
+#    parked in earlier rounds for burning ~6 min of budget without
+#    signal on the CPU backend. They run in `make test`; ROADMAP C8
+#    re-triages them against the installed jax.
 # Nothing marked slow is the only in-budget test of a feature that can
 # pass on this container. Run the full suite with `make test`.
 
@@ -157,7 +156,7 @@ bench-smoke:
 # fresh smoke record diffed against itself (the self-consistency check
 # bench-smoke runs); point them at two bench records / BENCH_r*.json
 # files to gate a real trajectory step, e.g.
-#   make bench-diff BASE=BENCH_r04.json NEW=BENCH_r05.json
+#   make bench-diff BASE=old.json NEW=new.json
 BASE ?= /tmp/_bench_diff_self.json
 NEW ?= /tmp/_bench_diff_self.json
 bench-diff:

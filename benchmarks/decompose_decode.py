@@ -44,14 +44,9 @@ def main():
     B = int(sys.argv[2]) if len(sys.argv) > 2 else 16
     quant = "--int8" in sys.argv
 
-    import os
-    import tempfile
-
     from distributed_compute_pytorch_tpu.utils.compilation_cache import (
         enable as enable_compile_cache)
-    enable_compile_cache(os.environ.get(
-        "DCP_COMPILE_CACHE",
-        os.path.join(tempfile.gettempdir(), "dcp_jax_cache")))
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

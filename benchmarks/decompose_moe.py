@@ -40,14 +40,9 @@ def two_length(time_n, iters, repeats=4):
 
 
 def main():
-    import os
-    import tempfile
-
     from distributed_compute_pytorch_tpu.utils.compilation_cache import (
         enable as enable_compile_cache)
-    enable_compile_cache(os.environ.get(
-        "DCP_COMPILE_CACHE",
-        os.path.join(tempfile.gettempdir(), "dcp_jax_cache")))
+    enable_compile_cache()
 
     import jax
     import jax.numpy as jnp

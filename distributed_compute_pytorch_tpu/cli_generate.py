@@ -45,17 +45,23 @@ def load_model_and_params(model_name: str, preset, vocab_size, max_seq_len,
     model from its knobs, restore the params subtree (straight into the
     mesh layout when ``mesh_spec`` is given — no host-side full copy,
     which is what lets a bigger-than-one-chip checkpoint load at all),
-    and optionally apply weight-only int8. Returns ``(model, params,
-    mesh)``. One implementation so the two CLIs cannot drift."""
+    and optionally apply weight-only int8. The parameters keep the dtype
+    the checkpoint was saved in. Returns ``(model, params, mesh)``. One
+    implementation so the two CLIs cannot drift."""
     import jax
 
     from distributed_compute_pytorch_tpu.models.registry import build_model
     from distributed_compute_pytorch_tpu.train.checkpoint import (
-        restore_params)
+        restore_params, saved_param_dtype)
 
     kw = {k: v for k, v in (("preset", preset),
                             ("vocab_size", vocab_size),
-                            ("max_seq_len", max_seq_len))
+                            ("max_seq_len", max_seq_len),
+                            # serve the weights in the dtype they were
+                            # trained in (dcp-train --param_dtype): a
+                            # bfloat16 checkpoint gives bfloat16 weights,
+                            # activations and KV cache
+                            ("param_dtype", saved_param_dtype(ckpt_path)))
           if v is not None}
     model = build_model(model_name, **kw)
     # ABSTRACT template: structure/shapes/dtypes only — a concrete init
@@ -156,15 +162,19 @@ def main(argv=None) -> int:
     p.add_argument("--force-cpu", action="store_true", dest="force_cpu")
     args = p.parse_args(argv)
 
-    if args.force_cpu:
-        import jax
-        jax.config.update("jax_platforms", "cpu")
     import jax
+    if args.force_cpu:
+        jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
-
     import numpy as np
 
     from distributed_compute_pytorch_tpu.infer import generate
+    from distributed_compute_pytorch_tpu.utils.compilation_cache import (
+        enable as enable_compile_cache)
+    from distributed_compute_pytorch_tpu.utils.logging import device_banner
+    enable_compile_cache()
+    # stderr: stdout carries the result lines
+    print(f"dcp-generate | {device_banner()}", file=sys.stderr, flush=True)
 
     model, params, mesh = load_model_and_params(
         args.model, args.model_preset, args.vocab_size, args.max_seq_len,

@@ -286,7 +286,12 @@ def main(argv=None) -> int:
     p.add_argument("--quantize", default=None, choices=("int8",),
                    help="weight-only int8 serving")
     p.add_argument("--kv_dtype", default="bf16", choices=("bf16", "int8"),
-                   help="KV-cache pool storage dtype (ISSUE 16): int8 "
+                   help="KV-cache pool storage dtype (ISSUE 16). 'bf16' "
+                        "means UNQUANTIZED: blocks are stored in the "
+                        "dtype the checkpoint's parameters were saved "
+                        "in (dcp-train --param_dtype) — bfloat16 for a "
+                        "bfloat16 checkpoint, float32 for a float32 "
+                        "one. int8 "
                         "stores each block as int8 with per-row f32 "
                         "scales — roughly half the HBM/host/disk/"
                         "handoff bytes per cached token, so ~1.9x the "
@@ -589,13 +594,20 @@ def main(argv=None) -> int:
         EXIT_PREEMPTED, PreemptionGuard)
     guard = PreemptionGuard()
     guard.__enter__()
+    import jax
     if args.force_cpu:
-        import jax
         jax.config.update("jax_platforms", "cpu")
     from distributed_compute_pytorch_tpu.cli_generate import (
         check_eos, check_tokenizer_vocab, load_model_and_params)
     from distributed_compute_pytorch_tpu.serve import (
         ContinuousBatcher, Request)
+    from distributed_compute_pytorch_tpu.utils.compilation_cache import (
+        enable as enable_compile_cache)
+    from distributed_compute_pytorch_tpu.utils.logging import device_banner
+    enable_compile_cache()
+    # stderr (stdout carries the result lines): a server that landed on
+    # the CPU because the accelerator runtime failed to load says so first
+    print(f"dcp-serve | {device_banner()}", file=sys.stderr, flush=True)
 
     model, params, mesh = load_model_and_params(
         args.model, args.model_preset, args.vocab_size, args.max_seq_len,
@@ -707,9 +719,17 @@ def main(argv=None) -> int:
         if disk_dir is not None and replica is not None:
             # one failure domain per replica: separate spill directories
             disk_dir = os.path.join(disk_dir, f"replica-{replica}")
+        rep_params = params if rep_params is None else rep_params
+        if replica is not None:
+            # replica i lives on local device i (round-robin when
+            # replicas outnumber chips): the engine keeps its pool, row
+            # state and programs wherever its parameters are
+            devs = jax.local_devices()
+            rep_params = jax.device_put(rep_params,
+                                        devs[replica % len(devs)])
         return ContinuousBatcher(
             model,
-            params if rep_params is None else rep_params,
+            rep_params,
             slots=args.slots, t_max=t_max,
             prompt_buf=prompt_buf, segment=args.segment,
             eos_id=args.eos_id, mesh=mesh,
@@ -841,6 +861,20 @@ def main(argv=None) -> int:
             metrics_f.write(json.dumps({"kind": "serve_final",
                                         "ts": time.time(),
                                         **snap}) + "\n")
+            # which Pallas kernels the compiled programs carry, and where
+            # each engine ran: one record per batcher, after serving
+            for i, b in enumerate(router.replicas if router is not None
+                                  else [cb]):
+                rec = {"kind": "serve_kernels", "ts": time.time(),
+                       "replica": i if router is not None else None,
+                       "engine": b.engine_info(),
+                       "stats": dict(b.stats)}
+                try:
+                    rec["programs"] = b.kernel_census()
+                except Exception as e:  # noqa: BLE001 — telemetry on an
+                    # exit path: record the failure, keep the real error
+                    rec["error"] = f"{type(e).__name__}: {e}"
+                metrics_f.write(json.dumps(rec) + "\n")
             metrics_f.close()
         if journal is not None:
             journal.close()

@@ -164,10 +164,6 @@ class Config:
     # stream's token dim sharded over `tensor` between blocks (transformer
     # models; numerics-transparent)
     seq_shard_activations: bool = False
-    compile_cache_dir: str | None = field(
-        default_factory=lambda: _env("DCP_COMPILE_CACHE"))
-                                     # persistent XLA compile cache (skip
-                                     # recompiles across restarts/relaunches)
     profile_dir: str | None = None   # opt-in XLA profiler traces (SURVEY §5.1)
     # --- telemetry (ISSUE 8, obs/): machine-readable metrics + host traces
     metrics_jsonl: str | None = None  # MetricLogger JSONL sink (train/eval/
@@ -367,9 +363,6 @@ class Config:
                        help="Megatron sequence-parallel activations: shard "
                             "the residual stream's token dim over `tensor` "
                             "between transformer blocks (tensor>1 meshes)")
-        p.add_argument("--compile_cache_dir", type=str, default=None,
-                       help="persistent XLA compile cache directory "
-                            "(env DCP_COMPILE_CACHE)")
         p.add_argument("--profile_dir", type=str, default=None)
         p.add_argument("--metrics_jsonl", type=str, default=None,
                        help="append machine-readable metric records "
@@ -382,8 +375,10 @@ class Config:
         p.add_argument("--collective_stats", action="store_true",
                        help="trace the train step once at startup and "
                             "record its gradient-collective op/byte "
-                            "census (jaxpr + compiled-HLO) to the "
-                            "registry and --metrics_jsonl")
+                            "census (jaxpr + compiled-HLO) and the "
+                            "compiled step's Pallas kernel (Mosaic "
+                            "custom call) census to the registry and "
+                            "--metrics_jsonl")
         p.add_argument("--flight_recorder", type=str, default=None,
                        help="record span/instant events in a bounded ring "
                             "and dump them as JSON to this path on any "
@@ -405,8 +400,7 @@ class Config:
         kw = {f.name: getattr(ns, f.name) for f in dataclasses.fields(cls)
               if hasattr(ns, f.name)}
         # env-derived fields fall back to env when flags were not given
-        for k in ("coordinator", "num_processes", "process_id",
-                  "compile_cache_dir"):
+        for k in ("coordinator", "num_processes", "process_id"):
             if kw.get(k) is None:
                 kw[k] = getattr(base, k)
         return cls(**kw)

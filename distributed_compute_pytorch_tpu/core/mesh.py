@@ -205,55 +205,16 @@ def current_mesh() -> Mesh | None:
     return _mesh_stack[-1] if _mesh_stack else None
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes it at the top level with ``axis_names`` naming the
-    MANUAL axes; older builds (0.4.x) only have
-    ``jax.experimental.shard_map.shard_map``, whose ``auto`` parameter
-    is the COMPLEMENT (axes left automatic) — translated here so the
-    partial-manual callers (the pipeline's per-stage region) keep one
-    spelling."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        kw = {} if axis_names is None else {"axis_names": axis_names}
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  **kw)
-    from jax.experimental.shard_map import shard_map as legacy_shard_map
-    kw = ({} if axis_names is None
-          else {"auto": frozenset(mesh.axis_names)
-                - frozenset(axis_names)})
-    # the legacy replication checker predates the varying-axes (pcast)
-    # protocol our manual bodies follow — disable it rather than teach
-    # it; partitioning correctness is unaffected (specs still bind)
-    return legacy_shard_map(f, mesh=mesh, in_specs=in_specs,
-                            out_specs=out_specs, check_rep=False, **kw)
-
-
-def pcast_varying(x, axes):
-    """``lax.pcast(x, axes, to="varying")`` when the current jax has it
-    (the varying-manual-axes protocol newer shard_map bodies must
-    follow); identity on older builds, whose legacy shard_map path runs
-    with ``check_rep=False`` and tracks no varying-ness. ``pcast`` is
-    computationally the identity either way — it only informs the
-    replication checker."""
-    pc = getattr(jax.lax, "pcast", None)
-    if pc is None:
-        return x
-    return pc(x, axes, to="varying")
-
-
 _manual_stack: list[frozenset] = []
 
 
 class use_manual_axes:
     """Trace-time declaration that ``axes`` are MANUAL in the enclosing
-    shard_map region, for jax versions whose sharding API cannot report
-    it (no ``get_abstract_mesh``). ``constrain``/``constrain_replicated``
-    consult this and drop the declared axes from their specs — the
-    correct semantics inside the region, where those dims are local.
-    Used by the ZeRO-1 quantized train path (``train/step.py``), whose
-    shard_map body runs the whole model forward manual over the dp axes.
+    shard_map region. ``constrain``/``constrain_replicated`` and
+    BatchNorm's sync statistics (:func:`manual_batch_axes`) consult
+    this and treat the declared axes' dims as local. Used by the
+    dp-manual train paths (``train/step.py``), whose shard_map body
+    runs the whole model forward manual over the dp axes.
     """
 
     def __init__(self, axes):
@@ -269,25 +230,14 @@ class use_manual_axes:
 
 def _manual_axis_names() -> tuple[set, object]:
     """``(manual_axis_names, abstract_mesh_or_None)`` from the current
-    trace context. ``jax.sharding.get_abstract_mesh``/``AxisType`` are
-    recent API (absent in older jax, e.g. 0.4.x); there the pipeline's
-    manual regions are covered by the EXPLICIT ``manual_axes`` plumbing
-    (``constrain_activations``/``constrain_seq_parallel`` no-op on it),
-    so falling back to "no manual axes known" preserves behaviour
-    everywhere the explicit path reaches — instead of the hard
-    AttributeError the missing symbol used to raise on every
-    mesh-active forward. Axes declared via :class:`use_manual_axes`
-    are always included (both jax generations)."""
+    trace context: the axes the abstract mesh types Manual, plus those
+    declared via :class:`use_manual_axes`."""
     extra: set = set().union(*_manual_stack) if _manual_stack else set()
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if get_am is None or axis_type is None:
-        return extra, None
-    am = get_am()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or am.empty:
         return extra, None
     manual = {n for n, t in zip(am.axis_names, am.axis_types)
-              if t == axis_type.Manual}
+              if t == jax.sharding.AxisType.Manual}
     return manual | extra, am
 
 
@@ -340,8 +290,6 @@ def constrain(x, spec: P):
     cleaned = tuple(clean(a) for a in spec)
     if all(a is None for a in cleaned):
         return x
-    # legacy shard_map (no abstract mesh): a constraint naming only
-    # still-automatic axes may bind against the concrete mesh
     target = am if (manual and am is not None) else mesh
     return jax.lax.with_sharding_constraint(
         x, jax.sharding.NamedSharding(target, P(*cleaned)))

@@ -50,8 +50,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from distributed_compute_pytorch_tpu.core.mesh import (
-    pcast_varying, shard_map)
 from distributed_compute_pytorch_tpu.parallel import collectives as coll
 
 
@@ -84,10 +82,11 @@ def make_divergence_check(mesh):
         return None
 
     def body(tree):
-        fp = pcast_varying(tree_fingerprint(tree), dp)
+        fp = lax.pcast(tree_fingerprint(tree), dp, to="varying")
         return lax.pmax(fp, dp) - lax.pmin(fp, dp)
 
-    fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P(), out_specs=P()))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                               out_specs=P()))
 
     def check(tree) -> int:
         return int(fn(tree))

@@ -96,23 +96,58 @@ def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
     # the one genuinely ineligible shape: causal q_len > kv_len (the
     # wrapper rejects it — top rows would attend nothing)
     eligible = not (causal and t > tk)
-    if impl == "pallas":
-        if not eligible:
-            raise ValueError(
-                f"impl='pallas' forced but causal q_len {t} > kv_len {tk} "
-                f"is not a meaningful attention shape")
-        from distributed_compute_pytorch_tpu.ops.pallas.flash_attention import (
-            flash_attention)
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               kv_mask=kv_mask, block_q=bq, block_k=bk)
-    if impl == "auto" and eligible and jax.default_backend() == "tpu":
-        from distributed_compute_pytorch_tpu.ops.pallas.flash_attention import (
-            flash_attention)
-        return flash_attention(q, k, v, causal=causal, scale=scale,
-                               kv_mask=kv_mask, block_q=bq, block_k=bk)
+    if impl == "pallas" and not eligible:
+        raise ValueError(
+            f"impl='pallas' forced but causal q_len {t} > kv_len {tk} "
+            f"is not a meaningful attention shape")
+    if impl == "pallas" or (impl == "auto" and eligible
+                            and jax.default_backend() == "tpu"):
+        return _flash_per_shard(q, k, v, kv_mask, causal=causal,
+                                scale=scale, block_q=bq, block_k=bk)
     mask = None if kv_mask is None else kv_mask[:, None, None, :].astype(bool)
     return dot_product_attention(q, k, v, causal=causal, scale=scale,
                                  mask=mask)
+
+
+def _flash_per_shard(q, k, v, kv_mask, **kw):
+    """The flash kernel where the operands live. A Mosaic call cannot be
+    partitioned — jax refuses to lower one into a multi-device program —
+    so under a mesh the kernel runs inside a ``shard_map`` manual over
+    every mesh axis: batch split over the batch axes, heads over
+    ``tensor``, each chip's kernel grid covering its LOCAL batch and
+    heads (no gather of q/k/v; forward and backward alike, the backward
+    kernels are the transpose of the same region). Off-mesh, on a
+    one-device mesh, or already inside a manual region (the pipeline's)
+    the kernel is called directly."""
+    from jax.sharding import PartitionSpec as P
+
+    from distributed_compute_pytorch_tpu.core.mesh import (
+        BATCH_AXES, _manual_axis_names, current_mesh)
+    from distributed_compute_pytorch_tpu.ops.pallas.flash_attention import (
+        flash_attention)
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1 or _manual_axis_names()[0]:
+        return flash_attention(q, k, v, kv_mask=kv_mask, **kw)
+
+    def split(dim, axes):
+        # a dim the axes do not divide stays whole (every shard computes
+        # all of it): correct, and never an all-gather into the kernel
+        axes = tuple(a for a in axes
+                     if a in mesh.axis_names and mesh.shape[a] > 1)
+        world = 1
+        for a in axes:
+            world *= mesh.shape[a]
+        return axes if axes and dim % world == 0 else None
+
+    batch = split(q.shape[0], BATCH_AXES)
+    spec = P(batch, split(q.shape[1], ("tensor",)), None, None)
+    masked = () if kv_mask is None else (kv_mask,)
+    return jax.shard_map(
+        lambda q, k, v, *m: flash_attention(
+            q, k, v, kv_mask=m[0] if m else None, **kw),
+        mesh=mesh, out_specs=spec,
+        in_specs=(spec, spec, spec) + (P(batch, None),) * len(masked),
+    )(q, k, v, *masked)
 
 
 def _pos_valid_mask(pos, t_max: int):

@@ -21,11 +21,12 @@ and DMAs it back — 8 KB of traffic instead of 75 MB. Aliasing keeps
 every other block of the cache untouched in the SAME buffer, which XLA
 honours through scan carries.
 
-SPMD caveat (same as ``fused_adamw``): a pallas custom call is opaque
-to the GSPMD partitioner — sharded operands would be all-gathered into
-it. Callers must use it only on unsharded caches (single-chip decode);
-``models/*.decode_step`` fall back to ``dynamic_update_slice`` when a
-mesh is active.
+SPMD caveat (same as ``fused_adamw``): a Mosaic call cannot be
+partitioned — jax refuses to lower one into a multi-device program
+outside ``shard_map``. The dispatchers below therefore take the kernel
+only when no mesh context is active (the caches then live whole on one
+device, whichever device of the host that is) and the XLA
+``dynamic_update_slice``/scatter forms under a mesh.
 """
 
 from __future__ import annotations
@@ -80,19 +81,21 @@ def cache_insert_pallas(cache, upd, pos, *, interpret: bool = False):
         # 0=pos, 1=upd, 2=cache) onto the output: the kernel touches one
         # 8-slot block; every other block stays in place, no copy
         input_output_aliases={2: 0},
+        name="dcp_cache_write",
         interpret=interpret,
     )(jnp.atleast_1d(pos).astype(jnp.int32), upd.astype(cache.dtype), cache)
 
 
 def _pallas_ok(caches: dict, axis: int = 2) -> bool:
-    """Single-chip unsharded TPU with every array's time-axis length
-    window-aligned (the sharding caveat in the module docstring,
-    enforced mechanically). ``axis``: the time axis — 2 for the plain
-    [B, hk, T, w] form, 3 for the kv-pair [2, B, hk, T, w] form. ONE
-    policy for both dispatchers."""
+    """TPU, no mesh context (so the caches are unsharded — every sharded
+    caller traces under ``use_mesh``), and every array's time-axis
+    length window-aligned. Decided from where the operands live, never
+    from how many chips the host has: an unsharded server on one chip
+    of a four-chip host takes the kernel like any other. ``axis``: the
+    time axis — 2 for the plain [B, hk, T, w] form, 3 for the kv-pair
+    [2, B, hk, T, w] form. ONE policy for every dispatcher."""
     from distributed_compute_pytorch_tpu.core.mesh import current_mesh
     return (jax.default_backend() == "tpu" and current_mesh() is None
-            and jax.device_count() == 1
             and all(c.shape[axis] % _window(c.dtype) == 0
                     for c in caches.values()))
 
@@ -175,6 +178,7 @@ def kv_insert_pallas(cache: dict, upd: dict, pos, *,
         out_shape=out_shapes,
         grid_spec=grid_spec,
         input_output_aliases=aliases,
+        name="dcp_kv_write",
         interpret=interpret,
     )(jnp.atleast_1d(pos).astype(jnp.int32),
       *[upd[k].astype(cache[k].dtype) for k in names],
@@ -188,10 +192,10 @@ def kv_insert_all(cache: dict, upd: dict, pos) -> dict:
     ``pos`` is either a scalar (lockstep decode: every row writes the
     same slot — ``infer.py``) or a ``[B]`` int32 vector (per-row decode:
     each row writes its OWN slot — ``serve.ContinuousBatcher``). Both
-    forms use a one-window-per-row Pallas kernel on an unsharded
-    single-device TPU and per-array ``dynamic_update_slice`` (scalar) /
-    a masked select (vector) elsewhere (CPU tests; sharded generation,
-    where a pallas call would defeat the GSPMD layout)."""
+    forms use a one-window-per-row Pallas kernel on an unsharded TPU
+    cache and per-array ``dynamic_update_slice`` (scalar) / a masked
+    select (vector) elsewhere (CPU tests; sharded generation, where a
+    Mosaic call cannot be partitioned)."""
     if jnp.ndim(pos) == 0:
         if _pallas_ok(cache, axis=3):
             return kv_insert_pallas(cache, upd, pos)
@@ -287,6 +291,7 @@ def kv_pool_insert_rows_pallas(cache: dict, upd: dict, blocks, offsets, *,
         out_shape=out_shapes,
         grid_spec=grid_spec,
         input_output_aliases=aliases,
+        name="dcp_kv_pool_write",
         interpret=interpret,
     )(blocks.astype(jnp.int32), offsets.astype(jnp.int32),
       *[upd[k].astype(cache[k].dtype) for k in names],
@@ -306,9 +311,9 @@ def _pool_scatter(cache, upd, blocks, offsets):
 
 def kv_pool_insert_all(cache: dict, upd: dict, blocks, offsets) -> dict:
     """Dispatcher for the paged pool write: the per-row Pallas kernel on
-    an unsharded single-device TPU (one window DMA per decode row), an
-    XLA scatter elsewhere (CPU tests; sharded pools, where a pallas call
-    would defeat the GSPMD layout)."""
+    an unsharded TPU pool (one window DMA per decode row), an XLA
+    scatter elsewhere (CPU tests; sharded pools, where a Mosaic call
+    cannot be partitioned)."""
     if _pallas_ok(cache, axis=3):
         return kv_pool_insert_rows_pallas(cache, upd, blocks, offsets)
     return {k: _pool_scatter(cache[k], upd[k], blocks, offsets)
@@ -371,6 +376,7 @@ def kv_insert_rows_pallas(cache: dict, upd: dict, pos, *,
         out_shape=out_shapes,
         grid_spec=grid_spec,
         input_output_aliases=aliases,
+        name="dcp_kv_rows_write",
         interpret=interpret,
     )(pos.astype(jnp.int32),
       *[upd[k].astype(cache[k].dtype) for k in names],

@@ -44,14 +44,6 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_BLOCK = 128
 _NEG_INF = -1e30
 
-# jax-version probe (same shim pattern as core/mesh.py): newer jax spells
-# it pltpu.CompilerParams; the container's 0.4.x only has
-# TPUCompilerParams (same dimension_semantics kwarg). Without this the
-# module — and everything importing it (fused_adamw, the flash suites) —
-# fails at IMPORT on older jax.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _dot_tt(a, b):
     """``a @ b.T`` via dot_general contracting the trailing dims — the MXU
@@ -69,6 +61,14 @@ def _dot_nt(a, b):
 
 def _use_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _out_struct(shape, dtype, like):
+    """Output aval that varies over the same manual mesh axes as the
+    operand ``like`` — inside a ``shard_map`` (how the kernels run under
+    a mesh, ``ops/attention.py``) the varying-axes check needs it said;
+    outside one the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 # ---------------------------------------------------------------------------
@@ -176,8 +176,8 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
+            _out_struct(q.shape, q.dtype, q),
+            _out_struct((bh, t, 1), jnp.float32, q),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, d), jnp.float32),
@@ -187,8 +187,9 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
         # batch*heads and q blocks are independent — declaring them parallel
         # lets Mosaic pipeline (double-buffer) block loads across grid steps;
         # only the kv axis carries the accumulator dependency
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="dcp_flash_fwd",
         interpret=_use_interpret(),
     )(*args)
     return o, lse
@@ -318,10 +319,11 @@ def _flash_bwd(res, g, kv_mask, heads, scale, causal, offset,
         grid=(bh, t // block_q, tk // block_k),
         in_specs=dq_specs,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=_out_struct(q.shape, q.dtype, q),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="dcp_flash_bwd_dq",
         interpret=_use_interpret(),
     )(q, k, v, g.astype(q.dtype), lse, delta, *extra)
 
@@ -349,13 +351,14 @@ def _flash_bwd(res, g, kv_mask, heads, scale, causal, offset,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            _out_struct(k.shape, k.dtype, q),
+            _out_struct(v.shape, v.dtype, q),
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="dcp_flash_bwd_dkv",
         interpret=_use_interpret(),
     )(q, k, v, g.astype(q.dtype), lse, delta, *extra)
     return dq, dk, dv

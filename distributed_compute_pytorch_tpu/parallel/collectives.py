@@ -485,6 +485,17 @@ def _hlo_shape_bytes(s: str) -> int:
     return total
 
 
+def compiled_hlo_text(fn, *args, **kwargs) -> str:
+    """The COMPILED HLO module of ``fn(*args)`` as text. ``fn`` may be a
+    jitted callable (has ``.lower``) or a plain function (jitted here);
+    ``args`` may be arrays or ``ShapeDtypeStruct``s. HLO text is a
+    compiler-internal format: callers must try/except what they parse
+    out of it rather than let a dialect change break a run."""
+    lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(
+        *args, **kwargs)
+    return lowered.compile().as_text()
+
+
 def hlo_collectives(fn, *args, **kwargs):
     """POST-COMPILE collective census: count the cross-device
     collectives (and their result wire bytes) in the compiled HLO
@@ -498,18 +509,15 @@ def hlo_collectives(fn, *args, **kwargs):
     documents). Reading the compiled module closes it: whatever XLA
     actually emitted — including partitioner-inserted all-reduces and
     async ``-start``/``-done`` pairs (counted once, at the start) —
-    is counted here.
+    is counted here. See :func:`count_hlo_collectives` for the record."""
+    return count_hlo_collectives(compiled_hlo_text(fn, *args, **kwargs))
 
-    ``fn`` may be a jitted callable (has ``.lower``) or a plain
-    function (jitted here). Returns ``{"ops": {name: count}, "count",
-    "bytes"}``; bytes are each op's RESULT shape sizes — the
-    per-participant output payload, comparable to the jaxpr census's
-    operand-bytes convention up to the algorithm's constant. HLO text
-    is a compiler-internal format: callers must try/except this (the
-    trainer does) rather than let a dialect change break training."""
-    lowered = (fn if hasattr(fn, "lower") else jax.jit(fn)).lower(
-        *args, **kwargs)
-    txt = lowered.compile().as_text()
+
+def count_hlo_collectives(txt: str) -> dict:
+    """``{"ops": {name: count}, "count", "bytes"}`` over compiled HLO
+    text; bytes are each op's RESULT shape sizes — the per-participant
+    output payload, comparable to the jaxpr census's operand-bytes
+    convention up to the algorithm's constant."""
     ops: dict[str, int] = {}
     count = 0
     nbytes = 0
@@ -527,3 +535,37 @@ def hlo_collectives(fn, *args, **kwargs):
             if "=" in line else line
         nbytes += _hlo_shape_bytes(lhs)
     return {"ops": ops, "count": count, "bytes": nbytes}
+
+
+# a Pallas kernel reaches the device as a Mosaic custom call; its name
+# (the ``name=`` every kernel in ops/pallas passes) rides the op_name
+# metadata as a scope
+_MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_KERNEL_NAME_RE = re.compile(r"\bdcp_[a-z0-9_]+")
+def count_hlo_kernels(txt: str) -> dict:
+    """POST-COMPILE kernel census, the custom-call sibling of
+    :func:`count_hlo_collectives`: the Mosaic (Pallas) calls in
+    compiled HLO text, by kernel name. Returns ``{"count": n,
+    "kernels": {name: count}, "shapes": {name: [shape, ...]}}`` —
+    ``shapes`` holds the first call's RESULT shapes per kernel (compiled
+    HLO prints operands by name only), so a caller can check each
+    chip's kernel covers its LOCAL batch (no gather feeding it). A
+    kernel that fell back to an XLA path is simply absent: this, not a
+    log line, is the proof it reached the device."""
+    kernels: dict[str, int] = {}
+    shapes: dict[str, list] = {}
+    for line in txt.splitlines():
+        if _MOSAIC_TARGET not in line:
+            continue
+        m = _OP_NAME_RE.search(line)
+        names = _KERNEL_NAME_RE.findall(m.group(1)) if m else []
+        name = names[-1] if names else "unnamed"
+        kernels[name] = kernels.get(name, 0) + 1
+        if name not in shapes:
+            # result shapes sit between '=' and the op
+            lhs = line.partition("=")[2].partition(" custom-call(")[0]
+            shapes[name] = [f"{dt}[{dims}]" for dt, dims
+                            in _HLO_SHAPE_RE.findall(lhs)]
+    return {"count": sum(kernels.values()), "kernels": kernels,
+            "shapes": shapes}

@@ -53,8 +53,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_compute_pytorch_tpu.core.mesh import (
-    pcast_varying as _pcast_varying)
+_pcast_varying = partial(lax.pcast, to="varying")
 
 
 # ---------------------------------------------------------------------------
@@ -491,10 +490,7 @@ def pipeline_blocks(block_apply, stacked_params, x, mesh: Mesh,
     out_specs = ((x_spec, jax.tree.map(lambda _: P(), aux_init))
                  if with_aux else x_spec)
 
-    from distributed_compute_pytorch_tpu.core.mesh import (
-        shard_map as _shard_map)
-
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=in_specs, out_specs=out_specs,
              axis_names=set(manual))
     def _pipe(params_local, x_mb, *maybe_mask):

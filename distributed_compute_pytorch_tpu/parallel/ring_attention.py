@@ -27,9 +27,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from distributed_compute_pytorch_tpu.core.mesh import (
-    pcast_varying as _pcast_varying)
-
 _NEG_INF = -1e30  # finite "minus infinity": keeps the online softmax NaN-free
 
 
@@ -106,9 +103,11 @@ def ring_attention_manual(q, k, v, axis: str, n_chunks: int, *,
         q_pos = jnp.tile(q_pos, groups)
     tq = q.shape[2]            # group-folded query length (G * chunk)
     vary = tuple(vary) or (axis,)
-    o = _pcast_varying(jnp.zeros((b, hk, tq, d), jnp.float32), vary)
-    m = _pcast_varying(jnp.full((b, hk, tq), _NEG_INF, jnp.float32), vary)
-    l = _pcast_varying(jnp.zeros((b, hk, tq), jnp.float32), vary)
+    o = lax.pcast(jnp.zeros((b, hk, tq, d), jnp.float32), vary,
+                  to="varying")
+    m = lax.pcast(jnp.full((b, hk, tq), _NEG_INF, jnp.float32), vary,
+                  to="varying")
+    l = lax.pcast(jnp.zeros((b, hk, tq), jnp.float32), vary, to="varying")
 
     # local block first (no communication), then permute-then-attend for
     # the remaining n-1 blocks — exactly n-1 neighbour exchanges total.
@@ -183,10 +182,7 @@ def ring_attention(q, k, v, mesh: Mesh, axis: str = "seq", *,
     if masked:
         kv_mask = kv_mask.astype(jnp.float32)
 
-    from distributed_compute_pytorch_tpu.core.mesh import (
-        shard_map as _shard_map)
-
-    @partial(_shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=((spec, spec, spec, mask_spec) if masked
                        else (spec, spec, spec)),
              out_specs=spec)

@@ -168,7 +168,6 @@ import contextlib
 import inspect
 import threading
 import time
-import warnings
 import weakref
 from dataclasses import dataclass, field, replace
 
@@ -195,6 +194,8 @@ from distributed_compute_pytorch_tpu.obs.tracing import instant, span
 from distributed_compute_pytorch_tpu.serve_journal import JOURNAL_STATS
 from distributed_compute_pytorch_tpu.serve_lifecycle import (
     CANCELLED, FAILED, OK, SHED, TIMEOUT, RequestResult)
+from distributed_compute_pytorch_tpu.train.checkpoint import (
+    dominant_float_dtype)
 from distributed_compute_pytorch_tpu.train.elastic import call_with_timeout
 from distributed_compute_pytorch_tpu.utils.quantize import quantize_kv
 
@@ -580,14 +581,15 @@ class ContinuousBatcher:
             self._dp = 1
         n_layers = int(jax.tree_util.tree_leaves(
             params["blocks"])[0].shape[0])
-        # compute dtype == the first floating param leaf's (bf16 serving
-        # params -> bf16 activations; int8-quantized trees surface their
-        # float scales, same outcome). kv_dtype="bf16" stores blocks in
+        # compute dtype == the dtype most floating parameter elements are
+        # in (bf16 serving params -> bf16 activations, whatever the f32
+        # norm scales say; int8-quantized trees surface their float
+        # embeddings, same outcome). kv_dtype="bf16" stores blocks in
         # that dtype; "int8" stores int8 blocks + a per-(position, head)
         # f32 scale leaf, quantized on write and dequantized on read
-        floats = [l for l in jax.tree.leaves(params)
-                  if jnp.issubdtype(l.dtype, jnp.floating)]
-        self._cdtype = floats[0].dtype if floats else jnp.float32
+        self._cdtype = dominant_float_dtype(
+            (l.shape, l.dtype) for l in jax.tree.leaves(params)
+        ) or jnp.dtype(jnp.float32)
         self.kv_dtype = kv_dtype
         # weights-version stamp (ISSUE 20): every KV byte this engine
         # caches (radix entries, tier sidecars, handoff payloads) is
@@ -649,10 +651,19 @@ class ContinuousBatcher:
         pool_blocks = -(-pool_blocks // self._dp) * self._dp
         self._n_layers = n_layers
 
-        def dev(x, spec):
+        # off-mesh the engine lives where its parameters live: a replica
+        # whose params were placed on local device i (dcp-serve
+        # --replicas) keeps its pool and row state there, and the
+        # compiled programs follow their committed operands
+        self._device = (None if mesh is not None else next(iter(
+            jax.tree.leaves(params)[0].devices())))
+
+        def zeros(shape, dtype, spec):
             if mesh is None:
-                return x
-            return jax.device_put(x, named_sharding(mesh, spec))
+                # born on the engine's device: nothing lands on device 0
+                return jnp.zeros(shape, dtype, device=self._device)
+            return jax.device_put(jnp.zeros(shape, dtype),
+                                  named_sharding(mesh, spec))
 
         # per-layer block POOLS [2(k/v), P, hk, bt, hd]: each tick's
         # write is one window DMA per row through the block table
@@ -663,22 +674,24 @@ class ContinuousBatcher:
         # consumer of the pool dict (attention ops, COW copies,
         # reset/reconstruct zeroing) treats the leaves generically.
         self._caches = [
-            {"kv": dev(jnp.zeros((2, pool_blocks, hk, self.bt, hd), dtype),
-                       _POOL_SPEC),
-             **({"scale": dev(jnp.zeros((2, pool_blocks, hk, self.bt, 1),
-                                        jnp.float32), _POOL_SPEC)}
+            {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
+                         _POOL_SPEC),
+             **({"scale": zeros((2, pool_blocks, hk, self.bt, 1),
+                                jnp.float32, _POOL_SPEC)}
                 if kv_dtype == "int8" else {})}
             for _ in range(n_layers)]
-        if (jax.default_backend() == "tpu"
-                and (mesh is not None
-                     or not _pallas_ok(self._caches[0], axis=3))):
-            warnings.warn(
-                "serving caches fall off the Pallas window-write fast "
-                "path (mesh active, multi-device, or a non-window-"
-                "aligned block size): every decode tick will pay a "
-                "full-pool-copy scatter (~3x slower measured for the "
-                "dense analogue)",
-                stacklevel=2)
+        # which engine writes the pool each tick is decided by where the
+        # pool lives: the Pallas window write off-mesh on TPU, the XLA
+        # scatter under a mesh (a Mosaic call cannot be partitioned) and
+        # on CPU. Off-mesh on TPU there is no second choice to fall to.
+        self._pallas_write = mesh is None and _pallas_ok(self._caches[0],
+                                                         axis=3)
+        if (jax.default_backend() == "tpu" and mesh is None
+                and not self._pallas_write):
+            raise ValueError(
+                f"the KV pool (block size {self.bt}) cannot take the "
+                f"Pallas window write on this TPU: every decode tick "
+                f"would copy the whole pool through an XLA scatter")
         # HBM bytes ONE gathered block read moves per (row, layer):
         # both K/V planes of every pool leaf (the int8 scale leaf
         # rides along when present) — the unit behind
@@ -687,8 +700,8 @@ class ContinuousBatcher:
             leaf.nbytes // leaf.shape[1]
             for leaf in self._caches[0].values())
         row_spec = P(("data", "fsdp"))
-        self._cur_tok = dev(jnp.zeros((slots,), jnp.int32), row_spec)
-        self._n_logical = dev(jnp.zeros((slots,), jnp.int32), row_spec)
+        self._cur_tok = zeros((slots,), jnp.int32, row_spec)
+        self._n_logical = zeros((slots,), jnp.int32, row_spec)
         # host-side paged-cache state: the refcounted block pool, the
         # per-row block tables (shipped with every dispatch; trash = 0),
         # and the radix prefix cache
@@ -758,6 +771,9 @@ class ContinuousBatcher:
         # frame records the TRUE session, not the continuation shape
         self._replay_admits: dict = {}
         self.ticks = 0             # decode ticks run this session
+        # abstract signature of each compiled program's FIRST dispatch
+        # (kernel_census re-lowers exactly these, off the serving path)
+        self._program_sigs: dict = {}
         self._zero_stats()
         # a restarted disk tier re-enters the radix: shards whose
         # sidecars carry prefix tokens AND match this engine's cache
@@ -991,6 +1007,7 @@ class ContinuousBatcher:
             "kvq": dict(self.kvq),
             "width": dict(self.width),
             "fleet": dict(self.fleet),
+            "engine": self.engine_info(),
             "slo": {name: h.summary() for name, h in self._slo.items()},
             "ticks": self.ticks,
             "slot_leaks": self.last_slot_leaks,
@@ -1002,6 +1019,17 @@ class ContinuousBatcher:
             # log cadence
             "mem": device_memory_gauges(self.obs, prefix="serve.mem."),
         }
+
+    def engine_info(self) -> dict:
+        """Where and in what dtype this engine runs, as IT sees it: a
+        bf16 checkpoint must show bf16 weights and pool here, a replica
+        its own device, and the pool write the engine that performs it."""
+        pool = self._caches[0]["kv"]
+        return {"param_dtype": str(self._cdtype),
+                "pool_dtype": str(pool.dtype),
+                "platform": jax.default_backend(),
+                "devices": sorted(d.id for d in pool.devices()),
+                "pool_write": "pallas" if self._pallas_write else "xla"}
 
     def prefix_match_len(self, tokens) -> int:
         """Affinity probe for the replica router: how many of
@@ -1272,6 +1300,32 @@ class ContinuousBatcher:
         return (use_mesh(self._mesh) if self._mesh is not None
                 else contextlib.nullcontext())
 
+    def _note_program(self, name: str, fn, args: tuple, kw: dict) -> None:
+        """Remember ``name``'s first dispatch as abstract values (shape,
+        dtype, and the sharding of committed operands)."""
+        if name not in self._program_sigs:
+            self._program_sigs[name] = (fn, jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(
+                    a.shape, a.dtype,
+                    sharding=a.sharding if a.committed else None),
+                args), kw)
+
+    def kernel_census(self) -> dict:
+        """``{program: count_hlo_kernels record}`` for the admission
+        prefill and decode segment this engine actually dispatched: the
+        Mosaic calls in each COMPILED program, by kernel name — the
+        proof that flash attention and the pool window write reached
+        the device instead of an XLA fallback. Re-lowers the recorded
+        first-dispatch signatures (persistent-cache hits) — call it
+        after serving, not between segments (``dcp-serve
+        --metrics_jsonl`` does)."""
+        from distributed_compute_pytorch_tpu.parallel.collectives import (
+            compiled_hlo_text, count_hlo_kernels)
+        with self._mesh_ctx():
+            return {name: count_hlo_kernels(
+                compiled_hlo_text(fn, *args, **kw))
+                for name, (fn, args, kw) in self._program_sigs.items()}
+
     def reset(self):
         """Fresh session on the SAME compiled programs: zero the pool,
         free every block, drop the radix cache and rewind every row.
@@ -1321,7 +1375,10 @@ class ContinuousBatcher:
         if weights_version is None:
             weights_version = self.weights_version + 1
         old = self.weights_version
-        self.params = params
+        # off-mesh the new weights follow the engine to ITS device (a
+        # no-op when the caller already placed them there)
+        self.params = (params if self._device is None
+                       else jax.device_put(params, self._device))
         self.weights_version = int(weights_version)
         self.reset()
         if self._radix is not None:
@@ -2496,16 +2553,18 @@ class ContinuousBatcher:
                 jax.profiler.start_trace(prof["dir"])
                 prof["active"] = True
             with span("dispatch_segment", rows=len(plan)):
-                with self._mesh_ctx():
-                    (self._caches, self._cur_tok, self._n_logical, toks
-                     ) = self._segment_c(
-                        self.params, self._caches,
+                args = (self.params, self._caches,
                         jnp.asarray(tables_now[:, :nb_w]),
                         self._cur_tok, self._n_logical,
                         jnp.asarray(self._row_pos, jnp.int32),
                         jnp.asarray(self._temp), jnp.asarray(self._topk),
-                        jnp.asarray(self._topp), jnp.asarray(self._seed),
-                        sampling=sampling)
+                        jnp.asarray(self._topp), jnp.asarray(self._seed))
+                self._note_program("segment", self._segment_c, args,
+                                   {"sampling": sampling})
+                with self._mesh_ctx():
+                    (self._caches, self._cur_tok, self._n_logical, toks
+                     ) = self._segment_c(*args, sampling=sampling)
+                del args
             if prof is not None and prof["active"]:
                 prof["remaining"] -= 1
                 if prof["remaining"] <= 0:
@@ -3107,13 +3166,15 @@ class ContinuousBatcher:
                 # attached-prefix gather dequantizes int8 blocks inside
                 # the admission forward (see _admit_impl)
                 self.kvq["dequant_reads"] += 1
-            with span("prefill_wave", rows=len(entries)), \
-                    self._mesh_ctx():
-                self._caches = self._admit_c(
-                    self.params, self._caches, jnp.asarray(tables_wave),
+            args = (self.params, self._caches, jnp.asarray(tables_wave),
                     jnp.asarray(prompt), jnp.asarray(pmask),
                     jnp.asarray(positions), jnp.asarray(prefix_mask),
-                    jnp.asarray(blk_idx), jnp.asarray(off_idx), **kw)
+                    jnp.asarray(blk_idx), jnp.asarray(off_idx))
+            self._note_program("admit", self._admit_c, args, kw)
+            with span("prefill_wave", rows=len(entries)), \
+                    self._mesh_ctx():
+                self._caches = self._admit_c(*args, **kw)
+            del args
         if final:
             rows_j = jnp.asarray([b for b, _ in final], jnp.int32)
             lasts = [known[-1] for _, known in final]

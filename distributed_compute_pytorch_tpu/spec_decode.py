@@ -1,8 +1,8 @@
 """Speculative-decoding proposers and configuration (serve-side).
 
 Decode is HBM-bound: every tick streams the full weight set to emit one
-token per row (BENCH_r05: llama bf16 0.541 ms/tick at ~0.73
-hbm_efficiency). Speculation verifies ``k`` DRAFTED tokens per weight
+token per row (llama bf16 0.541 ms/tick at ~0.73 hbm_efficiency in a
+pre-round record removed in PR 21). Speculation verifies ``k`` DRAFTED tokens per weight
 stream instead — the model layers grew a ``verify_step`` that scores a
 whole draft window in one forward pass (``models/*.py``,
 ``ops/attention.py::cache_verify_and_attend``), and

@@ -46,6 +46,10 @@ path, so one corrupted save costs ``checkpoint_every`` steps, not the
 run).
 
 No framework-specific pickle anywhere — everything is plain numpy + JSON.
+``.npz`` holds only numpy's own dtypes, so ml_dtypes leaves (bfloat16
+parameters and optimizer moments) are stored as their same-width unsigned
+view; every leaf's dtype name is recorded beside its CRC and views the
+bytes back on restore.
 """
 
 from __future__ import annotations
@@ -79,6 +83,36 @@ class CheckpointCorruptError(RuntimeError):
 
 def _crc(arr: np.ndarray) -> int:
     return zlib.crc32(np.ascontiguousarray(arr).tobytes()) & 0xFFFFFFFF
+
+
+def _storable(arr: np.ndarray) -> np.ndarray:
+    """``arr`` as ``.npz`` can hold it: ml_dtypes leaves (bfloat16) load
+    back as opaque void records, so they go in as the unsigned view of
+    the same width (same bytes, same CRC)."""
+    if arr.dtype.isbuiltin == 1:
+        return arr
+    return arr.view(f"u{arr.dtype.itemsize}")
+
+
+def _as_saved(arr: np.ndarray, dtype_name: str | None) -> np.ndarray:
+    """Undo :func:`_storable` with the dtype name the save recorded
+    (``None``: a checkpoint from before names were recorded — numpy's
+    own dtypes only, nothing to undo)."""
+    if dtype_name is None or arr.dtype.name == dtype_name:
+        return arr
+    return arr.view(jnp.dtype(dtype_name))
+
+
+def dominant_float_dtype(leaves):
+    """The floating dtype holding the most elements among ``(shape,
+    dtype)`` pairs — what ``--param_dtype`` set, since norm scales stay
+    float32 under bfloat16 weights. ``None`` when nothing floats."""
+    sizes: dict = {}
+    for shape, dtype in leaves:
+        dtype = jnp.dtype(dtype)
+        if jnp.issubdtype(dtype, jnp.floating):
+            sizes[dtype] = sizes.get(dtype, 0) + int(np.prod(shape))
+    return max(sizes, key=sizes.get) if sizes else None
 
 
 def _rotate(path: str, keep_last: int) -> None:
@@ -139,11 +173,14 @@ def _write_v1(path: str, host_tree, epoch: int, extra: dict | None,
     flat = _flatten(host_tree)
     manifest = {"format": _FORMAT_VERSION, "epoch": epoch,
                 "extra": extra or {},
-                "checksums": {k: _crc(v) for k, v in flat.items()}}
+                "checksums": {k: _crc(v) for k, v in flat.items()},
+                "leaves": {k: [v.dtype.name, list(v.shape)]
+                           for k, v in flat.items()}}
     _rotate(path, keep_last)
     atomic_write(path,
                  lambda f: np.savez(f, __manifest__=json.dumps(manifest),
-                                    **flat))
+                                    **{k: _storable(v)
+                                       for k, v in flat.items()}))
 
 
 def save(path: str, state, *, epoch: int = 0, extra: dict | None = None,
@@ -255,10 +292,11 @@ def save_sharded(path: str, state, *, epoch: int = 0,
             if is_coordinator():
                 arr = np.asarray(leaf)
                 name = f"{key}@full"
-                flat_entries[name] = arr
+                flat_entries[name] = _storable(arr)
                 part_index.append({"key": key, "entry": name,
                                    "span": _span_of((), arr.shape),
                                    "gshape": list(arr.shape),
+                                   "dtype": arr.dtype.name,
                                    "crc32": _crc(arr)})
             continue
         shape = leaf.shape
@@ -279,10 +317,11 @@ def save_sharded(path: str, state, *, epoch: int = 0,
             mine.discard(span)      # each distinct span once per process
             name = f"{key}@" + ",".join(f"{lo}:{hi}" for lo, hi in span)
             data = np.asarray(shard.data)
-            flat_entries[name] = data
+            flat_entries[name] = _storable(data)
             part_index.append({"key": key, "entry": name,
                                "span": [list(s) for s in span],
                                "gshape": list(shape),
+                               "dtype": data.dtype.name,
                                "crc32": _crc(data)})
     part_file = f"part-g{gen}-{pid:05d}.npz"
     atomic_write(os.path.join(path, part_file),
@@ -333,7 +372,7 @@ def save_sharded(path: str, state, *, epoch: int = 0,
 
 def _sharded_entry_map(path: str,
                        generation: int | None = None) -> dict[str, list]:
-    """leaf key -> [(part_file, entry_name, span, gshape, crc), ...].
+    """leaf key -> [(part_file, entry_name, span, gshape, crc, dtype), ...].
 
     Reads exactly the ``num_parts`` part manifests of the committed
     manifest's generation — parts from other (stale or half-written)
@@ -369,17 +408,17 @@ def _sharded_entry_map(path: str,
         for e in part["entries"]:
             entries.setdefault(e["key"], []).append(
                 (part["file"], e["entry"], e["span"], e.get("gshape"),
-                 e.get("crc32")))
+                 e.get("crc32"), e.get("dtype")))
     return entries
 
 
 def _assemble(path: str, pieces, span_lo, out):
     """Fill ``out`` (whose global position starts at ``span_lo``) from any
     overlapping saved pieces, verifying each piece's CRC as it is read.
-    ``pieces``: [(file, entry, span, gshape, crc), ...]."""
+    ``pieces``: [(file, entry, span, gshape, crc, dtype), ...]."""
     zcache: dict[str, Any] = {}
     try:
-        for fname, entry, span, _, crc in pieces:
+        for fname, entry, span, _, crc, dtype_name in pieces:
             # overlap of [span] with [span_lo, span_lo+out.shape)
             sel_src, sel_dst = [], []
             ok = True
@@ -401,7 +440,7 @@ def _assemble(path: str, pieces, span_lo, out):
                     raise CheckpointCorruptError(
                         f"{path}/{fname}: unreadable part file "
                         f"({e})") from e
-            data = zcache[fname][entry]
+            data = _as_saved(zcache[fname][entry], dtype_name)
             if crc is not None and _crc(data) != crc:
                 # verify-on-restore: bit rot / torn writes surface as a
                 # clear error, never as silently wrong weights
@@ -587,12 +626,12 @@ def restore(path: str, template, shardings=None, *, _prefix: str = "",
     with z:
         available = set(z.files)
         try:
-            checksums = json.loads(str(z["__manifest__"])).get(
-                "checksums", {})
+            manifest = json.loads(str(z["__manifest__"]))
         except Exception:
-            checksums = {}       # pre-integrity checkpoints
+            manifest = {}        # pre-integrity checkpoints
         _restore_v1_leaves(z, available, paths, flat_shardings, leaves,
-                           _prefix, checksums, path)
+                           _prefix, manifest.get("checksums", {}), path,
+                           manifest.get("leaves", {}))
     return jax.tree_util.tree_unflatten(treedef, leaves)
 
 
@@ -658,14 +697,15 @@ def _place(arr, shard):
 
 
 def _restore_v1_leaves(z, available, paths, flat_shardings, leaves,
-                       _prefix, checksums=None, src=""):
+                       _prefix, checksums=None, src="", saved=None):
     checksums = checksums or {}
+    saved = saved or {}
     for (path_keys, leaf), shard in zip(paths, flat_shardings):
         key = _prefix + _SEP.join(
             str(getattr(k, "key", getattr(k, "idx", k))) for k in path_keys)
         if key not in available:
             raise KeyError(f"checkpoint missing leaf {key!r}")
-        arr = z[key]
+        arr = _as_saved(z[key], saved.get(key, (None,))[0])
         if key in checksums and _crc(arr) != checksums[key]:
             # verify-on-restore (module docstring): corruption is a
             # loud, named error — never silently wrong weights
@@ -706,6 +746,26 @@ def _restore_v1_leaves(z, available, paths, flat_shardings, leaves,
             leaves.append(_place(np.asarray(arr, dtype=dtype), shard))
         else:
             leaves.append(_place(jnp.asarray(arr, dtype=dtype), shard))
+
+
+def saved_param_dtype(path: str):
+    """The parameter dtype a (v1 or v2) checkpoint was trained in — the
+    dominant floating dtype of its ``params`` subtree — read from the
+    manifests alone. ``None`` for checkpoints that predate the recorded
+    dtype names (numpy's own dtypes only; float32 in practice)."""
+    prefix = ".params" + _SEP
+    if os.path.isdir(path):
+        specs = [(gshape, name) for key, pieces
+                 in _sharded_entry_map(path).items()
+                 if key.startswith(prefix)
+                 for _, _, _, gshape, _, name in pieces[:1]]
+    else:
+        specs = [(shape, name) for k, (name, shape)
+                 in load_manifest(path).get("leaves", {}).items()
+                 if k.startswith(prefix)]
+    if any(shape is None or name is None for shape, name in specs):
+        return None
+    return dominant_float_dtype(specs)
 
 
 def restore_params(path: str, params_template, shardings=None):

@@ -33,7 +33,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_compute_pytorch_tpu.core.mesh import (
-    batch_sharding, shard_map, use_manual_axes, use_mesh)
+    batch_sharding, use_manual_axes, use_mesh)
 from distributed_compute_pytorch_tpu.parallel import collectives as coll
 from distributed_compute_pytorch_tpu.parallel.api import (
     DataParallel, tree_shardings)
@@ -359,10 +359,10 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
         — it never exists replicated."""
         p_specs = coll.tree_update_specs(params, dp_n, dp_ax)
         o_specs = coll.tree_update_specs(opt_state, dp_n, dp_ax)
-        body = shard_map(_local_update, mesh=mesh,
-                         in_specs=(p_specs, o_specs, p_specs),
-                         out_specs=(p_specs, o_specs),
-                         axis_names=set(dp_ax))
+        body = jax.shard_map(_local_update, mesh=mesh,
+                             in_specs=(p_specs, o_specs, p_specs),
+                             out_specs=(p_specs, o_specs),
+                             axis_names=set(dp_ax))
         new_p, new_o = body(grads, opt_state, params)
         repl = NamedSharding(mesh, P())
         new_p = jax.tree.map(
@@ -382,8 +382,6 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
         params, opt_state = state.params, state.opt_state
         p_specs = coll.tree_update_specs(params, dp_n, dp_ax)
         o_specs = coll.tree_update_specs(opt_state, dp_n, dp_ax)
-        # the key travels as raw data: key-dtype arrays predate legacy
-        # shard_map's input handling on older jax
         rng_data = jax.random.key_data(step_rng)
 
         def body(p, o, xs, ys, rd):
@@ -433,10 +431,10 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
             return new_p, new_o, loss
 
         repl_p = jax.tree.map(lambda _: P(), params)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(repl_p, o_specs, P(ax), P(ax), P()),
-                       out_specs=(repl_p, o_specs, P()),
-                       axis_names={ax})
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(repl_p, o_specs, P(ax), P(ax), P()),
+                           out_specs=(repl_p, o_specs, P()),
+                           axis_names={ax})
         # use_manual_axes: the model's internal layout pins (constrain /
         # constrain_activations) must drop the now-manual dp axis
         with use_mesh(mesh), use_manual_axes((ax,)), _layout_ctx():
@@ -560,11 +558,11 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
         out_specs = (repl_p, o_specs, repl_ms, P())
         if need_gn2:
             out_specs = out_specs + (P(),)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(repl_p, o_specs, repl_ms,
-                                 P(ax_spec), P(ax_spec), P()),
-                       out_specs=out_specs,
-                       axis_names=set(dp_ax))
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(repl_p, o_specs, repl_ms,
+                                     P(ax_spec), P(ax_spec), P()),
+                           out_specs=out_specs,
+                           axis_names=set(dp_ax))
         # use_manual_axes: constrain() pins AND BatchNorm's sync-stat
         # pmean (models/layers.py) key off the declared manual dp axes
         with use_mesh(mesh), use_manual_axes(dp_ax), _layout_ctx():

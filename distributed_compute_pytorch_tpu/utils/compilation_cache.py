@@ -1,42 +1,43 @@
-"""Persistent XLA compilation cache.
+"""Persistent XLA compilation cache — the one place that configures it.
 
 Compiled executables are cached on disk keyed by HLO hash, so re-runs of
-the same program (re-launches, supervisor restarts, bench invocations)
-skip compilation entirely — measured here: 4.2s -> 0.9s for a small
-program in a fresh process, tens of seconds for the transformer rungs.
-Especially valuable on relayed-TPU environments whose remote compile
-service is the least reliable link.
+the same program (re-launches, supervisor restarts, a serve process
+after its trainer) skip compilation. The directory is part of nothing the
+key hashes, but a cache that moves between runs never hits — so there is
+exactly one rule for where it lives:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself; nothing here
+  names a directory;
+- otherwise ``<checkout>/.jax_cache``, found from this package's own
+  location (listed in ``.gitignore``).
+
+Every entry point (``dcp-train``, ``dcp-serve``, ``dcp-generate``,
+``bench.py``, ``benchmarks/decompose_*.py``, ``tests/conftest.py``) calls
+:func:`enable` with no argument; nothing else touches
+``jax_compilation_cache_dir``.
 """
 
 from __future__ import annotations
 
 import os
 
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-def enable(cache_dir: str) -> None:
-    """Turn on the persistent compile cache (idempotent, safe pre/post
-    backend init).
 
-    CPU-pinned runs on jax 0.4.x are a hard NO-OP: executables
-    DESERIALIZED from the persistent cache segfault the 0.4.x CPU
-    backend when another thread device_puts concurrently (reproduced
-    deterministically on 0.4.37: a cache-hit donated train step with
-    the DeviceFeeder's prefetch thread live crashes the process —
-    prefetch=0 on the same run is clean — and it aborted the tier-1
-    suite at the first Trainer resume test, taking every
-    alphabetically-later test with it). CPU compiles are cheap; the
-    cache's value is the relayed-TPU remote compile service, where the
-    deserialization path is not affected.
-    """
+def enable() -> str:
+    """Turn on the persistent compile cache (idempotent, safe before or
+    after backend init) and return the directory in use."""
     import jax
 
-    pinned_cpu = "cpu" in (os.environ.get("JAX_PLATFORMS") or
-                           jax.config.jax_platforms or "").lower()
-    if pinned_cpu and jax.__version_info__ < (0, 5):
-        return
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = _CHECKOUT_CACHE
+        os.makedirs(cache_dir, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
     # cache everything: the default thresholds skip small/fast programs,
-    # but on a relayed TPU every avoided remote compile counts
+    # and a serving run compiles many of those
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    return cache_dir

@@ -32,6 +32,16 @@ def log0(*args, **kw) -> None:
         sys.stdout.flush()
 
 
+def device_banner() -> str:
+    """Platform, device kind and device count as JAX reports them — the
+    first line every CLI prints, so a run that fell back to the CPU
+    (accelerator runtime failed to load) is visible at a glance."""
+    import jax
+    devs = jax.devices()
+    return (f"platform: {devs[0].platform} | device_kind: "
+            f"{devs[0].device_kind} | devices: {len(devs)}")
+
+
 class MetricLogger:
     """stdout (reference cadence/format) + optional JSONL sink + the
     metrics registry (one record, three sinks)."""

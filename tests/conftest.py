@@ -9,7 +9,8 @@ imports jax.
 import os
 
 # DCP_TEST_TPU=1 keeps the real backend so the TPU-gated tests
-# (test_flash_tpu.py) run on hardware instead of skipping.
+# (test_flash_tpu.py, test_cache_update_tpu.py) run on hardware instead
+# of skipping.
 _USE_TPU = os.environ.get("DCP_TEST_TPU") == "1"
 
 if not _USE_TPU:
@@ -28,13 +29,10 @@ import jax  # noqa: E402
 from distributed_compute_pytorch_tpu.utils.compilation_cache import (  # noqa: E402
     enable as _enable_compile_cache)
 
-_enable_compile_cache(os.environ.get(
-    "DCP_COMPILE_CACHE",
-    os.path.join(os.path.dirname(__file__), ".jax_cache")))
+_enable_compile_cache()
 
-# Environments that preload jax at interpreter startup (e.g. a TPU-plugin
-# sitecustomize) have already latched JAX_PLATFORMS from their own env; the
-# config update below wins as long as no backend has initialised yet.
+# a pytest plugin may have imported jax before this file set the
+# environment; the config update wins as long as no backend is up yet
 if not _USE_TPU:
     jax.config.update("jax_platforms", "cpu")
 
@@ -46,3 +44,29 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 faked CPU devices, got {len(devs)}"
     return devs
+
+
+def _map_count() -> int:
+    try:
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:                      # no procfs: nothing to observe
+        return 0
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _drop_executables_before_the_map_limit():
+    """Every loaded CPU executable maps its own code pages (~6 mappings
+    each) and nothing unloads one while a jit cache still points at it:
+    the suite in ONE process climbs to ~63,000 mappings against Linux's
+    default ``vm.max_map_count`` of 65,530, and past it ``mmap`` fails
+    inside jaxlib and the interpreter segfaults (seen in
+    ``deserialize_executable`` at ~90% of tier-1, two runs in three).
+    Between modules, once the process holds more than a third of that,
+    drop the compiled programs; whatever a later module needs again
+    comes back from the persistent cache."""
+    yield
+    if _map_count() > 20000:
+        import gc
+        jax.clear_caches()
+        gc.collect()

@@ -98,8 +98,8 @@ def test_sharded_save_writes_per_shard_entries(tmp_path, devices8):
     # distinct span entries, each a quarter of the rows
     fc1 = [k for k in entries if k.endswith("fc1::kernel")]
     assert fc1, list(entries)[:10]
-    spans = sorted(tuple(tuple(s) for s in span)
-                   for _, _, span, _, _ in entries[fc1[0]])
+    spans = sorted(tuple(tuple(s) for s in piece[2])
+                   for piece in entries[fc1[0]])
     assert len(spans) == 4
     assert spans[0][0] == (0, 9216 // 4)
 

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from distributed_compute_pytorch_tpu.core.mesh import make_mesh, shard_map
+from distributed_compute_pytorch_tpu.core.mesh import make_mesh
 from distributed_compute_pytorch_tpu.parallel import collectives as coll
 
 
@@ -18,7 +18,7 @@ def _run_manual(fn, mesh, partials, out_sharded=True):
     """Run ``fn(local_contribution)`` inside a shard_map manual over
     ``data`` where rank i's local value is ``partials[i]`` (leading dim
     = dp axis)."""
-    body = shard_map(
+    body = jax.shard_map(
         lambda part: fn(part[0])[None],
         mesh=mesh, in_specs=P("data"),
         out_specs=P("data") if out_sharded else P(),

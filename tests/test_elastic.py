@@ -386,9 +386,8 @@ def _env():
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)     # 1 CPU device is enough and fastest
-    # share the suite's compile cache: each child process skips XLA compiles
-    env.setdefault("DCP_COMPILE_CACHE",
-                   os.path.join(os.path.dirname(__file__), ".jax_cache"))
+    # the child CLI finds the suite's compile cache by itself
+    # (utils/compilation_cache.py: one fixed place per checkout)
     return env
 
 

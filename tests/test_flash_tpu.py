@@ -5,9 +5,10 @@ compilation is exactly where Pallas kernels die, so this file compiles and
 runs the kernels on a REAL TPU and pins numerics against the dense path.
 Skipped automatically when no TPU is attached.
 
-Run on hardware with ``DCP_TEST_TPU=1 python -m pytest tests/test_flash_tpu.py``
-(the flag stops tests/conftest.py from forcing the CPU backend; run only
-this file — the rest of the suite expects the 8-device CPU mesh).
+Run on hardware with ``DCP_TEST_TPU=1 python -m pytest tests/test_flash_tpu.py
+tests/test_cache_update_tpu.py`` (the flag stops tests/conftest.py from
+forcing the CPU backend; run only the two TPU files — the rest of the suite
+expects the 8-device CPU mesh).
 """
 
 import jax
@@ -124,3 +125,47 @@ def test_auto_impl_dispatches_to_flash_on_tpu():
         q, k, v, causal=True, impl="pallas"))(q, k, v)
     np.testing.assert_array_equal(np.asarray(auto, np.float32),
                                   np.asarray(forced, np.float32))
+
+
+def test_train_step_shape_1024_blocks_on_tpu():
+    """The GPT-2-small train step's attention as the model dispatches it:
+    T=1024 picks the 1024x1024 blocks (``ops/attention.py::_pick_block``)
+    — forward AND both backward kernels at that block size."""
+    from distributed_compute_pytorch_tpu.ops import attention as A
+
+    q, k, v = _qkv(1024, B=2, H=12)
+
+    def loss(impl):
+        return lambda q, k, v: A.attention(
+            q, k, v, causal=True, impl=impl).astype(jnp.float32).sum()
+
+    gf = jax.jit(jax.grad(loss("auto"), argnums=(0, 1, 2)))(q, k, v)
+    gd = jax.jit(jax.grad(loss("xla"), argnums=(0, 1, 2)))(q, k, v)
+    for a, b, name in zip(gf, gd, ("dq", "dk", "dv")):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   atol=5e-2, rtol=5e-2, err_msg=name)
+
+
+def test_admission_prefill_shape_pad_and_mask_on_tpu():
+    """Serving's admission prefill as the model dispatches it: a causal
+    700-token window (no block divides it: padded to 768, 128 blocks) with
+    a per-row key-validity mask — the masked forward kernel."""
+    from distributed_compute_pytorch_tpu.ops import attention as A
+
+    B, H, T = 4, 12, 700
+    q, k, v = _qkv(T, B=B, H=H)
+    m = np.zeros((B, T), np.float32)
+    for i, n in enumerate([700, 512, 130, 16]):
+        m[i, :n] = 1.0
+    kv_mask = jnp.asarray(m)
+    out = jax.jit(lambda q, k, v: A.attention(
+        q, k, v, causal=True, kv_mask=kv_mask))(q, k, v)
+    ref = jax.jit(lambda q, k, v: A.attention(
+        q, k, v, causal=True, kv_mask=kv_mask, impl="xla"))(q, k, v)
+    # rows past each prompt's length are padded queries: garbage by
+    # contract on both paths, excluded here as they are from every loss
+    valid = np.broadcast_to(m[:, None, :, None] > 0, out.shape)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[valid],
+                               np.asarray(ref, np.float32)[valid],
+                               atol=3e-2, rtol=3e-2)

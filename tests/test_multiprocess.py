@@ -157,7 +157,7 @@ def test_checkpoint_written_correctly(two_process_run):
         # a cross-process-sharded leaf contributes spans from both parts
         entries = checkpoint._sharded_entry_map(path)
         fc1 = [k for k in entries if k.endswith("fc1::kernel")]
-        files = {f for f, _, _, _ in entries[fc1[0]]}
+        files = {piece[0] for piece in entries[fc1[0]]}
         assert files == {f"part-g{gen}-00000.npz", f"part-g{gen}-00001.npz"}
     else:
         path = os.path.join(out_dir, "ck.npz")
