@@ -1,0 +1,18 @@
+"""Model FLOP/s utilization of a training cell: tokens/s x FLOPs/token
+(from ``perfbench.flops``, recomputation not counted) over chips x the
+published bf16 peak, in %. Never clipped. Source: host_clock (the
+run's own tokens/s) and the table of peaks."""
+
+from perfbench import flops, peaks
+
+
+def read(spec, ctx):
+    tps = ctx["counters"].get("train_tokens_per_s")
+    if not tps:
+        return None
+    fn = getattr(flops, spec["flops_fn"])
+    per_token = fn(ctx["config"], ctx["counters"]["seq_len"])
+    peak = peaks.peaks_for(ctx["device_kind"])["bf16_flops"] * ctx["chips"]
+    return {"value": 100.0 * tps * per_token / peak,
+            "note": f"{per_token / 1e9:.4f} GFLOP/token against "
+                    f"{peak / 1e12:.0f} TFLOP/s (compute bound)"}
