@@ -2290,7 +2290,6 @@ def serve_load_smoke():
     trace_path = os.path.join(tempfile.gettempdir(),
                               "dcp_serve_load_trace.json")
     tracer.dump(trace_path)
-    tracer.close()
     with open(trace_path) as f:
         events = json.load(f)["traceEvents"]
     trace_errors = validate_chrome_trace(events)
@@ -2670,7 +2669,6 @@ def serve_disagg_smoke():
         path = os.path.join(tempfile.gettempdir(),
                             "dcp_serve_disagg_trace.json")
         tracer.dump(path)
-        tracer.close()
         with open(path) as f:
             events = json.load(f)["traceEvents"]
         ends = sorted(e["ts"] for e in events
@@ -2907,7 +2905,6 @@ def serve_width_smoke():
         path = os.path.join(tempfile.gettempdir(),
                             "dcp_serve_width_trace.json")
         tracer.dump(path)
-        tracer.close()
         with open(path) as f:
             events = json.load(f)["traceEvents"]
         ends = sorted(e["ts"] for e in events
@@ -3162,7 +3159,6 @@ def serve_journal_smoke():
             configure_tracer(prev)
         path = os.path.join(work, "trace.json")
         tracer.dump(path)
-        tracer.close()
         with open(path) as f:
             events = json.load(f)["traceEvents"]
         ends = sorted(e["ts"] for e in events

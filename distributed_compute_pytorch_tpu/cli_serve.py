@@ -881,7 +881,6 @@ def main(argv=None) -> int:
         if tracer is not None:
             configure_tracer(None)
             tracer.dump(args.trace_path)
-            tracer.close()
     for r, res in zip(reqs, results):
         rec = {"id": r["id"], "prompt": r["tokens"], "new": res.tokens,
                "status": res.status,

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from distributed_compute_pytorch_tpu.core.mesh import current_mesh
 from distributed_compute_pytorch_tpu.models import layers as L
+from distributed_compute_pytorch_tpu.obs.tracing import scope
 from distributed_compute_pytorch_tpu.models.transformer import (
     TransformerBlock, tp_partition_rules)
 from distributed_compute_pytorch_tpu.parallel.pipeline import (
@@ -107,10 +108,11 @@ class GPT2:
         c = self.config
         if positions is None:
             positions = jnp.arange(tokens.shape[1])
-        return (L.Embedding(c.vocab_size, c.d_model).apply(params["wte"],
-                                                           tokens)
-                + L.Embedding(c.max_seq_len, c.d_model).apply(params["wpe"],
-                                                              positions))
+        with scope("embed"):
+            return (L.Embedding(c.vocab_size, c.d_model).apply(
+                        params["wte"], tokens)
+                    + L.Embedding(c.max_seq_len, c.d_model).apply(
+                        params["wpe"], positions))
 
     def readout(self, params, x):
         """Final LayerNorm + weight-tied readout.
@@ -123,8 +125,10 @@ class GPT2:
             constrain_activations)
         c = self.config
         x = constrain_activations(x)
-        x = L.LayerNorm(c.d_model).apply(params["ln_f"], x)
-        return L.Embedding(c.vocab_size, c.d_model).attend(params["wte"], x)
+        with scope("head"):
+            x = L.LayerNorm(c.d_model).apply(params["ln_f"], x)
+            return L.Embedding(c.vocab_size, c.d_model).attend(
+                params["wte"], x)
 
     def kv_cache_spec(self):
         """(num_kv_heads, head_dim) a decode cache must hold per layer."""
@@ -138,7 +142,8 @@ class GPT2:
         layers_rng = None
         if train and rng is not None:
             emb_rng, layers_rng = jax.random.split(rng)
-            x = L.dropout(x, c.dropout_rate, emb_rng, train)
+            with scope("embed"):
+                x = L.dropout(x, c.dropout_rate, emb_rng, train)
         block = self._block()
         mesh = current_mesh()
         if (mesh is not None and "pipe" in mesh.axis_names
@@ -156,12 +161,14 @@ class GPT2:
     # --- loss protocol (next-token prediction: shift inside) ---
 
     def loss_fn(self, logits, tokens):
-        return L.cross_entropy_with_logits(logits[:, :-1], tokens[:, 1:],
-                                           "mean")
+        with scope("loss"):
+            return L.cross_entropy_with_logits(logits[:, :-1],
+                                               tokens[:, 1:], "mean")
 
     def loss_sum(self, logits, tokens):
-        return L.cross_entropy_with_logits(logits[:, :-1], tokens[:, 1:],
-                                           "sum")
+        with scope("loss"):
+            return L.cross_entropy_with_logits(logits[:, :-1],
+                                               tokens[:, 1:], "sum")
 
     def eval_metrics(self, logits, tokens, valid=None):
         """Token-level sums for eval aggregation (step.py eval protocol).

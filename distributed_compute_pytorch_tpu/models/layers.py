@@ -32,6 +32,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from distributed_compute_pytorch_tpu.obs.tracing import scope
+
 
 def _uniform(key, shape, bound, dtype):
     return jax.random.uniform(key, shape, dtype, minval=-bound, maxval=bound)
@@ -154,8 +156,9 @@ def dropout(x, rate: float, rng, train: bool,
     keep = 1.0 - rate
     mask_shape = tuple(1 if d in tuple(broadcast_dims) else s
                        for d, s in enumerate(x.shape))
-    mask = jax.random.bernoulli(rng, keep, mask_shape)
-    return jnp.where(mask, x / keep, 0.0).astype(x.dtype)
+    with scope("dropout"):
+        mask = jax.random.bernoulli(rng, keep, mask_shape)
+        return jnp.where(mask, x / keep, 0.0).astype(x.dtype)
 
 
 @dataclass(frozen=True)

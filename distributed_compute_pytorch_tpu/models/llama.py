@@ -28,6 +28,7 @@ from jax import lax
 
 from distributed_compute_pytorch_tpu.core.mesh import current_mesh
 from distributed_compute_pytorch_tpu.models import layers as L
+from distributed_compute_pytorch_tpu.obs.tracing import scope
 from distributed_compute_pytorch_tpu.models.transformer import (
     dispatch_attention)
 from distributed_compute_pytorch_tpu.ops import attention as A
@@ -136,16 +137,18 @@ class LlamaBlock:
         from jax.ad_checkpoint import checkpoint_name
         c = self.config
         dense = lambda din, dout: L.Dense(din, dout, use_bias=False)
-        h = L.RMSNorm(c.d_model, c.rms_eps).apply(params["mlp_norm"], x)
-        # both d->d_ff projections saved under remat="dots" (the product
-        # alone would not do: silu' needs gate_out and the gate grad needs
-        # up_out, so saving only silu(gate)*up still re-runs both matmuls)
-        gate_out = checkpoint_name(
-            dense(c.d_model, c.d_ff).apply(params["gate"], h), "mlp_pre")
-        up_out = checkpoint_name(
-            dense(c.d_model, c.d_ff).apply(params["up"], h), "mlp_pre")
-        gated = jax.nn.silu(gate_out) * up_out
-        return x + dense(c.d_ff, c.d_model).apply(params["down"], gated)
+        with scope("mlp"):
+            h = L.RMSNorm(c.d_model, c.rms_eps).apply(params["mlp_norm"], x)
+            # both d->d_ff projections saved under remat="dots" (the
+            # product alone would not do: silu' needs gate_out and the gate
+            # grad needs up_out, so saving only silu(gate)*up still re-runs
+            # both matmuls)
+            gate_out = checkpoint_name(
+                dense(c.d_model, c.d_ff).apply(params["gate"], h), "mlp_pre")
+            up_out = checkpoint_name(
+                dense(c.d_model, c.d_ff).apply(params["up"], h), "mlp_pre")
+            gated = jax.nn.silu(gate_out) * up_out
+            return x + dense(c.d_ff, c.d_model).apply(params["down"], gated)
 
     def _ssa(self, x, manual_axes):
         """Residual-stream layout pin at the block boundaries: Megatron
@@ -180,27 +183,28 @@ class LlamaBlock:
         dense = lambda din, dout: L.Dense(din, dout, use_bias=False)
 
         x = self._ssa(x, manual_axes)
-        h = L.RMSNorm(d, c.rms_eps).apply(params["attn_norm"], x)
-        pos = (self._positions(x.shape[1], tuple(manual_axes))
-               if positions is None else positions)
-        q, k, v = self._qkv(params, h, pos)
-        if kv_sink is not None:
-            # prefill capture: post-rope, kv-head width — exactly what the
-            # decode cache stores (suffix-only under a kv_prefix)
-            kv_sink.append((k, v))
-        if kv_prefix is not None:
-            from distributed_compute_pytorch_tpu.models.transformer import (
-                _concat_kv_prefix)
-            k, v, kv_mask = _concat_kv_prefix(kv_prefix, k, v, kv_mask)
-        # GQA K/V stay at num_kv_heads width: the dispatcher repeats heads
-        # only for the kernels that need it (ring paths rotate the narrow
-        # K/V — see dispatch_attention)
-        o = dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask,
-                               manual_axes=manual_axes)
-        from jax.ad_checkpoint import checkpoint_name
-        o = checkpoint_name(o, "attn_ctx")   # saved under remat="dots"
-        x = x + dense(c.num_heads * hd, d).apply(params["o"],
-                                                 A.merge_heads(o))
+        with scope("attn"):
+            h = L.RMSNorm(d, c.rms_eps).apply(params["attn_norm"], x)
+            pos = (self._positions(x.shape[1], tuple(manual_axes))
+                   if positions is None else positions)
+            q, k, v = self._qkv(params, h, pos)
+            if kv_sink is not None:
+                # prefill capture: post-rope, kv-head width — exactly what
+                # the decode cache stores (suffix-only under a kv_prefix)
+                kv_sink.append((k, v))
+            if kv_prefix is not None:
+                from distributed_compute_pytorch_tpu.models.transformer \
+                    import _concat_kv_prefix
+                k, v, kv_mask = _concat_kv_prefix(kv_prefix, k, v, kv_mask)
+            # GQA K/V stay at num_kv_heads width: the dispatcher repeats
+            # heads only for the kernels that need it (ring paths rotate
+            # the narrow K/V — see dispatch_attention)
+            o = dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask,
+                                   manual_axes=manual_axes)
+            from jax.ad_checkpoint import checkpoint_name
+            o = checkpoint_name(o, "attn_ctx")   # saved under remat="dots"
+            x = x + dense(c.num_heads * hd, d).apply(params["o"],
+                                                     A.merge_heads(o))
         return self._mlp(params, self._ssa(x, manual_axes))
 
     def decode_step(self, params, x, cache, pos, slot_mask=None):
@@ -224,16 +228,17 @@ class LlamaBlock:
         c = self.config
         d, hd = c.d_model, c.head_dim
         dense = lambda din, dout: L.Dense(din, dout, use_bias=False)
-        h = L.RMSNorm(d, c.rms_eps).apply(params["attn_norm"], x)
-        # scalar pos -> [1] (shared across rows); [B] pos -> [B, 1]
-        # (each row ropes this tick's single token at its own slot)
-        rope_pos = (pos[:, None] if jnp.ndim(pos) == 1
-                    else jnp.atleast_1d(pos))
-        q, k, v = self._qkv(params, h, rope_pos)
-        o, cache = A.cache_write_and_attend(q, k, v, cache, pos,
-                                            slot_mask=slot_mask)
-        x = x + dense(c.num_heads * hd, d).apply(params["o"],
-                                                 A.merge_heads(o))
+        with scope("attn"):
+            h = L.RMSNorm(d, c.rms_eps).apply(params["attn_norm"], x)
+            # scalar pos -> [1] (shared across rows); [B] pos -> [B, 1]
+            # (each row ropes this tick's single token at its own slot)
+            rope_pos = (pos[:, None] if jnp.ndim(pos) == 1
+                        else jnp.atleast_1d(pos))
+            q, k, v = self._qkv(params, h, rope_pos)
+            o, cache = A.cache_write_and_attend(q, k, v, cache, pos,
+                                                slot_mask=slot_mask)
+            x = x + dense(c.num_heads * hd, d).apply(params["o"],
+                                                     A.merge_heads(o))
         return self._mlp(params, x), cache
 
     def verify_step(self, params, x, cache, positions, slot_mask=None):
@@ -249,12 +254,13 @@ class LlamaBlock:
         c = self.config
         d, hd = c.d_model, c.head_dim
         dense = lambda din, dout: L.Dense(din, dout, use_bias=False)
-        h = L.RMSNorm(d, c.rms_eps).apply(params["attn_norm"], x)
-        q, k, v = self._qkv(params, h, positions)
-        o, cache = A.cache_verify_and_attend(q, k, v, cache, positions,
-                                             slot_mask=slot_mask)
-        x = x + dense(c.num_heads * hd, d).apply(params["o"],
-                                                 A.merge_heads(o))
+        with scope("attn"):
+            h = L.RMSNorm(d, c.rms_eps).apply(params["attn_norm"], x)
+            q, k, v = self._qkv(params, h, positions)
+            o, cache = A.cache_verify_and_attend(q, k, v, cache, positions,
+                                                 slot_mask=slot_mask)
+            x = x + dense(c.num_heads * hd, d).apply(params["o"],
+                                                     A.merge_heads(o))
         return self._mlp(params, x), cache
 
 
@@ -284,8 +290,9 @@ class LlamaLM:
         accepted for the shared decode protocol, ``infer.py``)."""
         del positions
         c = self.config
-        return L.Embedding(c.vocab_size, c.d_model).apply(params["wte"],
-                                                          tokens)
+        with scope("embed"):
+            return L.Embedding(c.vocab_size, c.d_model).apply(params["wte"],
+                                                              tokens)
 
     def readout(self, params, x):
         """Final norm + untied LM head: ``[.., d]`` -> ``[.., vocab]``.
@@ -296,9 +303,10 @@ class LlamaLM:
             constrain_activations)
         c = self.config
         x = constrain_activations(x)
-        x = L.RMSNorm(c.d_model, c.rms_eps).apply(params["norm_f"], x)
-        return L.Dense(c.d_model, c.vocab_size,
-                       use_bias=False).apply(params["lm_head"], x)
+        with scope("head"):
+            x = L.RMSNorm(c.d_model, c.rms_eps).apply(params["norm_f"], x)
+            return L.Dense(c.d_model, c.vocab_size,
+                           use_bias=False).apply(params["lm_head"], x)
 
     def kv_cache_spec(self):
         """(num_kv_heads, head_dim) a decode cache must hold per layer."""
@@ -325,12 +333,14 @@ class LlamaLM:
     # --- loss protocol (next-token prediction, same as GPT-2) ---
 
     def loss_fn(self, logits, tokens):
-        return L.cross_entropy_with_logits(logits[:, :-1], tokens[:, 1:],
-                                           "mean")
+        with scope("loss"):
+            return L.cross_entropy_with_logits(logits[:, :-1],
+                                               tokens[:, 1:], "mean")
 
     def loss_sum(self, logits, tokens):
-        return L.cross_entropy_with_logits(logits[:, :-1], tokens[:, 1:],
-                                           "sum")
+        with scope("loss"):
+            return L.cross_entropy_with_logits(logits[:, :-1],
+                                               tokens[:, 1:], "sum")
 
     def eval_metrics(self, logits, tokens, valid=None):
         pred = jnp.argmax(logits[:, :-1], axis=-1)

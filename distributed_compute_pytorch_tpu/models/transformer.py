@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from distributed_compute_pytorch_tpu.models import layers as L
+from distributed_compute_pytorch_tpu.obs.tracing import scope
 from distributed_compute_pytorch_tpu.ops import attention as A
 
 
@@ -256,18 +257,25 @@ class TransformerBlock:
         ln1 = L.LayerNorm(self.d_model)
         ln2 = L.LayerNorm(self.d_model)
         x = self._ssa(x, manual_axes)
+        # each sublayer's scope takes its norm and its residual add
         if self.pre_ln:
-            x = x + self._attn(params, ln1.apply(params["ln1"], x), r1,
-                               train, kv_mask, manual_axes, kv_sink,
-                               kv_prefix)
+            with scope("attn"):
+                x = x + self._attn(params, ln1.apply(params["ln1"], x), r1,
+                                   train, kv_mask, manual_axes, kv_sink,
+                                   kv_prefix)
             x = self._ssa(x, manual_axes)
-            x = x + self._mlp(params, ln2.apply(params["ln2"], x), r2, train)
+            with scope("mlp"):
+                x = x + self._mlp(params, ln2.apply(params["ln2"], x), r2,
+                                  train)
         else:  # post-LN (BERT)
-            x = ln1.apply(params["ln1"],
-                          x + self._attn(params, x, r1, train, kv_mask,
-                                         manual_axes, kv_sink))
+            with scope("attn"):
+                x = ln1.apply(params["ln1"],
+                              x + self._attn(params, x, r1, train, kv_mask,
+                                             manual_axes, kv_sink))
             x = self._ssa(x, manual_axes)
-            x = ln2.apply(params["ln2"], x + self._mlp(params, x, r2, train))
+            with scope("mlp"):
+                x = ln2.apply(params["ln2"],
+                              x + self._mlp(params, x, r2, train))
         return x
 
     def decode_step(self, params, x, cache, pos, slot_mask=None):
@@ -285,11 +293,13 @@ class TransformerBlock:
         """
         assert self.causal and self.pre_ln, "decode needs a causal pre-LN block"
         d = self.d_model
-        x, cache = attention_decode_tick(params, x, cache, pos,
-                                         num_heads=self.num_heads,
-                                         slot_mask=slot_mask)
-        h = L.LayerNorm(d).apply(params["ln2"], x)
-        return x + self._mlp(params, h, None, False), cache
+        with scope("attn"):
+            x, cache = attention_decode_tick(params, x, cache, pos,
+                                             num_heads=self.num_heads,
+                                             slot_mask=slot_mask)
+        with scope("mlp"):
+            h = L.LayerNorm(d).apply(params["ln2"], x)
+            return x + self._mlp(params, h, None, False), cache
 
     def verify_step(self, params, x, cache, positions, slot_mask=None):
         """One speculative VERIFY step: ``x [B, W, d]`` scores a whole
@@ -301,11 +311,13 @@ class TransformerBlock:
         rule relies on (``serve.ContinuousBatcher``)."""
         assert self.causal and self.pre_ln, "verify needs a causal pre-LN block"
         d = self.d_model
-        x, cache = attention_verify_tick(params, x, cache, positions,
-                                         num_heads=self.num_heads,
-                                         slot_mask=slot_mask)
-        h = L.LayerNorm(d).apply(params["ln2"], x)
-        return x + self._mlp(params, h, None, False), cache
+        with scope("attn"):
+            x, cache = attention_verify_tick(params, x, cache, positions,
+                                             num_heads=self.num_heads,
+                                             slot_mask=slot_mask)
+        with scope("mlp"):
+            h = L.LayerNorm(d).apply(params["ln2"], x)
+            return x + self._mlp(params, h, None, False), cache
 
 
 # Megatron-style tensor-parallel layout for the block param names above.

@@ -7,10 +7,20 @@ Three small host-side layers, none of which touch compiled code:
   ``ContinuousBatcher.stats``/``.waste`` are dict-compatible VIEWS over
   a per-batcher registry; the SLO histograms (queue-wait, TTFT, TPOT,
   e2e) live beside them and ``stats_snapshot()`` serialises the lot.
-- :mod:`.tracing` — nestable ``span("admit_wave")`` context managers
-  emitting Chrome-trace-event JSON (Perfetto-loadable) plus an optional
-  JSONL sink, instrumented through the serve scheduler's decision
-  points and the trainer's data-wait/step/eval/checkpoint phases.
+- :mod:`.tracing` — the program's names on one timeline. Nestable
+  ``span("admit_wave", rids=...)`` context managers through the serve
+  scheduler's decision points and the trainer's
+  data-wait/step/eval/checkpoint phases: each drives a
+  ``jax.profiler.TraceAnnotation``, so any running profile holds it on
+  the host plane on the device events' clock, and with a ``Tracer``
+  installed it is also Chrome-trace-event JSON (Perfetto-loadable).
+  ``scope(name)`` is the device side: ``jax.named_scope`` restricted to
+  the vocabulary ``SCOPES`` — ``embed``, ``attn``, ``mlp``, ``dropout``,
+  ``head``, ``loss``, ``optimizer``, ``grad_reduce`` in the train step;
+  ``admit``, ``decode`` and under them ``kv_gather``, ``kv_write``,
+  ``sample`` in the serve programs — which a profile's device ops then
+  carry in their ``op_name`` (metadata only: the compiled code is the
+  same).
 - :mod:`.loadgen` — the open-loop Poisson load harness behind
   ``bench.py --serve-load-smoke`` (the ROADMAP-3 load generator).
 - :mod:`.flight` — a bounded ring buffer of structured events fed from
@@ -25,8 +35,9 @@ Three small host-side layers, none of which touch compiled code:
 
 The whole layer is a no-op when disabled (``metrics.set_enabled(False)``
 or ``DCP_TELEMETRY=0``): record paths return before taking any lock and
-``span()`` hands back a shared null context — the disabled cost is one
-global read per call site (the <1% guard in ``tests/test_obs.py``).
+``span()`` hands back a shared null context, as it does whenever no
+profile is running and no tracer is installed — the cost is then a few
+global reads per call site (the <1% guard in ``tests/test_obs.py``).
 The ``stats``/``waste`` views stay live even when telemetry is off:
 they are functional scheduler counters, not optional diagnostics.
 """
@@ -38,12 +49,12 @@ from distributed_compute_pytorch_tpu.obs.flight import (
 from distributed_compute_pytorch_tpu.obs.metrics import (
     Counter, Gauge, Histogram, MetricDict, Registry, enabled, set_enabled)
 from distributed_compute_pytorch_tpu.obs.tracing import (
-    Tracer, configure_tracer, current_tracer, span)
+    SCOPES, Tracer, configure_tracer, current_tracer, scope, span)
 
 __all__ = [
     "Counter", "FlightRecorder", "Gauge", "Histogram", "MetricDict",
-    "Registry", "Tracer", "configure_flight", "configure_tracer",
-    "current_flight", "current_tracer", "dump_on_fault", "enabled",
-    "flight", "loadgen", "metrics", "regress", "set_enabled", "span",
-    "tracing",
+    "Registry", "SCOPES", "Tracer", "configure_flight",
+    "configure_tracer", "current_flight", "current_tracer",
+    "dump_on_fault", "enabled", "flight", "loadgen", "metrics", "regress",
+    "scope", "set_enabled", "span", "tracing",
 ]

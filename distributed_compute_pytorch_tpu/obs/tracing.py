@@ -1,35 +1,44 @@
-"""Host-side span tracing — Chrome-trace-event JSON, Perfetto-loadable.
+"""Spans and scopes: the program's own names on one timeline.
 
-The serve scheduler interleaves admit/dispatch/harvest/reconstruct
-decisions with overlapped device work; the trainer interleaves
-data-wait/step/eval/checkpoint. A mean timer cannot show WHERE a slow
-tick went — a trace of nested spans can, and the Chrome trace-event
-format (`"ph": "B"/"E"` pairs per thread, microsecond ``ts``) gets us
-the Perfetto UI for free.
+Two kinds of name, one module:
+
+- :func:`span` / :func:`instant` — HOST phases. The serve scheduler
+  interleaves admit/dispatch/harvest/reconstruct decisions with
+  overlapped device work; the trainer interleaves
+  data-wait/step/eval/checkpoint. Every span drives a
+  ``jax.profiler.TraceAnnotation``, so whatever profile is running (the
+  benchmark's traced slice, ``--profile_dir``, ``--profile_segments``)
+  holds it on the host plane, on the clock of the device events. With a
+  :class:`Tracer` installed (``--trace_path``) the same span is also a
+  Chrome-trace-event pair (``"ph": "B"/"E"`` per thread, microsecond
+  ``ts``), Perfetto-loadable on its own.
+- :func:`scope` — DEVICE work. ``jax.named_scope`` restricted to the
+  declared vocabulary :data:`SCOPES`: the name becomes part of the
+  ``op_name`` of every operation traced under it, so a profile's device
+  ops say which layer of the program they belong to. Metadata only: the
+  compiled code is the same with and without.
 
 Design points:
 
-- Spans are plain objects, not generator context managers: entering a
-  span appends one ``B`` event, exiting one ``E`` event, each a small
-  dict on an in-memory list under a lock. Nesting is implicit in the
-  B/E ordering per ``tid`` (``threading.get_native_id``), so spans
-  opened in the scheduler thread and the watchdogged fetch worker
-  interleave correctly in the same trace.
-- Timestamps come from ``time.perf_counter_ns`` relative to the
-  tracer's epoch — monotonic by construction (the validity property
-  ``tests/test_obs.py`` and the load smoke assert).
-- ``dump(path)`` writes the standard ``{"traceEvents": [...]}`` object;
-  an optional ``jsonl_path`` streams each completed event as a line at
-  span exit (crash-durable, machine-tailable).
-- The module-level :func:`span` uses the installed global tracer and
-  hands back a shared null context when there is none (or telemetry is
-  disabled): instrumented code pays one global read when tracing is
-  off. Install with :func:`configure_tracer`.
+- Spans are plain objects, not generator context managers. Nesting is
+  implicit in the B/E ordering per ``tid``
+  (``threading.get_native_id``), so spans opened in the scheduler thread
+  and the watchdogged fetch worker interleave correctly in one trace.
+- Tracer timestamps come from ``time.perf_counter_ns`` relative to the
+  tracer's epoch — monotonic by construction; ``dump`` records the
+  epoch's wall-clock time (``epoch_unix_ns``) so the JSON can be laid
+  beside a profile.
+- The three sinks are independent checks: the flight recorder's ring,
+  the profiler's TraceMe and the :class:`Tracer`. With no profile
+  running and no tracer installed — or with telemetry disabled
+  (``metrics.set_enabled(False)``) — :func:`span` hands back a shared
+  null context: instrumented code pays two global reads and the
+  profiler's one atomic read.
 
 Spans measure HOST decision time. JAX dispatch is asynchronous, so a
 ``dispatch_segment`` span covers tracing + enqueue, not device
-execution — the XLA profiler (``utils/timing.maybe_profile``,
-``dcp-serve --profile_dir``) owns the device side.
+execution; the device's side of the same instant is the scoped ops of
+the profile the span landed in.
 """
 
 from __future__ import annotations
@@ -39,7 +48,30 @@ import os
 import threading
 import time
 
+import jax
+from jax.profiler import TraceAnnotation
+
 from distributed_compute_pytorch_tpu.obs import flight, metrics
+
+# The device-side vocabulary, one name per layer boundary of PERF.md
+# section 3. ``dropout`` nests (``attn/dropout``, ``mlp/dropout``,
+# ``embed/dropout``); ``kv_gather``/``kv_write``/``sample`` nest under
+# ``admit``/``decode``. The benchmark's scope metrics
+# (``perfbench/layer_metrics``) name these and nothing else.
+SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
+          "optimizer", "grad_reduce",
+          "admit", "decode", "kv_gather", "kv_write", "sample")
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES`; any other
+    name raises while the program is traced. Operations traced under it
+    carry ``.../<name>/...`` in their ``op_name`` (backward operations
+    ``transpose(jvp(<name>))``), which is what a profile's reader sees."""
+    if name not in SCOPES:
+        raise ValueError(f"scope {name!r} is not declared in "
+                         f"obs.tracing.SCOPES {SCOPES}")
+    return jax.named_scope(name)
 
 
 class _NullSpan:
@@ -53,82 +85,90 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args):
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
 
+def _packed(args: dict) -> dict:
+    """Arguments as the profiler can hold them. TraceMe packs them as
+    ``name#k=v,k=v#``: a ``,`` or ``#`` inside a value would cut it short
+    in the profile, so in anything but a number they are written ``;``."""
+    return {k: v if isinstance(v, (int, float))
+            else str(v).replace(",", ";").replace("#", ";")
+            for k, v in args.items()}
+
+
 class _Span:
-    __slots__ = ("_tracer", "_name", "_args")
+    __slots__ = ("_tracer", "_name", "_args", "_late", "_ann")
 
     def __init__(self, tracer, name, args):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._late = None
 
     def __enter__(self):
-        self._tracer._emit("B", self._name, self._args)
+        self._ann = TraceAnnotation(self._name, **_packed(self._args or {}))
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self._tracer._emit("B", self._name, self._args)
         return self
 
+    def note(self, **args):
+        """Arguments known only inside the span (which requests a
+        harvest finished): they ride its end."""
+        self._ann.set_metadata(**_packed(args))
+        self._late = {**(self._late or {}), **args}
+
     def __exit__(self, *exc):
-        self._tracer._emit("E", self._name, None)
+        if self._tracer is not None:
+            self._tracer._emit("E", self._name, self._late)
+        self._ann.__exit__(*exc)
         return False
 
 
 class Tracer:
-    """Collects trace events in memory; optionally streams JSONL."""
+    """Collects trace events in memory; ``dump`` writes them."""
 
-    def __init__(self, jsonl_path: str | None = None):
+    def __init__(self):
         self._mu = threading.Lock()
         self._events: list[dict] = []
         self._epoch_ns = time.perf_counter_ns()
+        self._epoch_unix_ns = time.time_ns()
         self._pid = os.getpid()
-        self._f = open(jsonl_path, "a") if jsonl_path else None
 
-    def _emit(self, ph: str, name: str, args) -> None:
-        ev = {"name": name, "ph": ph, "pid": self._pid,
+    def _emit(self, ph: str, name: str, args, **extra) -> None:
+        ev = {"name": name, "ph": ph, **extra, "pid": self._pid,
               "tid": threading.get_native_id(),
               "ts": (time.perf_counter_ns() - self._epoch_ns) / 1e3}
         if args:
             ev["args"] = args
         with self._mu:
             self._events.append(ev)
-            if self._f is not None:
-                self._f.write(json.dumps(ev) + "\n")
 
     def span(self, name: str, **args) -> _Span:
         return _Span(self, name, args or None)
 
     def instant(self, name: str, **args) -> None:
         """A zero-duration marker (``ph: "i"`` — drain start, fault)."""
-        ev = {"name": name, "ph": "i", "s": "t", "pid": self._pid,
-              "tid": threading.get_native_id(),
-              "ts": (time.perf_counter_ns() - self._epoch_ns) / 1e3}
-        if args:
-            ev["args"] = args
-        with self._mu:
-            self._events.append(ev)
-            if self._f is not None:
-                self._f.write(json.dumps(ev) + "\n")
+        self._emit("i", name, args, s="t")
 
     def events(self) -> list[dict]:
         with self._mu:
             return list(self._events)
 
     def dump(self, path: str) -> None:
-        """Write the Perfetto/chrome://tracing-loadable trace object."""
-        with self._mu:
-            events = list(self._events)
-            if self._f is not None:
-                self._f.flush()
+        """Write the Perfetto/chrome://tracing-loadable trace object.
+        ``epoch_unix_ns`` is the wall-clock time of ``ts`` 0: a profile's
+        events (``profile_start_time`` of its ``Task Environment`` plane
+        + an event's offset) lie on the same wall clock."""
         with open(path, "w") as f:
-            json.dump({"traceEvents": events,
-                       "displayTimeUnit": "ms"}, f)
-
-    def close(self) -> None:
-        with self._mu:
-            if self._f is not None:
-                self._f.close()
-                self._f = None
+            json.dump({"traceEvents": self.events(),
+                       "displayTimeUnit": "ms",
+                       "epoch_unix_ns": self._epoch_unix_ns}, f)
 
 
 _GLOBAL: Tracer | None = None
@@ -148,33 +188,38 @@ def current_tracer() -> Tracer | None:
 
 
 def span(name: str, **args):
-    """Module-level span against the global tracer — the form the serve
-    scheduler and trainer call. No tracer (or telemetry disabled) means
-    the shared null context: one global read, zero allocation.
-
-    Also the flight recorder's feed point: every span/instant name that
-    flows through here lands in the installed
-    :mod:`~distributed_compute_pytorch_tpu.obs.flight` ring, so the
-    recorder sees the scheduler's event stream with no extra
-    instrumentation. The flight recorder works without a tracer (and
-    vice versa) — the two checks are independent."""
+    """Module-level span — the form the serve scheduler and trainer
+    call. It feeds three independent sinks: the installed
+    :mod:`~distributed_compute_pytorch_tpu.obs.flight` ring (every
+    span/instant name that flows through here lands there with no extra
+    instrumentation), the profiler (a ``TraceAnnotation`` opened at
+    ``__enter__``: in any running profile the span lies on the host
+    plane), and the global :class:`Tracer` when one is installed. Each
+    works without the others. With neither a profile running nor a
+    tracer installed (or with telemetry disabled) this is the shared
+    null context: one atomic read, nothing allocated."""
     f = flight._GLOBAL
     if f is not None:
         f.record(name, **args)
     t = _GLOBAL
-    if t is None or not metrics.enabled():
+    if not metrics.enabled() or (
+            t is None and not TraceAnnotation.is_enabled()):
         return _NULL_SPAN
-    return t.span(name, **args)
+    return _Span(t, name, args or None)
 
 
 def instant(name: str, **args) -> None:
     f = flight._GLOBAL
     if f is not None:
         f.record(name, **args)
-    t = _GLOBAL
-    if t is None or not metrics.enabled():
+    if not metrics.enabled():
         return
-    t.instant(name, **args)
+    if TraceAnnotation.is_enabled():
+        with TraceAnnotation(name, **_packed(args)):
+            pass
+    t = _GLOBAL
+    if t is not None:
+        t.instant(name, **args)
 
 
 def validate_chrome_trace(events: list[dict]) -> list[str]:

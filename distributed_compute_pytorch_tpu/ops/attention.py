@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from distributed_compute_pytorch_tpu.obs.tracing import scope
+
 
 def dot_product_attention(q, k, v, *, causal: bool = False, bias=None,
                           mask=None, scale: float | None = None):
@@ -345,6 +347,20 @@ def gather_kv_blocks(pool_leaf, table):
     return g.transpose(0, 1, 3, 2, 4, 5).reshape(s, B, hk, nb * bt, hd)
 
 
+def _paged_view(pool, table) -> dict:
+    """A paged pool's logical per-row view as attention reads it:
+    ``{"k", "v"}`` (plus ``"k_scale"``/``"v_scale"`` for the int8 form),
+    each ``[B, hk, nb * bt, hd]``. The K/V split is a copy of the whole
+    gathered view on this backend, so it counts with the gather."""
+    with scope("kv_gather"):
+        kv = gather_kv_blocks(pool["kv"], table)
+        view = {"k": kv[0], "v": kv[1]}
+        if "scale" in pool:
+            sc = gather_kv_blocks(pool["scale"], table)
+            view.update(k_scale=sc[0], v_scale=sc[1])
+        return view
+
+
 def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     """One decode tick against the PAGED pool cache format
     ``{"kv": [2, P, hk, bt, hd], "table": int32 [B, nb]}`` (plus
@@ -372,19 +388,21 @@ def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     blk = jnp.take_along_axis(table, (pos // bt)[:, None], axis=1)[:, 0]
     off = pos % bt
     if "scale" in pool:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        pool = kv_pool_insert_all(
-            pool, {"kv": jnp.stack([kq, vq]),
-                   "scale": jnp.stack([ks, vs])}, blk, off)
-        kv = gather_kv_blocks(pool["kv"], table)
-        sc = gather_kv_blocks(pool["scale"], table)
-        view = {"k": kv[0], "v": kv[1], "k_scale": sc[0], "v_scale": sc[1]}
+        with scope("kv_write"):
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            pool = kv_pool_insert_all(
+                pool, {"kv": jnp.stack([kq, vq]),
+                       "scale": jnp.stack([ks, vs])}, blk, off)
+        view = _paged_view(pool, table)
         out = cached_attention_q8(q, view, pos, slot_mask=slot_mask)
     else:
-        pool = kv_pool_insert_all(pool, {"kv": jnp.stack([k, v])}, blk, off)
-        kv = gather_kv_blocks(pool["kv"], table)
-        out = cached_attention(q, kv[0], kv[1], pos, slot_mask=slot_mask)
+        with scope("kv_write"):
+            pool = kv_pool_insert_all(pool, {"kv": jnp.stack([k, v])},
+                                      blk, off)
+        view = _paged_view(pool, table)
+        out = cached_attention(q, view["k"], view["v"], pos,
+                               slot_mask=slot_mask)
     return out, {**pool, "table": table}
 
 
@@ -441,18 +459,18 @@ def cache_verify_and_attend(q, k, v, cache, positions, *, slot_mask=None):
         return leaf.at[:, blk, :, off, :].set(upd, mode="drop")
 
     if "scale" in pool:
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        pool = {"kv": scatter(pool["kv"], jnp.stack([kq, vq])),
-                "scale": scatter(pool["scale"], jnp.stack([ks, vs]))}
-        kv = gather_kv_blocks(pool["kv"], table)
-        sc = gather_kv_blocks(pool["scale"], table)
-        view = {"k": kv[0], "v": kv[1], "k_scale": sc[0], "v_scale": sc[1]}
+        with scope("kv_write"):
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            pool = {"kv": scatter(pool["kv"], jnp.stack([kq, vq])),
+                    "scale": scatter(pool["scale"], jnp.stack([ks, vs]))}
+        view = _paged_view(pool, table)
         out = cached_attention_q8(q, view, positions, slot_mask=slot_mask)
     else:
-        pool = {"kv": scatter(pool["kv"], jnp.stack([k, v]))}
-        kv = gather_kv_blocks(pool["kv"], table)
-        out = cached_attention(q, kv[0], kv[1], positions,
+        with scope("kv_write"):
+            pool = {"kv": scatter(pool["kv"], jnp.stack([k, v]))}
+        view = _paged_view(pool, table)
+        out = cached_attention(q, view["k"], view["v"], positions,
                                slot_mask=slot_mask)
     return out, {**pool, "table": table}
 

@@ -154,9 +154,19 @@ histograms (queue-wait, TTFT, TPOT, e2e — measurement points on
 ``serve_lifecycle.RequestResult``) accumulate beside them and
 :meth:`ContinuousBatcher.stats_snapshot` serialises everything. The
 scheduler's decision points — ``admit_wave`` > ``prefill_wave``,
-``dispatch_segment``, ``harvest``, ``reconstruct``, drain/fault
-instants — run under ``obs.tracing.span`` (Chrome-trace events when a
-tracer is configured; a shared null context otherwise). Open-loop
+``dispatch_segment``, ``harvest``, ``reconstruct``, ``await_arrival``,
+drain/fault instants — run under ``obs.tracing.span``: each reaches
+any running profile's host plane (``--profile_dir``,
+``--profile_segments``) with the ids of the requests it handles
+(``rids``; ``harvest`` says which got their ``first`` token and which
+are ``done``), and is a Chrome-trace event when a tracer is configured
+(``--trace_path``); with neither it is a shared null context. The
+compiled programs name their parts for the same profile with
+``obs.tracing.scope`` (the vocabulary is listed once, in ``obs/``):
+``admit`` round the admission prefill, ``decode`` round the segment's
+tick, and inside them the models' ``embed``/``attn``/``mlp``/``head``
+and ``kv_gather`` (the paged view at the width rung), ``kv_write``,
+``sample``. Open-loop
 load rides in-band: ``Request.arrival_s`` delays admission to the
 request's arrival instant and the scheduler idles across arrival gaps
 (``obs/loadgen.py`` — the ROADMAP-3 Poisson load generator).
@@ -190,7 +200,8 @@ from distributed_compute_pytorch_tpu.kv_tier import (
 from distributed_compute_pytorch_tpu.obs import flight
 from distributed_compute_pytorch_tpu.obs import metrics as obs_metrics
 from distributed_compute_pytorch_tpu.obs.metrics import device_memory_gauges
-from distributed_compute_pytorch_tpu.obs.tracing import instant, span
+from distributed_compute_pytorch_tpu.obs.tracing import (
+    instant, scope, span)
 from distributed_compute_pytorch_tpu.serve_journal import JOURNAL_STATS
 from distributed_compute_pytorch_tpu.serve_lifecycle import (
     CANCELLED, FAILED, OK, SHED, TIMEOUT, RequestResult)
@@ -1428,74 +1439,75 @@ class ContinuousBatcher:
         """
         from distributed_compute_pytorch_tpu.ops.attention import (
             gather_kv_blocks)
-        model = self.model
-        Lp = prefix_mask.shape[1]
-        x = constrain(model.embed(params, prompt, positions),
-                      P(("data", "fsdp"), None, None))
-        blocks = params["blocks"]
-        new_caches = []
-        for i in range(self._n_layers):
-            p_i = jax.tree.map(lambda a: a[i], blocks)
-            sink: list = []
-            kw = {"kv_sink": sink, "kv_mask": pmask}
-            if Lp:
-                # attached-prefix K/V: gathered from the pool and
-                # resharded into the row-sharded compute layout (the
-                # portable-redistribution move). int8 pools dequantize
-                # here — the kv_prefix seam concatenates with the
-                # suffix's float K/V (models/transformer.py::
-                # _concat_kv_prefix), so the scales must be applied
-                # before the prefix leaves the pool's dtype domain
-                pk = gather_kv_blocks(caches[i]["kv"],
-                                      tables[:, :Lp // self.bt])
-                if "scale" in caches[i]:
-                    ps = gather_kv_blocks(caches[i]["scale"],
-                                          tables[:, :Lp // self.bt])
-                    pk = (pk.astype(jnp.float32) * ps).astype(
-                        self._cdtype)
-                pk = constrain(pk, _CACHE_SPEC)
-                kw["kv_prefix"] = (pk[0], pk[1], prefix_mask)
-            if self._block_takes_positions:
-                kw["positions"] = positions
-            if self._block_takes_moe_capacity and moe_capacity is not None:
-                # expert queues sized for each row's REAL token count:
-                # pads route nowhere (kv_mask) and every row is its own
-                # routing group (models/moe.py)
-                kw["moe_capacity"] = moe_capacity
-                if (self._block_takes_moe_capacity_rows
-                        and moe_capacity_rows is not None):
-                    kw["moe_capacity_rows"] = moe_capacity_rows
-            x = self._block.apply(p_i, x, **kw)
-            if isinstance(x, tuple):   # MoE blocks return (x, aux)
-                x = x[0]
-            (k, v), = sink             # [K, hk, ws, hd] — suffix only
-            # scatter each suffix token to its physical (block, offset):
+        with scope("admit"):
+            model = self.model
+            Lp = prefix_mask.shape[1]
+            x = constrain(model.embed(params, prompt, positions),
+                          P(("data", "fsdp"), None, None))
+            blocks = params["blocks"]
+            new_caches = []
+            for i in range(self._n_layers):
+                p_i = jax.tree.map(lambda a: a[i], blocks)
+                sink: list = []
+                kw = {"kv_sink": sink, "kv_mask": pmask}
+                if Lp:
+                    # attached-prefix K/V: gathered from the pool and
+                    # resharded into the row-sharded compute layout (the
+                    # portable-redistribution move). int8 pools dequantize
+                    # here — the kv_prefix seam concatenates with the
+                    # suffix's float K/V (models/transformer.py::
+                    # _concat_kv_prefix), so the scales must be applied
+                    # before the prefix leaves the pool's dtype domain
+                    with scope("kv_gather"):
+                        pk = gather_kv_blocks(caches[i]["kv"],
+                                              tables[:, :Lp // self.bt])
+                        if "scale" in caches[i]:
+                            ps = gather_kv_blocks(
+                                caches[i]["scale"],
+                                tables[:, :Lp // self.bt])
+                            pk = (pk.astype(jnp.float32) * ps).astype(
+                                self._cdtype)
+                    pk = constrain(pk, _CACHE_SPEC)
+                    kw["kv_prefix"] = (pk[0], pk[1], prefix_mask)
+                if self._block_takes_positions:
+                    kw["positions"] = positions
+                if self._block_takes_moe_capacity and moe_capacity is not None:
+                    # expert queues sized for each row's REAL token count:
+                    # pads route nowhere (kv_mask) and every row is its own
+                    # routing group (models/moe.py)
+                    kw["moe_capacity"] = moe_capacity
+                    if (self._block_takes_moe_capacity_rows
+                            and moe_capacity_rows is not None):
+                        kw["moe_capacity_rows"] = moe_capacity_rows
+                x = self._block.apply(p_i, x, **kw)
+                if isinstance(x, tuple):   # MoE blocks return (x, aux)
+                    x = x[0]
+                (k, v), = sink             # [K, hk, ws, hd] — suffix only
+                with scope("kv_write"):
+                    new_caches.append(self._admit_scatter(
+                        caches[i], k, v, blk_idx, off_idx))
+            return new_caches
+
+    @staticmethod
+    def _admit_scatter(cache, k, v, blk_idx, off_idx):
+        """One layer's admission write: each suffix token's K/V
+        (``[K, hk, ws, hd]``) scattered to its physical (block, offset).
+        int8 pools quantize per (row, head, position) HERE — fused into
+        the scatter, the same per-row symmetric form the decode tick's
+        write uses (ops/attention.py) — and scatter the f32 scales
+        through the identical index targets."""
+        def scatter(leaf, upd):
             # advanced indices at pool axes (1, 3) land broadcast-first,
-            # so the update region is [K, ws, 2, hk, hd]. int8 pools
-            # quantize per (row, head, position) HERE — fused into the
-            # admission scatter, the same per-row symmetric form the
-            # decode tick's write uses (ops/attention.py) — and scatter
-            # the f32 scales through the identical index targets.
-            if "scale" in caches[i]:
-                kq, ks = quantize_kv(k)
-                vq, vs = quantize_kv(v)
-                kv = jnp.stack([kq, vq])         # [2, K, hk, ws, hd]
-                sc = jnp.stack([ks, vs])         # [2, K, hk, ws, 1]
-                new = caches[i]["kv"].at[
-                    :, blk_idx, :, off_idx, :].set(
-                        kv.transpose(1, 3, 0, 2, 4), mode="drop")
-                news = caches[i]["scale"].at[
-                    :, blk_idx, :, off_idx, :].set(
-                        sc.transpose(1, 3, 0, 2, 4), mode="drop")
-                new_caches.append({"kv": constrain(new, _POOL_SPEC),
-                                   "scale": constrain(news, _POOL_SPEC)})
-                continue
-            kv = jnp.stack([k, v]).astype(caches[i]["kv"].dtype)
-            upd = kv.transpose(1, 3, 0, 2, 4)
-            new = caches[i]["kv"].at[:, blk_idx, :, off_idx, :].set(
-                upd, mode="drop")
-            new_caches.append({"kv": constrain(new, _POOL_SPEC)})
-        return new_caches
+            # so the update region is [K, ws, 2, hk, x]
+            return constrain(leaf.at[:, blk_idx, :, off_idx, :].set(
+                upd.astype(leaf.dtype).transpose(1, 3, 0, 2, 4),
+                mode="drop"), _POOL_SPEC)
+        if "scale" in cache:
+            kq, ks = quantize_kv(k)
+            vq, vs = quantize_kv(v)
+            return {"kv": scatter(cache["kv"], jnp.stack([kq, vq])),
+                    "scale": scatter(cache["scale"], jnp.stack([ks, vs]))}
+        return {"kv": scatter(cache["kv"], jnp.stack([k, v]))}
 
     def _copy_impl(self, caches, src, dst):
         """Copy-on-write block copies: pool blocks ``src [M]`` duplicated
@@ -1559,25 +1571,28 @@ class ContinuousBatcher:
             tick_keys = jnp.zeros((self.S,), jnp.uint32)      # unused xs
 
         def tick(carry, xs):
-            i, key = xs
-            tok, caches, n_log = carry
-            p = positions0 + 1 + i         # [B] per-row slot being written
-            x = constrain(model.embed(params, tok[:, None], n_log[:, None]),
-                          P(("data", "fsdp"), None, None))
-            new_caches = []
-            for li in range(self._n_layers):
-                p_l = jax.tree.map(lambda a: a[li], blocks)
-                paged = {**caches[li], "table": tables}
-                x, c2 = self._block.decode_step(p_l, x, paged, p)
-                new_caches.append(
-                    {name: constrain(leaf, _POOL_SPEC)
-                     for name, leaf in c2.items() if name != "table"})
-            logits = model.readout(params, x)[:, -1]
-            if sampling:
-                nxt = sample_rows(logits, temp, top_k, top_p, key)
-            else:
-                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return (nxt, new_caches, n_log + 1), nxt
+            with scope("decode"):
+                i, key = xs
+                tok, caches, n_log = carry
+                p = positions0 + 1 + i         # [B] per-row slot being written
+                x = constrain(
+                    model.embed(params, tok[:, None], n_log[:, None]),
+                    P(("data", "fsdp"), None, None))
+                new_caches = []
+                for li in range(self._n_layers):
+                    p_l = jax.tree.map(lambda a: a[li], blocks)
+                    paged = {**caches[li], "table": tables}
+                    x, c2 = self._block.decode_step(p_l, x, paged, p)
+                    new_caches.append(
+                        {name: constrain(leaf, _POOL_SPEC)
+                         for name, leaf in c2.items() if name != "table"})
+                logits = model.readout(params, x)[:, -1]
+                with scope("sample"):
+                    if sampling:
+                        nxt = sample_rows(logits, temp, top_k, top_p, key)
+                    else:
+                        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return (nxt, new_caches, n_log + 1), nxt
 
         (tok, caches, n_logical), toks = lax.scan(
             tick, (tok, caches, n_logical),
@@ -2379,7 +2394,8 @@ class ContinuousBatcher:
             take = pick_admissions(len(free))
             if not take:
                 return
-            with span("admit_wave", rows=len(take)):
+            with span("admit_wave", rows=len(take),
+                      rids=" ".join(jids[ri] for ri in take)):
                 now = time.monotonic()
                 rows = free[:len(take)]
                 entries, cow_all = [], []
@@ -2552,7 +2568,8 @@ class ContinuousBatcher:
                 # before this segment's dispatch
                 jax.profiler.start_trace(prof["dir"])
                 prof["active"] = True
-            with span("dispatch_segment", rows=len(plan)):
+            with span("dispatch_segment", rows=len(plan),
+                      rids=" ".join(jids[ri] for _, ri, _, _ in plan)):
                 args = (self.params, self._caches,
                         jnp.asarray(tables_now[:, :nb_w]),
                         self._cur_tok, self._n_logical,
@@ -2844,7 +2861,8 @@ class ContinuousBatcher:
                 harvest_verify(seg)
                 return
             _kind, toks, plan = seg
-            with span("harvest", overlapped=overlapped):
+            with span("harvest", overlapped=overlapped) as sp:
+                first_ids, done_ids = [], []
                 self.stats["fetches"] += 1
                 if overlapped:
                     self.stats["fetches_overlapped"] += 1
@@ -2880,6 +2898,7 @@ class ContinuousBatcher:
                             and first_tok_at[ri] is None):
                         # first generated token reached the host: TTFT
                         first_tok_at[ri] = now
+                        first_ids.append(jids[ri])
                         self._slo["ttft_s"].record(
                             max(0.0, now - arrive_at[ri]))
                     done = done_after
@@ -2894,6 +2913,9 @@ class ContinuousBatcher:
                     if done:
                         fin(ri, OK, slot.out)
                         free_row(b)
+                        done_ids.append(jids[ri])
+                sp.note(first=" ".join(first_ids) or "-",
+                        done=" ".join(done_ids) or "-")
                 if jr is not None:
                     jr.commit()        # harvest = the durability boundary
 
@@ -2983,7 +3005,8 @@ class ContinuousBatcher:
                     # is empty or holds only never-admissible requests
                     # (skip_fit horizon rejects, reported at exit)
                     return None
-                time.sleep(min(min(future) - now, 0.02))
+                with span("await_arrival"):
+                    time.sleep(min(min(future) - now, 0.02))
                 police()
                 admit_wave()
                 chunk_wave()

@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from distributed_compute_pytorch_tpu.core.mesh import (
     batch_sharding, use_manual_axes, use_mesh)
+from distributed_compute_pytorch_tpu.obs.tracing import scope
 from distributed_compute_pytorch_tpu.parallel import collectives as coll
 from distributed_compute_pytorch_tpu.parallel.api import (
     DataParallel, tree_shardings)
@@ -332,7 +333,7 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
     # batches to the batch axes, so jit sees fully-specified layouts and the
     # SPMD partitioner inserts the implied collectives.
 
-    def _local_update(g, o, p):
+    def _update(g, o, p):
         """Apply the optimizer to one (gradient, opt_state, params)
         triple. On the replicated path these are full arrays; inside the
         ZeRO-1 shard_map body they are the per-shard LOCAL arrays — every
@@ -347,6 +348,10 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
         updates, new_o = tx.update(g, o, p)
         return optax.apply_updates(p, updates), new_o
 
+    def _local_update(g, o, p):
+        with scope("optimizer"):
+            return _update(g, o, p)
+
     def _zero1_update(grads, opt_state, params):
         """RS -> shard-local update -> AG (the weight-update-sharding
         paper's transform, annotation-driven): the shard_map's in_specs
@@ -359,14 +364,15 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
         — it never exists replicated."""
         p_specs = coll.tree_update_specs(params, dp_n, dp_ax)
         o_specs = coll.tree_update_specs(opt_state, dp_n, dp_ax)
-        body = jax.shard_map(_local_update, mesh=mesh,
+        body = jax.shard_map(_update, mesh=mesh,
                              in_specs=(p_specs, o_specs, p_specs),
                              out_specs=(p_specs, o_specs),
                              axis_names=set(dp_ax))
-        new_p, new_o = body(grads, opt_state, params)
-        repl = NamedSharding(mesh, P())
-        new_p = jax.tree.map(
-            lambda a: lax.with_sharding_constraint(a, repl), new_p)
+        with scope("optimizer"):
+            new_p, new_o = body(grads, opt_state, params)
+            repl = NamedSharding(mesh, P())
+            new_p = jax.tree.map(
+                lambda a: lax.with_sharding_constraint(a, repl), new_p)
         return new_p, new_o
 
     def _quant_step(state: TrainState, x, y, step_rng):
@@ -410,7 +416,8 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
                 return coll.quantized_reduce_scatter(gl, ax, dp_n,
                                                      dim=d) / dp_n
 
-            g = jax.tree.map(reduce_leaf, g, p_specs)
+            with scope("grad_reduce"):
+                g = jax.tree.map(reduce_leaf, g, p_specs)
 
             def slice_leaf(pl, spec):
                 # params entered the region replicated (full local
@@ -523,14 +530,16 @@ def make_step_fns(model, tx: optax.GradientTransformation, mesh: Mesh,
 
             def reduce_leaf(gl, spec, pl):
                 d = coll.spec_shard_dim(spec)
-                if d is None:
-                    red = lax.psum(gl, dp_ax)
-                elif quant_collectives:
-                    red = coll.quantized_reduce_scatter(gl, dp_ax[0],
-                                                        dp_n, dim=d)
-                else:
-                    red = coll.reduce_scatter(gl, ax_spec, dim=d)
-                return (red.astype(jnp.float32) * scale).astype(pl.dtype)
+                with scope("grad_reduce"):
+                    if d is None:
+                        red = lax.psum(gl, dp_ax)
+                    elif quant_collectives:
+                        red = coll.quantized_reduce_scatter(gl, dp_ax[0],
+                                                            dp_n, dim=d)
+                    else:
+                        red = coll.reduce_scatter(gl, ax_spec, dim=d)
+                    return (red.astype(jnp.float32) * scale).astype(
+                        pl.dtype)
 
             def slice_leaf(pl, spec):
                 d = coll.spec_shard_dim(spec)

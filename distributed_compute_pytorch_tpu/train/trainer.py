@@ -457,7 +457,6 @@ class Trainer:
                 log0(f"span trace written to {self.config.trace_path}")
             finally:
                 configure_tracer(None)
-                self._tracer.close()
                 self._tracer = None
         if self._flight is not None and flight.current_flight() is self._flight:
             # uninstall OUR recorder (another run may install its own);
@@ -502,7 +501,8 @@ class Trainer:
             if b % cfg.log_every == 0:
                 # read the device scalar only at the logging cadence
                 # (reference cadence, main.py:64)
-                loss = float(metrics["loss"])
+                with span("log_read"):
+                    loss = float(metrics["loss"])
                 self._poll_nonfinite(loss, epoch, b)
                 self._poll_divergence(epoch, b)
                 self.logger.train_line(epoch, b, steps, loss)
@@ -523,7 +523,8 @@ class Trainer:
         # fence: fetch a value depending on the last step, so the epoch
         # timer stops after the device work and not after the enqueue
         if metrics is not None:
-            np.asarray(metrics["loss"])
+            with span("epoch_fence"):
+                np.asarray(metrics["loss"])
             # drain the skip flags queued since the last log line, so an
             # epoch can't end with unexamined non-finite skips
             self._poll_nonfinite(float(metrics["loss"]), epoch, steps - 1)

@@ -1,0 +1,273 @@
+"""The program's names on the profiler's timeline (ISSUE 24).
+
+Device side: the lowered programs carry the declared scopes of
+``obs.tracing.SCOPES`` in their ``op_name`` locations, forward and under
+``transpose(jvp(``. Host side: a ``span`` lands on the host plane of
+whatever profile is running, with its arguments, with and without a
+``Tracer``. And every scope or span a benchmark metric reads is one the
+program emits."""
+
+import glob
+import json
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+from distributed_compute_pytorch_tpu.core.mesh import make_mesh
+from distributed_compute_pytorch_tpu.models.registry import build_model
+from distributed_compute_pytorch_tpu.obs import tracing
+from distributed_compute_pytorch_tpu.serve import ContinuousBatcher
+from distributed_compute_pytorch_tpu.train.step import make_step_fns
+
+ROOT = pathlib.Path(__file__).resolve().parent.parent
+PKG = ROOT / "distributed_compute_pytorch_tpu"
+
+
+def _locations(lowered) -> set:
+    """Every ``loc("...")`` name of a lowered program: the name stacks
+    XLA turns into ``op_name``."""
+    return set(re.findall(r'loc\("([^"]+)"',
+                          lowered.as_text(debug_info=True)))
+
+
+def _has(locs, *path) -> bool:
+    """Some location holds ``path`` as consecutive ``/`` components."""
+    want = "/".join(path)
+    return any(re.search(rf"(^|/){re.escape(want)}(/|$)", n) for n in locs)
+
+
+def test_scope_refuses_an_undeclared_name():
+    with pytest.raises(ValueError, match="not declared"):
+        tracing.scope("attention")
+    with tracing.scope("attn"):      # a declared one is a plain context
+        pass
+
+
+TRAIN_SCOPES = {
+    # gpt2 tiny has no dropout of its own: the test turns it on
+    "gpt2": ("embed", "attn", "mlp", "head", "loss", "dropout"),
+    "llama": ("embed", "attn", "mlp", "head", "loss"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(TRAIN_SCOPES))
+def test_train_step_carries_every_training_scope(name):
+    kw = {"dropout_rate": 0.1} if name == "gpt2" else {}
+    model = build_model(name, preset="tiny", **kw)
+    mesh = make_mesh("data=1", devices=jax.devices()[:1])
+    init_fn, train_step, _ = make_step_fns(
+        model, optax.adamw(1e-3), mesh, donate=False)
+    state = init_fn(jax.random.key(0))
+    x = jnp.zeros((2, 16), jnp.int32)
+    locs = _locations(train_step.lower(state, x, x))
+    for s in TRAIN_SCOPES[name]:
+        if s == "dropout":
+            # inside the one helper, so it nests under its caller's scope
+            for outer in ("embed", "attn", "mlp"):
+                assert _has(locs, f"jvp({outer})", "dropout"), (outer, s)
+                assert _has(locs, f"transpose(jvp({outer}))", "dropout")
+            continue
+        assert _has(locs, f"jvp({s})"), s
+        assert _has(locs, f"transpose(jvp({s}))"), s
+    assert _has(locs, "optimizer")
+    assert not any("jvp(optimizer)" in n for n in locs)
+
+
+def test_zero1_update_is_one_optimizer_scope(devices8):
+    model = build_model("gpt2", preset="tiny")
+    mesh = make_mesh("data=8", devices=devices8)
+    init_fn, train_step, _ = make_step_fns(
+        model, optax.adamw(1e-3), mesh, donate=False, shard_update=True)
+    state = init_fn(jax.random.key(0))
+    x = jnp.zeros((8, 16), jnp.int32)
+    locs = _locations(train_step.lower(state, x, x))
+    assert _has(locs, "optimizer")
+    assert not _has(locs, "optimizer", "optimizer")
+
+
+@pytest.fixture(scope="module")
+def tiny_llama_cb():
+    model = build_model("llama", preset="tiny")
+    params, _ = model.init(jax.random.key(0))
+    return ContinuousBatcher(model, params, slots=2, t_max=32,
+                             prompt_buf=8, segment=4)
+
+
+def test_serve_programs_carry_the_serving_scopes(tiny_llama_cb):
+    from distributed_compute_pytorch_tpu.serve import Request
+    cb = tiny_llama_cb
+    out = cb.serve([Request(tokens=[5, 9, 12], max_new=3)])
+    assert len(out[0]) == 3
+    programs = cb._program_sigs      # each program's first dispatch
+    fn, args, kwargs = programs["segment"]
+    seg = _locations(fn.lower(*args, **kwargs))
+    for path in (("decode",), ("decode", "embed"), ("decode", "attn"),
+                 ("attn", "kv_gather"), ("attn", "kv_write"),
+                 ("decode", "mlp"), ("decode", "head"),
+                 ("decode", "sample")):
+        assert _has(seg, *path), path
+    fn, args, kwargs = programs["admit"]
+    adm = _locations(fn.lower(*args, **kwargs))
+    for path in (("admit",), ("admit", "embed"), ("admit", "attn"),
+                 ("admit", "mlp"), ("admit", "kv_write")):
+        assert _has(adm, *path), path
+    assert not _has(adm, "decode") and not _has(seg, "admit")
+
+
+def test_admission_prefix_gather_is_a_kv_gather():
+    """With the prefix cache on, a second request sharing a block-aligned
+    prefix attaches it: the admission program gathers the cached K/V."""
+    from distributed_compute_pytorch_tpu.serve import Request
+    model = build_model("llama", preset="tiny")
+    params, _ = model.init(jax.random.key(0))
+    cb = ContinuousBatcher(model, params, slots=2, t_max=64, prompt_buf=24,
+                           segment=4, prefix_cache=True)
+    seen = []
+    noted = cb._note_program
+    cb._note_program = lambda kind, fn, args, kwargs: (
+        seen.append((kind, fn, args, kwargs)),
+        noted(kind, fn, args, kwargs))[1]
+    prompt = list(range(1, 20))
+    cb.serve([Request(tokens=prompt, max_new=2)])
+    cb.serve([Request(tokens=prompt + [7], max_new=2)])
+    cb._note_program = noted
+    found = False
+    for kind, fn, args, kwargs in seen:
+        if kind != "admit":
+            continue
+        found |= _has(_locations(fn.lower(*args, **kwargs)),
+                      "admit", "kv_gather")
+    assert found
+
+
+def _emitted_span_names() -> set:
+    names = set()
+    for f in PKG.rglob("*.py"):
+        names |= set(re.findall(r'\bspan\(\s*"([a-z_]+)"', f.read_text()))
+    return names
+
+
+def test_every_name_a_benchmark_metric_reads_is_emitted():
+    spans = _emitted_span_names()
+    assert {"data_wait", "train_step", "epoch_fence", "log_read",
+            "admit_wave", "dispatch_segment", "harvest",
+            "await_arrival"} <= spans
+    read = 0
+    unscoped = {}
+    for f in sorted((ROOT / "perfbench" / "layer_metrics").glob("*.json")):
+        spec = json.loads(f.read_text())
+        if spec["reader"] == "trace_scope_share":
+            for s in spec.get("scope", []) + spec.get("except", []):
+                assert s in tracing.SCOPES, (f.name, s)
+                read += 1
+            if not spec.get("scope"):
+                unscoped[f.name] = spec
+        if spec["reader"] == "idle_owner_share":
+            assert spec["span"] in spec["owners"]
+            for s in spec["owners"]:
+                assert s in spans, (f.name, s)
+                read += 1
+        if spec["reader"] == "span_share":
+            for s in [spec["numerator"], *spec["denominator"]]:
+                assert s in spans, (f.name, s)
+    assert read >= 12
+    # an "unscoped" share leaves out every declared scope but the one that
+    # wraps its whole program: a scope added later cannot fall into it
+    assert unscoped
+    for name, spec in unscoped.items():
+        left = set(tracing.SCOPES) - set(spec["except"])
+        assert left <= {"admit", "decode"}, (name, left)
+
+
+def _host_events(trace_dir) -> list:
+    files = sorted(glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(files[-1])
+    out = []
+    for plane in data.planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                out.append((ev.name, dict(ev.stats), ev.duration_ns))
+    return out
+
+
+@pytest.mark.parametrize("with_tracer", [False, True])
+def test_span_reaches_the_profile_with_its_arguments(tmp_path, with_tracer):
+    tr = tracing.Tracer() if with_tracer else None
+    prev = tracing.configure_tracer(tr)
+    try:
+        with jax.profiler.trace(str(tmp_path)):
+            with tracing.span("x", rids="a,b", rows=2) as sp:
+                with tracing.span("inner"):
+                    pass
+                sp.note(done="a")
+            tracing.instant("marker", n=3)
+    finally:
+        tracing.configure_tracer(prev)
+    events = {name: stats for name, stats, _ in _host_events(tmp_path)}
+    # TraceMe's own packing would cut "a,b" at the comma
+    assert events["x"] == {"rids": "a;b", "rows": 2, "done": "a"}
+    assert "inner" in events and events["marker"] == {"n": 3}
+    if with_tracer:
+        evs = tr.events()
+        assert tracing.validate_chrome_trace(evs) == []
+        begin = next(e for e in evs if e["name"] == "x" and e["ph"] == "B")
+        end = next(e for e in evs if e["name"] == "x" and e["ph"] == "E")
+        assert begin["args"] == {"rids": "a,b", "rows": 2}
+        assert end["args"] == {"done": "a"}
+
+
+def test_span_with_neither_sink_allocates_nothing():
+    assert tracing.current_tracer() is None
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    s = tracing.span("x", rids="a b")
+    assert s is tracing.span("y") and type(s).__slots__ == ()
+    with s as inside:
+        inside.note(done="a")          # accepted and dropped
+    tracing.instant("x")
+
+
+def test_tracer_dump_carries_its_wall_clock_epoch(tmp_path):
+    import time
+    before = time.time_ns()
+    tr = tracing.Tracer()
+    after = time.time_ns()
+    with tr.span("a", k=1):
+        pass
+    out = tmp_path / "trace.json"
+    tr.dump(str(out))
+    doc = json.loads(out.read_text())
+    assert before <= doc["epoch_unix_ns"] <= after
+    assert tracing.validate_chrome_trace(doc["traceEvents"]) == []
+    assert [e["ph"] for e in doc["traceEvents"]] == ["B", "E"]
+    assert not hasattr(tr, "close")
+    with pytest.raises(TypeError):
+        tracing.Tracer(jsonl_path=str(tmp_path / "spans.jsonl"))
+
+
+def test_request_spans_share_the_requests_id(tiny_llama_cb):
+    """admit_wave, dispatch_segment and harvest name the requests they
+    handle by ``Request.request_id`` (else the positional default)."""
+    from distributed_compute_pytorch_tpu.serve import Request
+    tr = tracing.Tracer()
+    prev = tracing.configure_tracer(tr)
+    try:
+        tiny_llama_cb.serve([
+            Request(tokens=[5, 9], max_new=2, request_id="alpha"),
+            Request(tokens=[7], max_new=6)])
+    finally:
+        tracing.configure_tracer(prev)
+    evs = tr.events()
+    args = lambda name, ph: [e.get("args", {}) for e in evs
+                             if e["name"] == name and e["ph"] == ph]
+    assert args("admit_wave", "B")[0]["rids"] == "alpha req-1"
+    assert all(a["rids"] for a in args("dispatch_segment", "B"))
+    ends = args("harvest", "E")
+    assert [a["first"] for a in ends][0] == "alpha req-1"
+    assert {i for a in ends for i in a["done"].split()} >= {"alpha",
+                                                            "req-1"}
