@@ -18,7 +18,13 @@ recovered, identical prompts giving identical streams; the serve process
 saw bf16 weights and a bf16 pool; and the compiled programs carry the
 Pallas kernels that belong on the path as Mosaic custom calls (flash
 forward and backward in the train step, flash forward in admission
-prefill, the pool window write in the decode segment).
+prefill, the pool window write in the decode segment). A last leg serves
+a tiny Llama with heads of 128 lanes (random weights, float32 at the
+highest matmul precision, so that greedy streams can be compared token for
+token) through `ContinuousBatcher`: on the chip the decode tick must read
+the pool through the block-table kernel (`paged_read == "kernel"`,
+`dcp_paged_decode_attn` in the compiled segment) and every stream must
+equal `infer.generate`'s contiguous decode of the same prompt.
 
 The chip belongs to one process at a time, so this parent never imports
 JAX or the package: it starts one child after another (a probe, the train
@@ -88,6 +94,42 @@ print("PROBE " + json.dumps(rec), flush=True)
 from distributed_compute_pytorch_tpu import native
 print("NATIVE " + json.dumps(native.available()), flush=True)
 """
+
+# the paged decode read: a Llama whose heads are whole 128-lane tiles, so
+# the block-table kernel is eligible; prompts and budgets that cross block
+# (8) and chunk (512) edges, more requests than slots so rows park and are
+# reused. argv[1] = [t_max, [[prompt tokens, max_new], ...]]
+_PAGED = r"""
+import dataclasses, json, sys
+import jax, jax.numpy as jnp, numpy as np
+jax.config.update("jax_default_matmul_precision", "highest")
+from distributed_compute_pytorch_tpu.infer import generate
+from distributed_compute_pytorch_tpu.models.llama import LlamaConfig, LlamaLM
+from distributed_compute_pytorch_tpu.serve import ContinuousBatcher, Request
+t_max, shapes = json.loads(sys.argv[1])
+model = LlamaLM(dataclasses.replace(
+    LlamaConfig.tiny(), d_model=512, num_heads=4, num_kv_heads=2, d_ff=1024,
+    max_seq_len=t_max))
+params, _ = model.init(jax.random.key(0))
+rng = np.random.default_rng(0)
+reqs = [Request(tokens=[int(t) for t in rng.integers(0, 256, n)], max_new=m)
+        for n, m in shapes]
+cb = ContinuousBatcher(model, params, slots=3, t_max=t_max,
+                       prompt_buf=max(n for n, _ in shapes), segment=8)
+served = cb.serve(reqs)
+snap = cb.stats_snapshot()
+solo = [[int(t) for t in np.asarray(generate(
+            model, params, jnp.asarray([r.tokens], jnp.int32),
+            r.max_new))[0, len(r.tokens):]] for r in reqs]
+print("PAGED " + json.dumps({
+    "paged_read": snap["paged_read"], "engine": snap["engine"],
+    "head_dim": model.config.head_dim, "stats": snap["stats"],
+    "kernels": cb.kernel_census().get("segment", {}).get("kernels", {}),
+    "served": served, "solo": solo}), flush=True)
+"""
+PAGED_CHIP = [640, [[5, 12], [250, 20], [500, 40], [17, 30], [64, 9],
+                    [5, 12]]]
+PAGED_TINY = [48, [[3, 6], [14, 10], [20, 12], [5, 9], [3, 6]]]
 
 _COMPILE_RE = re.compile(
     r"Finished (?:tracing \+ transforming|jaxpr to MLIR module conversion|"
@@ -336,6 +378,35 @@ def serve_leg(name, size, env, ckpt, requests, extra, timeout,
             "streams": [r["new"] for r in lines]}
 
 
+def paged_leg(env, shapes, timeout, on_chip) -> dict:
+    """The decode tick's pool read through the block table: engaged where
+    the operands allow (on the chip, heads of 128), and token for token
+    what the contiguous decode gives."""
+    leg = run_child("paged", [sys.executable, "-c", _PAGED,
+                              json.dumps(shapes)], env, timeout)
+    rec = json.loads(re.search(r"^PAGED (.*)$", leg["log"], re.M)[1])
+    check(rec["head_dim"] == 128, f"paged: heads of {rec['head_dim']}")
+    check(rec["stats"]["faults"] == 0
+          and rec["stats"]["reconstructions"] == 0,
+          f"paged: recovered from {rec['stats']['faults']} fault(s)")
+    for i, (got, want) in enumerate(zip(rec["served"], rec["solo"])):
+        check(len(got) == shapes[1][i][1] and got == want,
+              f"paged: request {i} {shapes[1][i]} served {got}, the "
+              f"contiguous decode gives {want}")
+    want_path = "kernel" if on_chip else "gather"
+    check(rec["paged_read"] == want_path,
+          f"paged: the tick reads the pool through {rec['paged_read']!r}, "
+          f"not {want_path!r} ({rec['engine']})")
+    if on_chip:
+        check(rec["kernels"].get("dcp_paged_decode_attn", 0) >= 1,
+              f"paged: the block-table read is not in the compiled decode "
+              f"segment as a Mosaic call ({rec['kernels']})")
+    return {**{k: leg[k] for k in ("wall_s", "compile_s", "run_s")},
+            "paged_read": rec["paged_read"], "requests_same": len(shapes[1]),
+            "new_tokens": sum(len(t) for t in rec["served"]),
+            "kernels": rec["kernels"]}
+
+
 def four_chip_legs(size, env, corpus, requests, one, on_chip) -> dict:
     """--mesh data=4 at the same global batch, then --replicas 4, each
     checked against its one-chip leg."""
@@ -422,6 +493,8 @@ def main(argv) -> int:
             f"{one['train']['config']}")
         one["serve"] = serve_leg("serve", size, env, one["train"]["ckpt"],
                                  requests, [], min(700, left()), on_chip)
+        one["paged"] = paged_leg(env, PAGED_TINY if rehearse else PAGED_CHIP,
+                                 min(400, left()), on_chip)
         legs = dict(one)
         if probe["count"] >= 4:
             legs.update(four_chip_legs(size, env, corpus, requests, one,

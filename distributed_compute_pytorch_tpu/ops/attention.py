@@ -323,20 +323,22 @@ def gather_kv_blocks(pool_leaf, table):
     -> ``[s, B, hk, nb * bt, hd]`` — row ``b``'s logical slot ``t`` is
     ``pool_leaf[:, table[b, t // bt], :, t % bt]``.
 
-    This is the portable-XLA paged read, and its traffic is set
-    ENTIRELY by the table argument: ``O(B * nb * bt)`` bytes per layer
-    per tick for whatever ``nb`` the caller ships. The serve scheduler
-    slices the host tables to the smallest bucket-ladder rung covering
-    the live working set (``serve.py``, ISSUE 19), so a tick's gather
-    moves bytes proportional to live tokens, NOT to ``t_max`` — the
-    old fixed-horizon cost model (every tick gathering ``t_max``
-    slots, mostly trash-block reads for short rows) only returns when
-    bucketing is off (``decode_width_buckets=1``) or a session
-    actually fills the horizon. The gather still costs one extra HBM
-    round trip vs the dense per-row cache on current XLA:TPU — the
-    block-table Pallas decode kernel
-    (``ops/pallas/decode_attention.py``, ``block_tables=``) is the
-    reference for folding the table lookup into the stream itself.
+    This is the portable-XLA paged read: what every pool reads through
+    that :func:`paged_read_path` does not hand to the block-table kernel
+    (CPU, meshes, hd 64, the int8 pool, verify windows). Its traffic is
+    set ENTIRELY by the table argument: ``O(B * nb * bt)`` bytes per
+    layer per tick for whatever ``nb`` the caller ships — every row at
+    the width of the widest. The serve scheduler slices the host tables
+    to the smallest bucket-ladder rung covering the live working set
+    (``serve.py``, ISSUE 19), so a tick's gather moves bytes
+    proportional to the LONGEST live row, not to ``t_max``; the
+    fixed-horizon cost model (every tick gathering ``t_max`` slots)
+    only returns when bucketing is off (``decode_width_buckets=1``) or
+    a session actually fills the horizon. The gather is three copies on
+    current XLA:TPU (the gather, the transpose, the K/V split): 60% of
+    a Mistral-7B decode tick on the v5e (PERF.md, PR 24), which is why
+    eligible pools are read in place instead
+    (``ops/pallas/decode_attention.py::paged_decode_attention_pallas``).
     Under a mesh the gather's OUTPUT is constrained to the row-sharded
     decode layout by the caller, so attached blocks reshard into it
     via whatever collective the two layouts imply (the
@@ -361,12 +363,38 @@ def _paged_view(pool, table) -> dict:
         return view
 
 
+def paged_read_path(pool: dict, q_len: int, slot_mask=None) -> str:
+    """Which engine reads a paged pool for attention: ``"kernel"`` (the
+    block-table Pallas kernel, the pool read in place) or ``"gather"``
+    (:func:`_paged_view` + the dense cached attention). Decided from the
+    operands alone: the repo's one policy for Pallas dispatchers
+    (``cache_update._pallas_ok``: TPU backend, no mesh context, window-
+    aligned blocks), a float pool (no int8 ``scale`` leaf), ONE query
+    position, no ``slot_mask``, heads of whole 128-lane tiles, and a
+    block of all KV heads small enough for the kernel's VMEM scratch.
+    Everything else (CPU, a mesh, hd 64, the int8 pool, verify windows)
+    reads through the gather, exactly as before the kernel existed."""
+    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
+        _pallas_ok)
+    from distributed_compute_pytorch_tpu.ops.pallas.decode_attention import (
+        _chunk_blocks)
+    kv = pool["kv"]
+    eligible = (q_len == 1 and slot_mask is None and "scale" not in pool
+                and kv.shape[-1] % 128 == 0
+                and _chunk_blocks(kv.shape, kv.dtype.itemsize, 1) == 1
+                and _pallas_ok(pool, axis=3))
+    return "kernel" if eligible else "gather"
+
+
 def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     """One decode tick against the PAGED pool cache format
     ``{"kv": [2, P, hk, bt, hd], "table": int32 [B, nb]}`` (plus
     ``"scale"`` for the int8 form): row ``b`` writes its K/V at the
     physical (block, offset) its table maps logical slot ``pos[b]`` to,
-    then attends over its gathered logical view. The caller (the serve
+    then attends over its logical slots ``0 .. pos[b]``: through the
+    block table inside ``dcp_paged_decode_attn`` where
+    :func:`paged_read_path` says so, over the gathered logical view
+    otherwise. The caller (the serve
     scheduler) guarantees the written block is exclusively owned —
     shared prefix blocks are copy-on-write BEFORE a row may write into
     their span, so the write never mutates another row's reads.
@@ -374,10 +402,11 @@ def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     The working-set WIDTH flows from the table: a ``[B, nb_w]`` slice
     makes the gathered views, the position-validity masks, and the
     ``slot_mask`` plumbing all ``nb_w * bt`` wide (including the int8
-    ``scale`` leaf, gathered through the same table). The caller must
+    ``scale`` leaf, gathered through the same table); the kernel's cost
+    follows ``pos``, not the slice. The caller must
     ship a table covering ``max(pos) // bt`` — the write's
     ``take_along_axis`` clamps, which is only correct for parked rows
-    whose table is all-trash."""
+    whose table is all-trash (the kernel clamps the same way)."""
     from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
         kv_pool_insert_all)
     from distributed_compute_pytorch_tpu.utils.quantize import quantize_kv
@@ -400,9 +429,17 @@ def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
         with scope("kv_write"):
             pool = kv_pool_insert_all(pool, {"kv": jnp.stack([k, v])},
                                       blk, off)
-        view = _paged_view(pool, table)
-        out = cached_attention(q, view["k"], view["v"], pos,
-                               slot_mask=slot_mask)
+        if paged_read_path(pool, q.shape[2], slot_mask) == "kernel":
+            from distributed_compute_pytorch_tpu.ops.pallas import (
+                decode_attention)
+            # write-then-attend, as everywhere: a read placed before the
+            # aliased pool write costs XLA the in-place update
+            out = decode_attention.paged_decode_attention_pallas(
+                q, pool["kv"], table, pos)
+        else:
+            view = _paged_view(pool, table)
+            out = cached_attention(q, view["k"], view["v"], pos,
+                                   slot_mask=slot_mask)
     return out, {**pool, "table": table}
 
 

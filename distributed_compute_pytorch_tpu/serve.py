@@ -18,16 +18,21 @@ instead, with everything the TPU touches remaining static-shaped:
   from a host-side refcounted free list (``kv_pool.BlockPool``); decode
   writes resolve ``pos -> (table[pos // bt], pos % bt)`` (one window
   DMA per row on the Pallas path — ``kv_pool_insert_rows_pallas``) and
-  attention reads the row's gathered logical view
-  (``ops/attention.py::cache_write_and_attend``, paged format). Rows no
+  attention reads the row's logical slots through the same table: in
+  place, by the block-table kernel ``dcp_paged_decode_attn``, where the
+  pool is eligible (``ops/attention.py::paged_read_path``: unsharded on
+  a TPU, float, heads of 128 lanes), else over a gathered logical view
+  (``ops/attention.py::cache_write_and_attend``, paged format;
+  ``stats_snapshot()["paged_read"]`` names which). Rows no
   longer own contiguous cache memory, which is what makes PREFIX
   SHARING possible at all. Parked/free rows point at the reserved
   trash block, where their per-tick garbage writes can never corrupt a
   live or cached block. Each dispatch ships the tables SLICED to the
   smallest rung of a geometric width-bucket ladder covering the live
-  working set (``decode_width_buckets``; ISSUE 19), so per-tick KV
-  gather traffic tracks live tokens, not the horizon — one compiled
-  program per rung, token-identical at every width.
+  working set (``decode_width_buckets``; ISSUE 19), so the gathered
+  read's per-tick KV traffic tracks live tokens, not the horizon (the
+  kernel's follows each row's position whatever the rung) — one
+  compiled program per rung, token-identical at every width.
 - **Radix prefix cache** (``prefix_cache=True``): a host-side radix
   tree over prompt-HEAD tokens (``kv_pool.RadixCache``) maps a new
   request's longest cached prefix to already-prefilled blocks. The
@@ -59,7 +64,8 @@ instead, with everything the TPU touches remaining static-shaped:
   recycle indefinitely on the same compiled programs and a session
   never exhausts.
 - **Batched admission**: ALL pending prompts that fit free rows are
-  stacked into ONE compiled multi-row prefill per admission wave.
+  stacked into ONE compiled multi-row prefill per admission wave (of at
+  most ``_WAVE_TOKENS`` of prefill window; the rest wait for the next).
   Each prompt's tokens-but-the-last are prefilled (its SUFFIX past any
   cached prefix, attended against the gathered prefix K/V via the
   blocks' ``kv_prefix`` path); the LAST prompt token becomes the row's
@@ -165,7 +171,8 @@ compiled programs name their parts for the same profile with
 ``obs.tracing.scope`` (the vocabulary is listed once, in ``obs/``):
 ``admit`` round the admission prefill, ``decode`` round the segment's
 tick, and inside them the models' ``embed``/``attn``/``mlp``/``head``
-and ``kv_gather`` (the paged view at the width rung), ``kv_write``,
+and ``kv_gather`` (the paged view at the width rung, where the pool is
+read through the gather), ``kv_write``,
 ``sample``. Open-loop
 load rides in-band: ``Request.arrival_s`` delays admission to the
 request's arrival instant and the scheduler idles across arrival gaps
@@ -216,6 +223,17 @@ from distributed_compute_pytorch_tpu.utils.quantize import quantize_kv
 # instead of re-paying trace+compile (see the __init__ note).
 _PROGRAM_CACHE: dict = {}
 _PROGRAM_CACHE_LOCK = threading.Lock()
+
+# The most prefill window (rows x tokens) ONE admission wave holds. A
+# wave's activations are live all at once and every decoding row waits
+# while it runs: beside Mistral-7B's first 16 layers and a 5.4 GB pool
+# on a 16 GB v5e, 16 rows x 2048 tokens compile at 15.46 of 15.75 GB, 32
+# rows do not fit, and a row costs 0.1 s (PERF.md section 7, first
+# program defect). Requests past it wait one decode segment for the
+# next wave. Only windows this large are cut, and those are compute-
+# bound on any chip: two waves cost the device what one of their sum
+# would.
+_WAVE_TOKENS = 32768
 
 
 @dataclass
@@ -436,6 +454,8 @@ class ContinuousBatcher:
                  weights_version: int = 0):
         from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
             _pallas_ok, _window)
+        from distributed_compute_pytorch_tpu.ops.attention import (
+            paged_read_path)
         if prompt_buf > t_max:
             raise ValueError(f"prompt_buf {prompt_buf} > t_max {t_max}")
         if admit_policy not in ("fifo", "skip_fit"):
@@ -650,6 +670,9 @@ class ContinuousBatcher:
         # scatter whole-block and the program count at ~one per mode)
         self._chunk = (None if prefill_chunk_tokens is None else
                        -(-prefill_chunk_tokens // self.bt) * self.bt)
+        # rows per admission wave: each takes a whole window (the chunk,
+        # else up to prompt_buf) of the wave's static batch
+        self._wave_rows = max(1, _WAVE_TOKENS // (self._chunk or self.Tb))
         min_blocks = slots * self.nb + 1         # + the trash block
         if pool_blocks is None:
             pool_blocks = min_blocks + (4 * self.nb if prefix_cache else 0)
@@ -703,6 +726,15 @@ class ContinuousBatcher:
                 f"the KV pool (block size {self.bt}) cannot take the "
                 f"Pallas window write on this TPU: every decode tick "
                 f"would copy the whole pool through an XLA scatter")
+        # which engine READS the pool each decode tick: what the tick's
+        # trace will be told, by the same function under the same mesh
+        # context (ops/attention.py::paged_read_path): the block-table
+        # kernel over the pool in place, or the gathered logical view.
+        # Asked here and not noted by the trace: engines of one shape
+        # family share their jitted programs (_PROGRAM_CACHE), so a
+        # trace belongs to whichever engine dispatched first.
+        with self._mesh_ctx():
+            self._paged_read = paged_read_path(self._caches[0], 1)
         # HBM bytes ONE gathered block read moves per (row, layer):
         # both K/V planes of every pool leaf (the int8 scale leaf
         # rides along when present) — the unit behind
@@ -1021,6 +1053,8 @@ class ContinuousBatcher:
             "engine": self.engine_info(),
             "slo": {name: h.summary() for name, h in self._slo.items()},
             "ticks": self.ticks,
+            # static: the pool read the decode tick was compiled with
+            "paged_read": self._paged_read,
             "slot_leaks": self.last_slot_leaks,
             "block_leaks": self.last_block_leaks,
             "host_block_leaks": self.last_host_block_leaks,
@@ -1551,8 +1585,10 @@ class ContinuousBatcher:
         [B, S] next tokens and the carried state. Each tick's cache op
         is the PAGED format of ``ops/attention.py::
         cache_write_and_attend``: the write resolves through ``tables``
-        to one (block, offset) per row, attention reads the row's
-        gathered logical view. Rows not in the dispatch plan arrive with
+        to one (block, offset) per row, attention reads the row's live
+        blocks through the same table (in place where
+        ``stats_snapshot()["paged_read"]`` says ``kernel``, else a
+        gathered logical view). Rows not in the dispatch plan arrive with
         their table swapped for the all-trash row, so their unavoidable
         writes (the compiled segment ticks all rows) land in the
         reserved trash block. ``sampling`` (static) compiles the per-row
@@ -2389,9 +2425,12 @@ class ContinuousBatcher:
             suffix-token budget: rows past it admit mid-prompt (their
             slot carries the progress mark) and extend between decode
             segments via ``chunk_wave`` — a long-prompt admission storm
-            can never widen a single wave past the chunk."""
+            can never widen a single wave past the chunk. A wave takes
+            at most ``_wave_rows`` rows (``_WAVE_TOKENS`` of prefill
+            window); requests past that stay queued for the next wave,
+            one decode segment later."""
             free = [b for b, s in enumerate(table) if s.req_index < 0]
-            take = pick_admissions(len(free))
+            take = pick_admissions(min(len(free), self._wave_rows))
             if not take:
                 return
             with span("admit_wave", rows=len(take),

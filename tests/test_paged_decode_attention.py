@@ -1,0 +1,343 @@
+"""The paged decode read (``dcp_paged_decode_attn``,
+ops/pallas/decode_attention.py::paged_decode_attention_pallas): the pool
+read in place through the block table must give what the dense cached
+attention gives over the gathered logical view, at every position a
+serving row can sit at; and the dispatcher
+(``ops/attention.py::paged_read_path``) must take it exactly where the
+operands allow, nowhere else.
+
+On CPU the kernel runs in the Pallas interpreter (which models the async
+copies and their semaphores in this jax); the Mosaic-compiled kernel at
+the benchmark cell's shape runs under ``DCP_TEST_TPU=1`` on the chip,
+like tests/test_decode_attention.py."""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_compute_pytorch_tpu.core.mesh import make_mesh, use_mesh
+from distributed_compute_pytorch_tpu.ops import attention as A
+from distributed_compute_pytorch_tpu.ops.pallas.decode_attention import (
+    _CHUNK_TOKENS, _SCRATCH_BYTES, _chunk_blocks,
+    paged_decode_attention_pallas)
+
+_TPU = os.environ.get("DCP_TEST_TPU") == "1"
+on_tpu = pytest.mark.skipif(
+    not _TPU, reason="TPU-only (set DCP_TEST_TPU=1 on hardware)")
+# the tier-1 half: the interpreter, and what the rule says on a CPU backend
+on_cpu = pytest.mark.skipif(
+    _TPU, reason="CPU tier (the faked 8-device mesh, the interpreter)")
+
+BT = 8
+PARKED = -1          # a case's position for a row parked on the trash block
+
+
+def _paged_case(positions, *, hk, G, hd, nb, dtype, seed):
+    """Rows at ``positions`` (``PARKED`` = an all-trash table at position
+    0) over ONE pool: block 0 is the trash block, every row's live
+    blocks are drawn without replacement from a shuffled pool, table
+    entries past a row's live extent point at trash."""
+    rng = np.random.default_rng(seed)
+    live = [0 if p == PARKED else p // BT + 1 for p in positions]
+    n_blocks = 1 + sum(live)
+    pool = jnp.asarray(rng.standard_normal((2, n_blocks, hk, BT, hd)), dtype)
+    free = list(rng.permutation(np.arange(1, n_blocks)))
+    table = np.zeros((len(positions), nb), np.int32)
+    for b, n in enumerate(live):
+        table[b, :n] = [free.pop() for _ in range(n)]
+    q = jnp.asarray(rng.standard_normal((len(positions), hk * G, 1, hd)),
+                    dtype)
+    pos = jnp.asarray([max(p, 0) for p in positions], jnp.int32)
+    return q, pool, jnp.asarray(table), pos
+
+
+def _check(positions, *, hk, G, hd=128, nb, dtype, tol, interpret, seed=0):
+    q, pool, table, pos = _paged_case(positions, hk=hk, G=G, hd=hd, nb=nb,
+                                      dtype=dtype, seed=seed)
+    view = A.gather_kv_blocks(pool, table)
+    want = A.cached_attention(q, view[0], view[1], pos)
+    got = jax.jit(lambda *a: paged_decode_attention_pallas(
+        *a, interpret=interpret))(q, pool, table, pos)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+# positions a serving row can sit at: the first slot, a block's last and
+# the next block's first two, one short of / at / past a chunk edge, a
+# full prompt window, the horizon's last slot
+EDGE = _CHUNK_TOKENS - 1        # 511: the last slot of a DMA chunk
+POSITION_SETS = {
+    "block_edges": [0, 7, 8, 9],
+    "chunk_edges": [EDGE - 1, EDGE, EDGE + 1, 2 * _CHUNK_TOKENS],
+    "long_rows": [2047, 2559],
+    "mixed_with_parked": [9, PARKED, 2047, 0, EDGE + 1, PARKED],
+    "all_parked": [PARKED, PARKED],
+}
+
+
+@on_cpu
+@pytest.mark.parametrize("name", list(POSITION_SETS))
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_interpreted_kernel_matches_gathered_attention(name, dtype):
+    """Tier-1: the kernel's table walk, guarded copies, cross-row
+    prefetch, masks and online softmax, in the Pallas interpreter at a
+    narrow shape (2 KV heads of 4 query rows)."""
+    tol = 2e-5 if dtype == "float32" else 2e-2
+    _check(POSITION_SETS[name], hk=2, G=4, nb=320, dtype=getattr(jnp, dtype),
+           tol=tol, interpret=True)
+
+
+@on_cpu
+def test_interpreted_kernel_narrow_table_and_mha():
+    """A table slice narrower than one chunk (a low width rung: the
+    kernel must never read the table past it), one query row per KV
+    head, heads of 256 lanes, and a position beyond the shipped table
+    (a parked row's: clamped, as the gather path's write clamps)."""
+    _check([0, 15, 23], hk=3, G=1, hd=256, nb=3, dtype=jnp.float32,
+           tol=2e-5, interpret=True)
+    q, pool, table, pos = _paged_case([PARKED, 9], hk=2, G=2, hd=128, nb=2,
+                                      dtype=jnp.float32, seed=1)
+    got = paged_decode_attention_pallas(q, pool, table,
+                                        jnp.asarray([400, 9], jnp.int32),
+                                        interpret=True)
+    view = A.gather_kv_blocks(pool, table)
+    want = A.cached_attention(q, view[0], view[1],
+                              jnp.asarray([15, 9], jnp.int32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+# name: (KV heads, head width, dtype, table width) -> blocks to a chunk
+CHUNKS = {
+    "mistral_bf16": ((8, 128, "bfloat16", 320), 64),       # 512 tokens
+    "llama2_7b_mha_bf16": ((32, 128, "bfloat16", 320), 16),
+    "f32_pool_hd256": ((8, 256, "float32", 320), 16),
+    "mha_f32": ((32, 128, "float32", 320), 8),
+    "low_rung": ((8, 128, "bfloat16", 4), 4),
+    "one_block_a_buffer": ((128, 256, "float32", 320), 1),
+    "not_the_kernels": ((256, 256, "float32", 320), 0),
+}
+
+
+@on_cpu
+@pytest.mark.parametrize("name", list(CHUNKS))
+def test_chunk_follows_the_scratch_budget(name):
+    """The kernel's two VMEM buffers stay inside ``_SCRATCH_BYTES`` (a
+    quarter of what a v5e kernel may use unasked) whatever the pool's
+    heads and dtype; Mistral's shape keeps the measured 512 tokens."""
+    (hk, hd, dtype, nb_w), want = CHUNKS[name]
+    itemsize = jnp.dtype(dtype).itemsize
+    C = _chunk_blocks((2, 9, hk, BT, hd), itemsize, nb_w)
+    assert C == want
+    assert 2 * C * 2 * hk * BT * hd * itemsize <= _SCRATCH_BYTES
+
+
+@on_cpu
+def test_interpreted_kernel_budget_bound_chunk():
+    """32 KV heads in f32: the byte budget, not the token count, sets the
+    chunk (8 blocks: 64 tokens); rows short of, at and past its edges."""
+    assert _chunk_blocks((2, 9, 32, BT, 128), 4, 40) == 8
+    _check([62, 63, 64, 65, 300, PARKED], hk=32, G=1, nb=40,
+           dtype=jnp.float32, tol=2e-5, interpret=True)
+
+
+@pytest.fixture(scope="module")
+def one_v5e_chip():
+    """A DESCRIBED v5e chip: the TPU's compiler is installed here and
+    compiles for a chip that is not attached. Made inside the fixture,
+    never at import (one process at a time may load the TPU's library)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - whatever keeps libtpu away
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@on_cpu
+@pytest.mark.parametrize("name", [n for n, ((_, _, _, nb), c) in CHUNKS.items() if c and nb == 320])
+def test_kernel_compiles_for_a_described_v5e(name, one_v5e_chip):
+    """Mosaic takes the kernel at real widths, 32 rows over tables of 320
+    blocks: the scratch fits the default VMEM at every eligible shape (a
+    pool that does not compile has no second path to fall to)."""
+    (hk, hd, dtype, nb_w), _ = CHUNKS[name]
+    G = 1 if hk >= 32 else 4
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+    compiled = jax.jit(paged_decode_attention_pallas).lower(
+        arg((32, hk * G, 1, hd), dtype), arg((2, 64, hk, BT, hd), dtype),
+        arg((32, nb_w), jnp.int32), arg((32,), jnp.int32)).compile()
+    assert "dcp_paged_decode_attn" in compiled.as_text()
+
+
+@on_tpu
+@pytest.mark.parametrize("shape", ["llama2_7b_mha_bf16", "f32_pool_hd256"])
+def test_compiled_kernel_at_budget_bound_chunks(shape):
+    """Chip: pools whose 512-token chunk would not fit the default VMEM
+    (Llama-2-7B's 32 KV heads; an f32 pool of 8 x 256) run 128-token
+    chunks: block, chunk and window edges, a parked row."""
+    (hk, hd, dtype, nb_w), C = CHUNKS[shape]
+    assert C * BT == 128
+    f32 = dtype == "float32"
+    _check([0, 9, 126, 127, 128, 129, 2047, PARKED, 2559], hk=hk,
+           G=4 if f32 else 1, hd=hd, nb=nb_w, dtype=getattr(jnp, dtype),
+           tol=2e-2, interpret=False)
+
+
+@on_tpu
+@pytest.mark.parametrize("name", list(POSITION_SETS))
+def test_compiled_kernel_matches_gathered_attention_at_cell_shape(name):
+    """Chip: the Mosaic kernel at the benchmark cell's shape (Mistral-7B:
+    8 KV heads x 4 query rows x 128, blocks of 8 tokens, bf16, tables of
+    320 blocks)."""
+    _check(POSITION_SETS[name], hk=8, G=4, nb=320, dtype=jnp.bfloat16,
+           tol=2e-2, interpret=False)
+
+
+@on_tpu
+def test_compiled_kernel_float32_pool_and_low_rung():
+    _check([0, 9, 300, PARKED], hk=2, G=2, nb=64, dtype=jnp.float32,
+           tol=2e-2, interpret=False)
+    _check([0, 7, 15], hk=8, G=4, nb=2, dtype=jnp.bfloat16, tol=2e-2,
+           interpret=False)
+
+
+@on_tpu
+def test_compiled_tick_takes_the_kernel_and_matches_the_gather():
+    """The dispatcher on the chip: ``cache_write_and_attend`` over an
+    eligible pool writes then reads through the kernel, and gives what
+    the gather path gives for the same operands."""
+    q, pool, table, pos = _paged_case([9, 300, PARKED, 2047], hk=8, G=4,
+                                      hd=128, nb=320, dtype=jnp.bfloat16,
+                                      seed=3)
+    rng = np.random.default_rng(4)
+    k, v = (jnp.asarray(rng.standard_normal((4, 8, 1, 128)), jnp.bfloat16)
+            for _ in range(2))
+    cache = {"kv": pool, "table": table}
+    assert A.paged_read_path({"kv": pool}, 1) == "kernel"
+    got, new = jax.jit(A.cache_write_and_attend)(q, k, v, cache, pos)
+    blk = jnp.take_along_axis(table, (pos // BT)[:, None], axis=1)[:, 0]
+    want_pool = pool.at[:, blk, :, pos % BT].set(
+        jnp.moveaxis(jnp.stack([k, v])[:, :, :, 0], 1, 0))
+    # rows 0, 1, 3 own their written block; the parked row wrote trash
+    np.testing.assert_array_equal(np.asarray(new["kv"][:, 1:], np.float32),
+                                  np.asarray(want_pool[:, 1:], np.float32))
+    view = A.gather_kv_blocks(new["kv"], table)
+    want = A.cached_attention(q, view[0], view[1], pos)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+# ------------------------------------------------------ the eligibility rule
+
+def _pool(hd=128, dtype=jnp.bfloat16, scale=False, bt=BT, hk=2):
+    if scale:
+        return {"kv": jnp.zeros((2, 3, hk, 32, hd), jnp.int8),
+                "scale": jnp.zeros((2, 3, hk, 32, 1), jnp.float32)}
+    return {"kv": jnp.zeros((2, 3, hk, bt, hd), dtype)}
+
+
+ELIGIBILITY = {
+    # name: (backend, mesh, pool kwargs, q_len, slot_mask?, path)
+    "tpu_bf16_hd128": ("tpu", False, {}, 1, False, "kernel"),
+    "tpu_f32_hd256": ("tpu", False, {"hd": 256, "dtype": jnp.float32}, 1,
+                      False, "kernel"),
+    "cpu_backend": ("cpu", False, {}, 1, False, "gather"),
+    "under_a_mesh": ("tpu", True, {}, 1, False, "gather"),
+    "int8_pool_scale_leaf": ("tpu", False, {"scale": True}, 1, False,
+                             "gather"),
+    "verify_window_q_len_4": ("tpu", False, {}, 4, False, "gather"),
+    "slot_mask_given": ("tpu", False, {}, 1, True, "gather"),
+    "hd64_gpt2": ("tpu", False, {"hd": 64}, 1, False, "gather"),
+    "hd192_not_whole_tiles": ("tpu", False, {"hd": 192}, 1, False, "gather"),
+    "block_not_window_aligned": ("tpu", False, {"bt": 4}, 1, False,
+                                 "gather"),
+    "block_past_the_vmem_scratch": ("tpu", False, {
+        "hd": 256, "dtype": jnp.float32, "hk": 256}, 1, False, "gather"),
+}
+
+
+@on_cpu
+@pytest.mark.parametrize("name", list(ELIGIBILITY))
+def test_paged_read_path_is_decided_from_the_operands(name, monkeypatch,
+                                                      devices8):
+    """One rule, no flag: backend, mesh, pool leaves, query length, slot
+    mask and head width decide; each case names the path it takes."""
+    backend, mesh, pool_kw, q_len, masked, path = ELIGIBILITY[name]
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    pool = _pool(**pool_kw)
+    slot_mask = jnp.ones((1, 16), bool) if masked else None
+    if mesh:
+        with use_mesh(make_mesh("data=2", devices8[:2])):
+            assert A.paged_read_path(pool, q_len, slot_mask) == path
+    else:
+        assert A.paged_read_path(pool, q_len, slot_mask) == path
+
+
+@on_cpu
+@pytest.mark.parametrize("path", ["kernel", "gather"])
+def test_tick_dispatches_on_paged_read_path(path, monkeypatch):
+    """``cache_write_and_attend`` follows the rule: with it forced either
+    way (the kernel interpreted here) the same tick gives the same
+    attention output and the same pool."""
+    q, pool, table, pos = _paged_case([9, PARKED, 300], hk=2, G=4, hd=128,
+                                      nb=64, dtype=jnp.float32, seed=5)
+    rng = np.random.default_rng(6)
+    k, v = (jnp.asarray(rng.standard_normal((3, 2, 1, 128)), jnp.float32)
+            for _ in range(2))
+    from distributed_compute_pytorch_tpu.ops.pallas import decode_attention
+    called = []
+    real = decode_attention.paged_decode_attention_pallas
+
+    def interpreted(*a, **kw):
+        called.append(1)
+        return real(*a, **kw, interpret=True)
+    monkeypatch.setattr(decode_attention, "paged_decode_attention_pallas",
+                        interpreted)
+    base, base_pool = A.cache_write_and_attend(
+        q, k, v, {"kv": pool, "table": table}, pos)
+    assert not called                      # CPU: the gather, as before
+    monkeypatch.setattr(A, "paged_read_path", lambda *a, **kw: path)
+    got, got_pool = A.cache_write_and_attend(
+        q, k, v, {"kv": pool, "table": table}, pos)
+    assert bool(called) == (path == "kernel")
+    np.testing.assert_array_equal(np.asarray(got_pool["kv"]),
+                                  np.asarray(base_pool["kv"]))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(base),
+                               atol=2e-5, rtol=2e-5)
+
+
+@on_cpu
+@pytest.mark.parametrize("family", ["gpt2_hd16", "llama_hd128"])
+def test_stats_snapshot_names_the_read_path(family):
+    """An operator sees the compiled tick's read path without a profile;
+    on CPU it is the gather whatever the head width."""
+    from distributed_compute_pytorch_tpu.models.gpt2 import GPT2, GPT2Config
+    from distributed_compute_pytorch_tpu.models.llama import (
+        LlamaConfig, LlamaLM)
+    from distributed_compute_pytorch_tpu.serve import (
+        ContinuousBatcher, Request)
+    if family == "gpt2_hd16":
+        model = GPT2(GPT2Config.tiny())
+    else:
+        model = LlamaLM(dataclasses.replace(
+            LlamaConfig.tiny(), d_model=256, num_heads=2, num_kv_heads=1))
+        assert model.config.head_dim == 128
+    params, _ = model.init(jax.random.key(0))
+    cb = ContinuousBatcher(model, params, slots=2, t_max=32, prompt_buf=8,
+                           segment=4)
+    assert cb.stats_snapshot()["paged_read"] == "gather"
+    outs = cb.serve([Request(tokens=[3, 5, 7], max_new=4)])
+    assert len(outs[0]) == 4
+    assert cb.stats_snapshot()["paged_read"] == "gather"

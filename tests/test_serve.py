@@ -714,3 +714,31 @@ def test_moe_no_drop_contract_exact_parity():
                         jnp.asarray([req.tokens], jnp.int32), req.max_new)
         want = [int(t) for t in np.asarray(solo)[0, len(req.tokens):]]
         assert out == want, (i, out, want)
+
+
+def test_admission_wave_holds_at_most_wave_tokens_of_window(monkeypatch):
+    """A wave's prefill window is bounded (``serve._WAVE_TOKENS``): four
+    requests due together for four free rows go out two a wave, the
+    second wave one decode segment after the first, with the tokens of a
+    solo run; under the default bound (every other test's case) the same
+    call is one wave of four."""
+    from distributed_compute_pytorch_tpu import serve as serve_mod
+    model = GPT2(dataclasses.replace(GPT2Config.tiny(), max_seq_len=128))
+    params, _ = model.init(jax.random.key(0))
+    reqs = _requests(np.random.default_rng(11), 4)
+    calls, default = {}, serve_mod._WAVE_TOKENS
+    for bound in (default, 20):
+        monkeypatch.setattr(serve_mod, "_WAVE_TOKENS", bound)
+        cb = ContinuousBatcher(model, params, slots=4, t_max=128,
+                               prompt_buf=10, segment=2)
+        outs = cb.serve(reqs)
+        calls[bound] = (cb._wave_rows, cb.stats["prefill_calls"],
+                        cb.stats["prefill_rows"])
+        for req, out in zip(reqs, outs):
+            solo = generate(model, params,
+                            jnp.asarray([req.tokens], jnp.int32),
+                            req.max_new)
+            assert out == [int(t)
+                           for t in np.asarray(solo)[0, len(req.tokens):]]
+    assert calls[20] == (2, 2, 4)
+    assert calls[default][1:] == (1, 4)
