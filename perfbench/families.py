@@ -1,81 +1,69 @@
-"""The one place a model is named in code: a configuration's ``family``
-maps to the program's ``build_model`` name, the keyword arguments made
-from the published config's keys, the plain reference that goes with it,
-the config's dropout keys, and the shapes of its attention kernel's calls.
-A new family adds one entry here and a reference module."""
+"""A configuration's ``family`` names a module, ``perfbench/family/
+<family>.py``, found by that name the way a configuration, a traffic mix,
+a cell, a metric and a reader are found by theirs. Everything that is
+specific to a family sits behind this one lookup: how the program builds
+the model, the plain reference that goes with it, the config's dropout
+keys, the call shapes of its kernels, and its own counts of operations and
+bytes. A new family adds a module there and a reference module; no file is
+edited (``README.md``, "Adding things")."""
 
 from __future__ import annotations
 
 import importlib
+import pathlib
+
+from perfbench import bytes as nbytes
+from perfbench import flops
+
+_HERE = pathlib.Path(__file__).resolve().parent
 
 
-def _gpt2_kwargs(cfg: dict, run: dict) -> dict:
-    import jax.numpy as jnp
-    # the program has one rate, for the embedding and residual dropout;
-    # its flash-attention path has no dropout on the attention weights
-    # (``attn_pdrop`` is not applied: PERF.md, Open questions)
-    assert cfg["embd_pdrop"] == cfg["resid_pdrop"]
-    return dict(
-        num_layers=cfg["n_layer"], d_model=cfg["n_embd"],
-        num_heads=cfg["n_head"], d_ff=cfg.get("n_inner") or 4 * cfg["n_embd"],
-        vocab_size=cfg["vocab_size"], max_seq_len=cfg["n_positions"],
-        dropout_rate=float(cfg["resid_pdrop"]),
-        remat=run.get("remat", False),
-        param_dtype=jnp.dtype(run.get("param_dtype", "float32")))
-
-
-def _llama_kwargs(cfg: dict, run: dict) -> dict:
-    import jax.numpy as jnp
-    return dict(
-        vocab_size=cfg["vocab_size"], max_seq_len=run["max_seq_len"],
-        num_layers=cfg["num_hidden_layers"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_model=cfg["hidden_size"], d_ff=cfg["intermediate_size"],
-        rope_theta=float(cfg["rope_theta"]),
-        rms_eps=float(cfg["rms_norm_eps"]),
-        param_dtype=jnp.dtype(run.get("param_dtype", "bfloat16")))
-
-
-def _gpt2_train_attention(cfg: dict, counters: dict, chips: int) -> dict:
-    """Shapes of one flash-attention call of a train step: per-chip batch
-    x heads, lengths, head size."""
-    return dict(batch_heads=counters["global_batch"] // chips * cfg["n_head"],
-                q_len=counters["seq_len"], kv_len=counters["seq_len"],
-                head_dim=cfg["n_embd"] // cfg["n_head"])
-
-
-FAMILIES = {
-    "gpt2": {"build_model": "gpt2", "kwargs": _gpt2_kwargs,
-             "reference": "perfbench.reference.gpt2_ref",
-             "dropout_keys": ("attn_pdrop", "embd_pdrop", "resid_pdrop"),
-             "train_attention_shape": _gpt2_train_attention},
-    "mistral": {"build_model": "llama", "kwargs": _llama_kwargs,
-                "reference": "perfbench.reference.llama_ref",
-                "dropout_keys": ()},
-}
+def family(cfg: dict):
+    """The module of the configuration's family."""
+    name = f"perfbench.family.{cfg['family']}"
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise LookupError(
+            f"no family {cfg['family']!r}: looked for "
+            f"{_HERE / 'family' / (cfg['family'] + '.py')}") from None
 
 
 def without_dropout(cfg: dict) -> dict:
     """``cfg`` with the family's dropout keys at 0 (``cfg`` itself, equal,
     where they already are)."""
-    return {k: (0.0 if k in FAMILIES[cfg["family"]]["dropout_keys"] else v)
-            for k, v in cfg.items()}
+    keys = family(cfg).DROPOUT_KEYS
+    return {k: (0.0 if k in keys else v) for k, v in cfg.items()}
 
 
-def attention_shape(cfg: dict, which: str, counters: dict, chips: int):
-    """The call shapes of the family's attention kernel in a run of kind
-    ``which`` (a key ``<which>_attention_shape`` of the family's entry);
-    None where the family does not say."""
-    fn = FAMILIES[cfg["family"]].get(f"{which}_attention_shape")
-    return None if fn is None else fn(cfg, counters, chips)
+def kernel_shape(cfg: dict, which: str, counters: dict, chips: int):
+    """The shapes of one call of a kernel in a run, under the name
+    ``which`` that a kernel-roofline metric's file gives (``train``,
+    ``decode``): keyword arguments for the operation and byte functions
+    the file names. None where the family does not say."""
+    fn = getattr(family(cfg), "kernel_shapes", None)
+    return None if fn is None else fn(cfg, which, counters, chips)
+
+
+def count_fn(cfg: dict, name: str):
+    """The operation or byte function called ``name``: the family's own
+    where it has one, else the kernel's in ``flops.py`` / ``bytes.py``."""
+    for mod in (family(cfg), flops, nbytes):
+        fn = getattr(mod, name, None)
+        if callable(fn):
+            return fn
+    raise LookupError(
+        f"no count {name!r} in perfbench/family/{cfg['family']}.py, "
+        f"perfbench/flops.py or perfbench/bytes.py")
 
 
 def reference_module(cfg: dict):
-    return importlib.import_module(FAMILIES[cfg["family"]]["reference"])
+    return importlib.import_module(family(cfg).REFERENCE)
 
 
 def build_program_model(cfg: dict, run: dict):
     from distributed_compute_pytorch_tpu.models.registry import build_model
-    fam = FAMILIES[cfg["family"]]
-    return build_model(fam["build_model"], **fam["kwargs"](cfg, run))
+    fam = family(cfg)
+    return build_model(fam.BUILD_MODEL, **fam.model_kwargs(cfg, run))

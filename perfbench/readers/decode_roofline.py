@@ -1,11 +1,11 @@
-"""Roofline share of the decode tick: (weight bytes + K/V bytes of the
-live contexts, per tick) / HBM bandwidth over the device time per tick of
-the segment module, in %. Bytes from ``perfbench.bytes``; memory bound by
-construction (one token per slot per tick). Never clipped. Source:
-device_trace and program_counter."""
+"""Roofline share of the decode tick: the bytes one tick over all slots
+has to read (``decode_tick_bytes(cfg, live_context_tokens)`` of the
+configuration's family: for a dense model the weights + the K/V of the
+live contexts) / HBM bandwidth over the device time per tick of the
+segment module, in %. Memory bound by construction (one token per slot per
+tick). Never clipped. Source: device_trace and program_counter."""
 
-from perfbench import bytes as nbytes
-from perfbench import peaks
+from perfbench import families, peaks
 
 
 def read(spec, ctx):
@@ -17,7 +17,8 @@ def read(spec, ctx):
     live = ctx["counters"].get("mean_live_context_tokens")
     if ticks <= 0 or secs <= 0 or live is None:
         return None
-    by = nbytes.decode_tick_bytes(ctx["config"], live)
+    by = families.count_fn(ctx["config"], "decode_tick_bytes")(
+        ctx["config"], live)
     floor = by / peaks.peaks_for(ctx["device_kind"])["hbm_bytes_per_s"]
     return {"value": 100.0 * floor / (secs / ticks),
             "note": f"memory bound; {by / 1e9:.3f} GB per tick, "

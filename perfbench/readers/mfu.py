@@ -1,16 +1,16 @@
 """Model FLOP/s utilization of a training cell: tokens/s x FLOPs/token
-(from ``perfbench.flops``, recomputation not counted) over chips x the
-published bf16 peak, in %. Never clipped. Source: host_clock (the
-run's own tokens/s) and the table of peaks."""
+(``flops_fn`` of the configuration's family, recomputation not counted)
+over chips x the published bf16 peak, in %. Never clipped. Source:
+host_clock (the run's own tokens/s) and the table of peaks."""
 
-from perfbench import flops, peaks
+from perfbench import families, peaks
 
 
 def read(spec, ctx):
     tps = ctx["counters"].get("train_tokens_per_s")
     if not tps:
         return None
-    fn = getattr(flops, spec["flops_fn"])
+    fn = families.count_fn(ctx["config"], spec["flops_fn"])
     per_token = fn(ctx["config"], ctx["counters"]["seq_len"])
     peak = peaks.peaks_for(ctx["device_kind"])["bf16_flops"] * ctx["chips"]
     return {"value": 100.0 * tps * per_token / peak,

@@ -1,11 +1,13 @@
 """Roofline share of a named kernel: the least time the chip could take
-for the calls traced (operations and bytes from ``perfbench.flops`` /
-``perfbench.bytes`` of the call's shapes, times the number of calls
-traced) over the kernel's device time, in %. Says which bound it was taken
-against; never clipped. Source: device_trace."""
+for the calls traced (``flops_fn`` and ``bytes_fn`` of one call's shapes,
+times the number of calls traced) over the kernel's device time, in %. The
+call's shapes, ``causal`` and the like included, come from the
+configuration's family under the name ``shape`` (``families.kernel_shape``)
+and go to both functions as they come; the functions are the family's own
+or those of ``flops.py`` / ``bytes.py`` (``families.count_fn``). Says which
+bound it was taken against; never clipped. Source: device_trace."""
 
-from perfbench import bytes as nbytes
-from perfbench import families, flops, peaks
+from perfbench import families, peaks
 
 
 def read(spec, ctx):
@@ -16,14 +18,13 @@ def read(spec, ctx):
     calls = tr.op_count(spec["kernels"][0])
     if secs <= 0 or calls <= 0:
         return None
-    # the call's shapes come from the configuration's family
-    # (``families.py``): the metric's file says which kind of run
-    shape = families.attention_shape(ctx["config"], spec["shape"],
-                                     ctx["counters"], ctx["chips"])
+    cfg = ctx["config"]
+    shape = families.kernel_shape(cfg, spec["shape"], ctx["counters"],
+                                  ctx["chips"])
     if shape is None:
         return None
-    fl = getattr(flops, spec["flops_fn"])(causal=True, **shape) * calls
-    by = getattr(nbytes, spec["bytes_fn"])(**shape) * calls
+    fl = families.count_fn(cfg, spec["flops_fn"])(**shape) * calls
+    by = families.count_fn(cfg, spec["bytes_fn"])(**shape) * calls
     r = peaks.roofline(fl, by, secs, ctx["device_kind"])
     return {"value": r["share"],
             "note": f"{r['bound']} bound; {calls:.0f} calls, "

@@ -9,6 +9,12 @@ in this list; ``pass``: ``fwd`` or ``bwd`` keeps the operations outside or
 inside ``transpose(``. With ``of_module`` only operations that start inside
 such a module's execution count.
 
+A file without ``scope`` reads the time under NO declared scope: besides
+its ``except`` it leaves out every name of the program's vocabulary
+(``obs.tracing.SCOPES``, handed over by the run as ``ctx["scopes"]``) but
+those in ``wraps``, the scopes that wrap its whole program (``admit``,
+``decode``). A scope declared later is so left out without an edit here.
+
 Time is SELF time: an operation that holds others (a ``while`` and its
 body) counts for what its children leave. A fusion carries the path XLA
 gave it, its root's: an elementwise operation fused into a neighbour's
@@ -76,9 +82,14 @@ def _wanted(names, backward, spec) -> bool:
     return not any(n in spec.get("except", ()) for n in names)
 
 
-def share(lines: dict, spec: dict):
+def share(lines: dict, spec: dict, vocabulary=()):
     """The share over the device planes of ``lines`` (as
-    ``host_plane.device_lines`` gives them), or None."""
+    ``host_plane.device_lines`` gives them), or None. ``vocabulary``: the
+    scope names the program declares."""
+    if not spec.get("scope"):
+        spec = {**spec, "except": sorted(
+            set(spec.get("except", ()))
+            | (set(vocabulary) - set(spec.get("wraps", ()))))}
     declared = set(spec.get("scope", ())) | set(spec.get("except", ()))
     rx = re.compile(spec["of_module"]) if spec.get("of_module") else None
     seen, shares = False, []
@@ -117,4 +128,4 @@ def share(lines: dict, spec: dict):
 def read(spec, ctx):
     if ctx["trace"] is None:
         return None
-    return share(host_plane.device_lines(), spec)
+    return share(host_plane.device_lines(), spec, ctx.get("scopes", ()))

@@ -105,8 +105,12 @@ class Env:
 def layer_metrics(env, result, device_kind):
     """Every per-layer metric of the manifest that lists this cell (or
     lists none), read by the reader its file names."""
+    from distributed_compute_pytorch_tpu.obs import tracing
     here = ROOT / "perfbench"
     ctx = {"spans": result.get("spans"), "counters": result["counters"],
+           # the scope names the program declares (none in a program from
+           # before it had any): what "under no declared scope" leaves out
+           "scopes": tuple(getattr(tracing, "SCOPES", ())),
            "trace": result.get("trace"), "requests": result.get("requests"),
            "e2e": result["e2e"], "config": env.config,
            "traffic": env.traffic, "cell": env.cell, "chips": env.chips,

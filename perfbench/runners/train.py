@@ -81,7 +81,8 @@ class Program:
         config = Config(
             batch_size=gb, lr=self.opt["lr"],
             epochs=self.opt["schedule_epochs"], mesh=f"data={env.chips}",
-            model=cfg["family"], dataset="synthetic-lm", optimizer="adamw",
+            model=families.family(cfg).BUILD_MODEL, dataset="synthetic-lm",
+            optimizer="adamw",
             weight_decay=self.opt.get("weight_decay", 0.0),
             warmup_steps=self.opt.get("warmup_steps", 0), log_every=spe,
             seed=env.seed & 0x7FFFFFFF, compute_dtype=run_kw["compute_dtype"],

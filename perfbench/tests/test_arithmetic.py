@@ -8,6 +8,7 @@ import pytest
 
 from perfbench import bytes as nbytes
 from perfbench import flops, peaks
+from perfbench.family import gpt2, mistral
 from perfbench.percentiles import percentile, samples_beyond
 
 CONFIGS = pathlib.Path(__file__).resolve().parent.parent / "configs"
@@ -40,8 +41,8 @@ def test_percentile_counts_a_missing_request_as_infinite():
 ])
 def test_gpt2_train_flops_per_token(name, matmul_params, gflop):
     c = cfg(name)
-    assert flops.gpt2_matmul_params(c) == matmul_params
-    per_token = flops.gpt2_train_flops_per_token(c, 1024)
+    assert gpt2.gpt2_matmul_params(c) == matmul_params
+    per_token = gpt2.gpt2_train_flops_per_token(c, 1024)
     # 6 x matmul parameters + 6 x layers x T x d (causal half, fwd + bwd)
     assert per_token == 6 * matmul_params + 6 * c["n_layer"] * 1024 * c["n_embd"]
     assert per_token / 1e9 == pytest.approx(gflop, abs=5e-3)
@@ -50,16 +51,34 @@ def test_gpt2_train_flops_per_token(name, matmul_params, gflop):
 def test_mistral_bytes():
     c = cfg("mistral-7b-v0.3-l16")
     # 2 (K,V) x 8 KV heads x 128 x 2 B x 16 layers = 64 KiB a token
-    assert nbytes.kv_bytes_per_token(c) == 65536
+    assert mistral.kv_bytes_per_token(c) == 65536
     per_layer = 4096 * 4096 * 2 + 2 * 4096 * 1024 + 3 * 4096 * 14336
     assert per_layer == 218_103_808
-    assert flops.llama_matmul_params(c) == 16 * per_layer + 32768 * 4096
+    assert mistral.llama_matmul_params(c) == 16 * per_layer + 32768 * 4096
     # weights a tick reads: blocks + head + norms, bf16: 7.25 GB (the
     # embedding table, 0.27 GB more on the chip, is a gather of 32 rows)
-    assert nbytes.llama_weight_bytes(c) == 2 * (
+    assert mistral.llama_weight_bytes(c) == 2 * (
         16 * per_layer + 32768 * 4096 + 33 * 4096)
-    assert nbytes.decode_tick_bytes(c, 10_000) == (
-        nbytes.llama_weight_bytes(c) + 10_000 * 65536)
+    assert mistral.decode_tick_bytes(c, 10_000) == (
+        mistral.llama_weight_bytes(c) + 10_000 * 65536)
+
+
+def test_paged_decode_attention_counts_one_layers_live_context():
+    c = cfg("mistral-7b-v0.3-l16")
+    from perfbench import families
+    shape = families.kernel_shape(c, "decode",
+                                  {"mean_live_context_tokens": 3500.0}, 1)
+    assert shape == dict(live_context_tokens=3500.0, q_heads=32, kv_heads=8,
+                         head_dim=128, itemsize=2)
+    # K and V of 3500 tokens of ONE layer: a sixteenth of the tick's K/V
+    by = families.count_fn(c, "paged_decode_attn_bytes")(**shape)
+    assert by == 3500 * 2 * 8 * 128 * 2 == 3500 * 65536 / 16
+    fl = families.count_fn(c, "paged_decode_attn_flops")(**shape)
+    assert fl == 4 * 3500 * 32 * 128
+    r = peaks.roofline(fl, by, by / 819e9 / 0.25, "TPU v5 lite")
+    assert r["bound"] == "memory" and r["share"] == pytest.approx(25.0)
+    # no live contexts counted in the run: nothing to read
+    assert families.kernel_shape(c, "decode", {}, 1) is None
 
 
 def test_flash_counts_the_causal_half():
@@ -82,14 +101,32 @@ def test_peaks_table_and_roofline_never_clip():
     assert r["bound"] == "memory" and r["share"] == pytest.approx(50.0)
 
 
-def test_attention_call_shapes_come_from_the_family_table():
+def test_kernel_call_shapes_come_from_the_family_module():
     from perfbench import families
     counters = {"global_batch": 32, "seq_len": 1024}
-    got = families.attention_shape(cfg("gpt2-large"), "train", counters, 4)
+    got = families.kernel_shape(cfg("gpt2-large"), "train", counters, 4)
     assert got == dict(batch_heads=8 * 20, q_len=1024, kv_len=1024,
-                       head_dim=64)
-    assert families.attention_shape(cfg("mistral-7b-v0.3-l16"), "train",
-                                    counters, 1) is None
+                       head_dim=64, causal=True)
+    # the shape goes to the operation and the byte function as it comes
+    assert flops.flash_fwd_flops(**got) == 4 * 160 * (1024 * 1025 / 2) * 64
+    assert nbytes.flash_fwd_bytes(**got) == 160 * 64 * 2 * 4096
+    assert families.kernel_shape(cfg("mistral-7b-v0.3-l16"), "train",
+                                 counters, 1) is None
+    assert families.kernel_shape(cfg("gpt2-large"), "decode", counters,
+                                 1) is None
+
+
+def test_an_unknown_family_or_count_fails_with_where_it_was_looked_for():
+    from perfbench import families
+    with pytest.raises(LookupError, match=r"perfbench/family/nosuch\.py"):
+        families.family({"family": "nosuch"})
+    with pytest.raises(LookupError, match="family/gpt2.py.*flops.py"):
+        families.count_fn(cfg("gpt2-medium"), "no_such_count")
+    # the family's module is asked first, the kernels' modules second
+    assert families.count_fn(cfg("gpt2-medium"), "flash_fwd_flops") \
+        is flops.flash_fwd_flops
+    assert families.count_fn(cfg("mistral-7b-v0.3-l16"), "decode_tick_bytes") \
+        is mistral.decode_tick_bytes
 
 
 @pytest.mark.parametrize("name, changed", [

@@ -100,6 +100,67 @@ def test_a_loop_counts_for_what_its_body_leaves():
     assert share(scope=["decode"]) == pytest.approx(100 * 1000 / 1100)
 
 
+def unscoped_file(which):
+    import json
+    import pathlib
+    here = pathlib.Path(__file__).resolve().parent.parent
+    return json.load(open(here / "layer_metrics" / f"unscoped_share.{which}.json"))
+
+
+DECODE = [
+    op("while.1", 0, 1000, "jit(_segment_impl)/jit(main)/decode/while"),
+    op("fusion.2", 0, 300, "jit(_segment_impl)/jit(main)/decode/while/body/attn/kv_gather/gather"),
+    op("fusion.3", 300, 400, "jit(_segment_impl)/jit(main)/decode/while/body/attn/dot_general"),
+    op("fusion.4", 700, 200, "jit(_segment_impl)/jit(main)/decode/while/body/mlp/dot_general"),
+    op("fusion.5", 1000, 100, "jit(_segment_impl)/jit(main)/transpose"),
+]
+
+
+def test_unscoped_files_read_what_is_under_no_declared_scope():
+    """The two files as they are, over the hand-made lines: the values the
+    listed form gave (10.0 over the train lines, 100 x 200 / 1100 over the
+    decode lines), from the vocabulary the run hands over alone."""
+    from distributed_compute_pytorch_tpu.obs.tracing import SCOPES
+    train = lines(TRAIN, (("jit_train_step(1)", 0, 1000),
+                          ("jit_eval(2)", 1500, 400)))
+    decode = lines(DECODE, (("jit__segment_impl(9)", 0, 1100),))
+    for which, ls, want in (("train", train, 10.0),
+                            ("decode", decode, 100 * 200 / 1100)):
+        spec = unscoped_file(which)
+        assert set(spec["wraps"]) <= {"admit", "decode"} and "scope" not in spec
+        assert scoped.share(ls, spec, SCOPES) == pytest.approx(want)
+        # the list the file still carries (tier-1's test reads it) adds
+        # nothing to the vocabulary
+        bare = {k: v for k, v in spec.items() if k != "except"}
+        assert scoped.share(ls, bare, SCOPES) == pytest.approx(want)
+        assert set(spec["except"]) <= set(SCOPES) - set(spec["wraps"])
+
+
+def test_a_scope_declared_later_is_left_out_of_both_unscoped_shares():
+    """One more name in the vocabulary the run hands over, no file
+    edited: its operations leave both shares."""
+    from distributed_compute_pytorch_tpu.obs.tracing import SCOPES
+    assert "router" not in SCOPES
+    train = lines(TRAIN + [op("fusion.10", 1000, 100, P + "jvp(router)/top_k")],
+                  (("jit_train_step(1)", 0, 1100),))
+    S = "jit(_segment_impl)/jit(main)/decode/while/body/"
+    decode = lines(DECODE[:-1] + [op("fusion.6", 900, 100, S + "router/top_k"),
+                                  DECODE[-1]],
+                   (("jit__segment_impl(9)", 0, 1100),))
+    later = tuple(SCOPES) + ("router",)
+    for which, ls, without, with_ in (
+            ("train", train, 100 * 200 / 1100, 100 * 100 / 1100),
+            # the router's 100 come out of the loop's own self time either
+            # way; declared, they no longer count as unscoped
+            ("decode", decode, 100 * 200 / 1100, 100 * 100 / 1100)):
+        spec = unscoped_file(which)
+        assert scoped.share(ls, spec, SCOPES) == pytest.approx(without)
+        assert scoped.share(ls, spec, later) == pytest.approx(with_)
+    # a share that names its scope takes no notice of the vocabulary
+    assert scoped.share(train, {"scope": ["attn"], "of_module": "^jit_train"},
+                        later) == pytest.approx(100 * 300 / 1100)
+
+
 def span(name, start, dur, thread="/host:CPU#0:python3", **args):
     return {"thread": thread, "name": name, "args": args,
             "start": float(start), "dur": float(dur)}
