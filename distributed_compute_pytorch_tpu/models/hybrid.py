@@ -1,0 +1,338 @@
+"""A decoder built from a list of layer kinds: layer ``i`` is a (mixer,
+feed-forward) pair chosen by ``layer_types[i]`` and ``mlp_layer_types[i]``
+(ROADMAP C6: a hybrid is a configuration, not a file of its own).
+
+Mixers: ``"full_attention"`` (causal over the whole context) and
+``"sliding_attention"`` (causal over the last ``sliding_window`` tokens,
+the token itself counted). Feed-forwards: ``"dense"`` (SwiGLU) and
+``"sparse"`` (routed experts with a shared expert, of which this chip
+holds ``experts_held``: ``models/moe.py::HeldExperts``). The rest is the
+Llama recipe (``models/llama.py``: bias-free q/k/v/o at GQA width,
+half-split RoPE, RMSNorm, untied head) with three switches a published
+family sets: ``qk_norm`` (an RMSNorm over each head's channels of q and
+of k, before any rotation), ``rope_sliding_only`` (full layers rotate
+nothing) and the norm placement, which here is AFTER each sublayer
+(``h = h + norm(sublayer(h))``, no norm on a sublayer's input).
+
+The layers differ in shape, so the parameters are a per-layer list
+(``params["layers"][i]``), not one stacked tree, and the serving layer
+asks for the block and the parameters of layer ``i`` (``layer_block`` /
+``layer_params``) and the block for its ``cache_kind``: a full layer
+keeps the paged pool, a sliding layer a ring of ``ring_tokens`` slots a
+row that does not grow with the horizon (``ops/attention.py::
+ring_write_and_attend``).
+
+Served path only: ``apply`` (the whole forward, what the tests compare
+with the reference) and the cache protocol work; ``loss_fn``, the
+pipeline and the tensor-parallel rules raise ``NotImplementedError``.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from distributed_compute_pytorch_tpu.models import layers as L
+from distributed_compute_pytorch_tpu.models.moe import HeldExperts
+from distributed_compute_pytorch_tpu.models.transformer import (
+    dispatch_attention)
+from distributed_compute_pytorch_tpu.obs.tracing import scope
+from distributed_compute_pytorch_tpu.ops import attention as A
+from distributed_compute_pytorch_tpu.ops.rotary import apply_rope
+
+MIXERS = ("full_attention", "sliding_attention")
+MLPS = ("dense", "sparse")
+
+
+@dataclass(frozen=True)
+class HybridConfig:
+    vocab_size: int = 32000
+    max_seq_len: int = 2048
+    layer_types: tuple = ("sliding_attention", "full_attention")
+    mlp_layer_types: tuple = ("dense", "sparse")
+    sliding_window: int = 128
+    num_heads: int = 8
+    num_kv_heads: int = 2
+    head_dim: int = 64
+    d_model: int = 512
+    d_ff: int = 1024               # the dense layers' SwiGLU width
+    qk_norm: bool = True
+    rope_sliding_only: bool = True
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-6
+    # the sparse layers' experts (models/moe.py::HeldExperts)
+    num_experts: int = 8           # the router's width
+    experts_held: tuple = None     # (first, count); None = all
+    top_k: int = 2
+    moe_d_ff: int = 256
+    shared_d_ff: int = 256         # 0 = no shared expert
+    routed_scale: float = 1.0
+    norm_topk_prob: bool = True
+    param_dtype: jnp.dtype = jnp.float32
+
+    def __post_init__(self):
+        if len(self.layer_types) != len(self.mlp_layer_types):
+            raise ValueError(
+                f"layer_types ({len(self.layer_types)}) and mlp_layer_types "
+                f"({len(self.mlp_layer_types)}) name different depths")
+        for kinds, known in ((self.layer_types, MIXERS),
+                             (self.mlp_layer_types, MLPS)):
+            for k in kinds:
+                if k not in known:
+                    raise ValueError(f"layer kind {k!r} is none of {known}")
+        if self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_heads={self.num_heads} must be a multiple of "
+                f"num_kv_heads={self.num_kv_heads}")
+
+    @property
+    def num_layers(self) -> int:
+        return len(self.layer_types)
+
+    @classmethod
+    def tiny(cls) -> "HybridConfig":
+        """Every kind at toy sizes: a leading dense layer, then a period
+        of sliding/full layers over experts of which half are held."""
+        return cls(vocab_size=256, max_seq_len=64,
+                   layer_types=("sliding_attention",) * 3
+                   + ("full_attention", "sliding_attention"),
+                   mlp_layer_types=("dense",) + ("sparse",) * 4,
+                   sliding_window=8, num_heads=4, num_kv_heads=2,
+                   head_dim=16, d_model=64, d_ff=128, num_experts=8,
+                   experts_held=(0, 4), top_k=2, moe_d_ff=32,
+                   shared_d_ff=32, routed_scale=2.5)
+
+
+def _dense(din, dout):
+    return L.Dense(din, dout, use_bias=False)
+
+
+@dataclass(frozen=True)
+class HybridBlock:
+    """One layer: its mixer and its feed-forward, both followed by their
+    RMSNorm before the residual add."""
+
+    config: HybridConfig
+    mixer: str
+    mlp: str
+
+    @property
+    def window(self) -> int | None:
+        return (self.config.sliding_window
+                if self.mixer == "sliding_attention" else None)
+
+    @property
+    def cache_kind(self) -> str:
+        return "ring" if self.window else "paged"
+
+    def experts(self) -> HeldExperts:
+        c = self.config
+        return HeldExperts(c.d_model, c.moe_d_ff, c.num_experts, c.top_k,
+                           experts_held=c.experts_held,
+                           shared_d_ff=c.shared_d_ff,
+                           routed_scale=c.routed_scale,
+                           norm_topk_prob=c.norm_topk_prob,
+                           param_dtype=c.param_dtype)
+
+    def init(self, key):
+        c = self.config
+        ks = iter(jax.random.split(key, 8))
+        d, hd = c.d_model, c.head_dim
+        dense = lambda din, dout: L.Dense(din, dout, use_bias=False,
+                                          param_dtype=c.param_dtype)
+        p = {"q": dense(d, c.num_heads * hd).init(next(ks)),
+             "k": dense(d, c.num_kv_heads * hd).init(next(ks)),
+             "v": dense(d, c.num_kv_heads * hd).init(next(ks)),
+             "o": dense(c.num_heads * hd, d).init(next(ks)),
+             "post_attn_norm": L.RMSNorm(d, c.rms_eps).init(None),
+             "post_mlp_norm": L.RMSNorm(d, c.rms_eps).init(None)}
+        if c.qk_norm:
+            p["q_norm"] = L.RMSNorm(hd, c.rms_eps).init(None)
+            p["k_norm"] = L.RMSNorm(hd, c.rms_eps).init(None)
+        if self.mlp == "dense":
+            p.update(gate=dense(d, c.d_ff).init(next(ks)),
+                     up=dense(d, c.d_ff).init(next(ks)),
+                     down=dense(c.d_ff, d).init(next(ks)))
+        else:
+            p["moe"] = self.experts().init(next(ks))
+        return p
+
+    def _qkv(self, params, x, positions):
+        """Projected q/k/v at GQA width; QK-norm, then rotation where
+        this layer's kind rotates."""
+        c = self.config
+        d, hd = c.d_model, c.head_dim
+        q = A.split_heads(_dense(d, c.num_heads * hd).apply(params["q"], x),
+                          c.num_heads)
+        k = A.split_heads(_dense(d, c.num_kv_heads * hd).apply(params["k"], x),
+                          c.num_kv_heads)
+        v = A.split_heads(_dense(d, c.num_kv_heads * hd).apply(params["v"], x),
+                          c.num_kv_heads)
+        if c.qk_norm:
+            norm = L.RMSNorm(hd, c.rms_eps)
+            q = norm.apply(params["q_norm"], q)
+            k = norm.apply(params["k_norm"], k)
+        if self.window or not c.rope_sliding_only:
+            q = apply_rope(q, positions, c.rope_theta)
+            k = apply_rope(k, positions, c.rope_theta)
+        return q, k, v
+
+    def _attn_out(self, params, x, o):
+        c = self.config
+        a = _dense(c.num_heads * c.head_dim, c.d_model).apply(
+            params["o"], A.merge_heads(o))
+        return x + L.RMSNorm(c.d_model, c.rms_eps).apply(
+            params["post_attn_norm"], a)
+
+    def _mlp(self, params, x, token_mask=None, counts_sink=None):
+        c = self.config
+        with scope("mlp"):
+            if self.mlp == "dense":
+                g = jax.nn.silu(_dense(c.d_model, c.d_ff).apply(
+                    params["gate"], x))
+                m = _dense(c.d_ff, c.d_model).apply(
+                    params["down"],
+                    g * _dense(c.d_model, c.d_ff).apply(params["up"], x))
+            else:
+                m = self.experts().apply(params["moe"], x,
+                                         token_mask=token_mask,
+                                         counts_sink=counts_sink)
+            return x + L.RMSNorm(c.d_model, c.rms_eps).apply(
+                params["post_mlp_norm"], m)
+
+    def apply(self, params, x, *, kv_mask=None, kv_sink=None,
+              positions=None, counts_sink=None):
+        """The whole-sequence forward of one layer (prefill). ``kv_sink``
+        captures the K/V a cache stores (after QK-norm and rotation, at
+        kv-head width); ``kv_mask`` (``[B, T]``, 1 = real) hides pad keys
+        and keeps pad tokens out of the experts."""
+        T = x.shape[1]
+        with scope("attn"):
+            pos = jnp.arange(T) if positions is None else positions
+            q, k, v = self._qkv(params, x, pos)
+            if kv_sink is not None:
+                kv_sink.append((k, v))
+            if self.window:
+                with scope("attn_local"):
+                    o = A.attention(q, k, v, causal=True, kv_mask=kv_mask,
+                                    window=self.window)
+            else:
+                o = dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask)
+            x = self._attn_out(params, x, o)
+        return self._mlp(params, x, token_mask=kv_mask,
+                         counts_sink=counts_sink)
+
+    def decode_step(self, params, x, cache, pos, slot_mask=None,
+                    counts_sink=None, live=None):
+        """One cached decode tick, ``x [B, 1, d]`` at per-row slots ``pos
+        [B]``. ``cache`` is this layer's kind: the paged pool with its
+        table, or a ring ``{"kv": [2, B, hk, R, hd]}``. ``live`` (``[B]``, 1 =
+        a row in the plan) keeps parked rows out of the experts."""
+        with scope("attn"):
+            rope_pos = (pos[:, None] if jnp.ndim(pos) == 1
+                        else jnp.atleast_1d(pos))
+            q, k, v = self._qkv(params, x, rope_pos)
+            if self.window:
+                with scope("attn_local"):
+                    o, cache = A.ring_write_and_attend(
+                        q, k, v, cache, pos, self.window)
+            else:
+                o, cache = A.cache_write_and_attend(q, k, v, cache, pos,
+                                                    slot_mask=slot_mask)
+            x = self._attn_out(params, x, o)
+        # a parked row (live 0) routes nowhere: its token is garbage, and
+        # the experts' counts are of the rows in the plan
+        return self._mlp(params, x, token_mask=live,
+                         counts_sink=counts_sink), cache
+
+
+@dataclass(frozen=True)
+class HybridLM:
+    config: HybridConfig = HybridConfig()
+
+    # --- what the serving layer asks, layer by layer ---
+
+    @property
+    def num_layers(self) -> int:
+        return self.config.num_layers
+
+    def layer_block(self, i: int) -> HybridBlock:
+        c = self.config
+        return HybridBlock(c, c.layer_types[i], c.mlp_layer_types[i])
+
+    def layer_params(self, params, i: int):
+        return params["layers"][i]
+
+    @property
+    def ring_tokens(self) -> int:
+        """Slots of a sliding layer's ring: the window, in whole cache
+        write windows (``ops/pallas/cache_update.py``)."""
+        return -(-self.config.sliding_window // 16) * 16
+
+    def counted_experts(self) -> int:
+        """Held experts a sparse layer counts loads for (0: no sparse
+        layer, no counters)."""
+        c = self.config
+        if "sparse" not in c.mlp_layer_types:
+            return 0
+        return (c.experts_held or (0, c.num_experts))[1]
+
+    def kv_cache_spec(self):
+        return self.config.num_kv_heads, self.config.head_dim
+
+    def init(self, key):
+        c = self.config
+        ks = jax.random.split(key, c.num_layers + 2)
+        return {
+            "wte": L.Embedding(c.vocab_size, c.d_model,
+                               param_dtype=c.param_dtype).init(ks[0]),
+            "layers": [self.layer_block(i).init(ks[1 + i])
+                       for i in range(c.num_layers)],
+            "norm_f": L.RMSNorm(c.d_model, c.rms_eps).init(None),
+            "lm_head": L.Dense(c.d_model, c.vocab_size, use_bias=False,
+                               param_dtype=c.param_dtype).init(ks[-1]),
+        }, {}
+
+    def embed(self, params, tokens, positions=None):
+        del positions          # rotation lives in the sliding layers
+        c = self.config
+        with scope("embed"):
+            return L.Embedding(c.vocab_size, c.d_model).apply(params["wte"],
+                                                              tokens)
+
+    def readout(self, params, x):
+        c = self.config
+        with scope("head"):
+            x = L.RMSNorm(c.d_model, c.rms_eps).apply(params["norm_f"], x)
+            return L.Dense(c.d_model, c.vocab_size,
+                           use_bias=False).apply(params["lm_head"], x)
+
+    def apply(self, params, state, tokens, *, train: bool = False, rng=None,
+              kv_mask=None):
+        """``tokens [B, T]`` -> logits ``[B, T, vocab]``."""
+        del rng
+        if train:
+            raise NotImplementedError(
+                "HybridLM is served, not trained: its experts are one "
+                "chip's share and have no backward path")
+        x = self.embed(params, tokens)
+        for i in range(self.num_layers):
+            x = self.layer_block(i).apply(params["layers"][i], x,
+                                          kv_mask=kv_mask)
+        return self.readout(params, x), state
+
+    def _untrained(self, what):
+        raise NotImplementedError(
+            f"HybridLM has no {what}: only the served path (apply without "
+            f"train, ContinuousBatcher) is supported")
+
+    def loss_fn(self, logits, tokens):
+        self._untrained("loss_fn")
+
+    def loss_sum(self, logits, tokens):
+        self._untrained("loss_sum")
+
+    def partition_rules(self):
+        self._untrained("tensor-parallel partition rules")

@@ -39,6 +39,7 @@ from jax.sharding import PartitionSpec as P
 
 from distributed_compute_pytorch_tpu.core.mesh import current_mesh
 from distributed_compute_pytorch_tpu.models import layers as L
+from distributed_compute_pytorch_tpu.obs.tracing import scope
 
 
 # sharding pin that composes with the pipeline's manual regions (moved to
@@ -339,6 +340,183 @@ class MoELayer:
         aux = {"lb_loss": lb_loss, "z_loss": z_loss,
                "dropped_fraction": dropped}
         return y.reshape(B, T, d), aux
+
+
+@dataclass(frozen=True)
+class HeldExperts:
+    """Dropless routed experts with a shared expert, as ONE chip of an
+    expert-parallel set sees them: the router is ``num_experts`` wide,
+    this chip is told which experts it holds (``experts_held = (first,
+    count)``) and computes only the assignments that fall on them, plus
+    the shared expert. The result is this chip's partial sum; on one chip
+    there is no exchange, and nothing here stands in for the other chips.
+
+    Routing (the aux-loss-free form): ``s = sigmoid(W_r h)`` in float32
+    over all experts; the ``top_k`` experts with the largest ``s_e + b_e``
+    (``b`` a per-expert selection bias that does not enter the weights);
+    ``w_e = routed_scale * s_e / sum_{e' in T} s_e'`` when
+    ``norm_topk_prob``; every expert a SwiGLU. No capacity: no token is
+    dropped whatever the skew.
+
+    Two static shapes, two forms (both read every held expert's weights
+    once): up to ``dense_max_tokens`` tokens (a decode tick: a handful of
+    tokens an expert, bound by the weight stream) every held expert runs
+    over every token under its weight, zero where the token did not pick
+    it; above (an admission wave) the held assignments are sorted by
+    expert and go through grouped matrix products (``lax.ragged_dot``) in
+    windows of a static number of rows: one window when the load is near
+    uniform, more under skew, so the cost follows the assignments and
+    never the worst case. ``token_mask`` (1 = real) keeps pad tokens out:
+    they route nowhere.
+    """
+
+    d_model: int
+    d_ff: int                       # width of one routed expert
+    num_experts: int                # the router's width
+    top_k: int
+    experts_held: tuple = None      # (first, count); None = all of them
+    shared_d_ff: int = 0            # 0 = no shared expert
+    routed_scale: float = 1.0
+    norm_topk_prob: bool = True
+    dense_max_tokens: int = 512
+    param_dtype: jnp.dtype = jnp.float32
+
+    @property
+    def held(self) -> tuple:
+        first, count = self.experts_held or (0, self.num_experts)
+        if not (0 <= first and count >= 1
+                and first + count <= self.num_experts):
+            raise ValueError(
+                f"experts_held {self.experts_held} outside the "
+                f"{self.num_experts} experts of the router")
+        return int(first), int(count)
+
+    def init(self, key):
+        _, n = self.held
+        d, f, pd = self.d_model, self.d_ff, self.param_dtype
+        ks = jax.random.split(key, 8)
+        u = lambda k, shape, fan_in: jax.random.uniform(
+            k, shape, pd, -fan_in ** -0.5, fan_in ** -0.5)
+        p = {"router": {"kernel": u(ks[0], (d, self.num_experts), d)},
+             "router_bias": jnp.zeros((self.num_experts,), jnp.float32),
+             "experts": {"gate": u(ks[1], (n, d, f), d),
+                         "up": u(ks[2], (n, d, f), d),
+                         "down": u(ks[3], (n, f, d), f)}}
+        if self.shared_d_ff:
+            sf = self.shared_d_ff
+            p["shared"] = {"gate": {"kernel": u(ks[4], (d, sf), d)},
+                           "up": {"kernel": u(ks[5], (d, sf), d)},
+                           "down": {"kernel": u(ks[6], (sf, d), sf)}}
+        return p
+
+    def route(self, params, x):
+        """``x [N, d]`` -> (experts ``[N, k]`` int32, weights ``[N, k]``
+        float32), over the whole router."""
+        with scope("router"):
+            logits = jnp.dot(x, params["router"]["kernel"].astype(x.dtype),
+                             preferred_element_type=jnp.float32)
+            s = jax.nn.sigmoid(logits)
+            _, idx = jax.lax.top_k(
+                s + params["router_bias"].astype(jnp.float32), self.top_k)
+            w = jnp.take_along_axis(s, idx, axis=-1)
+            if self.norm_topk_prob:
+                w = w / jnp.sum(w, -1, keepdims=True)
+            return idx.astype(jnp.int32), w * self.routed_scale
+
+    def window_rows(self, n_tokens: int) -> int:
+        """Rows of one window of the sorted form: an eighth more than a
+        uniform router sends to the held experts, in whole 512s."""
+        _, n = self.held
+        expect = n_tokens * self.top_k * n / self.num_experts
+        return int(-(-(1.125 * expect) // 512) * 512)
+
+    def _dense(self, ex, x, local, w):
+        """Every held expert over every token: ``[n, N, d]`` products
+        under the token's weight for that expert (0 where not chosen)."""
+        _, n = self.held
+        onehot = (local[:, :, None] == jnp.arange(n)[None, None, :])
+        we = jnp.sum(jnp.where(onehot, w[:, :, None], 0.0), axis=1)  # [N, n]
+        xe = jnp.broadcast_to(x[None], (n,) + x.shape)
+        mm = lambda a, b: jnp.einsum("enk,ekf->enf", a, b.astype(a.dtype),
+                                     preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(mm(xe, ex["gate"])) * mm(xe, ex["up"])).astype(
+            x.dtype)
+        y = mm(h, ex["down"])                                    # [n, N, d]
+        return jnp.einsum("end,ne->nd", y, we).astype(x.dtype)
+
+    def _sorted(self, ex, x, local, w):
+        """Held assignments sorted by expert, grouped products over
+        windows of ``window_rows`` sorted rows, scatter-added back."""
+        _, n = self.held
+        N, k = local.shape
+        A = N * k
+        C = min(self.window_rows(N), -(-A // 8) * 8)
+        key = local.reshape(A)                 # n = not held / pad: last
+        order = jnp.argsort(key, stable=True)
+        tok = (order // k).astype(jnp.int32)
+        ws = w.reshape(A)[order]
+        sizes = jnp.bincount(key, length=n + 1)[:n].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        total = ends[-1]
+        # the windows read [s, s + C) of the sorted order: pad it so the
+        # last one never clamps back over rows already done
+        tok = jnp.concatenate([tok, jnp.zeros((C,), jnp.int32)])
+        ws = jnp.concatenate([ws, jnp.zeros((C,), ws.dtype)])
+        rd = lambda a, b, g: jax.lax.ragged_dot(
+            a, b.astype(a.dtype), g, preferred_element_type=jnp.float32)
+
+        def window(carry):
+            s, out = carry
+            t = jax.lax.dynamic_slice(tok, (s,), (C,))
+            wt = jax.lax.dynamic_slice(ws, (s,), (C,))
+            live = (s + jnp.arange(C)) < total
+            g = (jnp.clip(ends - s, 0, C)
+                 - jnp.clip(ends - sizes - s, 0, C)).astype(jnp.int32)
+            xg = x[t]
+            h = (jax.nn.silu(rd(xg, ex["gate"], g))
+                 * rd(xg, ex["up"], g)).astype(x.dtype)
+            y = rd(h, ex["down"], g) * jnp.where(live, wt, 0.0)[:, None]
+            # rows past the window's groups are unspecified by ragged_dot
+            y = jnp.where(live[:, None], y, 0.0).astype(out.dtype)
+            return s + C, out.at[t].add(y)
+
+        _, out = jax.lax.while_loop(
+            lambda c: c[0] < total, window,
+            (jnp.int32(0), jnp.zeros_like(x)))
+        return out
+
+    def apply(self, params, x, token_mask=None, counts_sink=None):
+        """``x [..., d]`` -> this chip's partial ``m`` of the same shape.
+        ``counts_sink`` (a list) is handed one int32 vector ``[2 +
+        count]``: the assignments of the unmasked tokens, those among
+        them that fell on held experts, and the held experts' loads."""
+        first, n = self.held
+        shape = x.shape
+        x = x.reshape(-1, shape[-1])
+        idx, w = self.route(params, x)
+        local = idx - first
+        on = (local >= 0) & (local < n)
+        if token_mask is not None:
+            on = on & (token_mask.reshape(-1, 1) > 0.5)
+        local = jnp.where(on, local, n)
+        if counts_sink is not None:
+            live = (jnp.ones((x.shape[0],), jnp.int32) if token_mask is None
+                    else (token_mask.reshape(-1) > 0.5).astype(jnp.int32))
+            load = jnp.bincount(local.reshape(-1), length=n + 1)[:n]
+            counts_sink.append(jnp.concatenate([
+                jnp.stack([jnp.sum(live) * self.top_k, jnp.sum(load)]),
+                load]).astype(jnp.int32))
+        with scope("experts"):
+            form = (self._dense if x.shape[0] <= self.dense_max_tokens
+                    else self._sorted)
+            y = form(params["experts"], x, local, w)
+        if self.shared_d_ff:
+            with scope("shared_expert"):
+                sp = params["shared"]
+                mm = lambda a, b: jnp.dot(a, b["kernel"].astype(a.dtype))
+                y = y + mm(jax.nn.silu(mm(x, sp["gate"])) * mm(x, sp["up"]),
+                           sp["down"])
+        return y.reshape(shape)
 
 
 @dataclass(frozen=True)

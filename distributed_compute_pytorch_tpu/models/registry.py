@@ -46,4 +46,9 @@ def build_model(name: str, **kw: Any):
         from distributed_compute_pytorch_tpu.models.llama import (
             LlamaConfig, LlamaLM)
         return LlamaLM(_transformer_config(LlamaConfig, LlamaConfig(), kw))
+    if name == "hybrid":
+        from distributed_compute_pytorch_tpu.models.hybrid import (
+            HybridConfig, HybridLM)
+        return HybridLM(_transformer_config(HybridConfig, HybridConfig(),
+                                            kw))
     raise ValueError(f"unknown model {name!r}")

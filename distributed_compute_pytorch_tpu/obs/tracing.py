@@ -56,11 +56,15 @@ from distributed_compute_pytorch_tpu.obs import flight, metrics
 # The device-side vocabulary, one name per layer boundary of PERF.md
 # section 3. ``dropout`` nests (``attn/dropout``, ``mlp/dropout``,
 # ``embed/dropout``); ``kv_gather``/``kv_write``/``sample`` nest under
-# ``admit``/``decode``. The benchmark's scope metrics
+# ``admit``/``decode``; ``router``/``experts``/``shared_expert`` nest in
+# ``mlp`` (routed-expert layers, ``models/moe.py::HeldExperts``) and
+# ``attn_local`` in ``attn`` (a window layer's attention: the banded
+# prefill and the ring read). The benchmark's scope metrics
 # (``perfbench/layer_metrics``) name these and nothing else.
 SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "optimizer", "grad_reduce",
-          "admit", "decode", "kv_gather", "kv_write", "sample")
+          "admit", "decode", "kv_gather", "kv_write", "sample",
+          "router", "experts", "shared_expert", "attn_local")
 
 
 def scope(name: str):

@@ -75,7 +75,7 @@ def _pick_block(t: int) -> int | None:
 
 def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
               kv_mask=None, impl: str = "auto", block_q: int | None = None,
-              block_k: int | None = None):
+              block_k: int | None = None, window: int | None = None):
     """Attention dispatcher: the Pallas flash kernel on TPU when shapes
     allow, the fused-by-XLA dense path otherwise.
 
@@ -85,9 +85,16 @@ def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
 
     impl: 'auto' (flash on TPU, dense elsewhere) | 'pallas' (force flash,
     interpret-mode off-TPU — used by tests) | 'xla' (force dense).
+
+    ``window``: causal self-attention over the last ``window`` keys (row
+    ``i`` sees ``j`` with ``i - window < j <= i``); ``k``/``v`` may then
+    stay at their own (fewer) heads. The banded flash forward skips what
+    lies outside the band; the dense path masks it.
     """
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
+    if window is not None:
+        return _window_attention(q, k, v, window, scale, kv_mask, impl)
     t, tk = q.shape[-2], k.shape[-2]
     # largest block dividing the length, else the MXU default — the flash
     # wrapper pads-and-masks non-multiples internally (r5; the old dense
@@ -109,6 +116,27 @@ def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
     mask = None if kv_mask is None else kv_mask[:, None, None, :].astype(bool)
     return dot_product_attention(q, k, v, causal=causal, scale=scale,
                                  mask=mask)
+
+
+def _window_attention(q, k, v, window, scale, kv_mask, impl):
+    """The two engines of a window layer's prefill (see
+    :func:`attention`). No mesh: a window layer is served off-mesh."""
+    if impl == "pallas" or (impl == "auto"
+                            and jax.default_backend() == "tpu"):
+        from distributed_compute_pytorch_tpu.ops.pallas.flash_attention \
+            import flash_attention_band
+        return flash_attention_band(q, k, v, window=window, scale=scale,
+                                    kv_mask=kv_mask)
+    t = q.shape[-2]
+    rep = q.shape[1] // k.shape[1]
+    if rep > 1:
+        k, v = jnp.repeat(k, rep, axis=1), jnp.repeat(v, rep, axis=1)
+    row = jnp.arange(t)[:, None]
+    col = jnp.arange(t)[None, :]
+    mask = ((col <= row) & (col > row - window))[None, None]
+    if kv_mask is not None:
+        mask = mask & kv_mask[:, None, None, :].astype(bool)
+    return dot_product_attention(q, k, v, scale=scale, mask=mask)
 
 
 def _flash_per_shard(q, k, v, kv_mask, **kw):
@@ -554,3 +582,54 @@ def cache_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     cache = kv_insert_all(cache, {"kv": jnp.stack([k, v])}, pos)
     return cached_attention(q, cache["kv"][0], cache["kv"][1], pos,
                             slot_mask=slot_mask), cache
+
+
+def ring_positions(pos, ring: int):
+    """The logical position each slot of a ring holds once position
+    ``pos [B]`` is written at slot ``pos % ring``: ``[B, ring]``, the
+    newest position at or before ``pos`` congruent to the slot; negative
+    where the row has not reached the slot yet."""
+    slots = jnp.arange(ring)[None, :]
+    return pos[:, None] - (pos[:, None] - slots) % ring
+
+
+def ring_write_and_attend(q, k, v, cache, pos, window: int):
+    """One decode tick of a WINDOW layer against its ring
+    ``{"kv": [2, B, hk, R, hd]}`` (``R >= window`` slots a row, whatever
+    the horizon): row ``b`` writes its K/V at slot ``pos[b] % R`` (the
+    kv-pair window write of the contiguous cache, in place), then attends
+    the whole ring under a position mask: slot ``s`` holds position
+    ``ring_positions(pos)[b, s]`` and counts if that is a position of the
+    row (``>= 0``) within the window (``> pos - window``). What a slot
+    held before (an older position, another request) is overwritten or
+    masked, never attended. Returns ``(o [B, H, 1, hd], new_cache)``."""
+    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
+        kv_insert_all)
+    ring = cache["kv"]
+    R = ring.shape[3]
+    pos = jnp.broadcast_to(jnp.atleast_1d(pos), (q.shape[0],))
+    with scope("kv_write"):
+        ring = kv_insert_all({"kv": ring}, {"kv": jnp.stack([k, v])},
+                             pos % R)["kv"]
+    held = ring_positions(pos, R)
+    valid = (held >= 0) & (held > (pos[:, None] - window))
+    B, H, q_len, hd = q.shape
+    hk = ring.shape[2]
+    # GQA reads the narrow ring: the group folds into the query dim
+    qg = q.reshape(B, hk, (H // hk) * q_len, hd)
+    out = dot_product_attention(qg, ring[0], ring[1],
+                                mask=valid[:, None, None, :])
+    return out.reshape(B, H, q_len, hd), {"kv": ring}
+
+
+def ring_from_prefill(k, v, n_tokens, ring: int):
+    """The ring rows a prefill leaves behind: ``k``/``v [K, hk, T, hd]``
+    hold positions ``0..T-1`` of which the first ``n_tokens [K]`` are real;
+    slot ``s`` takes the newest real position congruent to it (the last
+    ``min(n, ring)`` tokens), as :func:`ring_positions` will read them.
+    Slots the row has not reached hold a clamped copy that the position
+    mask hides. Returns ``[2, K, hk, ring, hd]``."""
+    src = ring_positions(n_tokens - 1, ring)              # [K, ring]
+    idx = jnp.clip(src, 0, k.shape[2] - 1)[:, None, :, None]
+    take = lambda a: jnp.take_along_axis(a, idx, axis=2)
+    return jnp.stack([take(k), take(v)])

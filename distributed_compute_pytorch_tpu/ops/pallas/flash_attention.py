@@ -76,7 +76,8 @@ def _out_struct(shape, dtype, like):
 # ---------------------------------------------------------------------------
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
-                scale, causal, offset, masked, block_q, block_k):
+                scale, causal, offset, masked, block_q, block_k,
+                window=None, nband=None):
     if masked:
         mask_ref, o_ref, lse_ref, acc, m_s, l_s = rest
     else:
@@ -93,8 +94,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
     # `offset` = kv_len - q_len (static): bottom-right-aligned causal for
     # cross-length attention (masked long-prompt prefill) — query row i
     # sits at absolute kv position i + offset. offset=0 is self-attention.
-    run = (ki * block_k < (qi + 1) * block_q + offset) if causal \
-        else (ki == ki)
+    # BANDED (`window`, self-attention with block_q == block_k): the kv
+    # grid axis walks only the `nband` blocks that can hold a key of the
+    # band, ending on the diagonal; `kb` is the block a step really holds
+    # (the index map clamps a block before the first to 0, and the step
+    # is skipped).
+    kb = ki if window is None else qi - (nband - 1) + ki
+    if window is not None:
+        run = kb >= 0
+    else:
+        run = (ki * block_k < (qi + 1) * block_q + offset) if causal \
+            else (ki == ki)
 
     @pl.when(run)
     def _compute():
@@ -109,9 +119,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         if causal:
             rows = offset + qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
+            cols = kb * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
             s = jnp.where(rows >= cols, s, _NEG_INF)
+            if window is not None:
+                # row i sees keys j with i - window < j <= i
+                s = jnp.where(cols > rows - window, s, _NEG_INF)
         if masked:
             # [1, Bk] f32 0/1 key-validity row broadcast down the q rows.
             # _NEG_INF (not -inf) keeps fully-masked rows NaN-free: their
@@ -150,22 +163,51 @@ def _mask_spec(heads, block_k):
 
 
 def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
-               block_q, block_k):
+               block_q, block_k, window=None, kv_heads=None):
     bh, t, d = q.shape
     tk = k.shape[1]
-    grid = (bh, t // block_q, tk // block_k)
     masked = kv_mask is not None
-    kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               offset=offset, masked=masked,
-                               block_q=block_q, block_k=block_k)
+    if window is None:
+        grid = (bh, t // block_q, tk // block_k)
+        kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
+                                   offset=offset, masked=masked,
+                                   block_q=block_q, block_k=block_k)
+        kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+        mask_spec = _mask_spec(heads, block_k) if masked else None
+        name = "dcp_flash_fwd"
+    else:
+        # the band: kv blocks qi - (nband - 1) .. qi of each q block, K/V
+        # read at THEIR head count (query head h reads kv head h // G:
+        # no repeated copy of K/V is made for the kernel)
+        assert causal and offset == 0 and block_q == block_k and t == tk
+        nband = -(-(window - 1) // block_k) + 1
+        grid = (bh, t // block_q, nband)
+        kernel = functools.partial(_fwd_kernel, scale=scale, causal=True,
+                                   offset=0, masked=masked,
+                                   block_q=block_q, block_k=block_k,
+                                   window=window, nband=nband)
+        hk = kv_heads or heads
+        g = heads // hk
+
+        def kv_row(b):
+            return (b // heads) * hk + (b % heads) // g
+
+        def kv_blk(i, j):
+            return jnp.maximum(i - (nband - 1) + j, 0)
+
+        kv_spec = pl.BlockSpec(
+            (1, block_k, d), lambda b, i, j: (kv_row(b), kv_blk(i, j), 0))
+        mask_spec = pl.BlockSpec(
+            (1, 1, block_k),
+            lambda b, i, j: (b // heads, 0, kv_blk(i, j))) if masked else None
+        name = "dcp_flash_fwd_band"
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
+        kv_spec, kv_spec,
     ]
     args = [q, k, v]
     if masked:
-        in_specs.append(_mask_spec(heads, block_k))
+        in_specs.append(mask_spec)
         args.append(kv_mask)
     o, lse = pl.pallas_call(
         kernel,
@@ -189,7 +231,7 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
         # only the kv axis carries the accumulator dependency
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        name="dcp_flash_fwd",
+        name=name,
         interpret=_use_interpret(),
     )(*args)
     return o, lse
@@ -425,6 +467,77 @@ def _flash_masked_vjp_bwd(heads, scale, causal, offset, block_q, block_k,
 
 
 _flash_masked.defvjp(_flash_masked_vjp_fwd, _flash_masked_vjp_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_band(q, k, v, kv_mask, heads, kv_heads, scale, window, block):
+    o, _ = _flash_fwd(q, k, v, kv_mask, heads, scale, True, 0, block, block,
+                      window=window, kv_heads=kv_heads)
+    return o
+
+
+def _flash_band_vjp_fwd(q, k, v, kv_mask, heads, kv_heads, scale, window,
+                        block):
+    return _flash_band(q, k, v, kv_mask, heads, kv_heads, scale, window,
+                       block), None
+
+
+def _flash_band_vjp_bwd(*_):
+    raise NotImplementedError(
+        "the banded flash forward (window=) has no backward: window "
+        "layers are served, not trained")
+
+
+_flash_band.defvjp(_flash_band_vjp_fwd, _flash_band_vjp_bwd)
+
+
+def band_block(window: int) -> int:
+    """Block of the banded forward: four times the window in a power of
+    two, 128..1024. Two blocks a query block hold the band; a smaller
+    block multiplies less outside it but the grid grows, and a grid step
+    costs more than its small products (window 128 at 2 rows x 64 heads
+    x 2048 on a v5e: blocks of 128 / 256 / 512 took 3.08 / 2.76 / 2.29
+    ms, PERF.md PR 28)."""
+    b = 128
+    while b < 4 * window and b < 1024:
+        b *= 2
+    return b
+
+
+def flash_attention_band(q, k, v, *, window: int,
+                         scale: float | None = None, kv_mask=None,
+                         block: int | None = None):
+    """Causal self-attention over a WINDOW: ``q [b, h, t, d]``, ``k``/``v``
+    ``[b, hk, t, d]`` with ``hk`` dividing ``h`` (grouped queries read
+    their KV head in place); row ``i`` sees keys ``j`` with ``i - window <
+    j <= i``. KV blocks wholly outside the band are never visited (the
+    grid's kv axis is the band's few blocks), blocks on its edge are
+    masked. Forward only. ``kv_mask`` as :func:`flash_attention`."""
+    b, h, t, d = q.shape
+    hk = k.shape[1]
+    if k.shape[2] != t or h % hk:
+        raise ValueError(
+            f"banded flash attention is self-attention over grouped heads: "
+            f"q {q.shape}, k {k.shape}")
+    scale = (d ** -0.5) if scale is None else scale
+    block = block or band_block(window)
+    pad = (-t) % block
+    if pad:
+        # padded keys lie after every real row, out of its causal reach
+        q, k, v = (jnp.pad(a, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                   for a in (q, k, v))
+    tp = t + pad
+    mask3 = None
+    if kv_mask is not None:
+        if kv_mask.shape != (b, t):
+            raise ValueError(f"kv_mask shape {kv_mask.shape} != {(b, t)}")
+        mask3 = jnp.pad(kv_mask.astype(jnp.float32),
+                        ((0, 0), (0, pad))).reshape(b, 1, tp)
+    o = _flash_band(q.reshape(b * h, tp, d), k.reshape(b * hk, tp, d),
+                    v.reshape(b * hk, tp, d), mask3, h, hk, scale, window,
+                    block)
+    o = o.reshape(b, h, tp, d)
+    return o[:, :, :t, :] if pad else o
 
 
 def flash_attention(q, k, v, *, causal: bool = False,

@@ -520,7 +520,23 @@ class ContinuousBatcher:
         self.eos_id = eos_id
         self.admit_policy = admit_policy
         self._mesh = mesh
-        self._block = model._block()
+        # a model built from a list of layer kinds (models/hybrid.py) is
+        # asked for the block, the parameters and the cache kind of layer
+        # i; every other family stacks ONE block kind (model._block(),
+        # params["blocks"] with a leading layer axis)
+        self._layer_blocks = (
+            [model.layer_block(i) for i in range(model.num_layers)]
+            if hasattr(model, "layer_block") else None)
+        if self._layer_blocks is not None:
+            self._refuse_for_layer_kinds(
+                prefix_cache=prefix_cache, speculate=speculate,
+                tiers=(host_cache_mb is not None
+                       or host_cache_blocks is not None
+                       or disk_cache_dir is not None),
+                kv_dtype=kv_dtype, mesh=mesh,
+                prefill_chunk_tokens=prefill_chunk_tokens)
+        self._block = (self._layer_blocks[0] if self._layer_blocks
+                       is not None else model._block())
         # does the block rope internally (needs absolute-slot positions
         # at admission)? Llama does; GPT-2/MoE embed positions instead.
         sig = inspect.signature(self._block.apply).parameters
@@ -610,8 +626,10 @@ class ContinuousBatcher:
             self._dp = dp
         else:
             self._dp = 1
-        n_layers = int(jax.tree_util.tree_leaves(
-            params["blocks"])[0].shape[0])
+        n_layers = (len(self._layer_blocks)
+                    if self._layer_blocks is not None else
+                    int(jax.tree_util.tree_leaves(
+                        params["blocks"])[0].shape[0]))
         # compute dtype == the dtype most floating parameter elements are
         # in (bf16 serving params -> bf16 activations, whatever the f32
         # norm scales say; int8-quantized trees surface their float
@@ -707,19 +725,47 @@ class ContinuousBatcher:
         # _POOL_SPEC, so the narrower leaf reuses the spec) — every
         # consumer of the pool dict (attention ops, COW copies,
         # reset/reconstruct zeroing) treats the leaves generically.
+        #
+        # TWO KINDS of cache in this one list: a layer whose kind is
+        # "ring" (a window layer of models/hybrid.py) keeps, instead of
+        # a pool, {"kv": [2, slots, hk, R, hd]} (the per-row contiguous
+        # cache format, no table): the last R >= window tokens of every
+        # slot, written at pos % R and read whole under a position mask
+        # (ops/attention.py::ring_write_and_attend). Its bytes a slot do
+        # not grow with t_max, and it takes no part in the block table,
+        # the radix cache or copy-on-write. self._cache_kinds says which
+        # layer is which.
+        self._cache_kinds = (("paged",) * n_layers
+                             if self._layer_blocks is None else
+                             tuple(b.cache_kind for b in self._layer_blocks))
+        if "paged" not in self._cache_kinds:
+            raise ValueError(
+                "a model of window layers only is not served: the "
+                "scheduler's block accounting needs one paged layer")
+        self._paged0 = self._cache_kinds.index("paged")
         self._caches = [
             {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
                          _POOL_SPEC),
              **({"scale": zeros((2, pool_blocks, hk, self.bt, 1),
                                 jnp.float32, _POOL_SPEC)}
                 if kv_dtype == "int8" else {})}
-            for _ in range(n_layers)]
+            if kind == "paged" else
+            {"kv": zeros((2, slots, hk, model.ring_tokens, hd), dtype,
+                         None)}
+            for kind in self._cache_kinds]
+        # the stats that each entry of a decode tick's count vector adds
+        # to (none for a model without held experts)
+        held = (model.counted_experts()
+                if hasattr(model, "counted_experts") else 0)
+        self._count_keys = (("expert_assignments", "expert_assignments_held")
+                            + tuple(f"expert_load_{e}" for e in range(held))
+                            if held else ())
         # which engine writes the pool each tick is decided by where the
         # pool lives: the Pallas window write off-mesh on TPU, the XLA
         # scatter under a mesh (a Mosaic call cannot be partitioned) and
         # on CPU. Off-mesh on TPU there is no second choice to fall to.
-        self._pallas_write = mesh is None and _pallas_ok(self._caches[0],
-                                                         axis=3)
+        self._pallas_write = mesh is None and _pallas_ok(
+            self._caches[self._paged0], axis=3)
         if (jax.default_backend() == "tpu" and mesh is None
                 and not self._pallas_write):
             raise ValueError(
@@ -734,14 +780,26 @@ class ContinuousBatcher:
         # family share their jitted programs (_PROGRAM_CACHE), so a
         # trace belongs to whichever engine dispatched first.
         with self._mesh_ctx():
-            self._paged_read = paged_read_path(self._caches[0], 1)
+            self._paged_read = paged_read_path(
+                self._caches[self._paged0], 1)
+        if (self._layer_blocks is not None and decode_width_buckets is None
+                and self._paged_read == "kernel"):
+            # the kernel's traffic follows each row's position whatever
+            # rung the table was shipped at (PERF.md, PR 25), so for a
+            # pool read in place the ladder buys nothing and costs a
+            # compiled segment a rung, the top one reached only by a row
+            # past half the horizon: mid-traffic. A model of layer kinds
+            # starts with the one full-width rung; the families that were
+            # served before it keep their ladder (and their programs).
+            self._width_ladder = (self.nb,)
+            self._cur_width = self.nb
         # HBM bytes ONE gathered block read moves per (row, layer):
         # both K/V planes of every pool leaf (the int8 scale leaf
         # rides along when present) — the unit behind
         # serve.width.bytes_saved_vs_full
         self._gather_block_bytes = sum(
             leaf.nbytes // leaf.shape[1]
-            for leaf in self._caches[0].values())
+            for leaf in self._caches[self._paged0].values())
         row_spec = P(("data", "fsdp"))
         self._cur_tok = zeros((slots,), jnp.int32, row_spec)
         self._n_logical = zeros((slots,), jnp.int32, row_spec)
@@ -924,7 +982,13 @@ class ContinuousBatcher:
             # copies, and the pool's peak allocated fraction
             "prefix_hits": 0, "cached_prefix_tokens": 0,
             "prefill_tokens_saved": 0, "cow_copies": 0,
-            "block_pool_occupancy": 0.0})
+            "block_pool_occupancy": 0.0,
+            # routed experts (models/moe.py::HeldExperts), counted on the
+            # device by the decode ticks and fetched with their tokens:
+            # assignments of the rows in the plan (ticks x top_k), those
+            # that fell on the experts this chip holds, and each held
+            # expert's load
+            **dict.fromkeys(self._count_keys, 0)})
         self.last_slot_leaks = 0   # rows still owned at serve() exit
         self.last_block_leaks = 0  # pool refs unaccounted at serve() exit
                                    # (both must be 0 — asserted by tests
@@ -1055,6 +1119,10 @@ class ContinuousBatcher:
             "ticks": self.ticks,
             # static: the pool read the decode tick was compiled with
             "paged_read": self._paged_read,
+            # static: per layer, "paged" (the block pool) or "ring"
+            "cache_kinds": list(self._cache_kinds),
+            **({"expert_load_max_over_mean": self._expert_load_spread()}
+               if self._count_keys else {}),
             "slot_leaks": self.last_slot_leaks,
             "block_leaks": self.last_block_leaks,
             "host_block_leaks": self.last_host_block_leaks,
@@ -1065,11 +1133,17 @@ class ContinuousBatcher:
             "mem": device_memory_gauges(self.obs, prefix="serve.mem."),
         }
 
+    def _expert_load_spread(self):
+        """Largest held expert's load over the mean load (1.0 = even);
+        None before any assignment was counted."""
+        load = [self.stats[key] for key in self._count_keys[2:]]
+        return max(load) * len(load) / sum(load) if sum(load) else None
+
     def engine_info(self) -> dict:
         """Where and in what dtype this engine runs, as IT sees it: a
         bf16 checkpoint must show bf16 weights and pool here, a replica
         its own device, and the pool write the engine that performs it."""
-        pool = self._caches[0]["kv"]
+        pool = self._caches[self._paged0]["kv"]
         return {"param_dtype": str(self._cdtype),
                 "pool_dtype": str(pool.dtype),
                 "platform": jax.default_backend(),
@@ -1267,7 +1341,7 @@ class ContinuousBatcher:
             self.kvq["bytes_saved_handoff"] += int(kv.nbytes) * 2 - total
         return True
 
-    def logit_probe(self, tokens) -> np.ndarray:
+    def logit_probe(self, tokens, prefill: int = 0) -> np.ndarray:
         """Teacher-forced per-position logits ``[n, V]`` (f32) for
         ``tokens``, computed through a SCRATCH one-row paged pool in
         THIS engine's KV dtype — token ``i`` embeds at logical count
@@ -1278,37 +1352,58 @@ class ContinuousBatcher:
         int8 engine over the same stream and records the per-position
         KL — the bounded-error half of the relaxed parity contract.
         The live pool is untouched (scratch blocks, scratch table);
-        under a mesh the scratch runs replicated."""
+        under a mesh the scratch runs replicated.
+
+        ``prefill``: the first ``prefill`` tokens go through the
+        ADMISSION program (a one-row wave into the scratch caches, every
+        layer's own kind of cache) and only the rest through decode
+        ticks: prefill-then-decode as a request lives it. The logits
+        returned are then those of positions ``prefill .. n-1``."""
         toks = [int(t) for t in tokens]
         n = len(toks)
         if n == 0:
             return np.zeros((0, 0), np.float32)
         nbp = -(-n // self.bt)
         scratch = [{name: jnp.zeros(
-                        (leaf.shape[0], nbp) + tuple(leaf.shape[2:]),
-                        leaf.dtype)
-                    for name, leaf in c.items()} for c in self._caches]
+                        (leaf.shape[0], 1 if kind == "ring" else nbp)
+                        + tuple(leaf.shape[2:]), leaf.dtype)
+                    for name, leaf in c.items()}
+                   for c, kind in zip(self._caches, self._cache_kinds)]
         table = jnp.arange(nbp, dtype=jnp.int32)[None, :]
         model = self.model
+        if prefill:
+            W = -(-prefill // self.bt) * self.bt
+            at = np.arange(W)
+            real = at < prefill
+            kw = ({"ring_rows": jnp.zeros((1,), jnp.int32)}
+                  if "ring" in self._cache_kinds else {})
+            with self._mesh_ctx():
+                scratch = jax.jit(self._admit_impl)(
+                    self.params, scratch, table,
+                    jnp.asarray([toks[:prefill] + [0] * (W - prefill)],
+                                jnp.int32),
+                    jnp.asarray(real[None], jnp.float32),
+                    jnp.asarray(at[None], jnp.int32),
+                    jnp.zeros((1, 0), jnp.float32),
+                    jnp.asarray(np.where(real, at // self.bt, nbp)[None],
+                                jnp.int32),
+                    jnp.asarray((at % self.bt)[None], jnp.int32), **kw)
 
         def step(params, caches, tok, pos):
             x = model.embed(params, tok[:, None], pos[:, None])
             new_caches = []
             for li in range(self._n_layers):
-                p_l = jax.tree.map(lambda a: a[li], params["blocks"])
-                paged = {**caches[li], "table": table}
-                x, c2 = self._block.decode_step(p_l, x, paged, pos)
-                new_caches.append({name: leaf
-                                   for name, leaf in c2.items()
-                                   if name != "table"})
+                x, c2 = self._decode_layer(li, params, x, caches[li],
+                                           table, pos, pin=False)
+                new_caches.append(c2)
             return new_caches, model.readout(params, x)[:, -1]
 
         step_c = jax.jit(step)
         out = []
         with self._mesh_ctx():
-            for i, t in enumerate(toks):
+            for i in range(prefill, n):
                 scratch, logits = step_c(
-                    self.params, scratch, jnp.asarray([t], jnp.int32),
+                    self.params, scratch, jnp.asarray([toks[i]], jnp.int32),
                     jnp.asarray([i], jnp.int32))
                 out.append(np.asarray(logits[0], jnp.float32))
         return np.stack(out)
@@ -1340,6 +1435,61 @@ class ContinuousBatcher:
             raise ValueError(f"segments must be >= 1, got {segments}")
         self._profile_req = {"remaining": int(segments),
                              "dir": profile_dir, "active": False}
+
+    @staticmethod
+    def _refuse_for_layer_kinds(*, prefix_cache, speculate, tiers, kv_dtype,
+                                mesh, prefill_chunk_tokens):
+        """What a model of layer kinds (window layers on rings, held
+        experts) cannot be served with yet, refused with the reason."""
+        why = {
+            "prefix_cache": (prefix_cache, "a cached prefix holds pool "
+                             "blocks only: a window layer's ring at the "
+                             "prefix's end is not kept, so an attached "
+                             "request could not be resumed"),
+            "speculate": (speculate is not None, "a verify window writes "
+                          "several ring slots at once and a rejected draft "
+                          "would have overwritten tokens the window still "
+                          "needs"),
+            "host_cache_mb/host_cache_blocks/disk_cache_dir": (
+                tiers, "KV tiers demote and promote pool blocks; rings "
+                "have none"),
+            "kv_dtype='int8'": (kv_dtype == "int8", "the ring has no "
+                                "scale leaf"),
+            "mesh": (mesh is not None, "the ring write and the held "
+                     "experts' grouped products are single-device "
+                     "programs, and the experts held are one chip's share "
+                     "already"),
+            "prefill_chunk_tokens": (
+                prefill_chunk_tokens is not None, "a chunk would have to "
+                "attend the ring of the chunk before it"),
+        }
+        for name, (asked, reason) in why.items():
+            if asked:
+                raise ValueError(
+                    f"{name} does not compose with a model of window "
+                    f"layers and held experts yet: {reason}")
+
+    def _layer(self, params, i: int):
+        """(block, parameters) of layer ``i``."""
+        if self._layer_blocks is None:
+            return self._block, jax.tree.map(lambda a: a[i],
+                                             params["blocks"])
+        return self._layer_blocks[i], self.model.layer_params(params, i)
+
+    def _decode_layer(self, i: int, params, x, cache, tables, pos,
+                      live=None, counts=None, pin: bool = True):
+        """One layer's decode tick against its own kind of cache; returns
+        ``(x, new_cache)``, the pool's leaves pinned to their layout
+        unless ``pin`` is off (a scratch pool has none)."""
+        block, p_l = self._layer(params, i)
+        kw = ({} if self._layer_blocks is None
+              else {"live": live, "counts_sink": counts})
+        if self._cache_kinds[i] == "ring":
+            return block.decode_step(p_l, x, cache, pos, **kw)
+        x, c2 = block.decode_step(p_l, x, {**cache, "table": tables}, pos,
+                                  **kw)
+        return x, {name: constrain(leaf, _POOL_SPEC) if pin else leaf
+                   for name, leaf in c2.items() if name != "table"}
 
     def _mesh_ctx(self):
         return (use_mesh(self._mesh) if self._mesh is not None
@@ -1441,7 +1591,8 @@ class ContinuousBatcher:
 
     def _admit_impl(self, params, caches, tables, prompt, pmask, positions,
                     prefix_mask, blk_idx, off_idx,
-                    moe_capacity=None, moe_capacity_rows=None):
+                    moe_capacity=None, moe_capacity_rows=None,
+                    ring_rows=None):
         """Prefill an admission WAVE into the block pool: ``K`` requests'
         UNSHARED suffix tokens (``prompt``/``pmask`` ``[K, ws]``, laid
         out from column 0 — an n-token suffix occupies columns
@@ -1464,6 +1615,12 @@ class ContinuousBatcher:
         observed to miscompile under mixed-axes meshes on this
         backend).
 
+        A layer whose cache is a RING takes the last tokens of each row's
+        head instead (``ops/attention.py::ring_from_prefill``), written
+        whole into the ring rows of the slots ``ring_rows [K]`` (a slot
+        id out of range = a pad row, dropped; None = wave row ``j`` is
+        slot ``j``, a wave over the first ``K`` slots).
+
         Each request's LAST prompt token is deliberately NOT prefilled:
         the host sets it as the row's current token and the next
         segment's first tick consumes it — writing its K/V at the
@@ -1478,10 +1635,9 @@ class ContinuousBatcher:
             Lp = prefix_mask.shape[1]
             x = constrain(model.embed(params, prompt, positions),
                           P(("data", "fsdp"), None, None))
-            blocks = params["blocks"]
             new_caches = []
             for i in range(self._n_layers):
-                p_i = jax.tree.map(lambda a: a[i], blocks)
+                block, p_i = self._layer(params, i)
                 sink: list = []
                 kw = {"kv_sink": sink, "kv_mask": pmask}
                 if Lp:
@@ -1513,13 +1669,20 @@ class ContinuousBatcher:
                     if (self._block_takes_moe_capacity_rows
                             and moe_capacity_rows is not None):
                         kw["moe_capacity_rows"] = moe_capacity_rows
-                x = self._block.apply(p_i, x, **kw)
+                x = block.apply(p_i, x, **kw)
                 if isinstance(x, tuple):   # MoE blocks return (x, aux)
                     x = x[0]
                 (k, v), = sink             # [K, hk, ws, hd] — suffix only
                 with scope("kv_write"):
-                    new_caches.append(self._admit_scatter(
-                        caches[i], k, v, blk_idx, off_idx))
+                    if self._cache_kinds[i] == "ring":
+                        new_caches.append(self._admit_ring(
+                            caches[i], k, v, pmask, ring_rows))
+                    elif self._layer_blocks is not None:
+                        new_caches.append(self._admit_blocks(
+                            caches[i], k, v, tables, pmask))
+                    else:
+                        new_caches.append(self._admit_scatter(
+                            caches[i], k, v, blk_idx, off_idx))
             return new_caches
 
     @staticmethod
@@ -1543,6 +1706,45 @@ class ContinuousBatcher:
                     "scale": scatter(cache["scale"], jnp.stack([ks, vs]))}
         return {"kv": scatter(cache["kv"], jnp.stack([k, v]))}
 
+    @staticmethod
+    def _admit_ring(cache, k, v, pmask, ring_rows):
+        """One WINDOW layer's admission write: the last tokens of each
+        row's head, laid out as the ring holds them
+        (``ops/attention.py::ring_from_prefill``), written whole into the
+        ring rows of the slots ``ring_rows``."""
+        from distributed_compute_pytorch_tpu.ops.attention import (
+            ring_from_prefill)
+        ring = cache["kv"]
+        n_tok = jnp.sum(pmask > 0.5, axis=1).astype(jnp.int32)
+        rows = jnp.arange(k.shape[0]) if ring_rows is None else ring_rows
+        return {"kv": ring.at[:, rows].set(
+            ring_from_prefill(k, v, n_tok, ring.shape[3]).astype(ring.dtype),
+            mode="drop")}
+
+    def _admit_blocks(self, cache, k, v, tables, pmask):
+        """One layer's admission write in WHOLE BLOCKS (the window starts
+        at position 0: no attached prefix, no chunk): block ``j`` of wave
+        row ``r`` goes to pool block ``tables[r, j]`` if it holds a real
+        token, else nowhere. One index on the pool's block axis, so the
+        pool is updated in place; the per-token scatter of
+        :meth:`_admit_scatter` indexes two axes and XLA transposes the
+        whole pool for it, twice (2.7 GB of scratch for a pool of one
+        layer). The tail of a row's last block takes the pad tokens' K/V:
+        past the row's live position, never attended, and overwritten by
+        the ticks that reach it."""
+        kv = jnp.stack([k, v])                         # [2, K, hk, ws, hd]
+        _, K, hk, ws, hd = kv.shape
+        nbw = ws // self.bt
+        kv = kv.reshape(2, K, hk, nbw, self.bt, hd).transpose(
+            0, 1, 3, 2, 4, 5).reshape(2, K * nbw, hk, self.bt, hd)
+        n_tok = jnp.sum(pmask > 0.5, axis=1)
+        real = (jnp.arange(nbw) * self.bt)[None, :] < n_tok[:, None]
+        pool = cache["kv"]
+        ids = jnp.where(real, tables[:, :nbw], pool.shape[1]).reshape(-1)
+        return {"kv": constrain(
+            pool.at[:, ids].set(kv.astype(pool.dtype), mode="drop"),
+            _POOL_SPEC)}
+
     def _copy_impl(self, caches, src, dst):
         """Copy-on-write block copies: pool blocks ``src [M]`` duplicated
         into ``dst [M]`` across every layer, one compiled dispatch per
@@ -1551,8 +1753,8 @@ class ContinuousBatcher:
         mask stops at the live position) and overwritten as the attacher
         writes its own suffix."""
         out = []
-        for c in caches:
-            out.append({name: constrain(
+        for c, kind in zip(caches, self._cache_kinds):
+            out.append(c if kind == "ring" else {name: constrain(
                 leaf.at[:, dst].set(leaf[:, src]), _POOL_SPEC)
                 for name, leaf in c.items()})
         return out
@@ -1596,7 +1798,13 @@ class ContinuousBatcher:
         keyed on (row seed, tokens-so-far) so sampled streams are
         scheduling- and attachment-invariant."""
         model = self.model
-        blocks = params["blocks"]
+        # rows out of the plan arrive with an all-trash table; a live
+        # row's first block never is the trash block. Held experts count
+        # the live rows only, on the device, in the scan's carry: the
+        # sums come back beside the tokens (a fifth result)
+        counted = bool(self._count_keys)
+        live = ((tables[:, 0] != BlockPool.TRASH).astype(jnp.float32)
+                if counted else None)
         if sampling:
             base = jax.vmap(jax.random.key)(seeds)
             keys = jax.vmap(lambda k, n0: jax.vmap(
@@ -1609,31 +1817,33 @@ class ContinuousBatcher:
         def tick(carry, xs):
             with scope("decode"):
                 i, key = xs
-                tok, caches, n_log = carry
+                tok, caches, n_log, *xc = carry
                 p = positions0 + 1 + i         # [B] per-row slot being written
                 x = constrain(
                     model.embed(params, tok[:, None], n_log[:, None]),
                     P(("data", "fsdp"), None, None))
                 new_caches = []
+                counts: list | None = [] if counted else None
                 for li in range(self._n_layers):
-                    p_l = jax.tree.map(lambda a: a[li], blocks)
-                    paged = {**caches[li], "table": tables}
-                    x, c2 = self._block.decode_step(p_l, x, paged, p)
-                    new_caches.append(
-                        {name: constrain(leaf, _POOL_SPEC)
-                         for name, leaf in c2.items() if name != "table"})
+                    x, c2 = self._decode_layer(li, params, x, caches[li],
+                                               tables, p, live, counts)
+                    new_caches.append(c2)
                 logits = model.readout(params, x)[:, -1]
                 with scope("sample"):
                     if sampling:
                         nxt = sample_rows(logits, temp, top_k, top_p, key)
                     else:
                         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (nxt, new_caches, n_log + 1), nxt
+                if counted:
+                    xc = [xc[0] + sum(counts)]
+                return (nxt, new_caches, n_log + 1, *xc), nxt
 
-        (tok, caches, n_logical), toks = lax.scan(
-            tick, (tok, caches, n_logical),
+        xc0 = ([jnp.zeros((len(self._count_keys),), jnp.int32)]
+               if counted else [])
+        (tok, caches, n_logical, *xc), toks = lax.scan(
+            tick, (tok, caches, n_logical, *xc0),
             (jnp.arange(self.S), tick_keys))
-        return caches, tok, n_logical, toks.transpose(1, 0)
+        return (caches, tok, n_logical, toks.transpose(1, 0), *xc)
 
     def _verify_impl(self, params, caches, tables, toks, positions0,
                      n_logical, temp, top_k, top_p, seeds,
@@ -1658,7 +1868,6 @@ class ContinuousBatcher:
         draft quality can only change HOW MANY tokens emit per pass,
         never which tokens."""
         model = self.model
-        blocks = params["blocks"]
         W = toks.shape[1]
         pos = positions0[:, None] + 1 + jnp.arange(W)[None, :]   # [B, W]
         npos = n_logical[:, None] + jnp.arange(W)[None, :]       # [B, W]
@@ -1666,9 +1875,9 @@ class ContinuousBatcher:
                       P(("data", "fsdp"), None, None))
         new_caches = []
         for li in range(self._n_layers):
-            p_l = jax.tree.map(lambda a: a[li], blocks)
+            block, p_l = self._layer(params, li)
             paged = {**caches[li], "table": tables}
-            x, c2 = self._block.verify_step(p_l, x, paged, pos)
+            x, c2 = block.verify_step(p_l, x, paged, pos)
             new_caches.append(
                 {name: constrain(leaf, _POOL_SPEC)
                  for name, leaf in c2.items() if name != "table"})
@@ -1889,7 +2098,7 @@ class ContinuousBatcher:
         for w in self._width_ladder:
             tables = np.full((self.B, w), BlockPool.TRASH, np.int32)
             with span("prewarm_width", blocks=int(w)), self._mesh_ctx():
-                (self._caches, self._cur_tok, self._n_logical, _
+                (self._caches, self._cur_tok, self._n_logical, *_
                  ) = self._segment_c(
                     self.params, self._caches, jnp.asarray(tables),
                     self._cur_tok, self._n_logical,
@@ -2618,8 +2827,8 @@ class ContinuousBatcher:
                 self._note_program("segment", self._segment_c, args,
                                    {"sampling": sampling})
                 with self._mesh_ctx():
-                    (self._caches, self._cur_tok, self._n_logical, toks
-                     ) = self._segment_c(*args, sampling=sampling)
+                    (self._caches, self._cur_tok, self._n_logical, toks,
+                     *xc) = self._segment_c(*args, sampling=sampling)
                 del args
             if prof is not None and prof["active"]:
                 prof["remaining"] -= 1
@@ -2645,7 +2854,8 @@ class ContinuousBatcher:
                 # host observation hook: drills flip drain flags /
                 # cancel requests at a deterministic segment
                 chaos.on_segment(self.stats["segments"])
-            return "plain", toks, plan
+            # held experts' counts ride with the tokens to the harvest
+            return "plain", (toks, *xc), plan
 
         def cow_for_write(plan):
             """Speculation rollback-safety guard (ISSUE 12): a verify
@@ -2899,7 +3109,7 @@ class ContinuousBatcher:
             if seg[0] == "spec":
                 harvest_verify(seg)
                 return
-            _kind, toks, plan = seg
+            _kind, outs, plan = seg     # (tokens, and the counts if any)
             with span("harvest", overlapped=overlapped) as sp:
                 first_ids, done_ids = [], []
                 self.stats["fetches"] += 1
@@ -2912,13 +3122,15 @@ class ContinuousBatcher:
                 def fetch():
                     if chaos is not None:
                         chaos.in_fetch(self.stats["segments"])
-                    return np.asarray(toks)
+                    return jax.device_get(outs)
 
                 if self.tick_timeout_s is not None:
-                    toks_h = call_with_timeout(fetch, self.tick_timeout_s,
-                                               "serve tick harvest")
+                    toks_h, *xc_h = call_with_timeout(
+                        fetch, self.tick_timeout_s, "serve tick harvest")
                 else:
-                    toks_h = fetch()
+                    toks_h, *xc_h = fetch()
+                for key, c in zip(self._count_keys, *xc_h):
+                    self.stats[key] += int(c)
                 now = time.monotonic()
                 for b, ri, take, done_after in plan:
                     if results[ri] is not None:
@@ -3219,6 +3431,11 @@ class ContinuousBatcher:
                 if self._block_takes_moe_capacity:
                     caps.append(self._block.prefill_capacity(len(known)))
             kw = {}
+            if "ring" in self._cache_kinds:
+                # the slot each wave row's ring is; pad rows out of range
+                kw["ring_rows"] = jnp.asarray(
+                    [b for b, *_ in entries] + [self.B] * (Kp - K),
+                    jnp.int32)
             if caps:
                 kw["moe_capacity"] = max(caps)
                 if self._block_takes_moe_capacity_rows:
