@@ -1493,7 +1493,7 @@ def serve_smoke():
     mesh = make_mesh("data=2,tensor=2")
     sharded = shard_pytree(params, pick_strategy(mesh, model), mesh)
     cb = ContinuousBatcher(model, sharded, slots=4, t_max=64,
-                           prompt_buf=8, segment=4, mesh=mesh)
+                           prompt_buf=16, segment=4, mesh=mesh)
     rng = np.random.default_rng(0)
 
     def toks():
@@ -1511,7 +1511,8 @@ def serve_smoke():
         # with the NEXT segment already dispatched
         "dispatch_before_fetch":
             s["fetches_overlapped"] == s["fetches"] - 1,
-        # batched admission: one prefill call per wave, not per request
+        # batched admission: the 4-token heads share the ladder's 8-token
+        # rung, one row a device in each dispatch, not one per request
         "batched_admission": (s["prefill_rows"] == len(reqs)
                               and s["prefill_calls"] < len(reqs)),
         "cache_sharded":

@@ -64,17 +64,30 @@ instead, with everything the TPU touches remaining static-shaped:
   recycle indefinitely on the same compiled programs and a session
   never exhausts.
 - **Batched admission**: ALL pending prompts that fit free rows are
-  stacked into ONE compiled multi-row prefill per admission wave (of at
-  most ``_WAVE_TOKENS`` of prefill window; the rest wait for the next).
-  Each prompt's tokens-but-the-last are prefilled (its SUFFIX past any
-  cached prefix, attended against the gathered prefix K/V via the
-  blocks' ``kv_prefix`` path); the LAST prompt token becomes the row's
-  current token, consumed by the next segment's first tick exactly as
-  standalone generation would — admission stays fetch-free. With the
-  prefix cache off every wave compiles at the one ``prompt_buf``-wide
-  window, exactly as before; attach waves compile per
-  (suffix-window, prefix-window) shape, both rounded to the block size
-  so the recurring hot-prefix traffic reuses a handful of programs.
+  admitted in ONE wave (of at most ``_WAVE_TOKENS`` of prefill window
+  between two decode segments; the rest wait for the next). A wave costs
+  what its prompts hold: with nothing attached each row takes the
+  smallest window RUNG that covers its head (``admission_ladder``:
+  ``prompt_buf``, a half, a quarter, an eighth of it, in whole blocks),
+  and the rows of a rung go out together, as many as keep the dispatch
+  at half of ``prompt_buf`` in window or under (a dispatch that size is
+  already compute-bound), so a wave is SEVERAL compiled prefills back to
+  back with no decode segment between them.
+  The shapes are a small fixed set (seven at ``prompt_buf`` 2048) that the
+  batcher builds once, at its first wave, by running each as a null
+  dispatch: no later wave, whatever its lengths or row count, meets a
+  program that is not already in ``jit``'s cache, and no dispatch needs
+  more memory than the one-row, full-window case. Each prompt's
+  tokens-but-the-last are prefilled (its SUFFIX past any cached prefix,
+  attended against the gathered prefix K/V via the blocks'
+  ``kv_prefix`` path); the LAST prompt token becomes the row's current
+  token, consumed by the next segment's first tick exactly as standalone
+  generation would — admission stays fetch-free. A window that starts at
+  position 0 is written into the pool in WHOLE BLOCKS through one index
+  on the block axis, in place; attach waves (one dispatch per
+  (suffix-window, prefix-window) shape, both rounded to the block size so
+  the recurring hot-prefix traffic reuses a handful of programs), chunk
+  extensions and int8 pools keep the per-token scatter.
 - **Mesh composition**: pass ``mesh=`` and the WHOLE serving session is
   sharded: pool BLOCKS over the batch axes (``data``/``fsdp``), KV
   heads over ``tensor`` (GQA: ``tensor`` must divide ``num_kv_heads``),
@@ -224,16 +237,67 @@ from distributed_compute_pytorch_tpu.utils.quantize import quantize_kv
 _PROGRAM_CACHE: dict = {}
 _PROGRAM_CACHE_LOCK = threading.Lock()
 
-# The most prefill window (rows x tokens) ONE admission wave holds. A
-# wave's activations are live all at once and every decoding row waits
-# while it runs: beside Mistral-7B's first 16 layers and a 5.4 GB pool
-# on a 16 GB v5e, 16 rows x 2048 tokens compile at 15.46 of 15.75 GB, 32
-# rows do not fit, and a row costs 0.1 s (PERF.md section 7, first
-# program defect). Requests past it wait one decode segment for the
-# next wave. Only windows this large are cut, and those are compute-
-# bound on any chip: two waves cost the device what one of their sum
-# would.
+# The most prefill window (rows x tokens, pad rows included: the sum of
+# ``R x W`` over a wave's dispatches) that runs BETWEEN TWO DECODE
+# SEGMENTS. Every decoding row waits while a wave runs, so this bounds
+# what an admission storm may add to one gap between tokens; requests
+# past it wait one decode segment for the next wave. It is no longer the
+# chip's memory: no dispatch holds more than ``prompt_buf`` tokens of
+# window (``admission_ladder``), so a wave of any number of rows fits
+# where the one-row, full-window case fits. Windows this large are
+# compute-bound on any chip: two waves cost the device what one of their
+# sum would. Not tuned.
 _WAVE_TOKENS = 32768
+
+
+def admission_ladder(prompt_buf: int, block: int) -> tuple:
+    """The shapes of an admission dispatch: ``((W, rows), ...)``, widest
+    window first. ``W_i = prompt_buf / 2^i`` for ``i = 0..3``, rounded up
+    to whole blocks, a rung under one block or equal to the one before it
+    dropped; rows of rung ``i`` go out at most ``2^(i-1)`` at a time (one
+    at a time at the two widest rungs), so no dispatch but the one-row,
+    full-window case holds more than half of ``prompt_buf`` in window (a
+    block's rounding apart). Half is where the v5e said a dispatch is
+    already compute-bound (a row of 1024 tokens takes 43.4 ms, two such
+    rows in one dispatch 81.9: PERF.md, PR 29), so wider dispatches would
+    buy nothing and cost a program each to build. 2048 / 8 gives
+    (2048, 1), (1024, 1), (512, 2), (256, 4): seven shapes."""
+    rungs = []
+    for i in range(4):
+        if i and prompt_buf < block << i:
+            break
+        w = -(-prompt_buf // (block << i)) * block
+        if not rungs or w < rungs[-1][0]:
+            rungs.append((w, 1 << max(0, i - 1)))
+    return tuple(rungs)
+
+
+def ladder_shapes(ladder: tuple, dp: int = 1) -> list:
+    """Every ``(R, W)`` a wave can dispatch: ``R`` is the batch-axes
+    product ``dp`` (1 off-mesh) times a power of two, up to the rung's
+    rows."""
+    return [(dp << e, w) for w, rows in ladder
+            for e in range((-(-rows // dp) - 1).bit_length() + 1)]
+
+
+def wave_dispatches(heads: list, ladder: tuple, dp: int = 1) -> list:
+    """Group a wave's rows into dispatches: ``[(W, R, [row indices])]``.
+    A row's rung is the smallest that covers its head; the rows of a rung
+    go out in arrival order, as many at a time as the rung takes (or as
+    the batch axes do, if more: a device's share is then still one row),
+    and each group is padded up to ``dp`` times a power of two with rows
+    that hold and write nothing."""
+    by_rung: dict = {}
+    for j, n in enumerate(heads):
+        w, rows = next(r for r in reversed(ladder) if r[0] >= n)
+        by_rung.setdefault((w, max(rows, dp)), []).append(j)
+    out = []
+    for (w, rows), idx in sorted(by_rung.items(), reverse=True):
+        for a in range(0, len(idx), rows):
+            take = idx[a:a + rows]
+            out.append(
+                (w, dp << (-(-len(take) // dp) - 1).bit_length(), take))
+    return out
 
 
 @dataclass
@@ -688,9 +752,12 @@ class ContinuousBatcher:
         # scatter whole-block and the program count at ~one per mode)
         self._chunk = (None if prefill_chunk_tokens is None else
                        -(-prefill_chunk_tokens // self.bt) * self.bt)
-        # rows per admission wave: each takes a whole window (the chunk,
-        # else up to prompt_buf) of the wave's static batch
-        self._wave_rows = max(1, _WAVE_TOKENS // (self._chunk or self.Tb))
+        # the shapes an admission dispatch takes when its window starts
+        # at position 0 (nothing attached, no chunking): chosen from what
+        # the wave's prompts hold, all built before the first request is
+        # served (_warm_ladder)
+        self._admit_ladder = admission_ladder(self.Tb, self.bt)
+        self._ladder_warm = False
         min_blocks = slots * self.nb + 1         # + the trash block
         if pool_blocks is None:
             pool_blocks = min_blocks + (4 * self.nb if prefix_cache else 0)
@@ -896,11 +963,11 @@ class ContinuousBatcher:
                     lambda n: ((n_layers, 2, -(-n // self.bt), hk,
                                 self.bt, hd), str(np_dtype)))
         # moe_capacity is STATIC: capacity shapes the routing one-hots, so
-        # each distinct (wave size, wave-max capacity) pair compiles its
-        # own admission program; per-row capacities ride along as a
-        # traced [K] vector. Suffix/prefix window widths are static per
-        # wave too — the prefix-cache-off path always compiles the one
-        # prompt_buf-wide window, attach waves one program per
+        # each distinct (dispatch shape, dispatch-max capacity) pair
+        # compiles its own admission program; per-row capacities ride
+        # along as a traced [K] vector. Suffix/prefix window widths are
+        # static per dispatch too — a wave that attaches nothing takes
+        # the admission ladder's shapes, attach waves one program per
         # (block-rounded suffix, prefix bucket rung) pair.
         #
         # Compiled-PROGRAM sharing: jitting bound methods makes every
@@ -971,7 +1038,12 @@ class ContinuousBatcher:
         # behind it issued AFTER the next segment's dispatch
         self.stats = obs_metrics.MetricDict(self.obs, "serve.", {
             "segments": 0, "fetches": 0, "fetches_overlapped": 0,
+            # admission, by DISPATCHES (one call of every kernel in the
+            # program each) and rows admitted; the real head tokens they
+            # prefilled and the window (rows x tokens, pads included)
+            # dispatched for them
             "prefill_calls": 0, "prefill_rows": 0,
+            "prefill_tokens": 0, "prefill_window_tokens": 0,
             # fault-tolerance counters (serve_lifecycle /
             # DESIGN.md "Serving under failure")
             "faults": 0, "reconstructions": 0,
@@ -1593,13 +1665,15 @@ class ContinuousBatcher:
                     prefix_mask, blk_idx, off_idx,
                     moe_capacity=None, moe_capacity_rows=None,
                     ring_rows=None):
-        """Prefill an admission WAVE into the block pool: ``K`` requests'
-        UNSHARED suffix tokens (``prompt``/``pmask`` ``[K, ws]``, laid
-        out from column 0 — an n-token suffix occupies columns
-        ``0..n-1``), each row's token ``t`` at LOGICAL position
+        """Prefill ONE DISPATCH of an admission wave into the block pool:
+        ``K`` requests' UNSHARED suffix tokens (``prompt``/``pmask``
+        ``[K, ws]``, laid out from column 0 — an n-token suffix occupies
+        columns ``0..n-1``), each row's token ``t`` at LOGICAL position
         ``positions[j, t] = m_j + t`` (``m_j`` = the row's cached-prefix
-        length, 0 with the prefix cache off) — ONE compiled forward for
-        the whole wave.
+        length, 0 with the prefix cache off) — one compiled forward for
+        the ``[K, ws]`` batch. ``_prefill_wave`` chooses the shape: a
+        rung of the admission ladder with the rows of the wave that it
+        covers, or the one window of an attach or chunk wave.
 
         When the wave carries attachments (static ``Lp =
         prefix_mask.shape[1] > 0``), each layer gathers the rows' cached
@@ -1610,10 +1684,15 @@ class ContinuousBatcher:
         ``m_j``. The computed suffix K/V scatter to their physical
         (block, offset) targets ``blk_idx``/``off_idx`` (out-of-range
         ids = pad slots, ``mode="drop"``) — pads both for rows shorter
-        than the window and for the rows padding ``K`` up to a
-        batch-axes multiple (an UNEVENLY batch-sharded prefill was
-        observed to miscompile under mixed-axes meshes on this
-        backend).
+        than the window and for the rows padding ``K`` up to a power of
+        two and a batch-axes multiple (an UNEVENLY batch-sharded prefill
+        was observed to miscompile under mixed-axes meshes on this
+        backend). With no attachment in the dispatch (``Lp == 0``,
+        static: every window starts at position 0) and a float pool the
+        write goes in whole blocks instead (``_admit_blocks``): the
+        per-token scatter indexes two axes of the pool and XLA transposes
+        the whole pool for it, twice a layer, whatever the dispatch holds
+        (33 ms of a Mistral-7B dispatch on a v5e: PERF.md, PR 29).
 
         A layer whose cache is a RING takes the last tokens of each row's
         head instead (``ops/attention.py::ring_from_prefill``), written
@@ -1677,7 +1756,8 @@ class ContinuousBatcher:
                     if self._cache_kinds[i] == "ring":
                         new_caches.append(self._admit_ring(
                             caches[i], k, v, pmask, ring_rows))
-                    elif self._layer_blocks is not None:
+                    elif Lp == 0 and "scale" not in caches[i]:
+                        # every window starts at position 0 (static)
                         new_caches.append(self._admit_blocks(
                             caches[i], k, v, tables, pmask))
                     else:
@@ -1722,16 +1802,16 @@ class ContinuousBatcher:
             mode="drop")}
 
     def _admit_blocks(self, cache, k, v, tables, pmask):
-        """One layer's admission write in WHOLE BLOCKS (the window starts
-        at position 0: no attached prefix, no chunk): block ``j`` of wave
-        row ``r`` goes to pool block ``tables[r, j]`` if it holds a real
-        token, else nowhere. One index on the pool's block axis, so the
-        pool is updated in place; the per-token scatter of
-        :meth:`_admit_scatter` indexes two axes and XLA transposes the
-        whole pool for it, twice (2.7 GB of scratch for a pool of one
-        layer). The tail of a row's last block takes the pad tokens' K/V:
-        past the row's live position, never attended, and overwritten by
-        the ticks that reach it."""
+        """One layer's admission write in WHOLE BLOCKS (every window of
+        the dispatch starts at position 0: nothing attached, no chunk
+        extension): block ``j`` of wave row ``r`` goes to pool block
+        ``tables[r, j]`` if it holds a real token, else nowhere. One index
+        on the pool's block axis, so the pool is updated in place; the
+        per-token scatter of :meth:`_admit_scatter` indexes two axes and
+        XLA transposes the whole pool for it, twice (2.7 GB of scratch
+        for a pool of one layer). The tail of a row's last block takes
+        the pad tokens' K/V: past the row's live position, never attended,
+        and overwritten by the ticks that reach it."""
         kv = jnp.stack([k, v])                         # [2, K, hk, ws, hd]
         _, K, hk, ws, hd = kv.shape
         nbw = ws // self.bt
@@ -2609,37 +2689,51 @@ class ContinuousBatcher:
             if draining["on"]:
                 return take                 # drain: admission stopped
             now = time.monotonic()
+            heads: list[int] = []
+
+            def room(ri: int) -> bool:
+                # the wave's bound: _WAVE_TOKENS of prefill window between
+                # two decode segments (the first row always goes)
+                heads.append(len(requests[ri].tokens) - 1)
+                if take and self._wave_window(heads) > _WAVE_TOKENS:
+                    heads.pop()
+                    return False
+                return True
+
             if self.admit_policy == "fifo":
                 # an unarrived head BLOCKS the wave: open-loop arrivals
                 # keep the same no-leapfrog fairness as submissions
                 while (queue and len(take) < k_free
-                       and arrive_at[queue[0]] <= now):
+                       and arrive_at[queue[0]] <= now and room(queue[0])):
                     take.append(queue.pop(0))
             else:
                 i = 0
                 while i < len(queue) and len(take) < k_free:
                     if (self._fits(requests[queue[i]])
-                            and arrive_at[queue[i]] <= now):
+                            and arrive_at[queue[i]] <= now
+                            and room(queue[i])):
                         take.append(queue.pop(i))
                     else:
                         i += 1
             return take
 
         def admit_wave():
-            """ONE multi-row prefill for every pending request that has
-            a free row (the batched admission: k admissions, 1 dispatch).
+            """ONE wave for every pending request that has a free row
+            (the batched admission: k admissions, the few dispatches
+            their window rungs need, back to back — ``_prefill_wave``).
             Radix attach + block allocation + COW copies happen here, on
             the host, before the wave's device work. All host->device,
             no fetch. With CHUNKED PREFILL on, the wave shares one
             suffix-token budget: rows past it admit mid-prompt (their
             slot carries the progress mark) and extend between decode
             segments via ``chunk_wave`` — a long-prompt admission storm
-            can never widen a single wave past the chunk. A wave takes
-            at most ``_wave_rows`` rows (``_WAVE_TOKENS`` of prefill
-            window); requests past that stay queued for the next wave,
-            one decode segment later."""
+            can never widen a single wave past the chunk. A wave holds
+            at most ``_WAVE_TOKENS`` of prefill window, counted as its
+            dispatches will hold it (``_wave_window``); requests past
+            that stay queued for the next wave, one decode segment
+            later."""
             free = [b for b, s in enumerate(table) if s.req_index < 0]
-            take = pick_admissions(min(len(free), self._wave_rows))
+            take = pick_admissions(len(free))
             if not take:
                 return
             with span("admit_wave", rows=len(take),
@@ -2688,8 +2782,7 @@ class ContinuousBatcher:
                 self.stats["cow_copies"] += len(cow_all)
                 if cow_all:
                     self._copy_blocks(cow_all)
-                self._prefill_wave(entries)
-                self.stats["prefill_calls"] += 1
+                self._prefill_wave(entries, self._chunk)
                 self.stats["prefill_rows"] += len(take)
                 if self._tier_promote_t0 is not None:
                     # the wave's promotion H2D copies were dispatched
@@ -2745,8 +2838,7 @@ class ContinuousBatcher:
             if not entries:
                 return
             with span("chunk_wave", rows=len(entries)):
-                self._prefill_wave(entries)
-                self.stats["prefill_calls"] += 1
+                self._prefill_wave(entries, self._chunk)
                 self.prefill["chunk_waves"] += 1
                 self.prefill["chunk_tokens"] += sum(
                     upto - m for _, _, m, upto in entries)
@@ -3355,14 +3447,24 @@ class ContinuousBatcher:
 
     # ---- admission / recovery waves ---------------------------------------
 
+    def _wave_window(self, heads: list) -> int:
+        """The prefill window (rows x tokens) a wave of rows with these
+        head lengths dispatches: what ``_WAVE_TOKENS`` bounds. Counted
+        from the ladder's dispatches; a chunked wave is one dispatch of a
+        chunk a row, and a row that may attach is counted at the widest
+        window it can take."""
+        if self._chunk is not None or self._radix is not None:
+            return len(heads) * (self._chunk or self._admit_ladder[0][0])
+        return sum(w * r for w, r, _ in wave_dispatches(
+            heads, self._admit_ladder, self._dp))
+
     def _prefill_wave(self, entries, window: int | None = None):
-        """ONE compiled multi-row prefill of ``entries`` ``(row,
-        known_tokens, from_m, upto)``: every entry's head tokens
-        ``known[from_m:upto]`` (logical positions ``from_m..upto-1``,
-        past its already-resident prefix) land from column 0 of a
-        static ``window``-wide batch and scatter into the row's
-        table-mapped blocks. ``from_m`` is the attached-prefix length
-        at admission, or the chunked-prefill progress mark on an
+        """Prefill ``entries`` ``(row, known_tokens, from_m, upto)``:
+        every entry's head tokens ``known[from_m:upto]`` (logical
+        positions ``from_m..upto-1``, past its already-resident prefix)
+        land from column 0 of a static-shaped batch and are written into
+        the row's table-mapped blocks. ``from_m`` is the attached-prefix
+        length at admission, or the chunked-prefill progress mark on an
         extension wave — the bottom-right-causal ``kv_prefix`` mask
         makes both the same computation. An entry REACHING its head
         (``upto == head_len``) finalises: the last known token becomes
@@ -3370,103 +3472,137 @@ class ContinuousBatcher:
         1``; a mid-chunk entry leaves the row parked for its next
         extension wave.
 
-        ``window`` defaults to ``prompt_buf`` when no entry attaches
-        (the one stable admission shape, exactly the pre-paged compile
-        behaviour) and to the block-rounded longest suffix otherwise;
-        with CHUNKING on it is the chunk itself. The prefix-gather
-        width ``Lp`` rides the bucket ladder (ISSUE 19): the smallest
-        rung covering the wave's longest attached prefix, garbage
-        beyond each row's prefix hidden by ``prefix_mask`` — the
-        program count stays bounded (one per (window, rung) pair,
-        where chunked attach used to pin ``Lp = t_max`` for the same
-        stability) and a short attach stops gathering the horizon.
-        Reconstruction passes the width its grown prefixes need.
-        Rows whose head is fully cached contribute zero suffix tokens
-        — a wave that is ALL attach skips the device prefill entirely
-        (the block lookup IS the admission). Pure dispatch — no
-        fetch."""
+        A wave whose windows all start at position 0 (nothing attached,
+        no chunking, no ``window`` given) costs what its prompts hold: its
+        rows are grouped by the smallest rung of ``admission_ladder`` that
+        covers each head and go out as SEVERAL compiled dispatches back to
+        back (``wave_dispatches``), each of at most half of ``prompt_buf``
+        in window unless it is one full-window row, the last group of a
+        rung padded with rows that hold and write nothing. The ladder's shapes are all built at the first
+        such wave (``_warm_ladder``), so no later wave meets a new one.
+
+        Otherwise the wave is ONE dispatch of every entry at one
+        ``window``: the one given (the chunk, with CHUNKING on; what
+        reconstruction needs for a head grown past ``prompt_buf``), else
+        the block-rounded longest suffix of a wave that attaches. The
+        prefix-gather width ``Lp`` rides the bucket ladder (ISSUE 19):
+        the smallest rung covering the wave's longest attached prefix,
+        garbage beyond each row's prefix hidden by ``prefix_mask`` — the
+        program count stays bounded (one per (window, rung) pair) and a
+        short attach stops gathering the horizon. Rows whose head is
+        fully cached contribute zero suffix tokens — a wave that is ALL
+        attach skips the device prefill entirely (the block lookup IS
+        the admission). Pure dispatch — no fetch."""
         suffixes = [upto - m for _, _, m, upto in entries]
         max_m = max(m for _, _, m, _ in entries)
-        if window is None:
-            if self._chunk is not None:
-                window = self._chunk
-            else:
-                window = (self.Tb if max_m == 0 else
-                          max(self.bt,
-                              -(-max(suffixes) // self.bt) * self.bt))
+        if window is None and max_m == 0:
+            self._warm_ladder()
+            groups = [(w, r, [entries[j] for j in take])
+                      for w, r, take in wave_dispatches(
+                          suffixes, self._admit_ladder, self._dp)]
+        else:
+            if window is None:
+                window = max(self.bt,
+                             -(-max(suffixes) // self.bt) * self.bt)
+            groups = [(window, -(-len(entries) // self._dp) * self._dp,
+                       entries)]
         Lp = 0 if max_m == 0 else self._bucket_width(max_m) * self.bt
+        for w, r, group in groups:
+            if any(upto > m for _, _, m, upto in group):
+                self._dispatch_prefill(group, r, w, Lp)
         final = [(b, known) for b, known, _m, upto in entries
                  if upto >= len(known) - 1]
-        if max(suffixes) > 0:
-            K = len(entries)
-            # pad the wave to a multiple of the batch-axes product: pad
-            # rows are all-masked and their scatter targets are OUT OF
-            # BOUNDS (dropped) — see _admit_impl's partitioner note;
-            # off-mesh _dp == 1
-            Kp = -(-K // self._dp) * self._dp
-            P_oob = self._pool.num_blocks
-            prompt = np.zeros((Kp, window), np.int32)
-            pmask = np.zeros((Kp, window), np.float32)
-            positions = np.tile(np.arange(window, dtype=np.int32),
-                                (Kp, 1))
-            prefix_mask = np.zeros((Kp, Lp), np.float32)
-            blk_idx = np.full((Kp, window), P_oob, np.int32)
-            off_idx = np.zeros((Kp, window), np.int32)
-            tables_wave = np.full((Kp, self.nb), BlockPool.TRASH,
-                                  np.int32)
-            caps = []
-            for j, (b, known, m, upto) in enumerate(entries):
-                suf = known[m:upto]
-                sn = len(suf)
-                if sn:
-                    prompt[j, :sn] = suf
-                    pmask[j, :sn] = 1.0
-                positions[j, :] += m
-                if m:
-                    prefix_mask[j, :m] = 1.0
-                tables_wave[j] = self._tables[b]
-                logical = m + np.arange(sn)
-                blk_idx[j, :sn] = self._tables[b][logical // self.bt]
-                off_idx[j, :sn] = logical % self.bt
-                if self._block_takes_moe_capacity:
-                    caps.append(self._block.prefill_capacity(len(known)))
-            kw = {}
-            if "ring" in self._cache_kinds:
-                # the slot each wave row's ring is; pad rows out of range
-                kw["ring_rows"] = jnp.asarray(
-                    [b for b, *_ in entries] + [self.B] * (Kp - K),
-                    jnp.int32)
-            if caps:
-                kw["moe_capacity"] = max(caps)
-                if self._block_takes_moe_capacity_rows:
-                    kw["moe_capacity_rows"] = jnp.asarray(
-                        caps + [1] * (Kp - K), jnp.int32)
-            if self.kv_dtype == "int8" and Lp > 0:
-                # attached-prefix gather dequantizes int8 blocks inside
-                # the admission forward (see _admit_impl)
-                self.kvq["dequant_reads"] += 1
-            args = (self.params, self._caches, jnp.asarray(tables_wave),
-                    jnp.asarray(prompt), jnp.asarray(pmask),
-                    jnp.asarray(positions), jnp.asarray(prefix_mask),
-                    jnp.asarray(blk_idx), jnp.asarray(off_idx))
-            self._note_program("admit", self._admit_c, args, kw)
-            with span("prefill_wave", rows=len(entries)), \
-                    self._mesh_ctx():
-                self._caches = self._admit_c(*args, **kw)
-            del args
         if final:
-            rows_j = jnp.asarray([b for b, _ in final], jnp.int32)
-            lasts = [known[-1] for _, known in final]
-            n_log = [len(known) - 1 for _, known in final]
-            with self._mesh_ctx():
-                self._cur_tok = self._cur_tok.at[rows_j].set(
-                    jnp.asarray(lasts, jnp.int32))
-                self._n_logical = self._n_logical.at[rows_j].set(
-                    jnp.asarray(n_log, jnp.int32))
+            # one shape whatever the wave holds: a mask over all slots
+            sel = np.zeros((self.B,), bool)
+            lasts = np.zeros((self.B,), np.int32)
+            n_log = np.zeros((self.B,), np.int32)
             for b, known in final:
+                sel[b], lasts[b], n_log[b] = True, known[-1], len(known) - 1
                 self._row_pos[b] = len(known) - 2  # head_len - 1
                 self._cur_h[b] = known[-1]     # host mirrors (spec path)
                 self._nlog_h[b] = len(known) - 1
+            with self._mesh_ctx():
+                self._cur_tok = jnp.where(sel, lasts, self._cur_tok)
+                self._n_logical = jnp.where(sel, n_log, self._n_logical)
+
+    def _warm_ladder(self) -> None:
+        """Build every shape of the admission ladder, once, before the
+        first wave that uses it: each runs as a NULL dispatch (every row
+        masked, every write target out of range) through ``_admit_c``, so
+        ``jit``'s own cache holds it and no later wave traces, compiles
+        or fetches a program, whatever its lengths and row count (an
+        ahead-of-time ``lower().compile()`` would not do: the later call
+        would trace again and fetch). A batcher that borrowed a warm
+        donor's programs only runs them. Blocks that take a STATIC
+        ``moe_capacity`` compile a program per capacity as their waves
+        come, which no null wave can cover."""
+        if self._ladder_warm:
+            return
+        self._ladder_warm = True
+        if self._block_takes_moe_capacity:
+            return
+        for r, w in ladder_shapes(self._admit_ladder, self._dp):
+            self._dispatch_prefill([], r, w, 0)
+
+    def _dispatch_prefill(self, entries, R: int, window: int,
+                          Lp: int) -> None:
+        """ONE compiled prefill of ``entries`` in a static ``[R, window]``
+        batch (``R >= len(entries)``; the rows past them are pads: all
+        masked, their write targets OUT OF BOUNDS and so dropped — see
+        ``_admit_impl``'s partitioner note)."""
+        K = len(entries)
+        P_oob = self._pool.num_blocks
+        prompt = np.zeros((R, window), np.int32)
+        pmask = np.zeros((R, window), np.float32)
+        positions = np.tile(np.arange(window, dtype=np.int32), (R, 1))
+        prefix_mask = np.zeros((R, Lp), np.float32)
+        blk_idx = np.full((R, window), P_oob, np.int32)
+        off_idx = np.zeros((R, window), np.int32)
+        tables_wave = np.full((R, self.nb), BlockPool.TRASH, np.int32)
+        caps = []
+        for j, (b, known, m, upto) in enumerate(entries):
+            suf = known[m:upto]
+            sn = len(suf)
+            if sn:
+                prompt[j, :sn] = suf
+                pmask[j, :sn] = 1.0
+            positions[j, :] += m
+            if m:
+                prefix_mask[j, :m] = 1.0
+            tables_wave[j] = self._tables[b]
+            logical = m + np.arange(sn)
+            blk_idx[j, :sn] = self._tables[b][logical // self.bt]
+            off_idx[j, :sn] = logical % self.bt
+            if self._block_takes_moe_capacity:
+                caps.append(self._block.prefill_capacity(len(known)))
+        kw = {}
+        if "ring" in self._cache_kinds:
+            # the slot each wave row's ring is; pad rows out of range
+            kw["ring_rows"] = jnp.asarray(
+                [b for b, *_ in entries] + [self.B] * (R - K), jnp.int32)
+        if caps:
+            kw["moe_capacity"] = max(caps)
+            if self._block_takes_moe_capacity_rows:
+                kw["moe_capacity_rows"] = jnp.asarray(
+                    caps + [1] * (R - K), jnp.int32)
+        if self.kv_dtype == "int8" and Lp > 0:
+            # attached-prefix gather dequantizes int8 blocks inside
+            # the admission forward (see _admit_impl)
+            self.kvq["dequant_reads"] += 1
+        args = (self.params, self._caches, jnp.asarray(tables_wave),
+                jnp.asarray(prompt), jnp.asarray(pmask),
+                jnp.asarray(positions), jnp.asarray(prefix_mask),
+                jnp.asarray(blk_idx), jnp.asarray(off_idx))
+        self._note_program("admit", self._admit_c, args, kw)
+        with span("prefill_wave", rows=K, window=window), \
+                self._mesh_ctx():
+            self._caches = self._admit_c(*args, **kw)
+        del args
+        if K:
+            self.stats["prefill_calls"] += 1
+            self.stats["prefill_tokens"] += int(pmask.sum())
+            self.stats["prefill_window_tokens"] += R * window
 
     def _reconstruct(self, table, requests, fin, free_row) -> None:
         """Device-failure session reconstruction: rebuild every live
@@ -3488,8 +3624,9 @@ class ContinuousBatcher:
         Rows whose grown prefix no longer fits the per-row horizon
         (window + segment-rounded remaining > t_max) cannot be rebuilt
         and are finalised ``failed`` WITH their partial stream. Rows
-        re-prefill in waves grouped by window width; each distinct
-        width compiles once, like any admission shape.
+        whose prefix still fits ``prompt_buf`` re-prefill as an admission
+        wave does, in the ladder's dispatches; longer ones in waves
+        grouped by window width, each distinct width compiled once.
         """
         # fresh device + host pool state on the SAME compiled programs:
         # the old buffers are untrusted after a fault. Order matters —
@@ -3525,8 +3662,9 @@ class ContinuousBatcher:
             req = requests[slot.req_index]
             known = list(req.tokens) + list(slot.out)
             head = len(known) - 1
-            # reuse the admission window when the prefix still fits it
-            # (no new compile); else the next block-aligned width
+            # a prefix that still fits the admission window goes out as
+            # an admission wave does (no new compile); a longer one takes
+            # the next block-aligned width
             W = (self.Tb if head <= self.Tb
                  else -(-head // self.bt) * self.bt)
             remaining = req.max_new - len(slot.out)
@@ -3544,8 +3682,9 @@ class ContinuousBatcher:
                 # the radix was cleared, so these allocations are always
                 # fresh blocks (m == 0) — replay never trusts dead K/V
                 self._assign_blocks(b, slot, known, remaining)
-            self._prefill_wave([(b, known, 0, len(known) - 1)
-                                for b, _, known, _ in rows], W)
+            self._prefill_wave(
+                [(b, known, 0, len(known) - 1) for b, _, known, _ in rows],
+                None if W == self.Tb else W)
             for b, slot, known, remaining in rows:
                 # host-known truth: the in-flight plan's budget
                 # decrement died with the old buffers

@@ -344,15 +344,17 @@ def test_segment_size_invariance():
 def test_transport_counters_overlap_and_batched_admission():
     """The scheduler's transport contract, by counter: one fetch per
     segment, every fetch with live rows behind it issued AFTER the next
-    segment's dispatch, and one prefill call per admission WAVE (the
-    first wave stacks as many requests as there are free rows)."""
+    segment's dispatch, and fewer prefill dispatches than requests (a
+    wave stacks the rows of one window rung into one dispatch)."""
     model = GPT2(dataclasses.replace(GPT2Config.tiny(), max_seq_len=128))
     params, _ = model.init(jax.random.key(0))
     rng = np.random.default_rng(41)
     # one long request keeps the pool live across every wave boundary
     reqs = ([Request(tokens=[1, 2, 3], max_new=24)]
             + _requests(rng, 6, min_new=4, max_new=4))
-    cb = ContinuousBatcher(model, params, slots=3, t_max=64, prompt_buf=10,
+    # prompt_buf 32: heads of up to 8 tokens share the ladder's lowest
+    # rung, two rows a dispatch
+    cb = ContinuousBatcher(model, params, slots=3, t_max=64, prompt_buf=32,
                            segment=4)
     outs = cb.serve(reqs)
     assert all(o for o in outs)
@@ -360,7 +362,7 @@ def test_transport_counters_overlap_and_batched_admission():
     assert s["fetches"] == s["segments"]
     assert s["fetches_overlapped"] == s["fetches"] - 1
     assert s["prefill_rows"] == len(reqs)
-    assert s["prefill_calls"] < len(reqs)     # waves, not per-request
+    assert s["prefill_calls"] < len(reqs)     # dispatches, not per-request
     # every row-tick attributed exactly once (the bench waste breakdown)
     w = cb.waste
     total = cb.ticks * cb.B
@@ -717,28 +719,39 @@ def test_moe_no_drop_contract_exact_parity():
 
 
 def test_admission_wave_holds_at_most_wave_tokens_of_window(monkeypatch):
-    """A wave's prefill window is bounded (``serve._WAVE_TOKENS``): four
-    requests due together for four free rows go out two a wave, the
-    second wave one decode segment after the first, with the tokens of a
+    """The prefill window that runs between two decode segments is bounded
+    (``serve._WAVE_TOKENS``, counted in the window the wave's dispatches
+    really hold: the sum of rows x window): four requests due together
+    for four free rows, two long (a 16-token window each) and two short
+    (one two-row dispatch of the 8-token rung), are 48 tokens of window.
+    Under a bound of 24 they go out a long and a short one a wave, the
+    second wave after a decode segment of the first, with the tokens of a
     solo run; under the default bound (every other test's case) the same
-    call is one wave of four."""
+    call is one wave of three dispatches."""
     from distributed_compute_pytorch_tpu import serve as serve_mod
     model = GPT2(dataclasses.replace(GPT2Config.tiny(), max_seq_len=128))
     params, _ = model.init(jax.random.key(0))
-    reqs = _requests(np.random.default_rng(11), 4)
-    calls, default = {}, serve_mod._WAVE_TOKENS
-    for bound in (default, 20):
+    rng = np.random.default_rng(11)
+    reqs = [Request([int(t) for t in rng.integers(0, 256, n)], 4)
+            for n in (14, 5, 6, 12)]
+    seen, default = {}, serve_mod._WAVE_TOKENS
+    for bound in (default, 24):
         monkeypatch.setattr(serve_mod, "_WAVE_TOKENS", bound)
         cb = ContinuousBatcher(model, params, slots=4, t_max=128,
-                               prompt_buf=10, segment=2)
+                               prompt_buf=32, segment=2)
+        assert cb._admit_ladder == ((32, 1), (16, 1), (8, 2))
         outs = cb.serve(reqs)
-        calls[bound] = (cb._wave_rows, cb.stats["prefill_calls"],
-                        cb.stats["prefill_rows"])
+        seen[bound] = (cb.stats["prefill_calls"], cb.stats["prefill_rows"],
+                       cb.stats["prefill_window_tokens"],
+                       cb.waste["parked_admission_lag"])
         for req, out in zip(reqs, outs):
             solo = generate(model, params,
                             jnp.asarray([req.tokens], jnp.int32),
                             req.max_new)
             assert out == [int(t)
                            for t in np.asarray(solo)[0, len(req.tokens):]]
-    assert calls[20] == (2, 2, 4)
-    assert calls[default][1:] == (1, 4)
+    # one wave: (1, 16) + (1, 16) + (2, 8); nobody waits a segment
+    assert seen[default] == (3, 4, 48, 0)
+    # two waves of (1, 16) + (1, 8): the second pair sat parked while the
+    # first wave's rows decoded
+    assert seen[24][:3] == (4, 4, 48) and seen[24][3] > 0

@@ -90,15 +90,17 @@ def test_mesh_serve_matches_sharded_generate(spec, slots, devices8):
     sharded = _sharded(model, params, mesh)
     rng = np.random.default_rng(3)
     reqs = _reqs(rng, 8)
+    # prompt_buf 16: heads of up to 8 tokens share the ladder's lower
+    # rung, as many rows a dispatch as the batch axes' product
     cb = ContinuousBatcher(model, sharded, slots=slots, t_max=64,
-                           prompt_buf=10, segment=3, mesh=mesh)
+                           prompt_buf=16, segment=3, mesh=mesh)
     outs = cb.serve([Request(list(r.tokens), r.max_new) for r in reqs])
     want = _solo_batch(model, sharded, mesh, reqs)
     for i, (out, w) in enumerate(zip(outs, want)):
         assert out == w, (spec, i, out, w)
     _assert_cache_sharded(cb, want_tensor="tensor" in spec)
-    # batched admission + overlap survived the mesh: the first wave
-    # stacked `slots` admissions into one prefill, one fetch/segment
+    # batched admission + overlap survived the mesh: the rows of a rung
+    # share a dispatch, one fetch/segment
     s = cb.stats
     assert s["prefill_rows"] == len(reqs) and s["prefill_calls"] < len(reqs)
     assert s["fetches"] == s["segments"]
