@@ -7,8 +7,8 @@ reference ConvNet (``/root/reference/main.py:20-45``) and one training step
 (``main.py:57-63``: forward, nll_loss, backward, Adadelta step) in torch on
 CPU with random MNIST-shaped data, and prints steady-state samples/sec.
 
-The number feeds ``bench.py``'s ``vs_baseline`` denominator (recorded in
-``benchmarks/baseline_measured.json`` with host provenance).
+``tests/test_torch_import.py`` imports this ConvNet as the reference the
+program's port is compared with.
 """
 
 import json

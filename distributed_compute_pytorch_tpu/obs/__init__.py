@@ -21,8 +21,9 @@ Three small host-side layers, none of which touch compiled code:
   ``sample`` in the serve programs — which a profile's device ops then
   carry in their ``op_name`` (metadata only: the compiled code is the
   same).
-- :mod:`.loadgen` — the open-loop Poisson load harness behind
-  ``bench.py --serve-load-smoke`` (the ROADMAP-3 load generator).
+- :mod:`.loadgen` — the open-loop Poisson load harness: requests that
+  carry their arrival time, served in one call (the router, fleet and
+  telemetry drills under ``tests/`` drive it).
 - :mod:`.flight` — a bounded ring buffer of structured events fed from
   the span/instant call sites, dumped as a schema-versioned JSON
   artifact on every failure path (watchdog, chaos, drain, nonfinite
@@ -30,8 +31,6 @@ Three small host-side layers, none of which touch compiled code:
 - :mod:`.sentinel` — the dp-replica divergence check (u32 fingerprint
   compared via pmax-pmin inside the mesh) and the per-step hash chain
   for bitwise run diffing.
-- :mod:`.regress` — ``bench-diff``: stage-by-stage comparison of two
-  bench records gated on each stage's recorded ``spread``.
 
 The whole layer is a no-op when disabled (``metrics.set_enabled(False)``
 or ``DCP_TELEMETRY=0``): record paths return before taking any lock and
@@ -43,7 +42,7 @@ they are functional scheduler counters, not optional diagnostics.
 """
 
 from distributed_compute_pytorch_tpu.obs import (
-    flight, loadgen, metrics, regress, tracing)
+    flight, loadgen, metrics, tracing)
 from distributed_compute_pytorch_tpu.obs.flight import (
     FlightRecorder, configure_flight, current_flight, dump_on_fault)
 from distributed_compute_pytorch_tpu.obs.metrics import (
@@ -55,6 +54,6 @@ __all__ = [
     "Counter", "FlightRecorder", "Gauge", "Histogram", "MetricDict",
     "Registry", "SCOPES", "Tracer", "configure_flight",
     "configure_tracer", "current_flight", "current_tracer",
-    "dump_on_fault", "enabled", "flight", "loadgen", "metrics", "regress",
-    "scope", "set_enabled", "span", "tracing",
+    "dump_on_fault", "enabled", "flight", "loadgen", "metrics", "scope",
+    "set_enabled", "span", "tracing",
 ]

@@ -17,8 +17,7 @@ deterministic given a seed — the same property the chaos harness
 inter-arrival gaps at ``rate_rps`` (a Poisson process), prompt lengths
 and budgets uniform over the given ranges, all from one seeded
 ``numpy`` generator. ``run_load(...)`` serves it and reduces the
-results + the batcher's SLO histograms into the report the bench smoke
-prints: goodput (ok tokens per wall second), completion mix, and
+results + the batcher's SLO histograms into one report: goodput (ok tokens per wall second), completion mix, and
 p50/p90/p95/p99 for queue-wait, TTFT, TPOT and e2e latency.
 """
 

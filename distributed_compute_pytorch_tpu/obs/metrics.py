@@ -152,8 +152,7 @@ class Histogram:
             return self.max
 
     def summary(self) -> dict:
-        """The serialisable digest embedded in ``stats_snapshot()`` and
-        the bench ``extra`` blocks."""
+        """The serialisable digest embedded in ``stats_snapshot()``."""
         if self.count == 0:
             return {"count": 0}
         return {"count": self.count,

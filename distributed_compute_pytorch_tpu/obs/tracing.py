@@ -230,8 +230,8 @@ def validate_chrome_trace(events: list[dict]) -> list[str]:
     """Structural validity of a trace-event list: every ``B`` has a
     matching same-name ``E`` on the same (pid, tid) in LIFO order, and
     timestamps are monotonically non-decreasing per (pid, tid). Returns
-    the list of violations (empty == valid) — used by the load smoke's
-    trace check and ``tests/test_obs.py``."""
+    the list of violations (empty == valid) — used by
+    ``tests/test_obs.py``."""
     problems: list[str] = []
     stacks: dict = {}
     last_ts: dict = {}

@@ -61,12 +61,8 @@ _BLOCKS = (1024, 512, 256, 128)
 
 def _pick_block(t: int) -> int | None:
     """Largest MXU-friendly block dividing ``t`` (bigger blocks = fewer grid
-    steps, and the f32 score block at 1024x1024 is only 4 MB of VMEM).
-    Measured on TPU v5 lite, bf16, causal, B=4/H=8/D=64 (bench.py harness,
-    2026-07-30): 1024/1024 beats the old 512/512 default by ~2x fwd at
-    T=1024 (1.44 vs 2.82 ms) and ~30% fwd+bwd at T=4096 (5.42 vs 7.44 ms);
-    inside the full GPT-2-small train step the switch is ~10% end-to-end
-    (102.7 -> 92.7 ms). 2048 blocks exceed the compile budget here."""
+    steps, and the f32 score block at 1024x1024 is only 4 MB of VMEM;
+    2048 blocks exceed the compile budget)."""
     for b in _BLOCKS:
         if t % b == 0:
             return b

@@ -1,7 +1,7 @@
 """Weight-only int8 matmul for decode: stream weights at half the bytes.
 
-KV-cache decode is weights-bandwidth-bound (bench.py decode roofline:
-every parameter is read once per tick). Storing matmul weights as int8
+KV-cache decode is weights-bandwidth-bound (every parameter is read
+once per tick: PERF.md section 5, the serve cells' decode tick). Storing matmul weights as int8
 with a per-output-channel scale halves that stream — IF the weights
 actually cross HBM as int8. Three formulations were measured on v5e
 (2026-07-31, decode-shaped scan, 12x[768,8192], B=16; bf16 weights

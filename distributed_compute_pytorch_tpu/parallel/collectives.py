@@ -444,8 +444,7 @@ def grad_collective_stats(fn_or_jaxpr, *args, dp_axes=None,
     the floor and are not gradient traffic). Returns ``{"boundary": n,
     "in_loop": n, "bytes": total}`` — the grad-accum contract is
     ``in_loop == 0`` and ``boundary``/``bytes`` independent of the
-    accumulation factor N (tests/test_grad_accum.py; bench.py's
-    ``_bench_grad_accum`` smoke asserts the same counters)."""
+    accumulation factor N (tests/test_grad_accum.py)."""
     recs = jaxpr_collectives(fn_or_jaxpr, *args)
     out = {"boundary": 0, "in_loop": 0, "bytes": 0}
     for r in recs:

@@ -164,8 +164,7 @@ instead of re-prefilled), ``cow_copies``, ``block_pool_occupancy``
 (peak allocated fraction). ``last_block_leaks`` extends the PR 5
 slot-leak discipline to blocks: after a serve call every pool
 reference must be owned by the radix tree (or the pinned trash block)
-— asserted by tests and the bench smokes alongside
-``last_slot_leaks``.
+— asserted by the tests alongside ``last_slot_leaks``.
 
 Telemetry (ISSUE 8, ``obs/``): ``stats``/``waste`` are dict-compatible
 VIEWS over a per-batcher ``obs.metrics.Registry``; per-request SLO
@@ -480,7 +479,7 @@ class ContinuousBatcher:
         roughly doubling resident prefix tokens per HBM/host/disk/
         handoff byte. Token-identical parity is SURRENDERED at int8;
         the replacement contract is bounded per-position logit error
-        and ≥99% greedy match (the ``--serve-kvq-smoke`` A/B gate).
+        and ≥99% greedy match (``tests/test_kv_quant.py``).
         Radix keys, CRC stamps and journal replay stay dtype-agnostic
         (they key on token ids, not bytes); handoff payloads carry a
         dtype stamp and mixed-dtype imports decline to replay.
@@ -1033,9 +1032,9 @@ class ContinuousBatcher:
         # reset(). Telemetry-disabled runs keep the views counting —
         # they are functional scheduler state, not diagnostics.
         self.obs = obs_metrics.Registry()
-        # transport counters (module docstring; asserted by the CPU
-        # bench smoke): fetches == segments, every fetch with live rows
-        # behind it issued AFTER the next segment's dispatch
+        # transport counters (module docstring; asserted by
+        # tests/test_serve.py): fetches == segments, every fetch with
+        # live rows behind it issued AFTER the next segment's dispatch
         self.stats = obs_metrics.MetricDict(self.obs, "serve.", {
             "segments": 0, "fetches": 0, "fetches_overlapped": 0,
             # admission, by DISPATCHES (one call of every kernel in the
@@ -1063,9 +1062,8 @@ class ContinuousBatcher:
             **dict.fromkeys(self._count_keys, 0)})
         self.last_slot_leaks = 0   # rows still owned at serve() exit
         self.last_block_leaks = 0  # pool refs unaccounted at serve() exit
-                                   # (both must be 0 — asserted by tests
-                                   # and the bench smokes)
-        # row-tick attribution for the bench's waste_breakdown: useful
+                                   # (both must be 0 — asserted by tests)
+        # row-tick attribution (the waste breakdown): useful
         # tokens = planned_ticks - tail (tail = post-eos + budget
         # rounding); parked ticks split by whether work was waiting
         self.waste = obs_metrics.MetricDict(self.obs, "serve.waste.", {
@@ -1174,8 +1172,9 @@ class ContinuousBatcher:
         and the snapshot can never disagree — same registry), the SLO
         histogram digests (count/mean/min/max/p50/p90/p95/p99), tick
         totals and the leak counters. This is the record ``dcp-serve``
-        heartbeats, ``--metrics_jsonl`` appends, and ``bench.py``
-        embeds in every serve-stage ``extra`` block."""
+        heartbeats, ``--metrics_jsonl`` appends, and the benchmark
+        (``perfbench/runners/serve.py``) reads as differences over a
+        window."""
         return {
             "stats": dict(self.stats),
             "waste": dict(self.waste),
@@ -1419,10 +1418,10 @@ class ContinuousBatcher:
         THIS engine's KV dtype — token ``i`` embeds at logical count
         ``i`` and writes/attends at slot ``i``, the exact (position,
         count) pairs serving uses, through the same fused
-        quantize-on-write / dequantize-on-read block route. The bench
-        A/B (``--serve-kvq-smoke``) runs the probe on a bf16 and an
-        int8 engine over the same stream and records the per-position
-        KL — the bounded-error half of the relaxed parity contract.
+        quantize-on-write / dequantize-on-read block route. Run on a
+        bf16 and an int8 engine over the same stream it gives the
+        per-position KL — the bounded-error half of the relaxed parity
+        contract (``tests/test_kv_quant.py``).
         The live pool is untouched (scratch blocks, scratch table);
         under a mesh the scratch runs replicated.
 
@@ -1482,11 +1481,11 @@ class ContinuousBatcher:
 
     def record_greedy_mismatch(self, position: int, expected: int,
                                got: int, stream: str = "") -> None:
-        """Bench A/B hook: one bf16-vs-int8 greedy divergence at
+        """A/B hook: one bf16-vs-int8 greedy divergence at
         ``position`` of ``stream``. Bumps
         ``serve.kvq.greedy_mismatches`` and drops a flight-recorder
         instant so every mismatch harvested during the A/B is
-        post-mortem visible (ISSUE 16 satellite) — the smoke gate is
+        post-mortem visible (ISSUE 16 satellite) — the parity gate is
         rate-based (>=99% match), so individual mismatches are
         expected, recorded, and bounded, not fatal."""
         self.kvq["greedy_mismatches"] += 1
@@ -1596,7 +1595,7 @@ class ContinuousBatcher:
     def reset(self):
         """Fresh session on the SAME compiled programs: zero the pool,
         free every block, drop the radix cache and rewind every row.
-        Lets a caller (the serve bench; a long-running server) run many
+        Lets a caller (the tests; a long-running server) run many
         sessions while paying trace+compile once."""
         if self._radix is not None:
             self._radix.clear()
@@ -3196,8 +3195,8 @@ class ContinuousBatcher:
         def harvest(seg, overlapped: bool):
             """THE one device->host fetch per segment, under the tick
             watchdog when configured. ``overlapped`` records whether
-            the next segment was already dispatched (the counter the
-            bench smoke asserts)."""
+            the next segment was already dispatched (the counter
+            ``tests/test_serve.py`` asserts)."""
             if seg[0] == "spec":
                 harvest_verify(seg)
                 return
@@ -3395,8 +3394,8 @@ class ContinuousBatcher:
                     horizon_msg(req) if not self._fits(req) else
                     "not served (scheduler exited with work queued)")
         # slot-accounting invariant: every row must be free at exit —
-        # a leak means a cancelled/failed row kept its slot (tests and
-        # the chaos bench smoke assert last_slot_leaks == 0)
+        # a leak means a cancelled/failed row kept its slot (the tests
+        # assert last_slot_leaks == 0)
         leaked = [b for b, s in enumerate(table) if s.req_index >= 0
                   and results[s.req_index] is None]
         self.last_slot_leaks = len(leaked)

@@ -382,8 +382,7 @@ class ElasticFleetController:
                      upgrade_to=None) -> list:
         """Serve an open-loop stream elastically: split ``requests``
         into ``window``-sized batches, ``route`` each, and run one
-        :meth:`control_step` between batches (the scale period — the
-        bench asserts goodput tracks an offered-load ramp within one).
+        :meth:`control_step` between batches (the scale period).
         Identity and the positional seed default are materialised over
         the WHOLE stream up front (the single-``route`` rule), so the
         windowed run is token-identical to a monolithic one — scale

@@ -18,8 +18,8 @@ batcher's scheduler the vocabulary for that:
   ticks, and poison rows: the serving extension of the trainer's
   ``--fault_at_step``/``--fault_mode`` pattern (``train/elastic.py``),
   gated by SEGMENT count instead of step count. Every recovery path in
-  the batcher is exercised through these hooks in tests and in
-  ``bench.py --serve-chaos-smoke``; production runs never construct one.
+  the batcher is exercised through these hooks in tests
+  (``tests/test_serve_faults.py``); production runs never construct one.
 - :func:`fetch_with_timeout` (via ``train/elastic.call_with_timeout``)
   — the tick watchdog: the per-segment token harvest is the only
   device->host read in the serve loop, so a dead or wedged device

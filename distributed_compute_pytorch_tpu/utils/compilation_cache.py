@@ -12,7 +12,7 @@ exactly one rule for where it lives:
   location (listed in ``.gitignore``).
 
 Every entry point (``dcp-train``, ``dcp-serve``, ``dcp-generate``,
-``bench.py``, ``benchmarks/decompose_*.py``, ``tests/conftest.py``) calls
+``perfbench/run.py``, ``tests/conftest.py``) calls
 :func:`enable` with no argument; nothing else touches
 ``jax_compilation_cache_dir``.
 """

@@ -1,11 +1,10 @@
-"""Forensics (obs/flight, obs/sentinel, obs/regress): the flight ring's
+"""Forensics (obs/flight, obs/sentinel): the flight ring's
 bounded/ordered/thread-safe semantics and its dump-on-every-failure-path
 contract (chaos drills must produce a dump NAMING the injected fault),
 the divergence sentinel catching a single-replica bit flip within one
 check on the faked dp mesh (and staying silent on clean runs), the
-hash chain's bitwise run-diffing determinism, the post-compile HLO
-collective census closing the SPMD-jit blind spot, and the bench-diff
-gate flagging a synthetic regression while passing self-vs-self."""
+hash chain's bitwise run-diffing determinism, and the post-compile HLO
+collective census closing the SPMD-jit blind spot."""
 
 import dataclasses
 import json
@@ -24,7 +23,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_compute_pytorch_tpu.core.mesh import make_mesh
 from distributed_compute_pytorch_tpu.models.gpt2 import GPT2, GPT2Config
-from distributed_compute_pytorch_tpu.obs import flight, regress, sentinel
+from distributed_compute_pytorch_tpu.obs import flight, sentinel
 from distributed_compute_pytorch_tpu.obs import metrics as obs_metrics
 from distributed_compute_pytorch_tpu.obs import tracing
 from distributed_compute_pytorch_tpu.parallel import collectives as coll
@@ -486,110 +485,3 @@ def test_hlo_census_sees_partitioner_inserted_collectives(devices8):
     g = jax.jit(lambda x: x * 2)
     none = coll.hlo_collectives(g, np.ones((4,), np.float32))
     assert none == {"ops": {}, "count": 0, "bytes": 0}
-
-
-# ---------------------------------------------------------------------------
-# bench-diff regression gate
-# ---------------------------------------------------------------------------
-
-_BASE = {
-    "schema_version": 1,
-    "zero1": {"spread": 0.03, "step_ms": 10.0, "opt_bytes": 1000},
-    "serve": {"spread": 0.05, "tok_per_s": 100.0, "segments": 5},
-    "flags": {"ok": True},
-}
-
-
-def test_diff_self_vs_self_passes():
-    rep = regress.diff_records(_BASE, json.loads(json.dumps(_BASE)))
-    assert rep["regressions"] == [] and rep["improvements"] == []
-    assert rep["compared"] >= 4
-
-
-def test_diff_flags_synthetic_2x_regression_and_improvement():
-    new = json.loads(json.dumps(_BASE))
-    new["zero1"]["step_ms"] = 20.0                    # 2x slower: BAD
-    new["serve"]["tok_per_s"] = 200.0                 # 2x faster: GOOD
-    rep = regress.diff_records(_BASE, new)
-    assert [r["key"] for r in rep["regressions"]] == ["zero1.step_ms"]
-    assert [r["key"] for r in rep["improvements"]] == ["serve.tok_per_s"]
-
-
-def test_diff_respects_recorded_spread_as_noise_floor():
-    new = json.loads(json.dumps(_BASE))
-    new["serve"]["tok_per_s"] = 91.0    # -9% < spread 0.05 * margin 2.0
-    assert regress.diff_records(_BASE, new)["regressions"] == []
-    new["serve"]["tok_per_s"] = 80.0    # -20% > the floor
-    rep = regress.diff_records(_BASE, new)
-    assert [r["key"] for r in rep["regressions"]] == ["serve.tok_per_s"]
-    # a wider margin absorbs it again
-    assert regress.diff_records(_BASE, new, margin=5.0)["regressions"] == []
-
-
-def test_diff_never_gates_unknown_direction_keys():
-    new = json.loads(json.dumps(_BASE))
-    new["serve"]["segments"] = 50                     # 10x: unknown dir
-    rep = regress.diff_records(_BASE, new)
-    assert rep["regressions"] == []
-    assert any(c["key"] == "serve.segments" for c in rep["changed"])
-    assert regress.direction("step_ms") == -1
-    assert regress.direction("p99") == -1
-    assert regress.direction("tok_per_s") == +1
-    assert regress.direction("segments") == 0
-
-
-def test_diff_main_exit_codes(tmp_path, capsys):
-    base = tmp_path / "base.json"
-    base.write_text(json.dumps(_BASE))
-    worse = json.loads(json.dumps(_BASE))
-    worse["zero1"]["step_ms"] = 30.0
-    new = tmp_path / "new.json"
-    new.write_text(json.dumps(worse))
-    assert regress.main([str(base), str(base)]) == 0  # self: passes
-    assert regress.main([str(base), str(new)]) == 1   # regression: fails
-    out = capsys.readouterr()
-    assert "REGRESSION zero1.step_ms" in out.err
-    assert regress.main([str(base)]) == 2             # usage
-    assert regress.main(["/nonexistent", str(base)]) == 2
-
-
-def test_load_record_handles_all_artifact_shapes(tmp_path):
-    bare = tmp_path / "bare.json"
-    bare.write_text(json.dumps(_BASE))
-    assert regress.load_record(str(bare)) == _BASE
-    wrapper = tmp_path / "wrap.json"                  # BENCH_r shape
-    wrapper.write_text(json.dumps(
-        {"n": 5, "cmd": "bench", "rc": 0, "tail": "...",
-         "parsed": _BASE}))
-    assert regress.load_record(str(wrapper)) == _BASE
-    log = tmp_path / "run.log"                        # last JSON line
-    log.write_text("noise\nmore noise\n" + json.dumps(_BASE) + "\n")
-    assert regress.load_record(str(log)) == _BASE
-    empty = tmp_path / "empty.log"
-    empty.write_text("no json here\n")
-    with pytest.raises(ValueError):
-        regress.load_record(str(empty))
-
-
-def test_historical_bench_records_self_diff(tmp_path, capsys):
-    """The real trajectory artifacts (BENCH_r*.json) load and self-diff
-    clean — the no-preprocessing contract."""
-    hist = sorted(f for f in os.listdir(REPO)
-                  if f.startswith("BENCH_r") and f.endswith(".json"))
-    if not hist:
-        pytest.skip("no BENCH_r*.json in repo")
-    p = os.path.join(REPO, hist[-1])
-    assert regress.main([p, p]) == 0
-    rep = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
-    assert rep["compared"] > 0 and rep["regressions"] == []
-
-
-def test_bench_print_record_stamps_schema(capsys):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.remove(REPO)
-    bench._print_record({"metric": "x", "value": 1.0})
-    rec = json.loads(capsys.readouterr().out)
-    assert rec["schema_version"] == bench.SCHEMA_VERSION == 1

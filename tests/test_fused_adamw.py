@@ -1,7 +1,6 @@
 """Fused AdamW Pallas kernel vs optax.adamw: step-for-step parity.
 
-Interpret mode on CPU; the real-TPU proof rides the bench (GPT-2 stage
-runs the fused optimizer) and tests/test_flash_tpu.py-style gating isn't
+Interpret mode on CPU; tests/test_flash_tpu.py-style gating isn't
 needed because the kernel is pure elementwise (no Mosaic-specific layout
 hazards beyond the tiling rule, which interpret mode now mirrors for the
 shapes used here).

@@ -16,8 +16,7 @@ deliberately surrendered; the relaxed contract pinned here is
   CLI/journal refuse inconsistent dtype configs up front.
 
 Kept CPU-cheap per the tier-1 budget note: tiny models, starved pools,
-shared compiled programs. The expensive bf16-vs-int8 A/B with KL
-recording lives in ``bench.py --serve-kvq-smoke``.
+shared compiled programs.
 """
 
 import dataclasses
@@ -167,8 +166,39 @@ def test_int8_mesh_sharded(devices8, gpt2):
     assert q8.last_block_leaks == 0
 
 
+def test_int8_pool_holds_1p8x_the_tokens_a_byte_at_hd64():
+    """The capacity the int8 pool is for, from the live cache arrays: a
+    cached token-head costs hd + 4 bytes under int8 (the +4 is its f32
+    scale) against 2 * hd under bf16, so at a production-shaped hd = 64
+    a pool byte holds 2 * 64 / 68 = 1.88x the resident prefix tokens
+    (tiny()'s hd = 16 gives 1.6x by geometry, hence the wider model).
+    Float K/V slabs count at the 2 bytes they ship as on the chip (the
+    CPU holds f32 stand-ins); scales count at their full width."""
+    cfg = dataclasses.replace(GPT2Config.tiny(), d_model=128, num_heads=2,
+                              max_seq_len=256)
+    model = GPT2(cfg)
+    assert model.kv_cache_spec()[1] == 64
+    params, _ = model.init(jax.random.key(1))
+    bf = ContinuousBatcher(model, params, **_COMMON)
+    q8 = ContinuousBatcher(model, params, **_COMMON, kv_dtype="int8")
+
+    def block_bytes(cb):
+        total = 0
+        for cache in cb._caches:
+            for name, leaf in cache.items():
+                per_block = int(np.prod(leaf.shape)) // leaf.shape[1]
+                ships_as_bf16 = (name == "kv" and jnp.issubdtype(
+                    leaf.dtype, jnp.floating))
+                total += per_block * (2 if ships_as_bf16
+                                      else leaf.dtype.itemsize)
+        return total
+
+    assert bf.bt == q8.bt                 # the same tokens a block
+    assert block_bytes(bf) / block_bytes(q8) >= 1.8
+
+
 def test_logit_probe_finite_kl(gpt2):
-    """The bench A/B's bounded-error gate: per-position KL between the
+    """The bounded-error gate: per-position KL between the
     bf16 and int8 probes is finite and small on a short stream, and
     the probe leaves the live pool untouched."""
     model, params = gpt2

@@ -272,6 +272,12 @@ def test_disk_spill_roundtrip(gpt2, tmp_path):
     t = dict(on.tier)
     assert t["disk_spills"] >= 1 and t["disk_hits"] >= 1
     assert t["disk_crc_miss"] == 0
+    # three heads round-robin over a pool that holds one beside a live
+    # row is LRU's worst case: without the tier every rehit finds its
+    # head evicted; with it the hits come back from host and disk while
+    # the device pool stays the fixed allocation it was
+    assert off.stats["prefix_hits"] == 0 and on.stats["prefix_hits"] > 0
+    assert 0 < on.stats["block_pool_occupancy"] <= 1.0
     assert on.last_block_leaks == 0 and on.last_host_block_leaks == 0
     # every disk-tier entry still indexes a live part; no orphan files
     disk_keys = {e.disk_key for e in on._radix.entries

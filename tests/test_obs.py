@@ -189,8 +189,8 @@ def test_span_disabled_paths():
 # ---------------------------------------------------------------------------
 
 def test_stats_snapshot_matches_legacy_views(tiny_cb):
-    """stats_snapshot() must agree with the legacy dicts (which tests
-    and bench consumers still index) AND with the registry gauges the
+    """stats_snapshot() must agree with the legacy dicts (which the
+    tests still index) AND with the registry gauges the
     MetricDict mirrors into — the three can never diverge."""
     rng = np.random.default_rng(7)
     results = tiny_cb.serve_detailed(_requests(rng, 6))
@@ -297,6 +297,38 @@ def test_arrival_gating_delays_admission(tiny_cb):
     bad.arrival_s = -1.0
     (res,) = tiny_cb.serve_detailed([bad])
     assert res.status == "failed" and "arrival_s" in res.error
+
+
+def test_open_loop_load_keeps_tokens_and_leaves_a_valid_trace(tiny_cb):
+    """Arrival gating must never change outputs: the stream offered at
+    its Poisson arrival times, with the spans traced, completes every
+    request with the tokens the same stream gives when all of it is due
+    at once; every request's first token is stamped, nothing leaks, and
+    the spans written DURING the drill validate as a Chrome trace."""
+    spec = loadgen.LoadSpec(n_requests=8, rate_rps=40.0, seed=0,
+                            prompt_len=(2, 9), max_new=(3, 7))
+    load = loadgen.offered_load(spec)
+    base = tiny_cb.serve_detailed(
+        [dataclasses.replace(r, arrival_s=0.0) for r in load])
+    tiny_cb.reset()
+    tr = tracing.Tracer()
+    prev = tracing.configure_tracer(tr)
+    try:
+        report = loadgen.run_load(
+            tiny_cb, [dataclasses.replace(r) for r in load])
+    finally:
+        tracing.configure_tracer(prev)
+    assert report["statuses"] == {"ok": len(load)}
+    assert [r.tokens for r in report["results"]] == \
+        [r.tokens for r in base]
+    assert report["completed_tokens"] == sum(len(r.tokens) for r in base)
+    assert report["slo"]["ttft_s"]["count"] == len(load)
+    assert report["snapshot"]["slot_leaks"] == 0
+    assert report["snapshot"]["block_leaks"] == 0
+    events = tr.events()
+    assert tracing.validate_chrome_trace(events) == []
+    assert {"admit_wave", "dispatch_segment", "harvest"} <= \
+        {e["name"] for e in events}
 
 
 @pytest.mark.slow

@@ -9,7 +9,7 @@ operands allow, nowhere else.
 On CPU the kernel runs in the Pallas interpreter (which models the async
 copies and their semaphores in this jax); the Mosaic-compiled kernel at
 the benchmark cell's shape runs under ``DCP_TEST_TPU=1`` on the chip,
-like tests/test_decode_attention.py."""
+like tests/test_flash_tpu.py."""
 
 import dataclasses
 import os

@@ -255,8 +255,7 @@ def test_int8_weight_quantized_parity():
 
 def test_reset_reuses_compiled_programs():
     """reset() rewinds a batcher for a fresh session on the same jitted
-    pieces — outputs match a brand-new batcher's (the serve bench leans
-    on this to keep compile out of its timed walls)."""
+    pieces — outputs match a brand-new batcher's."""
     model = GPT2(dataclasses.replace(GPT2Config.tiny(), max_seq_len=128))
     params, _ = model.init(jax.random.key(0))
     rng = np.random.default_rng(13)
@@ -363,7 +362,7 @@ def test_transport_counters_overlap_and_batched_admission():
     assert s["fetches_overlapped"] == s["fetches"] - 1
     assert s["prefill_rows"] == len(reqs)
     assert s["prefill_calls"] < len(reqs)     # dispatches, not per-request
-    # every row-tick attributed exactly once (the bench waste breakdown)
+    # every row-tick attributed exactly once (the waste breakdown)
     w = cb.waste
     total = cb.ticks * cb.B
     assert (w["planned_ticks"] + w["parked_admission_lag"]

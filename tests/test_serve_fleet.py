@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 from distributed_compute_pytorch_tpu.models.gpt2 import GPT2, GPT2Config
+from distributed_compute_pytorch_tpu.obs import flight
 from distributed_compute_pytorch_tpu.obs.loadgen import LoadSpec, offered_load
 from distributed_compute_pytorch_tpu.serve import ContinuousBatcher, Request
 from distributed_compute_pytorch_tpu.serve_fleet import (
@@ -293,6 +294,46 @@ def test_rolling_upgrade_mid_route_zero_drops(gpt2):
     assert router.stats["retire_migrations"] == \
         ctl.fleet["upgrade_migrations"]
     _assert_no_leaks(router)
+
+
+def test_ramp_scales_up_at_once_and_takes_a_push_in_the_same_stream(gpt2):
+    """One stream, both membership changes: a backlog hits a 1-replica
+    fleet (max 3) whose controller must decide ``up`` at its FIRST
+    control step, and a same-value weight push lands after the first
+    window through the rolling walk. Zero failed requests, tokens
+    identical to a fixed fleet's, every active member (added ones
+    too) on the new version, no member leaks, and the scale and
+    upgrade events are in the flight recorder for the post-mortem."""
+    model, params = gpt2
+    reqs = _requests(29, 16)
+    ref = _reference(gpt2, reqs)
+    router, ctl = _controller(gpt2, n=1, min_replicas=1, max_replicas=3,
+                              up_after=1, down_after=99)
+    decisions = []
+    control_step = ctl.control_step
+
+    def logged(queued=0):
+        decisions.append(control_step(queued))
+        return decisions[-1]
+
+    ctl.control_step = logged
+    rec = flight.FlightRecorder(capacity=512)
+    prev = flight.configure_flight(rec)
+    try:
+        res = ctl.serve_stream(_copies(reqs), window=4,
+                               upgrade_to=(params, 1))
+        kinds = {ev["kind"] for ev in rec.events()}
+    finally:
+        flight.configure_flight(prev)
+    assert decisions and decisions[0] == "up"
+    assert all(r.status == OK for r in res)
+    assert [r.tokens for r in res] == [r.tokens for r in ref]
+    assert ctl.fleet["scale_ups"] >= 1 and ctl.fleet["upgrades"] == 1
+    active = router.active_replicas()
+    assert len(active) > 1
+    assert all(router.replicas[i].weights_version == 1 for i in active)
+    _assert_no_leaks(router)
+    assert {"fleet_scale_up", "fleet_upgrade_step"} <= kinds
 
 
 def test_reload_weights_drops_cached_kv(gpt2):

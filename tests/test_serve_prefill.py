@@ -76,6 +76,10 @@ def _parity(model, params, reqs, chunk, **kw):
     assert on.prefill["chunked_admissions"] > 0
     assert on.prefill["chunk_waves"] > 0
     assert on.prefill["chunk_tokens"] > 0
+    # what bounds the stall of a decoding row: all the rows of a wave
+    # share ONE budget of ``chunk`` suffix tokens
+    assert on.prefill["chunk_tokens"] \
+        <= chunk * on.prefill["chunk_waves"]
     assert on.last_slot_leaks == 0 and on.last_block_leaks == 0
     return on
 

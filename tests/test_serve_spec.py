@@ -3,8 +3,8 @@
 every drill here is a parity pin — spec-on serving must be
 token-identical to spec-off serving (greedy AND sampled, bf16 and int8
 weights, off-mesh and mesh-sharded, through faults and auto-disable) no
-matter how bad the proposer is. Throughput is the bench's business
-(``bench.py --serve-spec-smoke``); correctness lives here.
+matter how bad the proposer is. Throughput is the benchmark's business
+(``perfbench/``: no cell speculates yet); correctness lives here.
 
 Kept CPU-cheap for tier-1 (ROADMAP budget note): tiny models, short
 streams, the k/segment sweep rides behind ``slow``.
@@ -119,6 +119,31 @@ def test_spec_greedy_parity_both_families(name, model):
     assert 0 < s["accepted"] <= s["proposed"]
     assert "spec" in on.stats_snapshot()
     _assert_clean(on)
+
+
+def test_spec_repetitive_stream_emits_more_than_a_token_a_window():
+    """The mechanism speculation pays with, by count: a verify window
+    costs one weight stream like a plain tick, so on a stream the
+    n-gram proposer can draft (looped periods, one prompt in four
+    random so rejects run too) each row-window must emit MORE than one
+    token, and a healthy acceptance rate never trips auto-disable."""
+    model = GPT2(dataclasses.replace(GPT2Config.tiny(), max_seq_len=128))
+    params, _ = model.init(jax.random.key(0))
+    rng = np.random.default_rng(0)
+    reqs = _repetitive_requests(rng, 9, max_new=16) + _requests(rng, 3)
+    k = 4
+    kw = dict(slots=4, t_max=64, prompt_buf=16, segment=4)
+    off = ContinuousBatcher(model, params, **kw)
+    on = ContinuousBatcher(model, params, speculate=SpecConfig(k=k), **kw)
+    assert on.serve(_clone(reqs)) == off.serve(_clone(reqs))
+    s = on.spec
+    row_windows = s["proposed"] / k          # k drafts a row-window
+    assert row_windows > 0
+    assert s["emitted_tokens"] / row_windows > 1.0, s
+    assert s["acceptance_rate"] > 0
+    assert s["autodisabled"] == 0
+    _assert_clean(on)
+    _assert_clean(off)
 
 
 def test_spec_int_coercion_and_int8_weight_parity():

@@ -95,20 +95,31 @@ def test_cli_rejects_bad_width_buckets():
                     "--decode_width_buckets", "0"])
 
 
-def test_parity_crossing_bucket_edge_greedy_and_sampled(gpt2):
+@pytest.mark.parametrize("t_max,long_new,saving", [
+    (64, 30, 1.0),
+    # a horizon provisioned DEEP for its traffic (32 blocks, the long
+    # row peaks near the 16-block rung): the waste the ladder is for,
+    # so the reads the full width would have made are at least twice
+    # the reads made
+    (256, 96, 2.0),
+])
+def test_parity_crossing_bucket_edge_greedy_and_sampled(t_max, long_new,
+                                                        saving):
     """The core contract: bucketing on vs off is token-identical while
     the long row GROWS its bucket mid-stream, with sampled rows amid
     greedy ones (the (seed, tokens-so-far) key schedule must not see
     the width), and the gather counters must show the traffic win."""
-    model, params = gpt2
+    model = GPT2(dataclasses.replace(GPT2Config.tiny(),
+                                     max_seq_len=max(128, t_max)))
+    params, _ = model.init(jax.random.key(0))
     rng = np.random.default_rng(19)
-    reqs = _edge_requests(rng)
+    reqs = _edge_requests(rng, long_new=long_new)
     for i in (1, 3):
         reqs[i].temperature = 0.9
         reqs[i].seed = 90 + i
 
     def run(**kw):
-        cb = ContinuousBatcher(model, params, slots=2, t_max=64,
+        cb = ContinuousBatcher(model, params, slots=2, t_max=t_max,
                                prompt_buf=10, segment=4, **kw)
         return cb, cb.serve(_clone(reqs))
 
@@ -118,6 +129,10 @@ def test_parity_crossing_bucket_edge_greedy_and_sampled(gpt2):
     assert on.width["bucket_growths"] >= 1
     assert on.width["gathered_block_reads"] \
         < on.width["full_width_block_reads"]
+    assert on.width["full_width_block_reads"] \
+        >= saving * on.width["gathered_block_reads"]
+    assert on.last_slot_leaks == 0 and on.last_block_leaks == 0
+    assert off.last_slot_leaks == 0 and off.last_block_leaks == 0
     assert on.width["bytes_saved_vs_full"] > 0
     assert 0.0 < on.width["bucket_occupancy"] <= 1.0
     # every dispatched width is a ladder rung -> the compiled program
