@@ -23,6 +23,7 @@ uses NCHW/OIHW; XLA:TPU strongly prefers channels-last).
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass, field
 from typing import Sequence
@@ -142,6 +143,36 @@ def avg_pool2d(x, window: int = 2, stride: int | None = None):
     return summed / (window * window)
 
 
+def _mask_and_scale(x, seed, keep: float, mask_shape):
+    """``x / keep`` where the generator's 32 bits of an element lie under
+    ``keep * 2**32`` and 0 elsewhere: Bernoulli(keep) decided on integers,
+    with no uniform float in between. The same ``seed`` gives the same bits,
+    which is what lets the backward pass draw them again."""
+    threshold = min(round(keep * 2 ** 32), 2 ** 32 - 1)
+    with scope("dropout"):
+        _, bits = lax.rng_bit_generator(seed, mask_shape, dtype=jnp.uint32)
+        return jnp.where(bits < jnp.uint32(threshold), x / keep,
+                         0.0).astype(x.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
+def _masked(x, seed, keep: float, mask_shape):
+    return _mask_and_scale(x, seed, keep, mask_shape)
+
+
+def _masked_fwd(x, seed, keep, mask_shape):
+    # the seed is all autodiff keeps between the passes; a compiler that
+    # merges the two draws keeps the mask as it sees fit (PERF.md, PR 31)
+    return _mask_and_scale(x, seed, keep, mask_shape), seed
+
+
+def _masked_bwd(keep, mask_shape, seed, g):
+    return _mask_and_scale(g, seed, keep, mask_shape), None
+
+
+_masked.defvjp(_masked_fwd, _masked_bwd)
+
+
 def dropout(x, rate: float, rng, train: bool,
             broadcast_dims: Sequence[int] = ()):
     """``nn.Dropout`` equivalent (reference ``main.py:25-26``). Pure: identity
@@ -153,12 +184,13 @@ def dropout(x, rate: float, rng, train: bool,
     """
     if not train or rate == 0.0:
         return x
-    keep = 1.0 - rate
     mask_shape = tuple(1 if d in tuple(broadcast_dims) else s
                        for d, s in enumerate(x.shape))
-    with scope("dropout"):
-        mask = jax.random.bernoulli(rng, keep, mask_shape)
-        return jnp.where(mask, x / keep, 0.0).astype(x.dtype)
+    # XLA's ``RngBitGenerator`` starts from ``uint32[4]``: the caller's key's
+    # words, twice, as JAX's ``rbg`` implementation seeds itself. Every key
+    # derivation above (``fold_in``, ``split``) stays on the caller's key.
+    seed = jnp.resize(jax.random.key_data(rng), (4,))
+    return _masked(x, seed, 1.0 - rate, mask_shape)
 
 
 @dataclass(frozen=True)

@@ -54,16 +54,21 @@ TRAIN_SCOPES = {
 }
 
 
-@pytest.mark.parametrize("name", sorted(TRAIN_SCOPES))
-def test_train_step_carries_every_training_scope(name):
-    kw = {"dropout_rate": 0.1} if name == "gpt2" else {}
+def _train_step_locations(name, **kw) -> set:
+    """The locations of the tiny model's lowered train step."""
     model = build_model(name, preset="tiny", **kw)
     mesh = make_mesh("data=1", devices=jax.devices()[:1])
     init_fn, train_step, _ = make_step_fns(
         model, optax.adamw(1e-3), mesh, donate=False)
     state = init_fn(jax.random.key(0))
     x = jnp.zeros((2, 16), jnp.int32)
-    locs = _locations(train_step.lower(state, x, x))
+    return _locations(train_step.lower(state, x, x))
+
+
+@pytest.mark.parametrize("name", sorted(TRAIN_SCOPES))
+def test_train_step_carries_every_training_scope(name):
+    kw = {"dropout_rate": 0.1} if name == "gpt2" else {}
+    locs = _train_step_locations(name, **kw)
     for s in TRAIN_SCOPES[name]:
         if s == "dropout":
             # inside the one helper, so it nests under its caller's scope
@@ -75,6 +80,20 @@ def test_train_step_carries_every_training_scope(name):
         assert _has(locs, f"transpose(jvp({s}))"), s
     assert _has(locs, "optimizer")
     assert not any("jvp(optimizer)" in n for n in locs)
+
+
+def test_dropout_scope_holds_the_bit_generator_in_both_passes():
+    """The masks' bits are XLA's ``RngBitGenerator``, an operation of its
+    own that no consumer fuses away, drawn under ``dropout`` in the forward
+    and again in the backward; the key derivations above the helper
+    (``fold_in``, ``split``) stay threefry and stay outside the scope."""
+    locs = _train_step_locations("gpt2", dropout_rate=0.1)
+    for outer in ("embed", "attn", "mlp"):
+        for way in (f"jvp({outer})", f"transpose(jvp({outer}))"):
+            assert _has(locs, way, "dropout", "rng_bit_generator"), way
+    under = [n for n in locs if _has({n}, "dropout")]
+    assert under and not any(
+        w in n for n in under for w in ("threefry", "random_bits", "uniform"))
 
 
 def test_zero1_update_is_one_optimizer_scope(devices8):
