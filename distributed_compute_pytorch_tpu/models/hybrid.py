@@ -2,17 +2,35 @@
 feed-forward) pair chosen by ``layer_types[i]`` and ``mlp_layer_types[i]``
 (ROADMAP C6: a hybrid is a configuration, not a file of its own).
 
-Mixers: ``"full_attention"`` (causal over the whole context) and
+Mixers: ``"full_attention"`` (causal over the whole context),
 ``"sliding_attention"`` (causal over the last ``sliding_window`` tokens,
-the token itself counted). Feed-forwards: ``"dense"`` (SwiGLU) and
+the token itself counted) and ``"latent_attention"`` (below).
+Feed-forwards: ``"dense"`` (SwiGLU) and
 ``"sparse"`` (routed experts with a shared expert, of which this chip
 holds ``experts_held``: ``models/moe.py::HeldExperts``). The rest is the
 Llama recipe (``models/llama.py``: bias-free q/k/v/o at GQA width,
 half-split RoPE, RMSNorm, untied head) with three switches a published
 family sets: ``qk_norm`` (an RMSNorm over each head's channels of q and
 of k, before any rotation), ``rope_sliding_only`` (full layers rotate
-nothing) and the norm placement, which here is AFTER each sublayer
-(``h = h + norm(sublayer(h))``, no norm on a sublayer's input).
+nothing) and ``norm_placement``: ``"post"`` norms each sublayer's OUTPUT
+(``h = h + norm(sublayer(h))``, no norm on its input), ``"pre"`` its
+INPUT (``h = h + sublayer(norm(h))``, the DeepSeek-V3 recipe).
+
+LATENT attention (MLA, the DeepSeek-V2/V3 recipe): queries through a
+low-rank bottleneck with its own norm (``q_down``, ``q_norm``, ``q_up``);
+keys and values through ONE joint down-projection ``kv_down`` to
+``kv_lora_rank`` compressed channels (normed, ``kv_norm``) plus a rotary
+key of ``qk_rope_head_dim`` channels that all heads share; a head's q/k
+are ``[nope, rope]`` (``qk_nope_head_dim + qk_rope_head_dim`` wide, the
+rope part rotated as interleaved pairs), its v ``v_head_dim``. What the
+cache keeps of a token is ``[c, k_rope]`` after norm and rotation, one
+vector of ``latent_width`` channels with no heads and no K/V pair (cache
+kind ``"latent"``). Two algebraically equal forms: ``apply`` (prefill)
+EXPANDS ``c`` through ``kv_up`` into every head's ``k_nope`` and ``v``
+and runs ordinary causal attention; ``decode_step`` ABSORBS ``kv_up``
+into the query (``q~ = W_uk q_nope``) and the output (``o = W_uv^T
+o~``), so the heads attend the cached vector itself
+(``ops/attention.py::latent_write_and_attend``).
 
 The layers differ in shape, so the parameters are a per-layer list
 (``params["layers"][i]``), not one stacked tree, and the serving layer
@@ -40,9 +58,10 @@ from distributed_compute_pytorch_tpu.models.transformer import (
     dispatch_attention)
 from distributed_compute_pytorch_tpu.obs.tracing import scope
 from distributed_compute_pytorch_tpu.ops import attention as A
-from distributed_compute_pytorch_tpu.ops.rotary import apply_rope
+from distributed_compute_pytorch_tpu.ops.rotary import (
+    apply_rope, apply_rope_interleaved)
 
-MIXERS = ("full_attention", "sliding_attention")
+MIXERS = ("full_attention", "sliding_attention", "latent_attention")
 MLPS = ("dense", "sparse")
 
 
@@ -71,6 +90,15 @@ class HybridConfig:
     routed_scale: float = 1.0
     norm_topk_prob: bool = True
     param_dtype: jnp.dtype = jnp.float32
+    # "post": norm each sublayer's output; "pre": its input
+    norm_placement: str = "post"
+    # the latent_attention layers' widths (num_heads heads; head_dim and
+    # num_kv_heads are not theirs)
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.mlp_layer_types):
@@ -82,6 +110,16 @@ class HybridConfig:
             for k in kinds:
                 if k not in known:
                     raise ValueError(f"layer kind {k!r} is none of {known}")
+        if self.norm_placement not in ("post", "pre"):
+            raise ValueError(
+                f"norm_placement {self.norm_placement!r} is neither 'post' "
+                f"nor 'pre'")
+        if "latent_attention" in self.layer_types and not all((
+                self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                self.qk_rope_head_dim, self.v_head_dim)):
+            raise ValueError(
+                "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
+                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
         if self.num_heads % self.num_kv_heads:
             raise ValueError(
                 f"num_heads={self.num_heads} must be a multiple of "
@@ -90,6 +128,11 @@ class HybridConfig:
     @property
     def num_layers(self) -> int:
         return len(self.layer_types)
+
+    @property
+    def latent_width(self) -> int:
+        """Channels a latent layer's cache keeps of a token."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
     @classmethod
     def tiny(cls) -> "HybridConfig":
@@ -111,8 +154,9 @@ def _dense(din, dout):
 
 @dataclass(frozen=True)
 class HybridBlock:
-    """One layer: its mixer and its feed-forward, both followed by their
-    RMSNorm before the residual add."""
+    """One layer: its mixer and its feed-forward, each with its RMSNorm:
+    on its output before the residual add (``norm_placement`` ``"post"``)
+    or on its input (``"pre"``)."""
 
     config: HybridConfig
     mixer: str
@@ -124,8 +168,18 @@ class HybridBlock:
                 if self.mixer == "sliding_attention" else None)
 
     @property
+    def latent(self) -> bool:
+        return self.mixer == "latent_attention"
+
+    @property
     def cache_kind(self) -> str:
+        if self.latent:
+            return "latent"
         return "ring" if self.window else "paged"
+
+    @property
+    def _pre(self) -> bool:
+        return self.config.norm_placement == "pre"
 
     def experts(self) -> HeldExperts:
         c = self.config
@@ -142,13 +196,26 @@ class HybridBlock:
         d, hd = c.d_model, c.head_dim
         dense = lambda din, dout: L.Dense(din, dout, use_bias=False,
                                           param_dtype=c.param_dtype)
-        p = {"q": dense(d, c.num_heads * hd).init(next(ks)),
-             "k": dense(d, c.num_kv_heads * hd).init(next(ks)),
-             "v": dense(d, c.num_kv_heads * hd).init(next(ks)),
-             "o": dense(c.num_heads * hd, d).init(next(ks)),
-             "post_attn_norm": L.RMSNorm(d, c.rms_eps).init(None),
-             "post_mlp_norm": L.RMSNorm(d, c.rms_eps).init(None)}
-        if c.qk_norm:
+        norms = (("pre_attn_norm", "pre_mlp_norm") if self._pre
+                 else ("post_attn_norm", "post_mlp_norm"))
+        if self.latent:
+            H, n, r = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
+            p = {"q_down": dense(d, c.q_lora_rank).init(next(ks)),
+                 "q_norm": L.RMSNorm(c.q_lora_rank, c.rms_eps).init(None),
+                 "q_up": dense(c.q_lora_rank, H * (n + r)).init(next(ks)),
+                 "kv_down": dense(d, c.latent_width).init(next(ks)),
+                 "kv_norm": L.RMSNorm(c.kv_lora_rank, c.rms_eps).init(None),
+                 "kv_up": dense(c.kv_lora_rank,
+                                H * (n + c.v_head_dim)).init(next(ks)),
+                 "o": dense(H * c.v_head_dim, d).init(next(ks))}
+        else:
+            p = {"q": dense(d, c.num_heads * hd).init(next(ks)),
+                 "k": dense(d, c.num_kv_heads * hd).init(next(ks)),
+                 "v": dense(d, c.num_kv_heads * hd).init(next(ks)),
+                 "o": dense(c.num_heads * hd, d).init(next(ks))}
+        for name in norms:
+            p[name] = L.RMSNorm(d, c.rms_eps).init(None)
+        if c.qk_norm and not self.latent:
             p["q_norm"] = L.RMSNorm(hd, c.rms_eps).init(None)
             p["k_norm"] = L.RMSNorm(hd, c.rms_eps).init(None)
         if self.mlp == "dense":
@@ -158,6 +225,10 @@ class HybridBlock:
         else:
             p["moe"] = self.experts().init(next(ks))
         return p
+
+    def _norm(self, params, name, x):
+        c = self.config
+        return L.RMSNorm(c.d_model, c.rms_eps).apply(params[name], x)
 
     def _qkv(self, params, x, positions):
         """Projected q/k/v at GQA width; QK-norm, then rotation where
@@ -179,48 +250,119 @@ class HybridBlock:
             k = apply_rope(k, positions, c.rope_theta)
         return q, k, v
 
+    def _latent_q_and_token(self, params, x, positions):
+        """A latent layer's queries ``[B, H, T, nope + rope]`` (the rope
+        part rotated) and what its cache keeps of each token ``[B, T,
+        latent_width]``: the normed compressed channels, then the rotated
+        rotary key all heads share."""
+        c = self.config
+        d, r, kvl = c.d_model, c.qk_rope_head_dim, c.kv_lora_rank
+        qw = c.num_heads * (c.qk_nope_head_dim + r)
+        cq = L.RMSNorm(c.q_lora_rank, c.rms_eps).apply(
+            params["q_norm"],
+            _dense(d, c.q_lora_rank).apply(params["q_down"], x))
+        q = A.split_heads(_dense(c.q_lora_rank, qw).apply(params["q_up"], cq),
+                          c.num_heads)
+        q = apply_rope_interleaved(q, positions, c.rope_theta, rotary_dim=r)
+        ckv = _dense(d, c.latent_width).apply(params["kv_down"], x)
+        comp = L.RMSNorm(kvl, c.rms_eps).apply(params["kv_norm"],
+                                               ckv[..., :kvl])
+        k_rope = apply_rope_interleaved(ckv[:, None, :, kvl:], positions,
+                                        c.rope_theta)[:, 0]
+        return q, jnp.concatenate([comp, k_rope], axis=-1)
+
+    def _latent_prefill(self, params, x, positions, kv_mask, kv_sink):
+        """The EXPANDED form: every head's ``k_nope`` and ``v`` from the
+        compressed channels, ordinary causal attention at q/k width
+        ``nope + rope`` and v width ``v_head_dim``."""
+        c = self.config
+        H, n, kvl = c.num_heads, c.qk_nope_head_dim, c.kv_lora_rank
+        q, token = self._latent_q_and_token(params, x, positions)
+        if kv_sink is not None:
+            kv_sink.append((token,))
+        with scope("latent_absorb"):
+            kv = A.split_heads(
+                _dense(kvl, H * (n + c.v_head_dim)).apply(
+                    params["kv_up"], token[..., :kvl]), H)
+        k_rope = jnp.broadcast_to(
+            token[:, None, :, kvl:],
+            kv.shape[:3] + (c.qk_rope_head_dim,))
+        k = jnp.concatenate([kv[..., :n], k_rope], axis=-1)
+        # the default scale is q's head width ** -0.5: (nope + rope)
+        return dispatch_attention(q, k, kv[..., n:], causal=True,
+                                  kv_mask=kv_mask)
+
+    def _latent_decode(self, params, x, cache, pos):
+        """The ABSORBED form: ``W_uk`` folded into the query and ``W_uv``
+        into the output, so the heads attend the cached vectors
+        themselves; equal to :meth:`_latent_prefill` in exact
+        arithmetic."""
+        c = self.config
+        H, n, kvl = c.num_heads, c.qk_nope_head_dim, c.kv_lora_rank
+        q, token = self._latent_q_and_token(params, x, pos[:, None])
+        w = params["kv_up"]["kernel"].astype(x.dtype).reshape(
+            kvl, H, n + c.v_head_dim)
+        with scope("latent_absorb"):
+            q_abs = jnp.einsum("bhn,chn->bhc", q[:, :, 0, :n], w[:, :, :n])
+        o_lat, cache = A.latent_write_and_attend(
+            jnp.concatenate([q_abs, q[:, :, 0, n:]], axis=-1), token[:, 0],
+            cache, pos, v_width=kvl,
+            scale=(n + c.qk_rope_head_dim) ** -0.5)
+        with scope("latent_absorb"):
+            o = jnp.einsum("bhc,chv->bhv", o_lat, w[:, :, n:])
+        return o[:, :, None, :], cache
+
     def _attn_out(self, params, x, o):
         c = self.config
-        a = _dense(c.num_heads * c.head_dim, c.d_model).apply(
-            params["o"], A.merge_heads(o))
-        return x + L.RMSNorm(c.d_model, c.rms_eps).apply(
-            params["post_attn_norm"], a)
+        o = A.merge_heads(o)
+        a = _dense(o.shape[-1], c.d_model).apply(params["o"], o)
+        return x + (a if self._pre
+                    else self._norm(params, "post_attn_norm", a))
 
     def _mlp(self, params, x, token_mask=None, counts_sink=None):
         c = self.config
         with scope("mlp"):
+            y = self._norm(params, "pre_mlp_norm", x) if self._pre else x
             if self.mlp == "dense":
                 g = jax.nn.silu(_dense(c.d_model, c.d_ff).apply(
-                    params["gate"], x))
+                    params["gate"], y))
                 m = _dense(c.d_ff, c.d_model).apply(
                     params["down"],
-                    g * _dense(c.d_model, c.d_ff).apply(params["up"], x))
+                    g * _dense(c.d_model, c.d_ff).apply(params["up"], y))
             else:
-                m = self.experts().apply(params["moe"], x,
+                m = self.experts().apply(params["moe"], y,
                                          token_mask=token_mask,
                                          counts_sink=counts_sink)
-            return x + L.RMSNorm(c.d_model, c.rms_eps).apply(
-                params["post_mlp_norm"], m)
+            return x + (m if self._pre
+                        else self._norm(params, "post_mlp_norm", m))
 
     def apply(self, params, x, *, kv_mask=None, kv_sink=None,
               positions=None, counts_sink=None):
         """The whole-sequence forward of one layer (prefill). ``kv_sink``
-        captures the K/V a cache stores (after QK-norm and rotation, at
-        kv-head width); ``kv_mask`` (``[B, T]``, 1 = real) hides pad keys
+        captures what a cache stores: the K/V pair (after QK-norm and
+        rotation, at kv-head width), or a latent layer's one token vector
+        ``(token,)``; ``kv_mask`` (``[B, T]``, 1 = real) hides pad keys
         and keeps pad tokens out of the experts."""
         T = x.shape[1]
         with scope("attn"):
             pos = jnp.arange(T) if positions is None else positions
-            q, k, v = self._qkv(params, x, pos)
-            if kv_sink is not None:
-                kv_sink.append((k, v))
-            if self.window:
-                with scope("attn_local"):
-                    o = A.attention(q, k, v, causal=True, kv_mask=kv_mask,
-                                    window=self.window)
+            y = self._norm(params, "pre_attn_norm", x) if self._pre else x
+            if self.latent:
+                with scope("attn_latent"):
+                    x = self._attn_out(params, x, self._latent_prefill(
+                        params, y, pos, kv_mask, kv_sink))
             else:
-                o = dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask)
-            x = self._attn_out(params, x, o)
+                q, k, v = self._qkv(params, y, pos)
+                if kv_sink is not None:
+                    kv_sink.append((k, v))
+                if self.window:
+                    with scope("attn_local"):
+                        o = A.attention(q, k, v, causal=True,
+                                        kv_mask=kv_mask, window=self.window)
+                else:
+                    o = dispatch_attention(q, k, v, causal=True,
+                                           kv_mask=kv_mask)
+                x = self._attn_out(params, x, o)
         return self._mlp(params, x, token_mask=kv_mask,
                          counts_sink=counts_sink)
 
@@ -228,20 +370,29 @@ class HybridBlock:
                     counts_sink=None, live=None):
         """One cached decode tick, ``x [B, 1, d]`` at per-row slots ``pos
         [B]``. ``cache`` is this layer's kind: the paged pool with its
-        table, or a ring ``{"kv": [2, B, hk, R, hd]}``. ``live`` (``[B]``, 1 =
+        table (K/V pairs, or a latent layer's token vectors), or a ring
+        ``{"kv": [2, B, hk, R, hd]}``. ``live`` (``[B]``, 1 =
         a row in the plan) keeps parked rows out of the experts."""
         with scope("attn"):
-            rope_pos = (pos[:, None] if jnp.ndim(pos) == 1
-                        else jnp.atleast_1d(pos))
-            q, k, v = self._qkv(params, x, rope_pos)
-            if self.window:
-                with scope("attn_local"):
-                    o, cache = A.ring_write_and_attend(
-                        q, k, v, cache, pos, self.window)
+            y = self._norm(params, "pre_attn_norm", x) if self._pre else x
+            if self.latent:
+                with scope("attn_latent"):
+                    o, cache = self._latent_decode(
+                        params, y, cache,
+                        jnp.broadcast_to(jnp.atleast_1d(pos), x.shape[:1]))
+                    x = self._attn_out(params, x, o)
             else:
-                o, cache = A.cache_write_and_attend(q, k, v, cache, pos,
-                                                    slot_mask=slot_mask)
-            x = self._attn_out(params, x, o)
+                rope_pos = (pos[:, None] if jnp.ndim(pos) == 1
+                            else jnp.atleast_1d(pos))
+                q, k, v = self._qkv(params, y, rope_pos)
+                if self.window:
+                    with scope("attn_local"):
+                        o, cache = A.ring_write_and_attend(
+                            q, k, v, cache, pos, self.window)
+                else:
+                    o, cache = A.cache_write_and_attend(
+                        q, k, v, cache, pos, slot_mask=slot_mask)
+                x = self._attn_out(params, x, o)
         # a parked row (live 0) routes nowhere: its token is garbage, and
         # the experts' counts are of the rows in the plan
         return self._mlp(params, x, token_mask=live,
@@ -282,6 +433,24 @@ class HybridLM:
     def kv_cache_spec(self):
         return self.config.num_kv_heads, self.config.head_dim
 
+    @property
+    def latent_width(self) -> int:
+        """Channels a token takes in a latent layer's pool."""
+        return self.config.latent_width
+
+    @property
+    def cache_block_tokens(self) -> int | None:
+        """Tokens to a pool block where the model implies one: a latent
+        layer's token is one short vector (1152 bytes at 576 bf16
+        channels), so a block of the default 8 tokens would be a 9 KB
+        copy and a table entry for every 8 tokens of a long context; 32
+        tokens make a block 40 KB, about a GQA block of 8 tokens at 8 KV
+        heads of 128. Measured on the v5e (PERF.md, PR 32): the decode
+        kernel's call takes 1.25 ms at 16, 1.02 at 32 and 1.01 at 64
+        tokens a block, and a smaller block wastes less of a row's last
+        one. None = the batcher's default."""
+        return 32 if "latent_attention" in self.config.layer_types else None
+
     def init(self, key):
         c = self.config
         ks = jax.random.split(key, c.num_layers + 2)
@@ -296,7 +465,7 @@ class HybridLM:
         }, {}
 
     def embed(self, params, tokens, positions=None):
-        del positions          # rotation lives in the sliding layers
+        del positions          # rotation lives in the layers' mixers
         c = self.config
         with scope("embed"):
             return L.Embedding(c.vocab_size, c.d_model).apply(params["wte"],

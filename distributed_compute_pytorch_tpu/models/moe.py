@@ -364,10 +364,11 @@ class HeldExperts:
     over every token under its weight, zero where the token did not pick
     it; above (an admission wave) the held assignments are sorted by
     expert and go through grouped matrix products (``lax.ragged_dot``) in
-    windows of a static number of rows: one window when the load is near
-    uniform, more under skew, so the cost follows the assignments and
-    never the worst case. ``token_mask`` (1 = real) keeps pad tokens out:
-    they route nowhere.
+    windows of a static number of rows: one window of ``window_rows`` when
+    the load is near uniform, and what it leaves, under skew, in windows
+    of ``tail_rows`` (an eighth of it), so the cost follows the
+    assignments and never the worst case. ``token_mask`` (1 = real) keeps
+    pad tokens out: they route nowhere.
     """
 
     d_model: int
@@ -430,6 +431,16 @@ class HeldExperts:
         expect = n_tokens * self.top_k * n / self.num_experts
         return int(-(-(1.125 * expect) // 512) * 512)
 
+    def tail_rows(self, n_tokens: int) -> int:
+        """Rows of the windows after the first: an eighth of it, in whole
+        512s. The grouped products skip the row tiles no group covers, but
+        a window's gather, activation and scatter-add run over all its
+        rows: a layer whose held share lies just over the first window's
+        edge paid for two whole windows (on the v5e half as much again for
+        a 16,384-token dispatch: PERF.md, PR 32) where it now pays for an
+        eighth more."""
+        return max(self.window_rows(n_tokens) // 8 // 512 * 512, 512)
+
     def _dense(self, ex, x, local, w):
         """Every held expert over every token: ``[n, N, d]`` products
         under the token's weight for that expert (0 where not chosen)."""
@@ -445,12 +456,14 @@ class HeldExperts:
         return jnp.einsum("end,ne->nd", y, we).astype(x.dtype)
 
     def _sorted(self, ex, x, local, w):
-        """Held assignments sorted by expert, grouped products over
-        windows of ``window_rows`` sorted rows, scatter-added back."""
+        """Held assignments sorted by expert, grouped products over one
+        window of ``window_rows`` sorted rows and, for what it leaves,
+        windows of ``tail_rows``, scatter-added back."""
         _, n = self.held
         N, k = local.shape
         A = N * k
         C = min(self.window_rows(N), -(-A // 8) * 8)
+        Ct = min(self.tail_rows(N), C)
         key = local.reshape(A)                 # n = not held / pad: last
         order = jnp.argsort(key, stable=True)
         tok = (order // k).astype(jnp.int32)
@@ -465,24 +478,28 @@ class HeldExperts:
         rd = lambda a, b, g: jax.lax.ragged_dot(
             a, b.astype(a.dtype), g, preferred_element_type=jnp.float32)
 
-        def window(carry):
-            s, out = carry
-            t = jax.lax.dynamic_slice(tok, (s,), (C,))
-            wt = jax.lax.dynamic_slice(ws, (s,), (C,))
-            live = (s + jnp.arange(C)) < total
-            g = (jnp.clip(ends - s, 0, C)
-                 - jnp.clip(ends - sizes - s, 0, C)).astype(jnp.int32)
-            xg = x[t]
-            h = (jax.nn.silu(rd(xg, ex["gate"], g))
-                 * rd(xg, ex["up"], g)).astype(x.dtype)
-            y = rd(h, ex["down"], g) * jnp.where(live, wt, 0.0)[:, None]
-            # rows past the window's groups are unspecified by ragged_dot
-            y = jnp.where(live[:, None], y, 0.0).astype(out.dtype)
-            return s + C, out.at[t].add(y)
+        def window(rows):
+            def body(carry):
+                s, out = carry
+                t = jax.lax.dynamic_slice(tok, (s,), (rows,))
+                wt = jax.lax.dynamic_slice(ws, (s,), (rows,))
+                live = (s + jnp.arange(rows)) < total
+                g = (jnp.clip(ends - s, 0, rows)
+                     - jnp.clip(ends - sizes - s, 0, rows)).astype(jnp.int32)
+                xg = x[t]
+                h = (jax.nn.silu(rd(xg, ex["gate"], g))
+                     * rd(xg, ex["up"], g)).astype(x.dtype)
+                y = rd(h, ex["down"], g) * jnp.where(live, wt, 0.0)[:, None]
+                # rows past the window's groups are unspecified by
+                # ragged_dot
+                y = jnp.where(live[:, None], y, 0.0).astype(out.dtype)
+                return s + rows, out.at[t].add(y)
+            return body
 
-        _, out = jax.lax.while_loop(
-            lambda c: c[0] < total, window,
-            (jnp.int32(0), jnp.zeros_like(x)))
+        first = jax.lax.cond(total > 0, window(C), lambda c: c,
+                             (jnp.int32(0), jnp.zeros_like(x)))
+        _, out = jax.lax.while_loop(lambda c: c[0] < total, window(Ct),
+                                    first)
         return out
 
     def apply(self, params, x, token_mask=None, counts_sink=None):

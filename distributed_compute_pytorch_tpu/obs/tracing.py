@@ -59,12 +59,17 @@ from distributed_compute_pytorch_tpu.obs import flight, metrics
 # ``admit``/``decode``; ``router``/``experts``/``shared_expert`` nest in
 # ``mlp`` (routed-expert layers, ``models/moe.py::HeldExperts``) and
 # ``attn_local`` in ``attn`` (a window layer's attention: the banded
-# prefill and the ring read). The benchmark's scope metrics
+# prefill and the ring read); ``attn_latent`` in ``attn`` too (everything
+# a latent-attention mixer does, ``models/hybrid.py``) and
+# ``latent_absorb`` inside it (the products with the up-projection of the
+# compressed K/V: its expansion in prefill, its absorption into query and
+# output in a decode tick). The benchmark's scope metrics
 # (``perfbench/layer_metrics``) name these and nothing else.
 SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "optimizer", "grad_reduce",
           "admit", "decode", "kv_gather", "kv_write", "sample",
-          "router", "experts", "shared_expert", "attn_local")
+          "router", "experts", "shared_expert", "attn_local",
+          "attn_latent", "latent_absorb")
 
 
 def scope(name: str):

@@ -467,6 +467,84 @@ def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     return out, {**pool, "table": table}
 
 
+def latent_read_path(pool: dict) -> str:
+    """Which engine reads a LATENT pool (``{"kv": [1, P, 1, bt, W]}``, a
+    token one vector: ``models/hybrid.py``, mixer ``latent_attention``) in
+    a decode tick: ``"kernel"`` (``dcp_paged_latent_decode_attn``, the
+    pool in place through the block table) or ``"gather"`` (the gathered
+    logical view and a dense softmax). The one policy for Pallas
+    dispatchers (``cache_update._pallas_ok``: TPU backend, no mesh
+    context, window-aligned blocks) and a float pool."""
+    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
+        _pallas_ok)
+    return ("kernel" if "scale" not in pool and _pallas_ok(pool, axis=3)
+            else "gather")
+
+
+def latent_pool_width(width: int) -> int:
+    """Channels a latent pool gives a token of ``width`` channels: whole
+    128-lane tiles (576 -> 640). A TPU lays an array's minor axis out in
+    such tiles whatever its length, so the padding costs no byte the chip
+    would not spend anyway, and a copy of a block in or out of the pool is
+    then whole tiles (Mosaic refuses to slice 576 of 640 lanes)."""
+    return -(-width // 128) * 128
+
+
+def pad_channels(x, width: int):
+    """``x [..., w]`` zero-padded on its last axis to ``width``."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def latent_write_and_attend(q, latent, cache, pos, *, v_width: int,
+                            scale: float):
+    """One decode tick of a LATENT-attention layer against its paged pool
+    ``{"kv": [1, P, 1, bt, Wp], "table": int32 [B, nb]}``: row ``b`` writes
+    its token's vector ``latent [B, W]`` (compressed K/V, then the rotary
+    key; zero-padded to the pool's ``Wp = latent_pool_width(W)``, as the
+    queries are) at the (block, offset) its table maps slot ``pos[b]`` to,
+    then its ``H`` absorbed queries ``q [B, H, W]`` attend slots ``0 ..
+    pos[b]``: the stored vector is every head's key and its first
+    ``v_width`` channels are the value. In place through the table where
+    :func:`latent_read_path` says ``kernel``; otherwise (CPU, a mesh) over
+    the gathered view, which is also the kernel's test oracle. The same
+    block table, write kernel and width rules as
+    :func:`_paged_write_and_attend`. Returns ``(o [B, H, v_width],
+    new_cache)``."""
+    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
+        kv_pool_insert_all)
+    table = cache["table"]
+    pool = {n: leaf for n, leaf in cache.items() if n != "table"}
+    bt, Wp = pool["kv"].shape[3:]
+    q, latent = pad_channels(q, Wp), pad_channels(latent, Wp)
+    pos = jnp.broadcast_to(jnp.atleast_1d(pos), (q.shape[0],))
+    blk = jnp.take_along_axis(table, (pos // bt)[:, None], axis=1)[:, 0]
+    with scope("kv_write"):
+        pool = kv_pool_insert_all(
+            pool, {"kv": latent[None, :, None, None, :]}, blk, pos % bt)
+    if latent_read_path(pool) == "kernel":
+        from distributed_compute_pytorch_tpu.ops.pallas import (
+            decode_attention)
+        out = decode_attention.paged_latent_decode_attention_pallas(
+            q, pool["kv"], table, pos, v_width=v_width, scale=scale)
+    else:
+        out = latent_attention_gathered(q, pool["kv"], table, pos,
+                                        v_width=v_width, scale=scale)
+    return out, {**pool, "table": table}
+
+
+def latent_attention_gathered(q, pool_kv, table, pos, *, v_width: int,
+                              scale: float):
+    """The portable read of a latent pool: the rows' logical views
+    gathered through ``table``, one dense masked softmax over them (all
+    heads share the one key, so the heads are the query axis)."""
+    with scope("kv_gather"):
+        lat = gather_kv_blocks(pool_kv, table)[0]        # [B, 1, T, W]
+    valid = _pos_valid_mask(pos, lat.shape[2])
+    out = dot_product_attention(q[:, None], lat, lat[..., :v_width],
+                                mask=valid, scale=scale)
+    return out[:, 0]
+
+
 def cache_verify_and_attend(q, k, v, cache, positions, *, slot_mask=None):
     """One speculative VERIFY step against the paged pool cache: all ``W``
     window positions of every row written and attended in a single pass.

@@ -25,6 +25,12 @@ How it is built:
 
 What it measured against the gather on the chip: PERF.md section 6,
 PR 25.
+
+:func:`paged_latent_decode_attention_pallas`
+(``dcp_paged_latent_decode_attn``) is the same stream over a LATENT pool
+(``models/hybrid.py``, mixer ``latent_attention``): a token is one vector
+with no K/V pair and no heads, every query head attends it, and its first
+channels are the value, so a block is fetched once and used twice.
 """
 
 from __future__ import annotations
@@ -70,34 +76,17 @@ _SCRATCH_BYTES = 4 << 20
 _ISSUE_GROUP = 4
 
 
-def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
-                         buf, sem, slot0_ref, *, chunk_blocks: int,
-                         nb_w: int):
-    """Grid step ``b`` attends row ``b``'s query over logical slots
-    ``0 .. pos[b]`` of ``pool_hbm [2, P, hk, bt, hd]`` (left in HBM)
-    through ``tbl_ref`` (the row-major ``[B, nb_w]`` table). The row's
-    live blocks stream in chunks of ``chunk_blocks``: every live block of
-    a chunk is one async copy ``pool[:, table[b, j]] -> buf[slot, j]``
-    (``[2, hk, bt, hd]``: both planes), all on one semaphore; the next
-    chunk's copies — the next ROW's first chunk after a row's last — are
-    started before the current chunk is waited for, so no row pays a
-    DMA latency of its own. ``slot0_ref`` (SMEM) carries the buffer
-    parity over the grid steps, which therefore run in order
-    (``arbitrary``). Blocks past ``pos[b] // bt`` are neither fetched
-    nor waited for (copies go out ``_ISSUE_GROUP`` to a loop step, the
-    last group filled up with the row's last live block again); buffer
-    slots no copy filled hold an earlier chunk's (finite) data, masked
-    to probability 0. Online softmax per KV head: f32 scores, running
-    max, sum and accumulator; the probabilities meet V in the pool's
-    dtype, as in ``cached_attention``."""
-    b = pl.program_id(0)
-    n_rows = pl.num_programs(0)
-    _, _, hk, bt, hd = pool_hbm.shape
-    C = chunk_blocks
+def _chunk_stream(pos_ref, tbl_ref, block_at, buf, sem, *, C: int,
+                  nb_w: int, bt: int):
+    """The block stream both decode kernels run: ``start(row, c, slot)``
+    issues the async copies of chunk ``c`` of ``row``'s live blocks
+    (``block_at(phys)``: the pool block in HBM) into ``buf[slot, j]``, all
+    on ``sem[slot]``; ``wait(row, c, slot)`` takes them up;
+    ``live_blocks(row)`` is the number of blocks the row's position
+    reaches. Blocks past ``pos[row] // bt`` are neither fetched nor
+    waited for: copies go out ``group`` to a loop step, the last group
+    filled up with the row's last live block again."""
     group = math.gcd(C, _ISSUE_GROUP)
-    G = q_ref.shape[2]
-    cdt = buf.dtype
-    scale = hd ** -0.5
 
     def live_blocks(row):
         # never past the shipped table (a parked row's position means
@@ -114,7 +103,7 @@ def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
             for u in range(group):
                 j = g * group + u
                 phys = tbl_ref[row * nb_w + jnp.minimum(lo + j, live - 1)]
-                pltpu.make_async_copy(pool_hbm.at[:, phys], buf.at[slot, j],
+                pltpu.make_async_copy(block_at(phys), buf.at[slot, j],
                                       sem.at[slot]).start()
             return carry
         lax.fori_loop(0, issue_steps(row, c), issue, 0)
@@ -130,6 +119,39 @@ def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
                 part = buf.at[slot, pl.ds(0, k)]
                 pltpu.make_async_copy(part, part, sem.at[slot]).wait()
             k //= 2
+
+    return live_blocks, start, wait
+
+
+def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
+                         buf, sem, slot0_ref, *, chunk_blocks: int,
+                         nb_w: int):
+    """Grid step ``b`` attends row ``b``'s query over logical slots
+    ``0 .. pos[b]`` of ``pool_hbm [2, P, hk, bt, hd]`` (left in HBM)
+    through ``tbl_ref`` (the row-major ``[B, nb_w]`` table). The row's
+    live blocks stream in chunks of ``chunk_blocks``
+    (:func:`_chunk_stream`): every live block of
+    a chunk is one async copy ``pool[:, table[b, j]] -> buf[slot, j]``
+    (``[2, hk, bt, hd]``: both planes), all on one semaphore; the next
+    chunk's copies — the next ROW's first chunk after a row's last — are
+    started before the current chunk is waited for, so no row pays a
+    DMA latency of its own. ``slot0_ref`` (SMEM) carries the buffer
+    parity over the grid steps, which therefore run in order
+    (``arbitrary``). Buffer
+    slots no copy filled hold an earlier chunk's (finite) data, masked
+    to probability 0. Online softmax per KV head: f32 scores, running
+    max, sum and accumulator; the probabilities meet V in the pool's
+    dtype, as in ``cached_attention``."""
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    _, _, hk, bt, hd = pool_hbm.shape
+    C = chunk_blocks
+    G = q_ref.shape[2]
+    cdt = buf.dtype
+    scale = hd ** -0.5
+    live_blocks, start, wait = _chunk_stream(
+        pos_ref, tbl_ref, lambda phys: pool_hbm.at[:, phys], buf, sem,
+        C=C, nb_w=nb_w, bt=bt)
 
     @pl.when(b == 0)
     def _():
@@ -253,3 +275,144 @@ def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
     )(jnp.broadcast_to(jnp.atleast_1d(pos).astype(jnp.int32), (B,)),
       table.reshape(-1).astype(jnp.int32), q.reshape(B, hk, G, hd), pool_kv)
     return out.reshape(B, H, 1, hd)
+
+
+# ---------------------------------------------------------------------------
+# LATENT pool: ``[1, P, 1, bt, W]``, a token ONE vector of ``W`` channels
+# (the compressed K/V of a latent-attention layer followed by its shared
+# rotary key, zero-padded to whole 128-lane tiles:
+# ``ops/attention.py::latent_pool_width``). All ``H`` absorbed queries of a
+# row attend that one vector as key; its first ``V`` channels are the value.
+# ---------------------------------------------------------------------------
+
+# A chunk in tokens, as ``_CHUNK_TOKENS`` above. A token is W x 2 bytes
+# (1280 at 576 channels in 640 lanes), so two buffers of 1024 tokens are
+# 2.6 MB. Measured on the v5e at the long-document cell's shape (64 rows
+# of ~8.6k live tokens, 32 heads; PERF.md, PR 32): chunks of 256 / 512 /
+# 1024 / 2048 tokens took 1.63 / 1.17 / 1.02 / 1.03 ms a call at blocks
+# of 32 tokens (1.88 / 1.43 / 1.25 / 1.17 at 16, 1.56 / 1.14 / 1.01 /
+# 1.02 at 64); issuing copies eight to a loop step instead of four
+# changed nothing at 32 and 64.
+_LATENT_CHUNK_TOKENS = 1024
+
+
+def _latent_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
+                          buf, sem, slot0_ref, *, chunk_blocks: int,
+                          nb_w: int, v_width: int, scale: float):
+    """Grid step ``b``: row ``b``'s ``H`` queries ``q_ref [1, H, W]`` over
+    logical slots ``0 .. pos[b]`` of ``pool_hbm [1, P, 1, bt, W]`` through
+    the table, streamed as :func:`_paged_decode_kernel` streams its pool
+    (one async copy a block, ``[bt, W]``). A chunk is the key of every
+    head (scores over its ``V`` compressed channels and over its ``W - V``
+    rotary channels, two products whose operands start on a lane tile) and,
+    in its first ``V`` channels, the value. Online softmax in f32; output
+    ``[1, H, V]``."""
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    _, _, _, bt, W = pool_hbm.shape
+    C, V = chunk_blocks, v_width
+    H = q_ref.shape[1]
+    cdt = buf.dtype
+    live_blocks, start, wait = _chunk_stream(
+        pos_ref, tbl_ref, lambda phys: pool_hbm.at[0, phys, 0], buf, sem,
+        C=C, nb_w=nb_w, bt=bt)
+
+    @pl.when(b == 0)
+    def _():
+        # as in _paged_decode_kernel: the scratch only ever holds pool data
+        buf[...] = jnp.zeros(buf.shape, cdt)
+        slot0_ref[0] = 0
+        start(0, 0, 0)
+
+    slot0 = slot0_ref[0]
+    pos = pos_ref[b]
+    n_chunks = pl.cdiv(live_blocks(b), C)
+    q = q_ref[0].astype(cdt)                         # [H, W]
+    q_c, q_r = q[:, :V], q[:, V:]
+    nt = (((1,), (1,)), ((), ()))
+
+    def chunk_step(c, carry):
+        m, l, acc = carry
+        slot = (slot0 + c) % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(b, c + 1, 1 - slot)
+
+        @pl.when(jnp.logical_and(c + 1 == n_chunks, b + 1 < n_rows))
+        def _():
+            start(b + 1, 0, 1 - slot)
+
+        wait(b, c, slot)
+        lat = buf[slot].reshape(C * bt, W)
+        val = lat[:, :V]
+        s = (lax.dot_general(q_c, val, nt,
+                             preferred_element_type=jnp.float32)
+             + lax.dot_general(q_r, lat[:, V:], nt,
+                               preferred_element_type=jnp.float32)) * scale
+        ids = c * (C * bt) + lax.broadcasted_iota(jnp.int32, (H, C * bt), 1)
+        s = jnp.where(ids <= pos, s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + lax.dot_general(
+            p.astype(cdt), val, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    init = (jnp.full((H, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, V), jnp.float32))
+    _, l, acc = lax.fori_loop(0, n_chunks, chunk_step, init)
+    slot0_ref[0] = (slot0 + n_chunks) % 2
+    out_ref[0] = (acc / l).astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("v_width", "scale", "interpret"))
+def paged_latent_decode_attention_pallas(q, pool_kv, table, pos, *,
+                                         v_width: int, scale: float,
+                                         interpret: bool = False):
+    """Single-position decode attention over a LATENT paged pool, read in
+    place. ``q [B, H, W]`` (this tick's absorbed queries: ``v_width``
+    channels against the compressed part, the rest against the rotary
+    key); ``pool_kv [1, P, 1, bt, W]`` (the serving pool leaf as it is);
+    ``table`` int32 ``[B, nb_w]``; ``pos`` int32 ``[B]`` (row ``b`` attends
+    slots ``0 .. pos[b]``). Returns ``[B, H, v_width]`` in ``q``'s dtype:
+    ``softmax(scale * q . latent) @ latent[..., :v_width]``, the same
+    mathematics as the gather + dense form of
+    ``ops/attention.py::latent_write_and_attend``. Jitted on its own for
+    the reason :func:`paged_decode_attention_pallas` gives."""
+    B, H, W = q.shape
+    s, _, hk, bt, Wp = pool_kv.shape
+    assert (s == 1 and hk == 1 and Wp == W and 0 < v_width < W
+            and W % 128 == 0 and v_width % 128 == 0), (
+        q.shape, pool_kv.shape, v_width)
+    nb_w = table.shape[1]
+    C = min(max(1, _LATENT_CHUNK_TOKENS // bt), nb_w)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, H, W), lambda b, p, t: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, H, v_width), lambda b, p, t: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, C, bt, W), pool_kv.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, chunk_blocks=C, nb_w=nb_w,
+                          v_width=v_width, scale=scale),
+        out_shape=jax.ShapeDtypeStruct((B, H, v_width), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="dcp_paged_latent_decode_attn",
+        interpret=interpret,
+    )(jnp.broadcast_to(jnp.atleast_1d(pos).astype(jnp.int32), (B,)),
+      table.reshape(-1).astype(jnp.int32), q, pool_kv)

@@ -114,7 +114,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         # forces multi-pass f32 matmuls at a fraction of peak
         q = q_ref[0]                                  # [Bq, D]
         k = k_ref[0]                                  # [Bk, D]
-        v = v_ref[0]                                  # [Bk, D]
+        v = v_ref[0]                                  # [Bk, Dv]
         s = _dot_tt(q, k) * scale
         if causal:
             rows = offset + qi * block_q + jax.lax.broadcasted_iota(
@@ -166,13 +166,19 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
                block_q, block_k, window=None, kv_heads=None):
     bh, t, d = q.shape
     tk = k.shape[1]
+    # v may have another head width than q and k (latent attention: 192
+    # against 128): the accumulator and the output take v's
+    dv = v.shape[2]
     masked = kv_mask is not None
+    v_spec = None
     if window is None:
         grid = (bh, t // block_q, tk // block_k)
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
                                    offset=offset, masked=masked,
                                    block_q=block_q, block_k=block_k)
         kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
+        if dv != d:
+            v_spec = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0))
         mask_spec = _mask_spec(heads, block_k) if masked else None
         name = "dcp_flash_fwd"
     else:
@@ -180,6 +186,7 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
         # read at THEIR head count (query head h reads kv head h // G:
         # no repeated copy of K/V is made for the kernel)
         assert causal and offset == 0 and block_q == block_k and t == tk
+        assert dv == d, (d, dv)
         nband = -(-(window - 1) // block_k) + 1
         grid = (bh, t // block_q, nband)
         kernel = functools.partial(_fwd_kernel, scale=scale, causal=True,
@@ -203,7 +210,7 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
         name = "dcp_flash_fwd_band"
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        kv_spec, kv_spec,
+        kv_spec, v_spec or kv_spec,
     ]
     args = [q, k, v]
     if masked:
@@ -214,15 +221,15 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
         grid=grid,
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
         ],
         out_shape=[
-            _out_struct(q.shape, q.dtype, q),
+            _out_struct((bh, t, dv), q.dtype, q),
             _out_struct((bh, t, 1), jnp.float32, q),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
@@ -336,6 +343,11 @@ def _flash_bwd(res, g, kv_mask, heads, scale, causal, offset,
     q, k, v, o, lse = res
     bh, t, d = q.shape
     tk = k.shape[1]
+    if v.shape[2] != d:
+        raise NotImplementedError(
+            f"flash attention with a v head width ({v.shape[2]}) other than "
+            f"q's and k's ({d}) has a forward only: latent-attention layers "
+            f"are served, not trained")
     do = g.astype(jnp.float32)
     # single-lane rank-3 [bh, t, 1]: a lane dim equal to the full array dim
     # satisfies the tiling rule without a 128-lane broadcast; lse arrives
@@ -546,6 +558,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     block_q: int = DEFAULT_BLOCK,
                     block_k: int = DEFAULT_BLOCK):
     """Fused attention: ``[b, h, t, d]`` in, same out. Differentiable.
+    ``v`` may have another head width than ``q`` and ``k`` (``[b, h, tk,
+    dv]``: a latent-attention head is 192 wide for q and k, 128 for v);
+    the output is then ``[b, h, t, dv]`` and only the forward exists.
 
     ``kv_mask``: optional ``[b, kv_len]`` key-validity mask (bool or 0/1
     float; True/1 = attend) — the padding mask for variable-length batches.
@@ -598,7 +613,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     qf = q.reshape(b * h, tp, d)
     kf = k.reshape(b * h, tkp, d)
-    vf = v.reshape(b * h, tkp, d)
+    dv = v.shape[-1]
+    vf = v.reshape(b * h, tkp, dv)
     if kv_mask is None:
         o = _flash(qf, kf, vf, scale, causal, offset, block_q, block_k)
     else:
@@ -607,5 +623,5 @@ def flash_attention(q, k, v, *, causal: bool = False,
         mask3 = kv_mask.astype(jnp.float32).reshape(b, 1, tkp)
         o = _flash_masked(qf, kf, vf, mask3, h,
                           scale, causal, offset, block_q, block_k)
-    o = o.reshape(b, h, tp, d)
+    o = o.reshape(b, h, tp, dv)
     return o[:, :, :t, :] if pad_q else o

@@ -4,7 +4,9 @@ encoding.
 Capability beyond the reference (whose only model is a position-free CNN,
 ``/root/reference/main.py:20-45``); needed for the modern decoder rung.
 Convention matches the open Llama implementations (half-split
-``rotate_half``, NOT interleaved pairs) so weights/numerics port 1:1.
+``rotate_half``, NOT interleaved pairs) so weights/numerics port 1:1;
+:func:`apply_rope_interleaved` is the other convention (adjacent pairs,
+the DeepSeek-V2/V3 latent-attention recipe with ``rope_interleave``).
 
 TPU notes: cos/sin are computed in float32 (bf16 phases lose precision at
 long context) and the rotation is two fused elementwise multiplies — XLA
@@ -59,3 +61,31 @@ def apply_rope(x, positions, theta: float = 10000.0):
     x32 = x.astype(jnp.float32)
     out = x32 * cos + _rotate_half(x32) * sin
     return out.astype(x.dtype)
+
+
+def apply_rope_interleaved(x, positions, theta: float = 10000.0,
+                           rotary_dim: int | None = None):
+    """Rotate the LAST ``rotary_dim`` channels of ``x [B, H, T, hd]``
+    (all of them by default) as INTERLEAVED pairs: channels ``(2i, 2i+1)``
+    of that tail turn by the angle ``pos * theta ** (-2i / rotary_dim)``;
+    the channels before it pass through. ``positions`` as
+    :func:`apply_rope`. A latent-attention head rotates 64 of its 192
+    channels this way (``models/hybrid.py``). The half-split form is this
+    rotation followed by one fixed permutation of the channels, so scores
+    between vectors rotated the same way are equal in both."""
+    hd = x.shape[-1]
+    rd = hd if rotary_dim is None else rotary_dim
+    half = rd // 2
+    inv_freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freqs = positions.astype(jnp.float32)[..., None] * inv_freq   # [.., T, rd/2]
+    lift = (lambda a: a[:, None]) if freqs.ndim == 3 else (
+        lambda a: a[None, None])
+    cos, sin = lift(jnp.cos(freqs)), lift(jnp.sin(freqs))
+    tail = x[..., hd - rd:].astype(jnp.float32)
+    pairs = tail.reshape(tail.shape[:-1] + (half, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+    out = out.reshape(tail.shape).astype(x.dtype)
+    if rd == hd:
+        return out
+    return jnp.concatenate([x[..., :hd - rd], out], axis=-1)

@@ -97,6 +97,15 @@ instead, with everything the TPU touches remaining static-shaped:
   collective the two layouts imply — the portable-redistribution move
   (arXiv:2112.01075) that resharded admission K/V in the dense design
   now reshards attached blocks.
+- **Caches of three kinds behind one block table**: a model of layer
+  kinds (``models/hybrid.py``) says per layer what it keeps: ``paged``
+  (the pool of K/V pairs above), ``ring`` (a window layer's last tokens a
+  slot, no table) or ``latent`` (a latent-attention layer: a paged pool on
+  the SAME table, free list and block writes whose token is one vector
+  with no heads and no K/V pair, read by its own decode kernel).
+  ``stats_snapshot()["cache_kinds"]`` / ``["cache_bytes_per_token"]`` say
+  which and at what cost; what such a model cannot be served with yet is
+  refused at construction (``_refuse_for_layer_kinds``).
 - **Overlapped host scheduler**: a plain queue, with the single
   device->host fetch per segment (the token harvest) OVERLAPPED with
   the next segment's execution: segment N+1 is dispatched BEFORE
@@ -518,7 +527,7 @@ class ContinuousBatcher:
         from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
             _pallas_ok, _window)
         from distributed_compute_pytorch_tpu.ops.attention import (
-            paged_read_path)
+            latent_pool_width, latent_read_path, paged_read_path)
         if prompt_buf > t_max:
             raise ValueError(f"prompt_buf {prompt_buf} > t_max {t_max}")
         if admit_policy not in ("fifo", "skip_fit"):
@@ -592,6 +601,7 @@ class ContinuousBatcher:
             if hasattr(model, "layer_block") else None)
         if self._layer_blocks is not None:
             self._refuse_for_layer_kinds(
+                {b.cache_kind for b in self._layer_blocks},
                 prefix_cache=prefix_cache, speculate=speculate,
                 tiers=(host_cache_mb is not None
                        or host_cache_blocks is not None
@@ -719,7 +729,12 @@ class ContinuousBatcher:
         # granularity — observationally free, the per-row position mask
         # stops at each row's live position)
         align = _window(dtype)
-        bt = kv_block_tokens if kv_block_tokens is not None else align
+        # a model of layer kinds whose cache implies a block size says so
+        # (a latent layer's token is one short vector: models/hybrid.py)
+        implied = (model.cache_block_tokens
+                   if self._layer_blocks is not None else None)
+        bt = kv_block_tokens if kv_block_tokens is not None else (
+            implied or align)
         self.bt = -(-bt // align) * align
         self.t_max = -(-t_max // self.bt) * self.bt
         self.nb = self.t_max // self.bt          # table entries per row
@@ -799,16 +814,25 @@ class ContinuousBatcher:
         # slot, written at pos % R and read whole under a position mask
         # (ops/attention.py::ring_write_and_attend). Its bytes a slot do
         # not grow with t_max, and it takes no part in the block table,
-        # the radix cache or copy-on-write. self._cache_kinds says which
-        # layer is which.
+        # the radix cache or copy-on-write. A THIRD kind, "latent" (a
+        # latent-attention layer), is a paged pool on the same block
+        # table, free list and block writes as "paged", whose token is
+        # ONE vector of model.latent_width channels with no K/V pair and
+        # no heads: {"kv": [1, P, 1, bt, Wp]} (Wp: the width in whole
+        # lane tiles, 576 -> 640), the pool's axes kept so that the block
+        # write, the copies and the zeroing below treat it as they treat a
+        # K/V pool. self._cache_kinds says which layer is which.
         self._cache_kinds = (("paged",) * n_layers
                              if self._layer_blocks is None else
                              tuple(b.cache_kind for b in self._layer_blocks))
-        if "paged" not in self._cache_kinds:
+        on_table = [i for i, kind in enumerate(self._cache_kinds)
+                    if kind != "ring"]
+        if not on_table:
             raise ValueError(
                 "a model of window layers only is not served: the "
                 "scheduler's block accounting needs one paged layer")
-        self._paged0 = self._cache_kinds.index("paged")
+        # the layer the block accounting and the engine report look at
+        self._paged0 = on_table[0]
         self._caches = [
             {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
                          _POOL_SPEC),
@@ -816,9 +840,20 @@ class ContinuousBatcher:
                                 jnp.float32, _POOL_SPEC)}
                 if kv_dtype == "int8" else {})}
             if kind == "paged" else
+            {"kv": zeros((1, pool_blocks, 1, self.bt,
+                          latent_pool_width(model.latent_width)),
+                         dtype, _POOL_SPEC)}
+            if kind == "latent" else
             {"kv": zeros((2, slots, hk, model.ring_tokens, hd), dtype,
                          None)}
             for kind in self._cache_kinds]
+        self._n_latent = self._cache_kinds.count("latent")
+        # bytes one layer of each kind keeps of one cached token, as
+        # allocated (a latent token's 576 channels in 640 lanes: 1280)
+        self._cache_bytes_per_token = {
+            kind: sum(leaf.nbytes // (leaf.shape[1] * leaf.shape[3])
+                      for leaf in c.values())
+            for c, kind in zip(self._caches, self._cache_kinds)}
         # the stats that each entry of a decode tick's count vector adds
         # to (none for a model without held experts)
         held = (model.counted_experts()
@@ -846,8 +881,10 @@ class ContinuousBatcher:
         # family share their jitted programs (_PROGRAM_CACHE), so a
         # trace belongs to whichever engine dispatched first.
         with self._mesh_ctx():
-            self._paged_read = paged_read_path(
-                self._caches[self._paged0], 1)
+            self._paged_read = (
+                latent_read_path(self._caches[self._paged0])
+                if self._cache_kinds[self._paged0] == "latent" else
+                paged_read_path(self._caches[self._paged0], 1))
         if (self._layer_blocks is not None and decode_width_buckets is None
                 and self._paged_read == "kernel"):
             # the kernel's traffic follows each row's position whatever
@@ -1043,6 +1080,11 @@ class ContinuousBatcher:
             # dispatched for them
             "prefill_calls": 0, "prefill_rows": 0,
             "prefill_tokens": 0, "prefill_window_tokens": 0,
+            # token vectors written into latent layers' pools (tokens x
+            # latent layers): admission's real tokens and the ticks of the
+            # rows in the plan, counted here on the host from what was
+            # dispatched
+            "latent_tokens_written": 0,
             # fault-tolerance counters (serve_lifecycle /
             # DESIGN.md "Serving under failure")
             "faults": 0, "reconstructions": 0,
@@ -1190,8 +1232,11 @@ class ContinuousBatcher:
             "ticks": self.ticks,
             # static: the pool read the decode tick was compiled with
             "paged_read": self._paged_read,
-            # static: per layer, "paged" (the block pool) or "ring"
+            # static: per layer, "paged" (the block pool of K/V pairs),
+            # "latent" (the block pool of token vectors) or "ring"
             "cache_kinds": list(self._cache_kinds),
+            # static: per kind, the bytes a layer keeps of a cached token
+            "cache_bytes_per_token": dict(self._cache_bytes_per_token),
             **({"expert_load_max_over_mean": self._expert_load_spread()}
                if self._count_keys else {}),
             "slot_leaks": self.last_slot_leaks,
@@ -1507,38 +1552,73 @@ class ContinuousBatcher:
         self._profile_req = {"remaining": int(segments),
                              "dir": profile_dir, "active": False}
 
-    @staticmethod
-    def _refuse_for_layer_kinds(*, prefix_cache, speculate, tiers, kv_dtype,
-                                mesh, prefill_chunk_tokens):
-        """What a model of layer kinds (window layers on rings, held
-        experts) cannot be served with yet, refused with the reason."""
-        why = {
-            "prefix_cache": (prefix_cache, "a cached prefix holds pool "
-                             "blocks only: a window layer's ring at the "
-                             "prefix's end is not kept, so an attached "
-                             "request could not be resumed"),
-            "speculate": (speculate is not None, "a verify window writes "
-                          "several ring slots at once and a rejected draft "
-                          "would have overwritten tokens the window still "
-                          "needs"),
-            "host_cache_mb/host_cache_blocks/disk_cache_dir": (
-                tiers, "KV tiers demote and promote pool blocks; rings "
-                "have none"),
-            "kv_dtype='int8'": (kv_dtype == "int8", "the ring has no "
-                                "scale leaf"),
-            "mesh": (mesh is not None, "the ring write and the held "
-                     "experts' grouped products are single-device "
-                     "programs, and the experts held are one chip's share "
-                     "already"),
-            "prefill_chunk_tokens": (
-                prefill_chunk_tokens is not None, "a chunk would have to "
-                "attend the ring of the chunk before it"),
+    # what a model of layer kinds cannot be served with yet, by the kind of
+    # cache that stands in the way: (whose layers, {option: reason}). The
+    # ring's entry speaks for the held experts too, which every such model
+    # has had beside its rings
+    _LAYER_KIND_REFUSALS = {
+        "ring": ("a model of window layers and held experts", {
+            "prefix_cache": "a cached prefix holds pool blocks only: a "
+                            "window layer's ring at the prefix's end is "
+                            "not kept, so an attached request could not "
+                            "be resumed",
+            "speculate": "a verify window writes several ring slots at "
+                         "once and a rejected draft would have "
+                         "overwritten tokens the window still needs",
+            "host_cache_mb/host_cache_blocks/disk_cache_dir":
+                "KV tiers demote and promote pool blocks; rings have none",
+            "kv_dtype='int8'": "the ring has no scale leaf",
+            "mesh": "the ring write and the held experts' grouped "
+                    "products are single-device programs, and the experts "
+                    "held are one chip's share already",
+            "prefill_chunk_tokens": "a chunk would have to attend the "
+                                    "ring of the chunk before it",
+        }),
+        "latent": ("latent-attention layers", {
+            "prefix_cache": "an attached prefix is gathered as K/V pairs "
+                            "at head width, and a latent pool holds "
+                            "neither: the expanded form would have to "
+                            "re-expand the prefix's compressed vectors",
+            "speculate": "a latent layer has no verify window: its decode "
+                         "form attends one absorbed query a row",
+            "host_cache_mb/host_cache_blocks/disk_cache_dir":
+                "the host and disk tiers are laid out for K/V pairs at "
+                "head width",
+            "kv_dtype='int8'": "the latent pool has no scale leaf, and a "
+                               "token's compressed and rotary channels "
+                               "would need scales of their own",
+            "mesh": "the latent decode kernel, the pool write and the "
+                    "held experts' grouped products are single-device "
+                    "programs",
+            "prefill_chunk_tokens": "a chunk would have to attend the "
+                                    "expanded K/V of the chunks before it",
+        }),
+    }
+
+    @classmethod
+    def _refuse_for_layer_kinds(cls, kinds, *, prefix_cache, speculate,
+                                tiers, kv_dtype, mesh, prefill_chunk_tokens):
+        """What a model of layer kinds cannot be served with yet, refused
+        with the reason of EVERY kind of cache among ``kinds`` (its
+        layers' ``cache_kind``) that stands in the way; a model of pool
+        layers alone is refused as one of rings is, for its held
+        experts."""
+        asked = {
+            "prefix_cache": prefix_cache,
+            "speculate": speculate is not None,
+            "host_cache_mb/host_cache_blocks/disk_cache_dir": tiers,
+            "kv_dtype='int8'": kv_dtype == "int8",
+            "mesh": mesh is not None,
+            "prefill_chunk_tokens": prefill_chunk_tokens is not None,
         }
-        for name, (asked, reason) in why.items():
-            if asked:
-                raise ValueError(
-                    f"{name} does not compose with a model of window "
-                    f"layers and held experts yet: {reason}")
+        present = ([k for k in cls._LAYER_KIND_REFUSALS if k in kinds]
+                   or ["ring"])
+        for name, on in asked.items():
+            if on:
+                raise ValueError("; ".join(
+                    f"{name} does not compose with {what} yet: {why[name]}"
+                    for what, why in map(cls._LAYER_KIND_REFUSALS.get,
+                                         present)))
 
     def _layer(self, params, i: int):
         """(block, parameters) of layer ``i``."""
@@ -1707,7 +1787,7 @@ class ContinuousBatcher:
         (no device->host read).
         """
         from distributed_compute_pytorch_tpu.ops.attention import (
-            gather_kv_blocks)
+            gather_kv_blocks, pad_channels)
         with scope("admit"):
             model = self.model
             Lp = prefix_mask.shape[1]
@@ -1750,18 +1830,25 @@ class ContinuousBatcher:
                 x = block.apply(p_i, x, **kw)
                 if isinstance(x, tuple):   # MoE blocks return (x, aux)
                     x = x[0]
-                (k, v), = sink             # [K, hk, ws, hd] — suffix only
+                kept, = sink     # (k, v) [K, hk, ws, hd], or (token,) [K, ws, W]
                 with scope("kv_write"):
                     if self._cache_kinds[i] == "ring":
                         new_caches.append(self._admit_ring(
-                            caches[i], k, v, pmask, ring_rows))
+                            caches[i], *kept, pmask, ring_rows))
+                    elif self._cache_kinds[i] == "latent":
+                        # never attached, never chunked (refused at
+                        # construction): every window starts at 0
+                        new_caches.append(self._admit_blocks(
+                            caches[i], pad_channels(
+                                kept[0], caches[i]["kv"].shape[-1])[
+                                    None, :, None], tables, pmask))
                     elif Lp == 0 and "scale" not in caches[i]:
                         # every window starts at position 0 (static)
                         new_caches.append(self._admit_blocks(
-                            caches[i], k, v, tables, pmask))
+                            caches[i], jnp.stack(kept), tables, pmask))
                     else:
                         new_caches.append(self._admit_scatter(
-                            caches[i], k, v, blk_idx, off_idx))
+                            caches[i], *kept, blk_idx, off_idx))
             return new_caches
 
     @staticmethod
@@ -1800,8 +1887,10 @@ class ContinuousBatcher:
             ring_from_prefill(k, v, n_tok, ring.shape[3]).astype(ring.dtype),
             mode="drop")}
 
-    def _admit_blocks(self, cache, k, v, tables, pmask):
-        """One layer's admission write in WHOLE BLOCKS (every window of
+    def _admit_blocks(self, cache, kv, tables, pmask):
+        """One layer's admission write in WHOLE BLOCKS (``kv [s, K, hk,
+        ws, hd]``: the K/V planes, or a latent layer's one plane of token
+        vectors with one "head"; every window of
         the dispatch starts at position 0: nothing attached, no chunk
         extension): block ``j`` of wave row ``r`` goes to pool block
         ``tables[r, j]`` if it holds a real token, else nowhere. One index
@@ -1811,11 +1900,10 @@ class ContinuousBatcher:
         for a pool of one layer). The tail of a row's last block takes
         the pad tokens' K/V: past the row's live position, never attended,
         and overwritten by the ticks that reach it."""
-        kv = jnp.stack([k, v])                         # [2, K, hk, ws, hd]
-        _, K, hk, ws, hd = kv.shape
+        s, K, hk, ws, hd = kv.shape
         nbw = ws // self.bt
-        kv = kv.reshape(2, K, hk, nbw, self.bt, hd).transpose(
-            0, 1, 3, 2, 4, 5).reshape(2, K * nbw, hk, self.bt, hd)
+        kv = kv.reshape(s, K, hk, nbw, self.bt, hd).transpose(
+            0, 1, 3, 2, 4, 5).reshape(s, K * nbw, hk, self.bt, hd)
         n_tok = jnp.sum(pmask > 0.5, axis=1)
         real = (jnp.arange(nbw) * self.bt)[None, :] < n_tok[:, None]
         pool = cache["kv"]
@@ -2941,6 +3029,8 @@ class ContinuousBatcher:
                 table[b].remaining -= take
                 ticks_charged[ri] += take
                 self.waste["planned_ticks"] += self.S
+            self.stats["latent_tokens_written"] += (
+                self._n_latent * len(plan) * self.S)
             if chaos is not None and chaos.on_segment is not None:
                 # host observation hook: drills flip drain flags /
                 # cancel requests at a deterministic segment
@@ -3602,6 +3692,8 @@ class ContinuousBatcher:
             self.stats["prefill_calls"] += 1
             self.stats["prefill_tokens"] += int(pmask.sum())
             self.stats["prefill_window_tokens"] += R * window
+            self.stats["latent_tokens_written"] += (
+                self._n_latent * int(pmask.sum()))
 
     def _reconstruct(self, table, requests, fin, free_row) -> None:
         """Device-failure session reconstruction: rebuild every live

@@ -297,3 +297,35 @@ def test_a_skewed_router_drops_nothing(form):
     assert counts[0] == 2 * n_live and counts[2] == n_live   # expert 0: all
     assert counts[3] == 0                                    # expert 1: none
     assert counts[1] == counts[2:].sum() > layer.window_rows(600)
+
+
+@pytest.mark.parametrize("held_rows", [0, 700, 1024, 1100, 1200])
+def test_the_sorted_forms_windows_cover_every_held_row(held_rows):
+    """600 tokens x 2 assignments: a first window of 1024 sorted rows, then
+    windows of 512 for what it leaves. No held row, a first window partly
+    and exactly full, one tail window partly full and every assignment
+    held: the sorted form equals the dense form over the same
+    assignments. float32: 1e-5 of outputs of size ~0.1."""
+    layer, p, _, _ = _layer_and_weights((0, 4), shared=False, dense_max=0)
+    assert (layer.window_rows(600), layer.tail_rows(600)) == (1024, 512)
+    rng = np.random.default_rng(held_rows)
+    local = np.full(1200, 4)                       # 4 = not held
+    local[rng.permutation(1200)[:held_rows]] = rng.integers(0, 4, held_rows)
+    local = jnp.asarray(local.reshape(600, 2), jnp.int32)
+    x = jax.random.normal(jax.random.key(9), (600, 64))
+    w = jax.random.uniform(jax.random.key(10), (600, 2))
+    got = layer._sorted(p["experts"], x, local, w)
+    want = layer._dense(p["experts"], x, local, w)
+    assert float(jnp.max(jnp.abs(got - want))) < 1e-5
+    assert (held_rows > 0) == bool(jnp.any(got != 0))
+
+
+def test_the_window_sizes_at_the_published_widths():
+    """The first window is an eighth over an even router's held rows and
+    a tail window an eighth of it, both in whole 512s: K-EXAONE's 2048-token
+    dispatch (16 of 128 held) and JoyAI's 16,384-token one (32 of 256)."""
+    kex = HeldExperts(6144, 2048, 128, 8, experts_held=(0, 16))
+    joy = HeldExperts(2048, 768, 256, 8, experts_held=(0, 32))
+    assert (kex.window_rows(2048), kex.tail_rows(2048)) == (2560, 512)
+    assert (joy.window_rows(16384), joy.tail_rows(16384)) == (18432, 2048)
+    assert (joy.window_rows(2048), joy.tail_rows(2048)) == (2560, 512)

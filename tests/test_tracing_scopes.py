@@ -162,6 +162,47 @@ def test_layer_kinds_carry_the_expert_and_window_scopes():
     assert _has(seg, "attn", "kv_write")             # the pool's
 
 
+def test_latent_layers_carry_the_latent_scopes():
+    """A latent-attention layer (``models/hybrid.py``): everything its
+    mixer does as ``attn_latent`` inside ``attn``, the products with the
+    up-projection of the compressed K/V (its expansion in the admission
+    program, its absorption into query and output in a tick) as
+    ``latent_absorb`` inside that, and the pool's write inside it too, in
+    both serve programs (three layers: admission needs no feed-forward
+    after the last layer's K/V, so the last layer's is not in its
+    program)."""
+    from distributed_compute_pytorch_tpu.serve import Request
+    model = build_model(
+        "hybrid", vocab_size=256, max_seq_len=64,
+        layer_types=("latent_attention",) * 3,
+        mlp_layer_types=("dense", "sparse", "sparse"), num_heads=4,
+        d_model=64,
+        d_ff=128, q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, norm_placement="pre",
+        qk_norm=False, num_experts=8, experts_held=(0, 4), top_k=2,
+        moe_d_ff=32, shared_d_ff=32)
+    params, _ = model.init(jax.random.key(0))
+    cb = ContinuousBatcher(model, params, slots=2, t_max=64, prompt_buf=32,
+                           segment=4)
+    out = cb.serve([Request(tokens=list(range(1, 13)), max_new=3)])
+    assert len(out[0]) == 3
+    for program, outer in (("segment", "decode"), ("admit", "admit")):
+        fn, args, kwargs = cb._program_sigs[program]
+        locs = _locations(fn.lower(*args, **kwargs))
+        for path in ((outer, "attn", "attn_latent"),
+                     ("attn_latent", "latent_absorb"),
+                     (outer, "mlp", "experts")):
+            assert _has(locs, *path), (program, path)
+        assert not _has(locs, "attn_local")
+        assert not _has(locs, "mlp", "attn_latent")
+    seg = _locations(cb._program_sigs["segment"][0].lower(
+        *cb._program_sigs["segment"][1], **cb._program_sigs["segment"][2]))
+    assert _has(seg, "attn_latent", "kv_write")      # the tick's one vector
+    adm = _locations(cb._program_sigs["admit"][0].lower(
+        *cb._program_sigs["admit"][1], **cb._program_sigs["admit"][2]))
+    assert _has(adm, "admit", "kv_write")            # the whole-block write
+
+
 def test_admission_prefix_gather_is_a_kv_gather():
     """With the prefix cache on, a second request sharing a block-aligned
     prefix attaches it: the admission program gathers the cached K/V."""
