@@ -61,8 +61,14 @@ _BLOCKS = (1024, 512, 256, 128)
 
 def _pick_block(t: int) -> int | None:
     """Largest MXU-friendly block dividing ``t`` (bigger blocks = fewer grid
-    steps, and the f32 score block at 1024x1024 is only 4 MB of VMEM;
-    2048 blocks exceed the compile budget)."""
+    steps; 2048 blocks exceed the compile budget). A causal block is not
+    multiplied whole for being large: the backward kernels walk the block
+    on the diagonal in sub-tiles inside its grid step and drop what lies
+    above the diagonal (``ops/pallas/flash_attention.py``). Smaller blocks
+    would let the grid skip the same pairs and lose more than they skip: a
+    block body costs 1.3-1.7 us of latency whatever it holds, and at
+    T = 1024 the train step takes 191 ms at blocks of 1024, 211 at 512 and
+    272 at 256 (PERF.md PR 35)."""
     for b in _BLOCKS:
         if t % b == 0:
             return b

@@ -10,8 +10,28 @@ FlashAttention recurrence mapped onto the Pallas TPU grid model:
   accumulators (o, m, l) in VMEM scratch persisting across kv steps
   (`@pl.when(kv==0)` init / `@pl.when(kv==last)` write, guide §Grid);
 - MXU matmuls via jnp.dot with preferred_element_type=float32 (guide §Math);
-- causal runs skip fully-masked kv blocks with `@pl.when`, mask the diagonal
-  block with broadcasted_iota (guide: 2D iota);
+- causal runs skip fully-masked kv blocks with `@pl.when` and mask the
+  blocks the diagonal can cross with broadcasted_iota (guide: 2D iota). In
+  the BACKWARD kernels the block ON the diagonal (square blocks whose first
+  row and first column meet: one block a head at the train cell's T = 1024)
+  is WALKED inside its grid step in sub-tiles (`_accumulate`): tiles above
+  the diagonal hold no pair and are dropped (28 of 64), the tile on it is
+  masked, the rest run without the iotas and the select. The walk is two
+  `fori_loop`s of static trip count over ONE tile body, unrolled only when
+  the kernel is lowered: the jaxpr holds the whole block's body and one
+  tile's whatever the block is, and Mosaic gets straight-line code with
+  constant strip and tile indices. Written as loops that Mosaic cannot
+  unroll (bounds from `program_id`) the same walk pays every tile's latency
+  in turn and ran 2-5 times SLOWER than the block whole; written out tile
+  by tile in Python it cost a serve cell 10-33 s of set-up (PERF.md PR 35).
+  Blocks off the diagonal run whole: their geometry hangs on `program_id`,
+  so a walk would be those loops. The FORWARD runs every block whole: at
+  head width 64 it is not bound by its pairs (walked, it read 1.04 ms a
+  call for 0.80), and a square tile pays an online-softmax rescale a tile.
+  The BLOCK stays large (`ops/attention.py::_pick_block`): a block body
+  pays its latency chain once for 1024 x 1024 pairs (the same kernels at
+  blocks of 512 / 256: the train step 191 -> 211 / 272 ms), and the walk
+  does inside it what the grid's own skip would do with a step each;
 - backward is the two-kernel split (dQ; dK/dV) using the saved logsumexp
   and the precomputed row term delta = rowsum(dO * O). A one-pass fused
   backward (sharing the recomputed score block between dQ and dK/dV) was
@@ -72,6 +92,99 @@ def _out_struct(shape, dtype, like):
 
 
 # ---------------------------------------------------------------------------
+# the walk of the diagonal block (backward kernels)
+# ---------------------------------------------------------------------------
+
+# Sub-tiles a side of the diagonal block, fewer where a tile would be under
+# 128 wide: 10 of 16 tiles hold a pair at 4, 36 of 64 at 8. Chosen on the
+# chip at the train cell's shape (PERF.md PR 35): the dQ kernel took 0.60 /
+# 0.70 ms a call at 4 / 8 and 0.77 whole, the dK/dV kernel 1.01 / 0.85 and
+# 1.10 (three products a tile against four: the more a tile multiplies, the
+# smaller it may be before its latency shows).
+_DQ_TILES = 4
+_DKV_TILES = 8
+
+
+def _diagonal_tiles(causal, block_q, block_k, most) -> int:
+    """Sub-tiles a side in which a kernel walks the block on the diagonal
+    (1 = the block runs whole): the most, up to ``most``, whose edge is a
+    multiple of 128 (a lane tile: the key mask is cut along lanes)."""
+    if not causal or block_q != block_k:
+        return 1
+    return max((n for n in range(2, most + 1) if block_q % (128 * n) == 0),
+               default=1)
+
+
+def _masked(s, keep):
+    """``s`` where ``keep``, else the finite stand-in for minus infinity."""
+    return jax.lax.select(keep, s, jnp.full_like(s, _NEG_INF))
+
+
+def _edge_mask(s, lead, window=None):
+    """Mask a score tile that the diagonal (or the band's low edge) crosses.
+    ``lead`` = absolute position of the tile's first row less that of its
+    first column: row ``i`` sees column ``j`` iff ``j - i <= lead`` (and,
+    in a band, ``i - j + lead < window``)."""
+    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    keep = rel >= -lead
+    if window is not None:
+        keep = keep & (rel < window - lead)
+    return _masked(s, keep)
+
+
+def _accumulate(step, load, keep, *, run, lead, causal, tiles, block,
+                by_rows):
+    """A backward kernel's grid step: ``keep(at, step(load(at), rows, cols,
+    mask))`` over the pairs of the block that can be seen. A block off the
+    diagonal runs whole (masked where causal: its place hangs on
+    ``program_id``). The block ON the diagonal (``lead`` 0) is walked in
+    ``tiles`` x ``tiles`` sub-tiles: a strip of the accumulator (``by_rows``:
+    of query rows, else of keys) is carried over the tiles across it, tiles
+    above the diagonal are dropped, only the tile on it is masked.
+
+    The walk is two ``fori_loop``s of static trip count over ONE tile body,
+    unrolled when the kernel is lowered: the jaxpr, traced anew in every
+    process, holds the whole block's body and one tile's whatever the block
+    is, and Mosaic gets straight-line code whose strip and tile indices are
+    constants, so its canonicaliser folds the ``lax.cond``s away, the
+    dropped tiles with them. (No ``pl.multiple_of`` on a strip's start: the
+    hint would keep it from folding into a static slice.)"""
+    whole = slice(None)
+    on_diagonal = (lead == 0) if tiles > 1 else False
+
+    @pl.when(run & jnp.logical_not(on_diagonal))
+    def _whole():
+        keep(whole, step(load(whole), whole, whole,
+                         (lambda s: _edge_mask(s, lead)) if causal
+                         else (lambda s: s)))
+
+    if tiles == 1:
+        return
+    sub = block // tiles
+
+    @pl.when(run & on_diagonal)
+    def _diagonal():
+        def strip(i, _):
+            mine = pl.ds(i * sub, sub)
+
+            def tile(j, carry):
+                gap = (i - j) if by_rows else (j - i)   # row less column
+                other = pl.ds(j * sub, sub)
+                rows, cols = (mine, other) if by_rows else (other, mine)
+                return jax.lax.cond(
+                    gap >= 0,
+                    lambda carry: step(carry, rows, cols, lambda s: jax.lax.cond(
+                        gap == 0, lambda s: _edge_mask(s, 0), lambda s: s, s)),
+                    lambda carry: carry, carry)
+
+            keep(mine, jax.lax.fori_loop(0, tiles, tile, load(mine),
+                                         unroll=True))
+
+        jax.lax.fori_loop(0, tiles, strip, None, unroll=True)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
@@ -117,21 +230,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         v = v_ref[0]                                  # [Bk, Dv]
         s = _dot_tt(q, k) * scale
         if causal:
-            rows = offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = kb * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-            if window is not None:
-                # row i sees keys j with i - window < j <= i
-                s = jnp.where(cols > rows - window, s, _NEG_INF)
+            s = _edge_mask(s, offset + qi * block_q - kb * block_k, window)
         if masked:
             # [1, Bk] f32 0/1 key-validity row broadcast down the q rows.
             # _NEG_INF (not -inf) keeps fully-masked rows NaN-free: their
             # p degenerates to uniform but their upstream do is zero, so no
             # garbage reaches the gradients (padded positions are excluded
             # from every loss).
-            s = jnp.where(mask_ref[0, 0][None, :] > 0.5, s, _NEG_INF)
+            s = _masked(s, jnp.broadcast_to(mask_ref[0] > 0.5, s.shape))
         m_prev = m_s[:, :1]                           # [Bq, 1]
         l_prev = l_s[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
@@ -248,6 +354,17 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
 # backward
 # ---------------------------------------------------------------------------
 
+def _score_grads(q, k, v, do, lse, delta, valid, mask, scale):
+    """``p`` and ``ds`` of one score tile from the saved row terms: the
+    softmax weights and their gradient before the scale. Operands stay in
+    their native dtype (MXU-native bf16 products into f32)."""
+    s = mask(_dot_tt(q, k) * scale)
+    if valid is not None:
+        s = _masked(s, jnp.broadcast_to(valid > 0.5, s.shape))
+    p = jnp.exp(s - lse)
+    return p, p * (_dot_tt(do, v) - delta)
+
+
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
                    scale, causal, offset, masked, block_q, block_k):
     if masked:
@@ -264,28 +381,23 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     run = (ki * block_k < (qi + 1) * block_q + offset) if causal \
         else (ki == ki)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]                  # native dtype: MXU-native bf16 matmul
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                              # [Bq, 1]
-        delta = delta_ref[0]                          # [Bq, 1]
-        s = _dot_tt(q, k) * scale
-        if causal:
-            rows = offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        if masked:
-            s = jnp.where(mask_ref[0, 0][None, :] > 0.5, s, _NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = _dot_tt(do, v)
-        ds = p * (dp - delta)
-        dq_acc[:] = dq_acc[:] + jnp.dot(
-            ds.astype(k.dtype), k, preferred_element_type=jnp.float32) * scale
+    def step(dq, rows, cols, mask):
+        """``dq`` of the query rows plus what the keys of ``cols`` add."""
+        k = k_ref[0, cols, :]
+        _, ds = _score_grads(
+            q_ref[0, rows, :], k, v_ref[0, cols, :], do_ref[0, rows, :],
+            lse_ref[0, rows, :], delta_ref[0, rows, :],
+            mask_ref[0, :, cols] if masked else None, mask, scale)
+        return dq + jnp.dot(ds.astype(k.dtype), k,
+                            preferred_element_type=jnp.float32) * scale
+
+    def keep(rows, dq):
+        dq_acc[rows, :] = dq
+
+    _accumulate(step, lambda rows: dq_acc[rows, :], keep, run=run,
+                lead=offset + qi * block_q - ki * block_k, causal=causal,
+                tiles=_diagonal_tiles(causal, block_q, block_k, _DQ_TILES),
+                block=block_q, by_rows=True)
 
     @pl.when(ki == last_k)
     def _write():
@@ -309,28 +421,25 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     run = ((qi + 1) * block_q + offset > ki * block_k) if causal \
         else (qi == qi)
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]                  # native dtype: MXU-native bf16 matmul
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                              # [Bq, 1]
-        delta = delta_ref[0]                          # [Bq, 1]
-        s = _dot_tt(q, k) * scale
-        if causal:
-            rows = offset + qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            cols = ki * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s = jnp.where(rows >= cols, s, _NEG_INF)
-        if masked:
-            s = jnp.where(mask_ref[0, 0][None, :] > 0.5, s, _NEG_INF)
-        p = jnp.exp(s - lse)                 # [Bq, Bk]
-        dv_acc[:] = dv_acc[:] + _dot_nt(p.astype(do.dtype), do)
-        dp = _dot_tt(do, v)
-        ds = p * (dp - delta)
-        dk_acc[:] = dk_acc[:] + _dot_nt(ds.astype(q.dtype), q) * scale
+    def step(dkv, rows, cols, mask):
+        """``dk`` and ``dv`` of the keys plus what the query rows add."""
+        dk, dv = dkv
+        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
+        p, ds = _score_grads(
+            q, k_ref[0, cols, :], v_ref[0, cols, :], do,
+            lse_ref[0, rows, :], delta_ref[0, rows, :],
+            mask_ref[0, :, cols] if masked else None, mask, scale)
+        return (dk + _dot_nt(ds.astype(q.dtype), q) * scale,
+                dv + _dot_nt(p.astype(do.dtype), do))
+
+    def keep(cols, dkv):
+        dk_acc[cols, :], dv_acc[cols, :] = dkv
+
+    _accumulate(step, lambda cols: (dk_acc[cols, :], dv_acc[cols, :]), keep,
+                run=run, lead=offset + qi * block_q - ki * block_k,
+                causal=causal,
+                tiles=_diagonal_tiles(causal, block_q, block_k, _DKV_TILES),
+                block=block_q, by_rows=False)
 
     @pl.when(qi == last_q)
     def _write():
@@ -338,6 +447,12 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
         dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
 
+# Under a jit of its own: a model's layers call the backward with the same
+# shapes and constants, so it is traced once a process and lowered once a
+# program instead of once a layer (the walk's unrolled tiles are lowered one
+# by one: 0.2-0.4 s a kernel where the whole block took 0.05).
+@functools.partial(jax.jit, static_argnames=(
+    "heads", "scale", "causal", "offset", "block_q", "block_k"))
 def _flash_bwd(res, g, kv_mask, heads, scale, causal, offset,
                block_q, block_k):
     q, k, v, o, lse = res

@@ -169,3 +169,47 @@ def test_admission_prefill_shape_pad_and_mask_on_tpu():
     np.testing.assert_allclose(np.asarray(out, np.float32)[valid],
                                np.asarray(ref, np.float32)[valid],
                                atol=3e-2, rtol=3e-2)
+
+
+@pytest.mark.parametrize("case", [
+    "causal-2048-masked", "offset-q512-kv2048", "d192-dv128-4096",
+    "band-w128-grouped", "band-w4096-grouped"])
+def test_walked_blocks_on_tpu(case):
+    """The serve cells' forward shapes, Mosaic-compiled, at blocks of 512
+    and 1024: causal with a key mask, a static offset, unequal q/k and v
+    head widths, and the band kernel over grouped heads."""
+    from distributed_compute_pytorch_tpu.ops import attention as A
+
+    ks = jax.random.split(jax.random.key(3), 3)
+    window = kv_mask = None
+    h, hk, t, tk, d, dv = 8, 8, 2048, 2048, 128, 128
+    if case == "causal-2048-masked":
+        m = np.zeros((2, tk), np.float32)
+        m[0], m[1, :1300] = 1.0, 1.0
+        kv_mask = jnp.asarray(m)
+    elif case == "offset-q512-kv2048":
+        t = 512
+    elif case == "d192-dv128-4096":
+        t = tk = 4096
+        d, dv = 192, 128
+    else:
+        hk, window = 2, int(case.split("-")[1][1:])
+        t = tk = 2048 if window == 128 else 6144
+    q = jax.random.normal(ks[0], (2, h, t, d), jnp.bfloat16)
+    k = jax.random.normal(ks[1], (2, hk, tk, d), jnp.bfloat16)
+    v = jax.random.normal(ks[2], (2, hk, tk, dv), jnp.bfloat16)
+
+    def run(impl):
+        return jax.jit(lambda q, k, v: A.attention(
+            q, k, v, causal=True, kv_mask=kv_mask, window=window,
+            impl=impl))(q, k, v)
+
+    out, ref = run("auto"), run("xla")
+    assert out.shape == (2, h, t, dv)
+    valid = np.ones(out.shape, bool)
+    if kv_mask is not None:     # rows past a prompt's length are pads
+        valid = np.broadcast_to(np.asarray(kv_mask)[:, None, :, None] > 0,
+                                out.shape)
+    np.testing.assert_allclose(np.asarray(out, np.float32)[valid],
+                               np.asarray(ref, np.float32)[valid],
+                               atol=3e-2, rtol=3e-2)
