@@ -343,6 +343,100 @@ class MoELayer:
 
 
 @dataclass(frozen=True)
+class SigmoidRouter:
+    """The router of :class:`HeldExperts` that is ONE matrix (the
+    aux-loss-free form): ``s = sigmoid(W_r h)`` in float32 over all
+    experts; the ``top_k`` with the largest ``s_e + b_e`` (``b`` a
+    selection bias that does not enter the weights); ``w_e = routed_scale
+    * s_e / sum_{e' in T} s_e'`` when ``norm_topk_prob``. It carries no
+    state from layer to layer."""
+
+    d_model: int
+    num_experts: int
+    top_k: int
+    routed_scale: float = 1.0
+    norm_topk_prob: bool = True
+    param_dtype: jnp.dtype = jnp.float32
+
+    def init(self, key):
+        d = self.d_model
+        return {"router": {"kernel": jax.random.uniform(
+                    key, (d, self.num_experts), self.param_dtype,
+                    -d ** -0.5, d ** -0.5)},
+                "router_bias": jnp.zeros((self.num_experts,), jnp.float32)}
+
+    def route(self, params, x, state=None):
+        logits = jnp.dot(x, params["router"]["kernel"].astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        s = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(
+            s + params["router_bias"].astype(jnp.float32), self.top_k)
+        w = jnp.take_along_axis(s, idx, axis=-1)
+        if self.norm_topk_prob:
+            w = w / jnp.sum(w, -1, keepdims=True)
+        return idx.astype(jnp.int32), w * self.routed_scale, state
+
+
+@dataclass(frozen=True)
+class MLPRouter:
+    """A router that is a small NETWORK with state carried from layer to
+    layer (the ZAYA1 recipe): ``rs = W_d h + c_d + gamma * rs_prev``
+    (``hidden`` wide; ``rs_prev`` the state the layer below handed up,
+    none into the first layer: depth averaging), ``z = RMSNorm(rs)``, ``l
+    = W_3 gelu(W_2 gelu(W_1 z + c_1) + c_2)``, ``p = softmax(l)`` over
+    ``num_choices``, the ONE choice ``argmax(p + b)`` (``b`` a selection
+    bias) with the weight ``p`` of that choice, not renormalised. A choice
+    no chip holds (a skip) is :class:`HeldExperts`' business, not the
+    router's. ``gamma`` is stored as its offset from 0.5.
+
+    ``W_d`` multiplies in the activations' type into float32; the state
+    and everything after it stay in float32 at the highest matmul
+    precision (three products ``hidden`` wide: the argmax over a softmax
+    has near-ties that one bfloat16 pass would decide)."""
+
+    d_model: int
+    hidden: int
+    num_choices: int
+    eps: float = 1e-5
+    param_dtype: jnp.dtype = jnp.float32
+
+    def init(self, key):
+        d, R, pd = self.d_model, self.hidden, self.param_dtype
+        ks = jax.random.split(key, 4)
+        u = lambda k, shape: jax.random.uniform(
+            k, shape, pd, -shape[0] ** -0.5, shape[0] ** -0.5)
+        lin = lambda k, i, o: {"kernel": u(k, (i, o)),
+                               "bias": jnp.zeros((o,), pd)}
+        return {"router": {"down": lin(ks[0], d, R),
+                           "gamma": jnp.zeros((R,), pd),
+                           "norm": {"scale": jnp.ones((R,), jnp.float32)},
+                           "fc1": lin(ks[1], R, R), "fc2": lin(ks[2], R, R),
+                           "out": {"kernel": u(ks[3], (R, self.num_choices))}},
+                "router_bias": jnp.zeros((self.num_choices,), jnp.float32)}
+
+    def route(self, params, x, state=None):
+        r = params["router"]
+        f32 = lambda a: a.astype(jnp.float32)
+        mm = lambda a, w: jnp.dot(a, f32(w),
+                                  precision=jax.lax.Precision.HIGHEST)
+        rs = jnp.dot(x, r["down"]["kernel"].astype(x.dtype),
+                     preferred_element_type=jnp.float32) + f32(
+                         r["down"]["bias"])
+        if state is not None:
+            rs = rs + (0.5 + f32(r["gamma"])) * state
+        z = rs * jax.lax.rsqrt(
+            jnp.mean(rs * rs, -1, keepdims=True) + self.eps) * f32(
+                r["norm"]["scale"])
+        gelu = lambda a: jax.nn.gelu(a, approximate=True)
+        z = gelu(mm(z, r["fc1"]["kernel"]) + f32(r["fc1"]["bias"]))
+        z = gelu(mm(z, r["fc2"]["kernel"]) + f32(r["fc2"]["bias"]))
+        probs = jax.nn.softmax(mm(z, r["out"]["kernel"]), axis=-1)
+        idx = jnp.argmax(probs + f32(params["router_bias"]), axis=-1)
+        w = jnp.take_along_axis(probs, idx[:, None], axis=-1)
+        return idx[:, None].astype(jnp.int32), w, rs
+
+
+@dataclass(frozen=True)
 class HeldExperts:
     """Dropless routed experts with a shared expert, as ONE chip of an
     expert-parallel set sees them: the router is ``num_experts`` wide,
@@ -356,7 +450,15 @@ class HeldExperts:
     (``b`` a per-expert selection bias that does not enter the weights);
     ``w_e = routed_scale * s_e / sum_{e' in T} s_e'`` when
     ``norm_topk_prob``; every expert a SwiGLU. No capacity: no token is
-    dropped whatever the skew.
+    dropped whatever the skew. That router is the default
+    (:class:`SigmoidRouter`, built from the fields below); ``router`` takes
+    another part with its own parameters and its own ``route(params, x,
+    state) -> (idx, w, state)`` (:class:`MLPRouter`: a network with state
+    carried from layer to layer, which :meth:`apply_with_state` threads).
+    ``skip_index`` names a choice of the router that NO chip holds (a
+    token sent there gets a zero output at no cost, which is what ``held``
+    already means) so that the counts can tell it from an expert held
+    elsewhere.
 
     Two static shapes, two forms (both read every held expert's weights
     once): up to ``dense_max_tokens`` tokens (a decode tick: a handful of
@@ -381,6 +483,13 @@ class HeldExperts:
     norm_topk_prob: bool = True
     dense_max_tokens: int = 512
     param_dtype: jnp.dtype = jnp.float32
+    router: object = None           # None = SigmoidRouter of the fields above
+    skip_index: int | None = None   # a choice of the router no chip holds
+
+    def _router(self):
+        return self.router or SigmoidRouter(
+            self.d_model, self.num_experts, self.top_k, self.routed_scale,
+            self.norm_topk_prob, self.param_dtype)
 
     @property
     def held(self) -> tuple:
@@ -398,8 +507,7 @@ class HeldExperts:
         ks = jax.random.split(key, 8)
         u = lambda k, shape, fan_in: jax.random.uniform(
             k, shape, pd, -fan_in ** -0.5, fan_in ** -0.5)
-        p = {"router": {"kernel": u(ks[0], (d, self.num_experts), d)},
-             "router_bias": jnp.zeros((self.num_experts,), jnp.float32),
+        p = {**self._router().init(ks[0]),
              "experts": {"gate": u(ks[1], (n, d, f), d),
                          "up": u(ks[2], (n, d, f), d),
                          "down": u(ks[3], (n, f, d), f)}}
@@ -410,19 +518,11 @@ class HeldExperts:
                            "down": {"kernel": u(ks[6], (sf, d), sf)}}
         return p
 
-    def route(self, params, x):
+    def route(self, params, x, state=None):
         """``x [N, d]`` -> (experts ``[N, k]`` int32, weights ``[N, k]``
-        float32), over the whole router."""
+        float32, the router's state to hand up), over the whole router."""
         with scope("router"):
-            logits = jnp.dot(x, params["router"]["kernel"].astype(x.dtype),
-                             preferred_element_type=jnp.float32)
-            s = jax.nn.sigmoid(logits)
-            _, idx = jax.lax.top_k(
-                s + params["router_bias"].astype(jnp.float32), self.top_k)
-            w = jnp.take_along_axis(s, idx, axis=-1)
-            if self.norm_topk_prob:
-                w = w / jnp.sum(w, -1, keepdims=True)
-            return idx.astype(jnp.int32), w * self.routed_scale
+            return self._router().route(params, x, state)
 
     def window_rows(self, n_tokens: int) -> int:
         """Rows of one window of the sorted form: an eighth more than a
@@ -503,14 +603,27 @@ class HeldExperts:
         return out
 
     def apply(self, params, x, token_mask=None, counts_sink=None):
-        """``x [..., d]`` -> this chip's partial ``m`` of the same shape.
+        """``x [..., d]`` -> this chip's partial ``m`` of the same shape
+        (:meth:`apply_with_state` for a router without state)."""
+        return self.apply_with_state(params, x, None, token_mask,
+                                     counts_sink)[0]
+
+    def apply_with_state(self, params, x, state, token_mask=None,
+                         counts_sink=None):
+        """``x [..., d]``, the router state ``[..., R]`` of the layer below
+        (None: none, or the first layer) -> (this chip's partial ``m``, the
+        state this layer's router hands up).
         ``counts_sink`` (a list) is handed one int32 vector ``[2 +
         count]``: the assignments of the unmasked tokens, those among
-        them that fell on held experts, and the held experts' loads."""
+        them that fell on held experts, and the held experts' loads; with
+        a ``skip_index``, ``[3 + count]``: those that fell on the skip
+        choice come third."""
         first, n = self.held
         shape = x.shape
         x = x.reshape(-1, shape[-1])
-        idx, w = self.route(params, x)
+        if state is not None:
+            state = state.reshape(x.shape[0], -1)
+        idx, w, state = self.route(params, x, state)
         local = idx - first
         on = (local >= 0) & (local < n)
         if token_mask is not None:
@@ -520,9 +633,12 @@ class HeldExperts:
             live = (jnp.ones((x.shape[0],), jnp.int32) if token_mask is None
                     else (token_mask.reshape(-1) > 0.5).astype(jnp.int32))
             load = jnp.bincount(local.reshape(-1), length=n + 1)[:n]
-            counts_sink.append(jnp.concatenate([
-                jnp.stack([jnp.sum(live) * self.top_k, jnp.sum(load)]),
-                load]).astype(jnp.int32))
+            head = [jnp.sum(live) * self.top_k, jnp.sum(load)]
+            if self.skip_index is not None:
+                head.append(jnp.sum((idx == self.skip_index)
+                                    * live[:, None]))
+            counts_sink.append(jnp.concatenate(
+                [jnp.stack(head), load]).astype(jnp.int32))
         with scope("experts"):
             form = (self._dense if x.shape[0] <= self.dense_max_tokens
                     else self._sorted)
@@ -533,7 +649,9 @@ class HeldExperts:
                 mm = lambda a, b: jnp.dot(a, b["kernel"].astype(a.dtype))
                 y = y + mm(jax.nn.silu(mm(x, sp["gate"])) * mm(x, sp["up"]),
                            sp["down"])
-        return y.reshape(shape)
+        if state is not None:
+            state = state.reshape(shape[:-1] + state.shape[-1:])
+        return y.reshape(shape), state
 
 
 @dataclass(frozen=True)

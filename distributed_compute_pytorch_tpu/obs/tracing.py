@@ -63,13 +63,18 @@ from distributed_compute_pytorch_tpu.obs import flight, metrics
 # a latent-attention mixer does, ``models/hybrid.py``) and
 # ``latent_absorb`` inside it (the products with the up-projection of the
 # compressed K/V: its expansion in prefill, its absorption into query and
-# output in a decode tick). The benchmark's scope metrics
+# output in a decode tick); ``attn_cca`` in ``attn`` likewise
+# (everything a compressed-convolutional-attention mixer does) and
+# ``cca_mix`` inside it (what it adds round the projections and the
+# attention product: the two convolutions over the sequence, the query-key
+# mean, the norms and the temperature, the rotation, the value shift, the
+# tail's read and write). The benchmark's scope metrics
 # (``perfbench/layer_metrics``) name these and nothing else.
 SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "optimizer", "grad_reduce",
           "admit", "decode", "kv_gather", "kv_write", "sample",
           "router", "experts", "shared_expert", "attn_local",
-          "attn_latent", "latent_absorb")
+          "attn_latent", "latent_absorb", "attn_cca", "cca_mix")
 
 
 def scope(name: str):

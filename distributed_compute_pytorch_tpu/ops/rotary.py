@@ -46,14 +46,24 @@ def _rotate_half(x):
     return jnp.concatenate([-x2, x1], axis=-1)
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
+def apply_rope(x, positions, theta: float = 10000.0,
+               rotary_dim: int | None = None):
     """Rotate ``x [B, H, T, hd]`` by integer ``positions`` — ``[T]``
     (shared) or ``[B, T]`` (per-row).
 
     ``positions`` may be traced (the pipeline's seq-manual path offsets
     them by ``axis_index('seq') * chunk``).
+
+    ``rotary_dim`` rotates the FIRST ``rotary_dim`` channels only
+    (half-split pairs ``(i, i + rotary_dim / 2)`` at ``theta ** (-2i /
+    rotary_dim)``: a ``partial_rotary_factor``); the rest pass through.
     """
-    cos, sin = rope_cos_sin(positions, x.shape[-1], theta)
+    hd = x.shape[-1]
+    if rotary_dim is not None and rotary_dim != hd:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rotary_dim], positions, theta),
+             x[..., rotary_dim:]], axis=-1)
+    cos, sin = rope_cos_sin(positions, hd, theta)
     if cos.ndim == 3:              # [B, T, hd] -> broadcast over heads
         cos, sin = cos[:, None], sin[:, None]
     else:                          # [T, hd] -> broadcast over batch+heads

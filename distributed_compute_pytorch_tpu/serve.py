@@ -97,12 +97,17 @@ instead, with everything the TPU touches remaining static-shaped:
   collective the two layouts imply — the portable-redistribution move
   (arXiv:2112.01075) that resharded admission K/V in the dense design
   now reshards attached blocks.
-- **Caches of three kinds behind one block table**: a model of layer
+- **Caches of four kinds behind one block table**: a model of layer
   kinds (``models/hybrid.py``) says per layer what it keeps: ``paged``
   (the pool of K/V pairs above), ``ring`` (a window layer's last tokens a
-  slot, no table) or ``latent`` (a latent-attention layer: a paged pool on
+  slot, no table), ``latent`` (a latent-attention layer: a paged pool on
   the SAME table, free list and block writes whose token is one vector
-  with no heads and no K/V pair, read by its own decode kernel).
+  with no heads and no K/V pair, read by its own decode kernel) or
+  ``paged+tail`` (a compressed-convolutional-attention layer: a ``paged``
+  pool in every respect AND a fixed-size tail a slot, ``{"tail": [slots,
+  tail_width]}``, that admission writes from the last real tokens of each
+  row's window and every tick reads and rewrites for the rows in the
+  plan: state beside the pool that is handed from prefill to decode).
   ``stats_snapshot()["cache_kinds"]`` / ``["cache_bytes_per_token"]`` say
   which and at what cost; what such a model cannot be served with yet is
   refused at construction (``_refuse_for_layer_kinds``).
@@ -821,7 +826,17 @@ class ContinuousBatcher:
         # no heads: {"kv": [1, P, 1, bt, Wp]} (Wp: the width in whole
         # lane tiles, 576 -> 640), the pool's axes kept so that the block
         # write, the copies and the zeroing below treat it as they treat a
-        # K/V pool. self._cache_kinds says which layer is which.
+        # K/V pool. A FOURTH, "paged+tail" (a compressed-convolutional-
+        # attention layer), is a "paged" entry in every respect (table,
+        # free list, whole-block admission write, the decode kernel, the
+        # window write) with one more leaf beside the pool, {"tail":
+        # [slots, model.tail_width]} in the compute dtype: what the NEXT
+        # token of each slot needs of the last two (models/hybrid.py says
+        # what, and why that type rounds nothing more). Zero = a slot that
+        # holds nothing yet, so reset() and reconstruction zero it with
+        # the pool; admission writes it for the slots it fills, a tick for
+        # the rows in its plan. self._cache_kinds says which layer is
+        # which; self._pool_of(c) is an entry's block-pool leaves.
         self._cache_kinds = (("paged",) * n_layers
                              if self._layer_blocks is None else
                              tuple(b.cache_kind for b in self._layer_blocks))
@@ -840,6 +855,10 @@ class ContinuousBatcher:
                                 jnp.float32, _POOL_SPEC)}
                 if kv_dtype == "int8" else {})}
             if kind == "paged" else
+            {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
+                         _POOL_SPEC),
+             "tail": zeros((slots, model.tail_width), self._cdtype, None)}
+            if kind == "paged+tail" else
             {"kv": zeros((1, pool_blocks, 1, self.bt,
                           latent_pool_width(model.latent_width)),
                          dtype, _POOL_SPEC)}
@@ -848,25 +867,40 @@ class ContinuousBatcher:
                          None)}
             for kind in self._cache_kinds]
         self._n_latent = self._cache_kinds.count("latent")
+        # does any layer keep state by SLOT (a ring, a tail)? Then an
+        # admission dispatch is told which slot each of its rows fills
+        self._slot_state = bool(
+            {"ring", "paged+tail"} & set(self._cache_kinds))
+        self._n_tail = self._cache_kinds.count("paged+tail")
         # bytes one layer of each kind keeps of one cached token, as
         # allocated (a latent token's 576 channels in 640 lanes: 1280)
         self._cache_bytes_per_token = {
             kind: sum(leaf.nbytes // (leaf.shape[1] * leaf.shape[3])
-                      for leaf in c.values())
+                      for leaf in self._pool_of(c).values())
             for c, kind in zip(self._caches, self._cache_kinds)}
+        # bytes one layer of a kind keeps of a SLOT beside what grows with
+        # its tokens, as allocated (kinds that keep none are left out)
+        self._state_bytes_per_slot = {
+            kind: c["tail"].nbytes // slots
+            for c, kind in zip(self._caches, self._cache_kinds)
+            if "tail" in c}
         # the stats that each entry of a decode tick's count vector adds
         # to (none for a model without held experts)
         held = (model.counted_experts()
                 if hasattr(model, "counted_experts") else 0)
-        self._count_keys = (("expert_assignments", "expert_assignments_held")
-                            + tuple(f"expert_load_{e}" for e in range(held))
-                            if held else ())
+        self._count_keys = (
+            ("expert_assignments", "expert_assignments_held")
+            # assignments on a choice that no chip holds (a skip)
+            + (("expert_assignments_skipped",)
+               if getattr(model, "counts_skips", False) else ())
+            + tuple(f"expert_load_{e}" for e in range(held))
+            if held else ())
         # which engine writes the pool each tick is decided by where the
         # pool lives: the Pallas window write off-mesh on TPU, the XLA
         # scatter under a mesh (a Mosaic call cannot be partitioned) and
         # on CPU. Off-mesh on TPU there is no second choice to fall to.
         self._pallas_write = mesh is None and _pallas_ok(
-            self._caches[self._paged0], axis=3)
+            self._pool_of(self._caches[self._paged0]), axis=3)
         if (jax.default_backend() == "tpu" and mesh is None
                 and not self._pallas_write):
             raise ValueError(
@@ -884,7 +918,8 @@ class ContinuousBatcher:
             self._paged_read = (
                 latent_read_path(self._caches[self._paged0])
                 if self._cache_kinds[self._paged0] == "latent" else
-                paged_read_path(self._caches[self._paged0], 1))
+                paged_read_path(
+                    self._pool_of(self._caches[self._paged0]), 1))
         if (self._layer_blocks is not None and decode_width_buckets is None
                 and self._paged_read == "kernel"):
             # the kernel's traffic follows each row's position whatever
@@ -902,7 +937,7 @@ class ContinuousBatcher:
         # serve.width.bytes_saved_vs_full
         self._gather_block_bytes = sum(
             leaf.nbytes // leaf.shape[1]
-            for leaf in self._caches[self._paged0].values())
+            for leaf in self._pool_of(self._caches[self._paged0]).values())
         row_spec = P(("data", "fsdp"))
         self._cur_tok = zeros((slots,), jnp.int32, row_spec)
         self._n_logical = zeros((slots,), jnp.int32, row_spec)
@@ -1085,6 +1120,10 @@ class ContinuousBatcher:
             # rows in the plan, counted here on the host from what was
             # dispatched
             "latent_tokens_written": 0,
+            # per-slot tails written (rows x layers that keep one):
+            # admission's rows and the ticks of the rows in the plan,
+            # counted the same way
+            "tail_rows_written": 0,
             # fault-tolerance counters (serve_lifecycle /
             # DESIGN.md "Serving under failure")
             "faults": 0, "reconstructions": 0,
@@ -1237,6 +1276,8 @@ class ContinuousBatcher:
             "cache_kinds": list(self._cache_kinds),
             # static: per kind, the bytes a layer keeps of a cached token
             "cache_bytes_per_token": dict(self._cache_bytes_per_token),
+            # static: per kind that keeps one, the bytes of a slot's tail
+            "state_bytes_per_slot": dict(self._state_bytes_per_slot),
             **({"expert_load_max_over_mean": self._expert_load_spread()}
                if self._count_keys else {}),
             "slot_leaks": self.last_slot_leaks,
@@ -1252,7 +1293,8 @@ class ContinuousBatcher:
     def _expert_load_spread(self):
         """Largest held expert's load over the mean load (1.0 = even);
         None before any assignment was counted."""
-        load = [self.stats[key] for key in self._count_keys[2:]]
+        load = [self.stats[key] for key in self._count_keys
+                if key.startswith("expert_load_")]
         return max(load) * len(load) / sum(load) if sum(load) else None
 
     def engine_info(self) -> dict:
@@ -1481,6 +1523,7 @@ class ContinuousBatcher:
             return np.zeros((0, 0), np.float32)
         nbp = -(-n // self.bt)
         scratch = [{name: jnp.zeros(
+                        (1,) + tuple(leaf.shape[1:]) if name == "tail" else
                         (leaf.shape[0], 1 if kind == "ring" else nbp)
                         + tuple(leaf.shape[2:]), leaf.dtype)
                     for name, leaf in c.items()}
@@ -1492,7 +1535,7 @@ class ContinuousBatcher:
             at = np.arange(W)
             real = at < prefill
             kw = ({"ring_rows": jnp.zeros((1,), jnp.int32)}
-                  if "ring" in self._cache_kinds else {})
+                  if self._slot_state else {})
             with self._mesh_ctx():
                 scratch = jax.jit(self._admit_impl)(
                     self.params, scratch, table,
@@ -1507,10 +1550,11 @@ class ContinuousBatcher:
 
         def step(params, caches, tok, pos):
             x = model.embed(params, tok[:, None], pos[:, None])
-            new_caches = []
+            new_caches, carry = [], None
             for li in range(self._n_layers):
-                x, c2 = self._decode_layer(li, params, x, caches[li],
-                                           table, pos, pin=False)
+                x, c2, carry = self._decode_layer(
+                    li, params, x, caches[li], table, pos, pin=False,
+                    carry=carry)
                 new_caches.append(c2)
             return new_caches, model.readout(params, x)[:, -1]
 
@@ -1593,6 +1637,23 @@ class ContinuousBatcher:
             "prefill_chunk_tokens": "a chunk would have to attend the "
                                     "expanded K/V of the chunks before it",
         }),
+        "paged+tail": ("layers that keep a per-slot tail beside the pool", {
+            "prefix_cache": "a cached prefix holds pool blocks only: the "
+                            "tail at the prefix's end is not kept, so the "
+                            "suffix's first token would lack the tokens "
+                            "before it",
+            "speculate": "a rejected draft would have advanced the tail "
+                         "past the accepted tokens",
+            "host_cache_mb/host_cache_blocks/disk_cache_dir":
+                "KV tiers demote and promote pool blocks; a tail is none",
+            "kv_dtype='int8'": "the tail has no scale leaf, and the int8 "
+                               "pool is read through the gather",
+            "mesh": "the tail is indexed by slot where the pool is sharded "
+                    "by block, and the held experts' grouped products are "
+                    "single-device programs",
+            "prefill_chunk_tokens": "a chunk would need the tail of the "
+                                    "chunk before it",
+        }),
     }
 
     @classmethod
@@ -1627,20 +1688,35 @@ class ContinuousBatcher:
                                              params["blocks"])
         return self._layer_blocks[i], self.model.layer_params(params, i)
 
+    @staticmethod
+    def _pool_of(cache: dict) -> dict:
+        """The block-pool leaves of a layer's cache entry (all of them but
+        a per-slot tail)."""
+        return {name: leaf for name, leaf in cache.items() if name != "tail"}
+
     def _decode_layer(self, i: int, params, x, cache, tables, pos,
-                      live=None, counts=None, pin: bool = True):
+                      live=None, counts=None, pin: bool = True, carry=None):
         """One layer's decode tick against its own kind of cache; returns
-        ``(x, new_cache)``, the pool's leaves pinned to their layout
-        unless ``pin`` is off (a scratch pool has none)."""
+        ``(x, new_cache, carry)``, the pool's leaves pinned to their
+        layout unless ``pin`` is off (a scratch pool has none). ``carry``
+        is what a block that carries took from the block below and hands
+        the next (None for every other block)."""
         block, p_l = self._layer(params, i)
         kw = ({} if self._layer_blocks is None
               else {"live": live, "counts_sink": counts})
-        if self._cache_kinds[i] == "ring":
-            return block.decode_step(p_l, x, cache, pos, **kw)
-        x, c2 = block.decode_step(p_l, x, {**cache, "table": tables}, pos,
-                                  **kw)
-        return x, {name: constrain(leaf, _POOL_SPEC) if pin else leaf
-                   for name, leaf in c2.items() if name != "table"}
+        carries = getattr(block, "carries", False)
+        if carries:
+            kw["carry"] = carry
+        paged = self._cache_kinds[i] != "ring"
+        out = block.decode_step(
+            p_l, x, {**cache, "table": tables} if paged else cache, pos,
+            **kw)
+        x, c2, carry = out if carries else (*out, None)
+        if paged:
+            c2 = {name: constrain(leaf, _POOL_SPEC)
+                  if pin and name != "tail" else leaf
+                  for name, leaf in c2.items() if name != "table"}
+        return x, c2, carry
 
     def _mesh_ctx(self):
         return (use_mesh(self._mesh) if self._mesh is not None
@@ -1777,7 +1853,9 @@ class ContinuousBatcher:
         head instead (``ops/attention.py::ring_from_prefill``), written
         whole into the ring rows of the slots ``ring_rows [K]`` (a slot
         id out of range = a pad row, dropped; None = wave row ``j`` is
-        slot ``j``, a wave over the first ``K`` slots).
+        slot ``j``, a wave over the first ``K`` slots). A layer that keeps
+        a per-slot TAIL beside its pool writes the tail its block captured
+        at each row's last real token into the same slots.
 
         Each request's LAST prompt token is deliberately NOT prefilled:
         the host sets it as the row's current token and the next
@@ -1793,11 +1871,14 @@ class ContinuousBatcher:
             Lp = prefix_mask.shape[1]
             x = constrain(model.embed(params, prompt, positions),
                           P(("data", "fsdp"), None, None))
-            new_caches = []
+            new_caches, carry = [], None
             for i in range(self._n_layers):
                 block, p_i = self._layer(params, i)
                 sink: list = []
                 kw = {"kv_sink": sink, "kv_mask": pmask}
+                carries = getattr(block, "carries", False)
+                if carries:
+                    kw["carry"] = carry
                 if Lp:
                     # attached-prefix K/V: gathered from the pool and
                     # resharded into the row-sharded compute layout (the
@@ -1828,9 +1909,13 @@ class ContinuousBatcher:
                             and moe_capacity_rows is not None):
                         kw["moe_capacity_rows"] = moe_capacity_rows
                 x = block.apply(p_i, x, **kw)
-                if isinstance(x, tuple):   # MoE blocks return (x, aux)
+                if carries:
+                    x, carry = x
+                elif isinstance(x, tuple):   # MoE blocks return (x, aux)
                     x = x[0]
-                kept, = sink     # (k, v) [K, hk, ws, hd], or (token,) [K, ws, W]
+                # (k, v) [K, hk, ws, hd], (token,) [K, ws, W], or
+                # (k, v, tail [K, tail_width])
+                kept, = sink
                 with scope("kv_write"):
                     if self._cache_kinds[i] == "ring":
                         new_caches.append(self._admit_ring(
@@ -1842,6 +1927,19 @@ class ContinuousBatcher:
                             caches[i], pad_channels(
                                 kept[0], caches[i]["kv"].shape[-1])[
                                     None, :, None], tables, pmask))
+                    elif self._cache_kinds[i] == "paged+tail":
+                        # never attached, never chunked (refused at
+                        # construction): whole blocks, and the rows' tails
+                        # into the slots they fill (a pad row's is dropped)
+                        k, v, tail = kept
+                        rows = (jnp.arange(k.shape[0]) if ring_rows is None
+                                else ring_rows)
+                        new_caches.append({
+                            **self._admit_blocks(
+                                caches[i], jnp.stack([k, v]), tables, pmask),
+                            "tail": caches[i]["tail"].at[rows].set(
+                                tail.astype(caches[i]["tail"].dtype),
+                                mode="drop")})
                     elif Lp == 0 and "scale" not in caches[i]:
                         # every window starts at position 0 (static)
                         new_caches.append(self._admit_blocks(
@@ -1921,8 +2019,11 @@ class ContinuousBatcher:
         writes its own suffix."""
         out = []
         for c, kind in zip(caches, self._cache_kinds):
-            out.append(c if kind == "ring" else {name: constrain(
-                leaf.at[:, dst].set(leaf[:, src]), _POOL_SPEC)
+            # rings and tails belong to slots, not to blocks (and nothing
+            # that copies a block is served with a tail)
+            out.append(c if kind == "ring" else {
+                name: leaf if name == "tail" else constrain(
+                    leaf.at[:, dst].set(leaf[:, src]), _POOL_SPEC)
                 for name, leaf in c.items()})
         return out
 
@@ -1989,11 +2090,12 @@ class ContinuousBatcher:
                 x = constrain(
                     model.embed(params, tok[:, None], n_log[:, None]),
                     P(("data", "fsdp"), None, None))
-                new_caches = []
+                new_caches, lcarry = [], None
                 counts: list | None = [] if counted else None
                 for li in range(self._n_layers):
-                    x, c2 = self._decode_layer(li, params, x, caches[li],
-                                               tables, p, live, counts)
+                    x, c2, lcarry = self._decode_layer(
+                        li, params, x, caches[li], tables, p, live, counts,
+                        carry=lcarry)
                     new_caches.append(c2)
                 logits = model.readout(params, x)[:, -1]
                 with scope("sample"):
@@ -3031,6 +3133,8 @@ class ContinuousBatcher:
                 self.waste["planned_ticks"] += self.S
             self.stats["latent_tokens_written"] += (
                 self._n_latent * len(plan) * self.S)
+            self.stats["tail_rows_written"] += (
+                self._n_tail * len(plan) * self.S)
             if chaos is not None and chaos.on_segment is not None:
                 # host observation hook: drills flip drain flags /
                 # cancel requests at a deterministic segment
@@ -3597,7 +3701,9 @@ class ContinuousBatcher:
                        entries)]
         Lp = 0 if max_m == 0 else self._bucket_width(max_m) * self.bt
         for w, r, group in groups:
-            if any(upto > m for _, _, m, upto in group):
+            # a row that prefills nothing still takes its slot's tail over
+            # from the former tenant: it has to be written (as zero)
+            if self._n_tail or any(upto > m for _, _, m, upto in group):
                 self._dispatch_prefill(group, r, w, Lp)
         final = [(b, known) for b, known, _m, upto in entries
                  if upto >= len(known) - 1]
@@ -3666,8 +3772,8 @@ class ContinuousBatcher:
             if self._block_takes_moe_capacity:
                 caps.append(self._block.prefill_capacity(len(known)))
         kw = {}
-        if "ring" in self._cache_kinds:
-            # the slot each wave row's ring is; pad rows out of range
+        if self._slot_state:
+            # the slot each wave row's ring or tail is; pad rows out of range
             kw["ring_rows"] = jnp.asarray(
                 [b for b, *_ in entries] + [self.B] * (R - K), jnp.int32)
         if caps:
@@ -3694,6 +3800,7 @@ class ContinuousBatcher:
             self.stats["prefill_window_tokens"] += R * window
             self.stats["latent_tokens_written"] += (
                 self._n_latent * int(pmask.sum()))
+            self.stats["tail_rows_written"] += self._n_tail * K
 
     def _reconstruct(self, table, requests, fin, free_row) -> None:
         """Device-failure session reconstruction: rebuild every live

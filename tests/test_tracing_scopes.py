@@ -203,6 +203,41 @@ def test_latent_layers_carry_the_latent_scopes():
     assert _has(adm, "admit", "kv_write")            # the whole-block write
 
 
+def test_cca_layers_carry_the_cca_scopes():
+    """A compressed-convolutional-attention layer (``models/hybrid.py``):
+    everything its mixer does as ``attn_cca`` inside ``attn``; what it adds
+    round the projections and the attention product (the convolutions, the
+    mean, the norms, the rotation, the value shift, the tail's read and
+    write) as ``cca_mix`` inside that; the MLP router under ``router`` and
+    the experts under ``experts`` inside ``mlp``; in both serve programs."""
+    from distributed_compute_pytorch_tpu.serve import Request
+    model = build_model(
+        "hybrid", vocab_size=256, max_seq_len=64,
+        layer_types=("cca_attention",) * 2,
+        mlp_layer_types=("sparse_top1",) * 2, num_heads=4, num_kv_heads=2,
+        head_dim=16, d_model=64, norm_placement="pre", qk_norm=False,
+        partial_rotary_factor=0.5, num_experts=5, experts_held=(0, 4),
+        top_k=1, moe_d_ff=32, shared_d_ff=0, router_hidden=16,
+        scale_residual_merge=True, tie_embeddings=True)
+    params, _ = model.init(jax.random.key(0))
+    cb = ContinuousBatcher(model, params, slots=2, t_max=64, prompt_buf=32,
+                           segment=4)
+    out = cb.serve([Request(tokens=list(range(1, 13)), max_new=3)])
+    assert len(out[0]) == 3
+    for program, outer in (("segment", "decode"), ("admit", "admit")):
+        fn, args, kwargs = cb._program_sigs[program]
+        locs = _locations(fn.lower(*args, **kwargs))
+        for path in ((outer, "attn", "attn_cca"), ("attn_cca", "cca_mix"),
+                     (outer, "mlp", "router"), (outer, "mlp", "experts")):
+            assert _has(locs, *path), (program, path)
+        assert not _has(locs, "attn_latent") and not _has(locs, "attn_local")
+        assert not _has(locs, "mlp", "attn_cca")
+        assert not _has(locs, "cca_mix", "experts")
+    seg = _locations(cb._program_sigs["segment"][0].lower(
+        *cb._program_sigs["segment"][1], **cb._program_sigs["segment"][2]))
+    assert _has(seg, "attn_cca", "kv_write")         # the tick's pool write
+
+
 def test_admission_prefix_gather_is_a_kv_gather():
     """With the prefix cache on, a second request sharing a block-aligned
     prefix attaches it: the admission program gathers the cached K/V."""
@@ -259,7 +294,7 @@ def test_every_name_a_benchmark_metric_reads_is_emitted():
         if spec["reader"] == "span_share":
             for s in [spec["numerator"], *spec["denominator"]]:
                 assert s in spans, (f.name, s)
-    assert read >= 12
+    assert read >= 17
     # an "unscoped" share leaves out the program's whole vocabulary but
     # the scopes that wrap its program (``wraps``): the reader takes
     # ``obs.tracing.SCOPES`` from the run (``perfbench/readers/
