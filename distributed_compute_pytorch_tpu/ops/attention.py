@@ -436,7 +436,10 @@ def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
     follows ``pos``, not the slice. The caller must
     ship a table covering ``max(pos) // bt`` — the write's
     ``take_along_axis`` clamps, which is only correct for parked rows
-    whose table is all-trash (the kernel clamps the same way)."""
+    whose table is all-trash. A PARKED row (its table's first entry the
+    trash block: a live row's never is) still writes, into trash; what it
+    attends is nobody's to read: the kernel passes it by and returns
+    zeros for it, the gather attends the trash block."""
     from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
         kv_pool_insert_all)
     from distributed_compute_pytorch_tpu.utils.quantize import quantize_kv
@@ -513,7 +516,7 @@ def latent_write_and_attend(q, latent, cache, pos, *, v_width: int,
     ``v_width`` channels are the value. In place through the table where
     :func:`latent_read_path` says ``kernel``; otherwise (CPU, a mesh) over
     the gathered view, which is also the kernel's test oracle. The same
-    block table, write kernel and width rules as
+    block table, write kernel, width rules and parked rows as
     :func:`_paged_write_and_attend`. Returns ``(o [B, H, v_width],
     new_cache)``."""
     from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (

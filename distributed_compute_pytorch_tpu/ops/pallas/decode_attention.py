@@ -21,6 +21,14 @@ How it is built:
 - **Dynamic length**: the chunk loop bound follows ``pos``, a traced
   scalar (scalar-prefetched), so blocks beyond a row's position are
   never fetched. XLA cannot express this with static shapes.
+- **Parked rows are passed by**: a row whose table's first entry is the
+  reserved trash block holds no request (``_live_from``: the paged
+  format's own fact, read from the table every caller already ships; no
+  flag). Its grid step issues no copy and no product and writes ZEROS:
+  finite, and nobody reads that row. The stream is chained from live row
+  to live row, so the rows between cost a grid step each (0.16 us on the
+  v5e against the 2 us of a chunk of masked tokens: PERF.md section 6,
+  PR 37). Live rows' outputs are bit for bit what they were.
 - **Online softmax** (the flash recipe) in f32.
 
 What it measured against the gather on the chip: PERF.md section 6,
@@ -43,6 +51,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from distributed_compute_pytorch_tpu.kv_pool import BlockPool
 
 
 # ---------------------------------------------------------------------------
@@ -76,21 +86,51 @@ _SCRATCH_BYTES = 4 << 20
 _ISSUE_GROUP = 4
 
 
-def _chunk_stream(pos_ref, tbl_ref, block_at, buf, sem, *, C: int,
-                  nb_w: int, bt: int):
-    """The block stream both decode kernels run: ``start(row, c, slot)``
-    issues the async copies of chunk ``c`` of ``row``'s live blocks
-    (``block_at(phys)``: the pool block in HBM) into ``buf[slot, j]``, all
-    on ``sem[slot]``; ``wait(row, c, slot)`` takes them up;
-    ``live_blocks(row)`` is the number of blocks the row's position
-    reaches. Blocks past ``pos[row] // bt`` are neither fetched nor
-    waited for: copies go out ``group`` to a loop step, the last group
-    filled up with the row's last live block again."""
+def _live_from(table):
+    """int32 ``[B + 1]``: entry ``b`` is the first LIVE row at or after
+    ``b``, ``B`` where there is none (entry ``B`` always). A row is
+    PARKED when its table's first entry is the reserved trash block
+    (``kv_pool.py::BlockPool.TRASH``: the scheduler hands every slot out
+    of a segment's plan an all-trash table, and a live row's first block
+    never is it). The one array tells a kernel's grid step all it asks:
+    row ``b`` is live iff entry ``b`` is ``b``, the stream opens at entry
+    0 and goes on from row ``b`` to entry ``b + 1``."""
+    B = table.shape[0]
+    rows = jnp.where(table[:, 0] != BlockPool.TRASH,
+                     jnp.arange(B, dtype=jnp.int32), B)
+    return jnp.concatenate([lax.cummin(rows, reverse=True),
+                            jnp.full((1,), B, jnp.int32)])
+
+
+def _stream_rows(pos_ref, tbl_ref, nxt_ref, block_at, out_ref, buf, sem,
+                 slot0_ref, *, C: int, nb_w: int, bt: int, live_row):
+    """The grid step both decode kernels run, step ``b`` for row ``b``.
+
+    A LIVE row (``nxt_ref[b] == b``, :func:`_live_from`) streams its live
+    blocks (those its position reaches, never past the shipped table) in
+    chunks of ``C`` into ``buf[slot]``: every block of a chunk is one
+    async copy ``block_at(table[b, j]) -> buf[slot, j]``, all on
+    ``sem[slot]``, issued ``group`` to a loop step (the last group filled
+    up with the row's last live block again) and taken up in at most
+    ``log2(C) + 1`` waits. The next chunk's copies are started before the
+    current chunk is waited for, and after a row's last chunk they are the
+    next LIVE row's first (``nxt_ref[b + 1]``; the first live row's are
+    started by step 0), so no row pays a DMA latency of its own and a
+    parked last row leaves no copy in flight. ``live_row()`` gives the
+    row's ``(init, attend, finish)``: ``attend(c, slot, carry)`` folds
+    chunk ``c`` into the online-softmax ``carry`` (from ``init``),
+    ``finish(carry)`` writes the row's output. ``slot0_ref`` (SMEM)
+    carries the buffer parity from live row to live row, so the grid
+    steps run in order (``arbitrary``).
+
+    A PARKED row issues no copy, waits for none and multiplies nothing:
+    its block of ``out_ref`` is zeros and the stream passes it by."""
+    b = pl.program_id(0)
+    n_rows = pl.num_programs(0)
+    live = nxt_ref[b] == b
     group = math.gcd(C, _ISSUE_GROUP)
 
     def live_blocks(row):
-        # never past the shipped table (a parked row's position means
-        # nothing; its table is all-trash and any block of it will do)
         return jnp.clip(pos_ref[row] // bt + 1, 1, nb_w)
 
     def issue_steps(row, c):
@@ -120,91 +160,108 @@ def _chunk_stream(pos_ref, tbl_ref, block_at, buf, sem, *, C: int,
                 pltpu.make_async_copy(part, part, sem.at[slot]).wait()
             k //= 2
 
-    return live_blocks, start, wait
+    @pl.when(b == 0)
+    def _():
+        # whatever the scratch held (NaN patterns included) must never
+        # meet a zero probability: from here on it only holds pool data
+        buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        slot0_ref[0] = 0
+
+        @pl.when(nxt_ref[0] < n_rows)
+        def _():
+            start(nxt_ref[0], 0, 0)
+
+    @pl.when(live)
+    def _():
+        slot0 = slot0_ref[0]
+        n_chunks = pl.cdiv(live_blocks(b), C)
+        nxt = nxt_ref[b + 1]
+        init, attend, finish = live_row()
+
+        def chunk_step(c, carry):
+            slot = (slot0 + c) % 2
+
+            @pl.when(c + 1 < n_chunks)
+            def _():
+                start(b, c + 1, 1 - slot)
+
+            @pl.when(jnp.logical_and(c + 1 == n_chunks, nxt < n_rows))
+            def _():
+                start(nxt, 0, 1 - slot)
+
+            wait(b, c, slot)
+            return attend(c, slot, carry)
+
+        carry = lax.fori_loop(0, n_chunks, chunk_step, init)
+        slot0_ref[0] = (slot0 + n_chunks) % 2
+        finish(carry)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        out_ref[...] = jnp.zeros(out_ref.shape, out_ref.dtype)
 
 
-def _paged_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
-                         buf, sem, slot0_ref, *, chunk_blocks: int,
+def _paged_decode_kernel(pos_ref, tbl_ref, nxt_ref, q_ref, pool_hbm,
+                         out_ref, buf, sem, slot0_ref, *, chunk_blocks: int,
                          nb_w: int):
     """Grid step ``b`` attends row ``b``'s query over logical slots
     ``0 .. pos[b]`` of ``pool_hbm [2, P, hk, bt, hd]`` (left in HBM)
-    through ``tbl_ref`` (the row-major ``[B, nb_w]`` table). The row's
-    live blocks stream in chunks of ``chunk_blocks``
-    (:func:`_chunk_stream`): every live block of
-    a chunk is one async copy ``pool[:, table[b, j]] -> buf[slot, j]``
-    (``[2, hk, bt, hd]``: both planes), all on one semaphore; the next
-    chunk's copies — the next ROW's first chunk after a row's last — are
-    started before the current chunk is waited for, so no row pays a
-    DMA latency of its own. ``slot0_ref`` (SMEM) carries the buffer
-    parity over the grid steps, which therefore run in order
-    (``arbitrary``). Buffer
+    through ``tbl_ref`` (the row-major ``[B, nb_w]`` table), streamed by
+    :func:`_stream_rows` (a block's copy is ``[2, hk, bt, hd]``: both
+    planes); a parked row's output is zeros. Buffer
     slots no copy filled hold an earlier chunk's (finite) data, masked
     to probability 0. Online softmax per KV head: f32 scores, running
     max, sum and accumulator; the probabilities meet V in the pool's
     dtype, as in ``cached_attention``."""
     b = pl.program_id(0)
-    n_rows = pl.num_programs(0)
     _, _, hk, bt, hd = pool_hbm.shape
     C = chunk_blocks
     G = q_ref.shape[2]
     cdt = buf.dtype
     scale = hd ** -0.5
-    live_blocks, start, wait = _chunk_stream(
-        pos_ref, tbl_ref, lambda phys: pool_hbm.at[:, phys], buf, sem,
-        C=C, nb_w=nb_w, bt=bt)
 
-    @pl.when(b == 0)
-    def _():
-        # whatever the scratch held (NaN patterns included) must never
-        # meet a zero probability: from here on it only holds pool data
-        buf[...] = jnp.zeros(buf.shape, cdt)
-        slot0_ref[0] = 0
-        start(0, 0, 0)
+    def live_row():
+        q = q_ref[0].astype(cdt)                     # [hk, G, hd]
 
-    slot0 = slot0_ref[0]
-    pos = pos_ref[b]
-    n_chunks = pl.cdiv(live_blocks(b), C)
-    q = q_ref[0].astype(cdt)                         # [hk, G, hd]
+        def attend(c, slot, carry):
+            ms, ls, accs = carry
+            ids = c * (C * bt) + lax.broadcasted_iota(
+                jnp.int32, (G, C * bt), 1)
+            valid = ids <= pos_ref[b]
+            new_m, new_l, new_acc = [], [], []
+            for h in range(hk):
+                k = buf[slot, :, 0, h].reshape(C * bt, hd)
+                v = buf[slot, :, 1, h].reshape(C * bt, hd)
+                s = lax.dot_general(
+                    q[h], k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                s = jnp.where(valid, s, -1e30)
+                m_new = jnp.maximum(ms[h], jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(ms[h] - m_new)
+                p = jnp.exp(s - m_new)
+                new_l.append(ls[h] * alpha
+                             + jnp.sum(p, axis=1, keepdims=True))
+                new_acc.append(accs[h] * alpha + lax.dot_general(
+                    p.astype(cdt), v, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32))
+                new_m.append(m_new)
+            return tuple(new_m), tuple(new_l), tuple(new_acc)
 
-    def chunk_step(c, carry):
-        ms, ls, accs = carry
-        slot = (slot0 + c) % 2
+        def finish(carry):
+            _, ls, accs = carry
+            for h in range(hk):
+                out_ref[0, h] = (accs[h] / ls[h]).astype(out_ref.dtype)
 
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start(b, c + 1, 1 - slot)
+        return (
+            (tuple(jnp.full((G, 1), -jnp.inf, jnp.float32)
+                   for _ in range(hk)),
+             tuple(jnp.zeros((G, 1), jnp.float32) for _ in range(hk)),
+             tuple(jnp.zeros((G, hd), jnp.float32) for _ in range(hk))),
+            attend, finish)
 
-        @pl.when(jnp.logical_and(c + 1 == n_chunks, b + 1 < n_rows))
-        def _():
-            start(b + 1, 0, 1 - slot)
-
-        wait(b, c, slot)
-        ids = c * (C * bt) + lax.broadcasted_iota(jnp.int32, (G, C * bt), 1)
-        valid = ids <= pos
-        new_m, new_l, new_acc = [], [], []
-        for h in range(hk):
-            k = buf[slot, :, 0, h].reshape(C * bt, hd)
-            v = buf[slot, :, 1, h].reshape(C * bt, hd)
-            s = lax.dot_general(q[h], k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, -1e30)
-            m_new = jnp.maximum(ms[h], jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(ms[h] - m_new)
-            p = jnp.exp(s - m_new)
-            new_l.append(ls[h] * alpha + jnp.sum(p, axis=1, keepdims=True))
-            new_acc.append(accs[h] * alpha + lax.dot_general(
-                p.astype(cdt), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32))
-            new_m.append(m_new)
-        return tuple(new_m), tuple(new_l), tuple(new_acc)
-
-    init = (tuple(jnp.full((G, 1), -jnp.inf, jnp.float32) for _ in range(hk)),
-            tuple(jnp.zeros((G, 1), jnp.float32) for _ in range(hk)),
-            tuple(jnp.zeros((G, hd), jnp.float32) for _ in range(hk)))
-    _, ls, accs = lax.fori_loop(0, n_chunks, chunk_step, init)
-    slot0_ref[0] = (slot0 + n_chunks) % 2
-    for h in range(hk):
-        out_ref[0, h] = (accs[h] / ls[h]).astype(out_ref.dtype)
+    _stream_rows(pos_ref, tbl_ref, nxt_ref,
+                 lambda phys: pool_hbm.at[:, phys], out_ref, buf, sem,
+                 slot0_ref, C=C, nb_w=nb_w, bt=bt, live_row=live_row)
 
 
 def _chunk_blocks(pool_shape, itemsize: int, nb_w: int) -> int:
@@ -249,9 +306,9 @@ def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
     G = H // hk
     C = _chunk_blocks(pool_kv.shape, pool_kv.dtype.itemsize, nb_w)
     assert C >= 1, ("a block pair does not fit the scratch", pool_kv.shape)
-    row_spec = pl.BlockSpec((1, hk, G, hd), lambda b, p, t: (b, 0, 0, 0))
+    row_spec = pl.BlockSpec((1, hk, G, hd), lambda b, p, t, n: (b, 0, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
             row_spec,
@@ -273,7 +330,8 @@ def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
         name="dcp_paged_decode_attn",
         interpret=interpret,
     )(jnp.broadcast_to(jnp.atleast_1d(pos).astype(jnp.int32), (B,)),
-      table.reshape(-1).astype(jnp.int32), q.reshape(B, hk, G, hd), pool_kv)
+      table.reshape(-1).astype(jnp.int32), _live_from(table),
+      q.reshape(B, hk, G, hd), pool_kv)
     return out.reshape(B, H, 1, hd)
 
 
@@ -296,77 +354,61 @@ def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
 _LATENT_CHUNK_TOKENS = 1024
 
 
-def _latent_decode_kernel(pos_ref, tbl_ref, q_ref, pool_hbm, out_ref,
-                          buf, sem, slot0_ref, *, chunk_blocks: int,
+def _latent_decode_kernel(pos_ref, tbl_ref, nxt_ref, q_ref, pool_hbm,
+                          out_ref, buf, sem, slot0_ref, *, chunk_blocks: int,
                           nb_w: int, v_width: int, scale: float):
     """Grid step ``b``: row ``b``'s ``H`` queries ``q_ref [1, H, W]`` over
     logical slots ``0 .. pos[b]`` of ``pool_hbm [1, P, 1, bt, W]`` through
     the table, streamed as :func:`_paged_decode_kernel` streams its pool
-    (one async copy a block, ``[bt, W]``). A chunk is the key of every
+    (:func:`_stream_rows`; one async copy a block, ``[bt, W]``; a parked
+    row's output is zeros). A chunk is the key of every
     head (scores over its ``V`` compressed channels and over its ``W - V``
     rotary channels, two products whose operands start on a lane tile) and,
     in its first ``V`` channels, the value. Online softmax in f32; output
     ``[1, H, V]``."""
     b = pl.program_id(0)
-    n_rows = pl.num_programs(0)
     _, _, _, bt, W = pool_hbm.shape
     C, V = chunk_blocks, v_width
     H = q_ref.shape[1]
     cdt = buf.dtype
-    live_blocks, start, wait = _chunk_stream(
-        pos_ref, tbl_ref, lambda phys: pool_hbm.at[0, phys, 0], buf, sem,
-        C=C, nb_w=nb_w, bt=bt)
-
-    @pl.when(b == 0)
-    def _():
-        # as in _paged_decode_kernel: the scratch only ever holds pool data
-        buf[...] = jnp.zeros(buf.shape, cdt)
-        slot0_ref[0] = 0
-        start(0, 0, 0)
-
-    slot0 = slot0_ref[0]
-    pos = pos_ref[b]
-    n_chunks = pl.cdiv(live_blocks(b), C)
-    q = q_ref[0].astype(cdt)                         # [H, W]
-    q_c, q_r = q[:, :V], q[:, V:]
     nt = (((1,), (1,)), ((), ()))
 
-    def chunk_step(c, carry):
-        m, l, acc = carry
-        slot = (slot0 + c) % 2
+    def live_row():
+        q = q_ref[0].astype(cdt)                     # [H, W]
+        q_c, q_r = q[:, :V], q[:, V:]
 
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start(b, c + 1, 1 - slot)
+        def attend(c, slot, carry):
+            m, l, acc = carry
+            lat = buf[slot].reshape(C * bt, W)
+            val = lat[:, :V]
+            s = (lax.dot_general(q_c, val, nt,
+                                 preferred_element_type=jnp.float32)
+                 + lax.dot_general(q_r, lat[:, V:], nt,
+                                   preferred_element_type=jnp.float32)
+                 ) * scale
+            ids = c * (C * bt) + lax.broadcasted_iota(
+                jnp.int32, (H, C * bt), 1)
+            s = jnp.where(ids <= pos_ref[b], s, -1e30)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc = acc * alpha + lax.dot_general(
+                p.astype(cdt), val, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
 
-        @pl.when(jnp.logical_and(c + 1 == n_chunks, b + 1 < n_rows))
-        def _():
-            start(b + 1, 0, 1 - slot)
+        def finish(carry):
+            _, l, acc = carry
+            out_ref[0] = (acc / l).astype(out_ref.dtype)
 
-        wait(b, c, slot)
-        lat = buf[slot].reshape(C * bt, W)
-        val = lat[:, :V]
-        s = (lax.dot_general(q_c, val, nt,
-                             preferred_element_type=jnp.float32)
-             + lax.dot_general(q_r, lat[:, V:], nt,
-                               preferred_element_type=jnp.float32)) * scale
-        ids = c * (C * bt) + lax.broadcasted_iota(jnp.int32, (H, C * bt), 1)
-        s = jnp.where(ids <= pos, s, -1e30)
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc * alpha + lax.dot_general(
-            p.astype(cdt), val, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+        return ((jnp.full((H, 1), -jnp.inf, jnp.float32),
+                 jnp.zeros((H, 1), jnp.float32),
+                 jnp.zeros((H, V), jnp.float32)), attend, finish)
 
-    init = (jnp.full((H, 1), -jnp.inf, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, V), jnp.float32))
-    _, l, acc = lax.fori_loop(0, n_chunks, chunk_step, init)
-    slot0_ref[0] = (slot0 + n_chunks) % 2
-    out_ref[0] = (acc / l).astype(out_ref.dtype)
+    _stream_rows(pos_ref, tbl_ref, nxt_ref,
+                 lambda phys: pool_hbm.at[0, phys, 0], out_ref, buf, sem,
+                 slot0_ref, C=C, nb_w=nb_w, bt=bt, live_row=live_row)
 
 
 @functools.partial(jax.jit,
@@ -392,13 +434,14 @@ def paged_latent_decode_attention_pallas(q, pool_kv, table, pos, *,
     nb_w = table.shape[1]
     C = min(max(1, _LATENT_CHUNK_TOKENS // bt), nb_w)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, W), lambda b, p, t: (b, 0, 0)),
+            pl.BlockSpec((1, H, W), lambda b, p, t, n: (b, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, H, v_width), lambda b, p, t: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, v_width),
+                               lambda b, p, t, n: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, C, bt, W), pool_kv.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -415,4 +458,4 @@ def paged_latent_decode_attention_pallas(q, pool_kv, table, pos, *,
         name="dcp_paged_latent_decode_attn",
         interpret=interpret,
     )(jnp.broadcast_to(jnp.atleast_1d(pos).astype(jnp.int32), (B,)),
-      table.reshape(-1).astype(jnp.int32), q, pool_kv)
+      table.reshape(-1).astype(jnp.int32), _live_from(table), q, pool_kv)

@@ -1115,6 +1115,10 @@ class ContinuousBatcher:
             # dispatched for them
             "prefill_calls": 0, "prefill_rows": 0,
             "prefill_tokens": 0, "prefill_window_tokens": 0,
+            # slot-ticks dispatched under an all-trash table (rows out of
+            # the plan: ``waste``'s two parked counts together), for which
+            # the paged decode kernels attend nothing
+            "decode_rows_parked": 0,
             # token vectors written into latent layers' pools (tokens x
             # latent layers): admission's real tokens and the ticks of the
             # rows in the plan, counted here on the host from what was
@@ -3078,6 +3082,7 @@ class ContinuousBatcher:
                     key = ("parked_admission_lag" if pending
                            else "parked_drain")
                     self.waste[key] += self.S
+                    self.stats["decode_rows_parked"] += self.S
                     if table[b].pf_known is not None:
                         self.prefill["stall_ticks"] += self.S
             # width bucket (ISSUE 19): the segment's S ticks write
@@ -3221,6 +3226,7 @@ class ContinuousBatcher:
                     key = ("parked_admission_lag" if pending
                            else "parked_drain")
                     self.waste[key] += W
+                    self.stats["decode_rows_parked"] += W
                     if table[b].pf_known is not None:
                         self.prefill["stall_ticks"] += W
             # width bucket (ISSUE 19): a verify window writes slots

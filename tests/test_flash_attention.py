@@ -351,3 +351,43 @@ def test_kernel_body_does_not_grow_with_block_offset_or_window(kernel, masked):
     # backward: the whole block's body and one sub-tile's; forward: as it was
     assert dots == (2 if "bwd" in kernel else 1) * before_dots
     assert eqns <= 2 * before_eqns, (eqns, before_eqns)
+
+
+# (equations, dot_generals) of the paged decode kernels before a grid step
+# could pass a parked row by (PR 37's parent): the skip is a ``pl.when``
+# round the one body, not a second chunk loop
+_DECODE_BEFORE_THE_SKIP = {
+    "dcp_paged_decode_attn.mistral_8_kv_heads": (655, 16),
+    "dcp_paged_decode_attn.2_kv_heads": (457, 4),
+    "dcp_paged_latent_decode_attn": (421, 3),
+}
+
+
+@pytest.mark.parametrize("case", list(_DECODE_BEFORE_THE_SKIP))
+def test_decode_kernel_body_does_not_grow_with_the_skip_or_the_rows(case):
+    """The decode kernels are traced in every set-up too: their bodies
+    stay within a quarter of what they were before parked rows were
+    skipped, with the same products, whatever the number of rows."""
+    from distributed_compute_pytorch_tpu.ops.pallas import decode_attention
+    kernel = case.split(".")[0]
+    sizes = set()
+    for B in (4, 32, 128):
+        table, pos = jnp.zeros((B, 320), jnp.int32), jnp.zeros((B,), jnp.int32)
+        if kernel == "dcp_paged_latent_decode_attn":
+            args = (jnp.zeros((B, 32, 640), jnp.bfloat16),
+                    jnp.zeros((1, 9, 1, 32, 640), jnp.bfloat16), table, pos)
+
+            def call(*a):
+                return decode_attention.paged_latent_decode_attention_pallas(
+                    *a, v_width=512, scale=0.1)
+        else:
+            hk = 8 if "mistral" in case else 2
+            args = (jnp.zeros((B, hk * 4, 1, 128), jnp.bfloat16),
+                    jnp.zeros((2, 9, hk, 8, 128), jnp.bfloat16), table, pos)
+            call = decode_attention.paged_decode_attention_pallas
+        sizes.add(_kernel_sizes(call, *args)[kernel])
+    assert len(sizes) == 1, sizes
+    (eqns, dots), = sizes
+    before_eqns, before_dots = _DECODE_BEFORE_THE_SKIP[case]
+    assert dots == before_dots
+    assert eqns <= 1.25 * before_eqns, (eqns, before_eqns)

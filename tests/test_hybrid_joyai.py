@@ -191,6 +191,54 @@ def test_latent_decode_kernel_matches_the_gather(bt, chunk, monkeypatch):
     assert float(jnp.max(jnp.abs(got - want))) < 2e-5
 
 
+# True = the row is parked (an all-trash table); live rows sit at
+# LATENT_POS: several chunks long wherever a parked run has a live side
+LATENT_POS = [300, 639, 64, 200, 500, 0]
+LATENT_PARKED = {
+    "first_parked": [1, 1, 0, 0, 0, 0],
+    "last_parked": [0, 0, 0, 0, 0, 1],
+    "alternating": [1, 0, 1, 0, 1, 0],
+    "one_live_row_in_the_middle": [1, 1, 1, 0, 1, 1],
+    "long_rows_round_a_parked_run": [0, 0, 1, 1, 0, 1],
+    "all_parked": [1, 1, 1, 1, 1, 1],
+    "none_parked": [0, 0, 0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_PARKED))
+def test_latent_decode_kernel_skips_parked_rows(name, monkeypatch):
+    """A row whose table is all trash is parked: the kernel attends
+    nothing for it and writes zeros. Wherever the parked rows are, a live
+    row's output is BIT FOR BIT the kernel's own with every row live (the
+    stream's hand-over past a parked run changes nothing a row can see)
+    and matches the gather (float32: 2e-5). Chunks of 64 tokens: the live
+    rows hold up to ten."""
+    from distributed_compute_pytorch_tpu.ops.pallas import decode_attention
+    monkeypatch.setattr(decode_attention, "_LATENT_CHUNK_TOKENS", 64)
+    rng = np.random.default_rng(1)
+    B, H, W, V, T, bt = len(LATENT_POS), 4, 256, 128, 640, 16
+    nb = T // bt
+    P = B * nb + 1
+    pool = jnp.asarray(rng.standard_normal((1, P, 1, bt, W)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((B, H, W)), jnp.float32)
+    table = jnp.asarray(
+        rng.permutation(P - 1)[:B * nb].reshape(B, nb) + 1, jnp.int32)
+    pos = jnp.asarray(LATENT_POS, jnp.int32)
+    parked = np.asarray(LATENT_PARKED[name], bool)
+
+    def run(table):
+        return np.asarray(
+            decode_attention.paged_latent_decode_attention_pallas.__wrapped__(
+                q, pool, table, pos, v_width=V, scale=0.07, interpret=True))
+    every_row_live = run(table)
+    got = run(jnp.where(parked[:, None], 0, table))
+    want = np.asarray(A.latent_attention_gathered(
+        q, pool, table, pos, v_width=V, scale=0.07))
+    assert not got[parked].any()
+    np.testing.assert_array_equal(got[~parked], every_row_live[~parked])
+    assert np.abs(got - want)[~parked].max(initial=0.0) < 2e-5
+
+
 @pytest.mark.parametrize("t,masked", [(300, False), (256, True)])
 def test_flash_forward_at_unequal_widths_matches_a_dense_softmax(t, masked):
     """q and k heads of 48 channels, v heads of 32 (the shape class of 192

@@ -55,6 +55,17 @@ def _paged_case(positions, *, hk, G, hd, nb, dtype, seed):
     return q, pool, jnp.asarray(table), pos
 
 
+def _assert_live_match_and_parked_zero(got, want, parked, tol):
+    """Live rows give what the gathered reference gives; a parked row's
+    output is exactly zero (the kernel attends nothing for it)."""
+    assert got.shape == want.shape and got.dtype == want.dtype
+    got, want = np.asarray(got, np.float32), np.asarray(want, np.float32)
+    parked = np.asarray(parked, bool)
+    np.testing.assert_allclose(got[~parked], want[~parked],
+                               atol=tol, rtol=tol)
+    assert not got[parked].any()
+
+
 def _check(positions, *, hk, G, hd=128, nb, dtype, tol, interpret, seed=0):
     q, pool, table, pos = _paged_case(positions, hk=hk, G=G, hd=hd, nb=nb,
                                       dtype=dtype, seed=seed)
@@ -62,10 +73,8 @@ def _check(positions, *, hk, G, hd=128, nb, dtype, tol, interpret, seed=0):
     want = A.cached_attention(q, view[0], view[1], pos)
     got = jax.jit(lambda *a: paged_decode_attention_pallas(
         *a, interpret=interpret))(q, pool, table, pos)
-    assert got.shape == want.shape and got.dtype == want.dtype
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=tol, rtol=tol)
+    _assert_live_match_and_parked_zero(
+        got, want, [p == PARKED for p in positions], tol)
 
 
 # positions a serving row can sit at: the first slot, a block's last and
@@ -93,15 +102,60 @@ def test_interpreted_kernel_matches_gathered_attention(name, dtype):
            tol=tol, interpret=True)
 
 
+# live (a position, some past a chunk's edge so that the prefetch is
+# handed over a parked run on both of its sides) and PARKED rows
+PARKED_PATTERNS = {
+    "first_parked": [PARKED, PARKED, EDGE + 9, 3],
+    "last_parked": [2 * _CHUNK_TOKENS, 40, PARKED],
+    "alternating": [PARKED, EDGE + 1, PARKED, 700, PARKED, 5],
+    "one_live_row_in_the_middle": [PARKED, PARKED, 1100, PARKED, PARKED],
+    "long_rows_round_a_parked_run": [1300, PARKED, PARKED, PARKED, 1030],
+    "all_parked": [PARKED, PARKED, PARKED],
+    "none_parked": [600, 0, EDGE, 1025],
+}
+
+
+def _park(table, parked):
+    """``table`` with the ``parked`` rows swapped for the all-trash row, as
+    ``serve.py``'s dispatch hands rows out of the plan to the device."""
+    return jnp.where(jnp.asarray(parked)[:, None], 0, table)
+
+
+@on_cpu
+@pytest.mark.parametrize("name", list(PARKED_PATTERNS))
+def test_interpreted_kernel_skips_parked_rows(name):
+    """Whatever rows are parked, and wherever: a live row's output is BIT
+    FOR BIT the one the kernel gives with every row live (same chunks,
+    same order of products; the stream's hand-over and the buffer parity
+    change nothing a row can see), it matches the gathered reference, and
+    a parked row's output is exactly zero."""
+    pattern = PARKED_PATTERNS[name]
+    parked = [p == PARKED for p in pattern]
+    # every row live (a parked one at some short position of its own) ...
+    q, pool, table, pos = _paged_case(
+        [17 * b + 1 if p == PARKED else p for b, p in enumerate(pattern)],
+        hk=2, G=4, hd=128, nb=320, dtype=jnp.float32, seed=7)
+    run = jax.jit(lambda *a: paged_decode_attention_pallas(
+        *a, interpret=True))
+    every_row_live = np.asarray(run(q, pool, table, pos))
+    # ... then the pattern's rows handed the all-trash table
+    got = run(q, pool, _park(table, parked), pos)
+    view = A.gather_kv_blocks(pool, table)
+    _assert_live_match_and_parked_zero(
+        got, A.cached_attention(q, view[0], view[1], pos), parked, 2e-5)
+    live = ~np.asarray(parked)
+    np.testing.assert_array_equal(np.asarray(got)[live], every_row_live[live])
+
+
 @on_cpu
 def test_interpreted_kernel_narrow_table_and_mha():
     """A table slice narrower than one chunk (a low width rung: the
     kernel must never read the table past it), one query row per KV
     head, heads of 256 lanes, and a position beyond the shipped table
-    (a parked row's: clamped, as the gather path's write clamps)."""
+    (clamped, as the gather path's write clamps)."""
     _check([0, 15, 23], hk=3, G=1, hd=256, nb=3, dtype=jnp.float32,
            tol=2e-5, interpret=True)
-    q, pool, table, pos = _paged_case([PARKED, 9], hk=2, G=2, hd=128, nb=2,
+    q, pool, table, pos = _paged_case([15, 9], hk=2, G=2, hd=128, nb=2,
                                       dtype=jnp.float32, seed=1)
     got = paged_decode_attention_pallas(q, pool, table,
                                         jnp.asarray([400, 9], jnp.int32),
@@ -180,6 +234,26 @@ def test_kernel_compiles_for_a_described_v5e(name, one_v5e_chip):
     assert "dcp_paged_decode_attn" in compiled.as_text()
 
 
+@on_cpu
+def test_latent_kernel_compiles_for_a_described_v5e(one_v5e_chip):
+    """Mosaic takes ``dcp_paged_latent_decode_attn`` at the long-document
+    cell's shape: 64 rows of 32 heads over vectors of 640 lanes (512 of
+    them the value), blocks of 32 tokens, tables of 608 blocks."""
+    from distributed_compute_pytorch_tpu.ops.pallas.decode_attention import (
+        paged_latent_decode_attention_pallas)
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+    compiled = jax.jit(
+        paged_latent_decode_attention_pallas,
+        static_argnames=("v_width", "scale")).lower(
+        arg((64, 32, 640), jnp.bfloat16),
+        arg((1, 64, 1, 32, 640), jnp.bfloat16),
+        arg((64, 608), jnp.int32), arg((64,), jnp.int32),
+        v_width=512, scale=0.07).compile()
+    assert "dcp_paged_latent_decode_attn" in compiled.as_text()
+
+
 @on_tpu
 @pytest.mark.parametrize("shape", ["llama2_7b_mha_bf16", "f32_pool_hd256"])
 def test_compiled_kernel_at_budget_bound_chunks(shape):
@@ -234,9 +308,7 @@ def test_compiled_tick_takes_the_kernel_and_matches_the_gather():
                                   np.asarray(want_pool[:, 1:], np.float32))
     view = A.gather_kv_blocks(new["kv"], table)
     want = A.cached_attention(q, view[0], view[1], pos)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               atol=2e-2, rtol=2e-2)
+    _assert_live_match_and_parked_zero(got, want, [0, 0, 1, 0], 2e-2)
 
 
 # ------------------------------------------------------ the eligibility rule
@@ -289,8 +361,8 @@ def test_paged_read_path_is_decided_from_the_operands(name, monkeypatch,
 @pytest.mark.parametrize("path", ["kernel", "gather"])
 def test_tick_dispatches_on_paged_read_path(path, monkeypatch):
     """``cache_write_and_attend`` follows the rule: with it forced either
-    way (the kernel interpreted here) the same tick gives the same
-    attention output and the same pool."""
+    way (the kernel interpreted here) the same tick gives the same pool
+    and the same attention output for every live row."""
     q, pool, table, pos = _paged_case([9, PARKED, 300], hk=2, G=4, hd=128,
                                       nb=64, dtype=jnp.float32, seed=5)
     rng = np.random.default_rng(6)
@@ -314,8 +386,10 @@ def test_tick_dispatches_on_paged_read_path(path, monkeypatch):
     assert bool(called) == (path == "kernel")
     np.testing.assert_array_equal(np.asarray(got_pool["kv"]),
                                   np.asarray(base_pool["kv"]))
-    np.testing.assert_allclose(np.asarray(got), np.asarray(base),
-                               atol=2e-5, rtol=2e-5)
+    if path == "kernel":        # the kernel attends nothing for a parked row
+        _assert_live_match_and_parked_zero(got, base, [0, 1, 0], 2e-5)
+    else:
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(base))
 
 
 @on_cpu

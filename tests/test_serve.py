@@ -370,6 +370,53 @@ def test_transport_counters_overlap_and_batched_admission():
     assert w["planned_ticks"] >= sum(len(o) for o in outs)
 
 
+@pytest.mark.parametrize("read", ["kernel", "gather"])
+def test_fewer_requests_than_slots_parks_rows_and_serves_the_same(
+        read, monkeypatch):
+    """Two requests in four slots: two rows ride every segment under the
+    all-trash table. ``stats["decode_rows_parked"]`` counts those
+    slot-ticks (the waste breakdown's two parked counts together), and
+    the tokens are each prompt's standalone generation whichever engine
+    reads the pool: the gather, or the block-table kernel (interpreted
+    here), which attends nothing for a parked row."""
+    from distributed_compute_pytorch_tpu import serve
+    from distributed_compute_pytorch_tpu.ops import attention as A
+    from distributed_compute_pytorch_tpu.ops.pallas import decode_attention
+    model = LlamaLM(dataclasses.replace(
+        LlamaConfig.tiny(), d_model=256, num_heads=2, num_kv_heads=1,
+        max_seq_len=128))
+    assert model.config.head_dim == 128     # heads the kernel takes
+    params, _ = model.init(jax.random.key(0))
+    # engines of one shape family share their programs: this one's (the
+    # interpreted kernel inside) stay its own
+    monkeypatch.setattr(serve, "_PROGRAM_CACHE", {})
+    real = decode_attention.paged_decode_attention_pallas
+    called = []
+
+    def interpreted(*a, **kw):
+        called.append(1)
+        return real(*a, **kw, interpret=True)
+    monkeypatch.setattr(decode_attention, "paged_decode_attention_pallas",
+                        interpreted)
+    monkeypatch.setattr(A, "paged_read_path", lambda *a, **kw: read)
+    reqs = [Request(tokens=[3, 5, 7, 11, 13, 17, 19, 23, 29, 31], max_new=9),
+            Request(tokens=[2, 4], max_new=5)]
+    cb = ContinuousBatcher(model, params, slots=4, t_max=64, prompt_buf=16,
+                           segment=4)
+    outs = cb.serve([Request(list(r.tokens), r.max_new) for r in reqs])
+    assert bool(called) == (read == "kernel")
+    for r, out in zip(reqs, outs):
+        alone = generate(model, params, jnp.asarray([r.tokens], jnp.int32),
+                         max_new_tokens=r.max_new)
+        assert out == [int(t) for t in alone[0, len(r.tokens):]]
+    snap = cb.stats_snapshot()
+    parked = snap["stats"]["decode_rows_parked"]
+    assert parked == (snap["waste"]["parked_admission_lag"]
+                      + snap["waste"]["parked_drain"])
+    assert parked >= 2 * cb.ticks           # two slots never held a request
+    assert parked + snap["waste"]["planned_ticks"] == cb.ticks * cb.B
+
+
 def test_reset_clears_counters():
     model = GPT2(dataclasses.replace(GPT2Config.tiny(), max_seq_len=128))
     params, _ = model.init(jax.random.key(0))
