@@ -75,8 +75,8 @@ def run_load(cb, requests: list, *, drain=None,
     """Serve an arrival-stamped stream and reduce to the load report.
 
     Returns ``{"wall_s", "goodput_tok_s", "ok", "completed_tokens",
-    "statuses", "slo": {queue_wait_s|ttft_s|tpot_s|e2e_s: {count, mean,
-    p50, p90, p95, p99, ...}}, "results", "snapshot"}`` — ``results``
+    "statuses", "slo": {queue_wait_s|ttft_s|tpot_s|e2e_s|delivery_gap_s:
+    {count, mean, p50, p90, p95, p99, ...}}, "results", "snapshot"}`` — ``results``
     are the raw ``RequestResult``s (token-parity checks), ``snapshot``
     the batcher's full ``stats_snapshot()``.
     """

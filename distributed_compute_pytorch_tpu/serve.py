@@ -209,6 +209,8 @@ from __future__ import annotations
 
 import contextlib
 import inspect
+import math
+from bisect import bisect_right
 import threading
 import time
 import weakref
@@ -261,6 +263,20 @@ _PROGRAM_CACHE_LOCK = threading.Lock()
 # compute-bound on any chip: two waves cost the device what one of their
 # sum would. Not tuned.
 _WAVE_TOKENS = 32768
+
+
+# The buckets of the gap between two deliveries of one request
+# (``_run::count_gaps``): their upper edges in seconds, spaced as
+# ``obs.metrics.Histogram`` spaces its own, 16 a decade from 10 ms to 10 s
+# (a gap under 10 ms counts in the first), and the ``stats`` counter of
+# each, one more than the edges for the gaps beyond the last.
+# ``delivery_gap_upto_<ms>`` carries the edge in ms at three significant
+# digits (``_inf`` where there is none), so a reader of the counters needs
+# no copy of the edges.
+_GAP_EDGES_S = tuple(1e-2 * 10.0 ** (i / 16) for i in range(1, 49))
+_GAP_COUNTERS = (
+    *(f"delivery_gap_upto_{float(f'{1e3 * e:.3g}'):g}" for e in _GAP_EDGES_S),
+    "delivery_gap_upto_inf")
 
 
 def admission_ladder(prompt_buf: int, block: int) -> tuple:
@@ -866,7 +882,6 @@ class ContinuousBatcher:
             {"kv": zeros((2, slots, hk, model.ring_tokens, hd), dtype,
                          None)}
             for kind in self._cache_kinds]
-        self._n_latent = self._cache_kinds.count("latent")
         # does any layer keep state by SLOT (a ring, a tail)? Then an
         # admission dispatch is told which slot each of its rows fills
         self._slot_state = bool(
@@ -1119,15 +1134,17 @@ class ContinuousBatcher:
             # the plan: ``waste``'s two parked counts together), for which
             # the paged decode kernels attend nothing
             "decode_rows_parked": 0,
-            # token vectors written into latent layers' pools (tokens x
-            # latent layers): admission's real tokens and the ticks of the
-            # rows in the plan, counted here on the host from what was
-            # dispatched
-            "latent_tokens_written": 0,
-            # per-slot tails written (rows x layers that keep one):
-            # admission's rows and the ticks of the rows in the plan,
-            # counted the same way
-            "tail_rows_written": 0,
+            # deliveries that closed a gap (every harvest handing a request
+            # new tokens, but the request's first), split by whether the
+            # device ran admission between the two segments that delivered
+            # (``_run::mark_segment``), the gaps' sums in seconds, and
+            # their distribution: one count a gap in the bucket named by
+            # its upper edge in ms. The one store of the distribution:
+            # ``stats_snapshot()["slo"]["delivery_gap_s"]`` is read off it
+            "deliveries_clear": 0, "deliveries_behind_admission": 0,
+            "delivery_gap_s_clear": 0.0,
+            "delivery_gap_s_behind_admission": 0.0,
+            **dict.fromkeys(_GAP_COUNTERS, 0),
             # fault-tolerance counters (serve_lifecycle /
             # DESIGN.md "Serving under failure")
             "faults": 0, "reconstructions": 0,
@@ -1250,6 +1267,33 @@ class ContinuousBatcher:
         self._slo = {name: self.obs.histogram(f"serve.slo.{name}")
                      for name in ("queue_wait_s", "ttft_s", "tpot_s",
                                   "e2e_s")}
+        self._longest_gap_s = 0.0       # between two deliveries of a request
+
+    def _gap_summary(self) -> dict:
+        """The digest of the gaps between two deliveries of one request,
+        in the form of the SLO histograms' and read off the ``stats``
+        counters that hold the distribution: a percentile is the upper
+        edge of the bucket it falls in (up to a bucket's 15.5% over),
+        held to the longest gap there was."""
+        st = self.stats
+        counts = [st[name] for name in _GAP_COUNTERS]
+        total = sum(counts)
+        if not total:
+            return {"count": 0}
+
+        def edge(q):
+            rank, below = math.ceil(q * total), 0
+            for upper, count in zip((*_GAP_EDGES_S, math.inf), counts):
+                below += count
+                if count and below >= rank:
+                    return min(upper, self._longest_gap_s)
+
+        return {"count": total,
+                "mean": (st["delivery_gap_s_clear"]
+                         + st["delivery_gap_s_behind_admission"]) / total,
+                "max": self._longest_gap_s,
+                "p50": edge(0.50), "p90": edge(0.90),
+                "p95": edge(0.95), "p99": edge(0.99)}
 
     def stats_snapshot(self) -> dict:
         """One JSON-serialisable view of everything the batcher
@@ -1271,7 +1315,8 @@ class ContinuousBatcher:
             "width": dict(self.width),
             "fleet": dict(self.fleet),
             "engine": self.engine_info(),
-            "slo": {name: h.summary() for name, h in self._slo.items()},
+            "slo": {**{name: h.summary() for name, h in self._slo.items()},
+                    "delivery_gap_s": self._gap_summary()},
             "ticks": self.ticks,
             # static: the pool read the decode tick was compiled with
             "paged_read": self._paged_read,
@@ -2686,6 +2731,13 @@ class ContinuousBatcher:
                      for i in range(n)]
         admit_at: list[float | None] = [None] * n
         first_tok_at: list[float | None] = [None] * n
+        # delivery stamps (``delivered``): when the request's newest
+        # delivery reached the host, the mark of the segment that made it
+        # (``mark_segment``), how many it has had and the longest gap
+        last_delivery_at: list[float | None] = [None] * n
+        last_mark: list[int | None] = [None] * n
+        deliveries = [0] * n
+        max_gap: list[float | None] = [None] * n
         # journal identities: the positional default makes a whole call
         # deterministic by id the same way the seed default does by
         # stream; explicit ids win (the router / recovery replays set
@@ -2717,6 +2769,7 @@ class ContinuousBatcher:
                 recoveries=recs[i],
                 cached_prefix_tokens=cached_prefix[i],
                 queue_wait_s=qw, ttft_s=ttft, tpot_s=tpot,
+                max_gap_s=max_gap[i], deliveries=deliveries[i],
                 request_id=jids[i])
             if jr is not None:
                 # terminal frame: no tokens (the admit's emitted prefix
@@ -3049,11 +3102,69 @@ class ContinuousBatcher:
                                 head, [int(x) for x in
                                        self._tables[b, :nb_head]])
 
+        window_at_segment = [self.stats["prefill_window_tokens"]]
+
+        def mark_segment() -> tuple:
+            """The segment about to go out: its MARK, the running count
+            of admission programs that reached the device before it
+            (prefill dispatches, chunk extensions among them, and the copy
+            and promote programs, which raise no ``prefill_calls``), and
+            the prefill window dispatched since the segment before it (the
+            dispatch span's note). The mark rides the segment to its
+            harvest: two segments with one mark had no admission between
+            them on the DEVICE, whatever the host's clock says (the
+            harvest of segment k runs after segment k+1 went out)."""
+            window = self.stats["prefill_window_tokens"]
+            since = window - window_at_segment[0]
+            window_at_segment[0] = window
+            return (self.stats["prefill_calls"] + self.stats["cow_copies"]
+                    + self.tier["promotions"]), since
+
+        def delivered(ri: int, now: float, mark: int, groups: dict):
+            """A harvest handed request ``ri`` new tokens at ``now`` (the
+            harvest's one clock read) from a segment dispatched at
+            ``mark``: stamp the delivery and, unless it is the request's
+            first (that interval is TTFT's), file its gap in ``groups``
+            under the previous delivery's stamp and mark. Rows whose
+            previous delivery came from one segment share both."""
+            prev = last_delivery_at[ri]
+            if prev is not None:
+                gap = now - prev
+                if max_gap[ri] is None or gap > max_gap[ri]:
+                    max_gap[ri] = gap
+                key = (prev, last_mark[ri])
+                groups[key] = groups.get(key, 0) + 1
+            deliveries[ri] += 1
+            last_delivery_at[ri] = now
+            last_mark[ri] = mark
+
+        def count_gaps(groups: dict, now: float, mark: int) -> dict:
+            """The gaps one harvest closed (``delivered``) into ``stats``,
+            one update a distinct gap: CLEAR where no admission program
+            reached the device between the two segments that delivered,
+            BEHIND ADMISSION otherwise. Returns the harvest span's notes:
+            the longest gap and the deliveries behind admission."""
+            longest, behind = 0.0, 0
+            for (prev, before), rows in groups.items():
+                gap = now - prev
+                kind = "clear"
+                if mark != before:
+                    kind = "behind_admission"
+                    behind += rows
+                longest = max(longest, gap)
+                self.stats["deliveries_" + kind] += rows
+                self.stats["delivery_gap_s_" + kind] += gap * rows
+                self.stats[_GAP_COUNTERS[bisect_right(_GAP_EDGES_S,
+                                                      gap)]] += rows
+            self._longest_gap_s = max(self._longest_gap_s, longest)
+            return {"gap_max_ms": round(1e3 * longest, 3),
+                    "behind_admission": behind}
+
         def dispatch_segment():
             """Dispatch ONE compiled segment (no fetch). Returns the
-            (device tokens, plan) pair the later harvest consumes, or
-            None when no row has budget left to tick. Budget depletion
-            is applied HERE, at dispatch — it is host-known — so the
+            (device tokens, plan, admission mark) record the later harvest
+            consumes, or None when no row has budget left to tick. Budget
+            depletion is applied HERE, at dispatch — it is host-known — so the
             overlapping caller can decide about segment N+1 without
             waiting for segment N's tokens; rows that are done (or
             free) are parked at position 0 with their table swapped for
@@ -3102,8 +3213,10 @@ class ContinuousBatcher:
                 # before this segment's dispatch
                 jax.profiler.start_trace(prof["dir"])
                 prof["active"] = True
+            mark, admitted = mark_segment()
             with span("dispatch_segment", rows=len(plan),
-                      rids=" ".join(jids[ri] for _, ri, _, _ in plan)):
+                      rids=" ".join(jids[ri] for _, ri, _, _ in plan),
+                      admitted_window_tokens=admitted):
                 args = (self.params, self._caches,
                         jnp.asarray(tables_now[:, :nb_w]),
                         self._cur_tok, self._n_logical,
@@ -3136,16 +3249,12 @@ class ContinuousBatcher:
                 table[b].remaining -= take
                 ticks_charged[ri] += take
                 self.waste["planned_ticks"] += self.S
-            self.stats["latent_tokens_written"] += (
-                self._n_latent * len(plan) * self.S)
-            self.stats["tail_rows_written"] += (
-                self._n_tail * len(plan) * self.S)
             if chaos is not None and chaos.on_segment is not None:
                 # host observation hook: drills flip drain flags /
                 # cancel requests at a deterministic segment
                 chaos.on_segment(self.stats["segments"])
             # held experts' counts ride with the tokens to the harvest
-            return "plain", (toks, *xc), plan
+            return "plain", (toks, *xc), plan, mark
 
         def cow_for_write(plan):
             """Speculation rollback-safety guard (ISSUE 12): a verify
@@ -3243,7 +3352,9 @@ class ContinuousBatcher:
             if prof is not None and not prof["active"]:
                 jax.profiler.start_trace(prof["dir"])
                 prof["active"] = True
-            with span("dispatch_verify", rows=len(plan)):
+            mark, admitted = mark_segment()   # after this window's own copies
+            with span("dispatch_verify", rows=len(plan),
+                      admitted_window_tokens=admitted):
                 with self._mesh_ctx():
                     self._caches, true = self._verify_c(
                         self.params, self._caches,
@@ -3273,7 +3384,7 @@ class ContinuousBatcher:
                 self.waste["planned_ticks"] += W
             if chaos is not None and chaos.on_segment is not None:
                 chaos.on_segment(self.stats["segments"])
-            return "spec", true, plan
+            return "spec", true, plan, mark
 
         def maybe_autodisable():
             """Throughput guard: over each window of
@@ -3315,8 +3426,9 @@ class ContinuousBatcher:
             fetched ``[B, W]`` array; per-row state (position, logical
             count, current token) advances by the emitted length only,
             which is the entire rollback."""
-            _kind, true_dev, plan = seg
-            with span("harvest_verify", rows=len(plan)):
+            _kind, true_dev, plan, mark = seg
+            with span("harvest_verify", rows=len(plan)) as sp:
+                gaps = {}
                 self.stats["fetches"] += 1
                 if chaos is not None:
                     chaos.pre_fetch(self.stats["segments"],
@@ -3371,12 +3483,15 @@ class ContinuousBatcher:
                         slot.out = slot.out[
                             :slot.out.index(self.eos_id) + 1]
                         done = True
-                    if jr is not None and len(slot.out) > prev_out:
-                        # post-trim: only DELIVERED tokens are journaled
-                        jr.delta(jids[ri], slot.out[prev_out:])
+                    if len(slot.out) > prev_out:
+                        delivered(ri, now, mark, gaps)
+                        if jr is not None:
+                            # post-trim: only DELIVERED tokens are journaled
+                            jr.delta(jids[ri], slot.out[prev_out:])
                     if done:
                         fin(ri, OK, slot.out)
                         free_row(b)
+                sp.note(**count_gaps(gaps, now, mark))
                 if jr is not None:
                     jr.commit()        # harvest = the durability boundary
                 if self.spec["proposed"]:
@@ -3400,9 +3515,9 @@ class ContinuousBatcher:
             if seg[0] == "spec":
                 harvest_verify(seg)
                 return
-            _kind, outs, plan = seg     # (tokens, and the counts if any)
+            _kind, outs, plan, mark = seg   # (tokens, and the counts if any)
             with span("harvest", overlapped=overlapped) as sp:
-                first_ids, done_ids = [], []
+                first_ids, done_ids, gaps = [], [], {}
                 self.stats["fetches"] += 1
                 if overlapped:
                     self.stats["fetches_overlapped"] += 1
@@ -3449,15 +3564,18 @@ class ContinuousBatcher:
                         slot.out = slot.out[
                             :slot.out.index(self.eos_id) + 1]
                         done = True
-                    if jr is not None and len(slot.out) > prev_out:
-                        # post-trim: only DELIVERED tokens are journaled
-                        jr.delta(jids[ri], slot.out[prev_out:])
+                    if len(slot.out) > prev_out:
+                        delivered(ri, now, mark, gaps)
+                        if jr is not None:
+                            # post-trim: only DELIVERED tokens are journaled
+                            jr.delta(jids[ri], slot.out[prev_out:])
                     if done:
                         fin(ri, OK, slot.out)
                         free_row(b)
                         done_ids.append(jids[ri])
                 sp.note(first=" ".join(first_ids) or "-",
-                        done=" ".join(done_ids) or "-")
+                        done=" ".join(done_ids) or "-",
+                        **count_gaps(gaps, now, mark))
                 if jr is not None:
                     jr.commit()        # harvest = the durability boundary
 
@@ -3804,9 +3922,6 @@ class ContinuousBatcher:
             self.stats["prefill_calls"] += 1
             self.stats["prefill_tokens"] += int(pmask.sum())
             self.stats["prefill_window_tokens"] += R * window
-            self.stats["latent_tokens_written"] += (
-                self._n_latent * int(pmask.sum()))
-            self.stats["tail_rows_written"] += self._n_tail * K
 
     def _reconstruct(self, table, requests, fin, free_row) -> None:
         """Device-failure session reconstruction: rebuild every live

@@ -103,6 +103,19 @@ class RequestResult:
     land in the batcher's SLO histograms
     (``ContinuousBatcher.stats_snapshot()["slo"]``).
 
+    What ``tpot_s`` averages away: ``deliveries`` counts the harvests
+    that handed this request new tokens (a DELIVERY: up to a segment's
+    tokens reach the host at one instant) and ``max_gap_s`` is the
+    longest interval between two consecutive ones (``None`` with fewer
+    than two; the interval before the first is ``ttft_s``'s) — the
+    freeze a streaming client saw behind somebody else's prefill, which
+    a request that then caught up hides in its mean. Every gap also
+    lands in the ``stats`` counters ``deliveries_*`` / ``delivery_gap_*``,
+    split by whether the device ran admission between the two segments
+    that delivered; ``stats_snapshot()["slo"]["delivery_gap_s"]`` is
+    their digest. Through the router both cover the request's placements
+    (the gap ACROSS a migration or hop is not stamped).
+
     Replica-set metadata (set by ``serve_router.ServeRouter``; inert
     for direct single-batcher callers): ``migrated`` counts how many
     times the request's session was replayed onto a DIFFERENT replica
@@ -120,6 +133,8 @@ class RequestResult:
     queue_wait_s: float | None = None
     ttft_s: float | None = None
     tpot_s: float | None = None
+    max_gap_s: float | None = None
+    deliveries: int = 0
     migrated: int = 0
     replica: int | None = None
     # the request's stable identity (ISSUE 15): set from

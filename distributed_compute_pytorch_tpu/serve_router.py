@@ -194,6 +194,11 @@ class CircuitBreaker:
         self.state = HALF_OPEN
 
 
+def _longer(a: float | None, b: float | None) -> float | None:
+    """The longer of two gaps, either of which may not exist."""
+    return max((g for g in (a, b) if g is not None), default=None)
+
+
 @dataclass
 class _Session:
     """Router-side host state for one routed request: everything needed
@@ -215,6 +220,18 @@ class _Session:
     cached_prefix: int = 0
     queue_wait_s: float | None = None
     ttft_s: float | None = None
+    deliveries: int = 0
+    max_gap_s: float | None = None     # the longest inside a placement
+
+    def bank(self, r: RequestResult) -> None:
+        """Keep what a placement that ended short of the request
+        reported: its partial stream and the metadata that adds up."""
+        self.tokens.extend(r.tokens)
+        self.ticks += r.ticks
+        self.recoveries += r.recoveries
+        self.cached_prefix += r.cached_prefix_tokens
+        self.deliveries += r.deliveries
+        self.max_gap_s = _longer(self.max_gap_s, r.max_gap_s)
 
 
 class _ReplicaDrain:
@@ -742,6 +759,8 @@ class ServeRouter:
                 cached_prefix_tokens=sess.cached_prefix
                 + r.cached_prefix_tokens,
                 queue_wait_s=sess.queue_wait_s, ttft_s=ttft, tpot_s=tpot,
+                max_gap_s=_longer(sess.max_gap_s, r.max_gap_s),
+                deliveries=sess.deliveries + r.deliveries,
                 migrated=sess.migrated, replica=i,
                 request_id=sess.req.request_id)
 
@@ -909,10 +928,7 @@ class ServeRouter:
                 if (retiring and r.status in (SHED, CANCELLED)
                         and not (sess.deadline_at is not None
                                  and now >= sess.deadline_at)):
-                    sess.tokens.extend(r.tokens)
-                    sess.ticks += r.ticks
-                    sess.recoveries += r.recoveries
-                    sess.cached_prefix += r.cached_prefix_tokens
+                    sess.bank(r)
                     sess.migrated += 1
                     self.stats["migrations"] += 1
                     self.stats["retire_migrations"] += 1
@@ -927,10 +943,7 @@ class ServeRouter:
                     # prompt prefilled, first token out, budget left:
                     # hop to the decode tier carrying the finished KV
                     # blocks (a planned move — not a migration)
-                    sess.tokens.extend(r.tokens)
-                    sess.ticks += r.ticks
-                    sess.recoveries += r.recoveries
-                    sess.cached_prefix += r.cached_prefix_tokens
+                    sess.bank(r)
                     sess.phase = "decode"
                     self.stats["prefill_hops"] += 1
                     self._handoff(i, sess)
@@ -966,10 +979,7 @@ class ServeRouter:
                 # exact — migration continues from it
                 if sess.ttft_s is None and r.ttft_s is not None:
                     sess.ttft_s = slo_base + r.ttft_s
-                sess.tokens.extend(r.tokens)
-                sess.ticks += r.ticks
-                sess.recoveries += r.recoveries
-                sess.cached_prefix += r.cached_prefix_tokens
+                sess.bank(r)
             if sess.deadline_at is not None and now >= sess.deadline_at:
                 self.stats["failover_sheds"] += 1
                 shed_for(j, f"deadline expired during failover of "

@@ -425,10 +425,6 @@ def test_serving_is_greedy_equal_to_the_reference_and_counts_tails():
     snap = cb.stats_snapshot()
     st = snap["stats"]
     assert st["prefill_tokens"] == 39 + 4 + 60 + 0 + 1 + 32
-    # a tail a row a layer: every admitted row, then every tick of a row
-    # in the plan
-    assert st["tail_rows_written"] == 3 * (
-        st["prefill_rows"] + snap["waste"]["planned_ticks"])
     assert st["prefill_rows"] == 6
     assert st["expert_assignments"] == 3 * snap["waste"]["planned_ticks"]
     assert (st["expert_assignments_held"] + st["expert_assignments_skipped"]
@@ -438,7 +434,7 @@ def test_serving_is_greedy_equal_to_the_reference_and_counts_tails():
     cb.reset()
     assert not any(leaf.any() for c in cb._caches for leaf in c.values())
     again = cb.serve_detailed(reqs[:1])[0]
-    assert again.status == "ok" and cb.stats["tail_rows_written"] > 0
+    assert again.status == "ok" and cb.stats["prefill_rows"] > 0
 
 
 TAILS = "layers that keep a per-slot tail beside the pool"
