@@ -710,7 +710,7 @@ def main(argv=None) -> int:
         journal.config({"kv_dtype": args.kv_dtype,
                         "weights_version": args.weights_version})
 
-    def build_batcher(replica=None, rep_params=None, weights_version=None):
+    def build_batcher(rep_params, replica=None, weights_version=None):
         hb_cb = None
         if args.heartbeat:
             hb_cb = (on_heartbeat if replica is None else
@@ -719,11 +719,11 @@ def main(argv=None) -> int:
         if disk_dir is not None and replica is not None:
             # one failure domain per replica: separate spill directories
             disk_dir = os.path.join(disk_dir, f"replica-{replica}")
-        rep_params = params if rep_params is None else rep_params
         if replica is not None:
             # replica i lives on local device i (round-robin when
             # replicas outnumber chips): the engine keeps its pool, row
-            # state and programs wherever its parameters are
+            # state and programs wherever its parameters are. The copy
+            # made here (from the host) is the engine's alone
             devs = jax.local_devices()
             rep_params = jax.device_put(rep_params,
                                         devs[replica % len(devs)])
@@ -752,15 +752,25 @@ def main(argv=None) -> int:
                              if weights_version is None
                              else weights_version))
 
+    # ONE copy of the weights a device. An engine cuts the stacked tree
+    # it is handed into its own per-layer form at its first dispatch and
+    # lets go of each stacked leaf as it goes (ContinuousBatcher.
+    # _cut_weights), so no stacked tree may outlive the hand-over on an
+    # engine's device. A fleet's copy (later replicas and the upgrade
+    # walk build from it) waits on the HOST, and every replica takes a
+    # copy of its own to its device; a single engine takes the restored
+    # tree itself and this function drops its reference.
     router = None
     if args.replicas > 1 or elastic:
         from distributed_compute_pytorch_tpu.serve_router import ServeRouter
-        router = ServeRouter([build_batcher(i)
+        params = jax.device_get(params)
+        router = ServeRouter([build_batcher(params, i)
                               for i in range(args.replicas)],
                              prefill_replicas=args.prefill_replicas)
         cb = router.replicas[0]        # profile/SIGUSR1 target
     else:
-        cb = build_batcher()
+        cb = build_batcher(params)
+        params = None
 
     controller = None
     upgrade_to = None
@@ -771,19 +781,18 @@ def main(argv=None) -> int:
                                               args.replicas)
         controller = ElasticFleetController(
             router,
-            lambda p, wv, slot: build_batcher(slot, rep_params=p,
+            lambda p, wv, slot: build_batcher(p, slot,
                                               weights_version=wv),
             params=params, weights_version=args.weights_version,
             policy=ScalePolicy(min_replicas=lo, max_replicas=hi))
         if args.upgrade_to:
             # the new weights load through the same checkpoint-restore
-            # path as the serving set; the rolling walk pushes them
-            # after the first window
-            _, new_params, _ = load_model_and_params(
+            # path as the serving set and wait on the host beside it; the
+            # rolling walk pushes them after the first window
+            upgrade_to = (jax.device_get(load_model_and_params(
                 args.model, args.model_preset, args.vocab_size,
                 args.max_seq_len, args.upgrade_to, mesh_spec=args.mesh,
-                quantize=args.quantize)
-            upgrade_to = (new_params, args.weights_version + 1)
+                quantize=args.quantize)[1]), args.weights_version + 1)
 
     if args.prewarm_widths:
         # one batcher warms the fleet: replicas share compiled programs

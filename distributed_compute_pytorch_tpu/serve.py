@@ -208,6 +208,7 @@ request's arrival instant and the scheduler idles across arrival gaps
 from __future__ import annotations
 
 import contextlib
+import functools
 import inspect
 import math
 from bisect import bisect_right
@@ -220,7 +221,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from distributed_compute_pytorch_tpu.core.mesh import (
     constrain, named_sharding, use_mesh)
@@ -277,6 +278,16 @@ _GAP_EDGES_S = tuple(1e-2 * 10.0 ** (i / 16) for i in range(1, 49))
 _GAP_COUNTERS = (
     *(f"delivery_gap_upto_{float(f'{1e3 * e:.3g}'):g}" for e in _GAP_EDGES_S),
     "delivery_gap_upto_inf")
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def _unstack(leaf, sharding):
+    """One stacked leaf ``[L, ...]`` as its ``L`` per-layer slices. Under a
+    mesh ``sharding`` is the leaf's own on the axes that remain; off-mesh
+    (``None``) a slice stays where the leaf is."""
+    cut = tuple(leaf[i] for i in range(leaf.shape[0]))
+    return cut if sharding is None else tuple(
+        lax.with_sharding_constraint(c, sharding) for c in cut)
 
 
 def admission_ladder(prompt_buf: int, block: int) -> tuple:
@@ -416,8 +427,22 @@ class ContinuousBatcher:
 
     Args:
       model: any ``infer.py``-contract model (GPT-2 / Llama / MoE).
-      params: its (possibly quantized) parameters — already committed
-        to the mesh layout when ``mesh`` is given.
+      params: its (possibly quantized) parameters, in the form
+        ``model.init`` and a checkpoint give them (a stacked family's
+        ``params["blocks"]`` with a leading layer axis on every leaf) —
+        already committed to the mesh layout when ``mesh`` is given. The
+        caller's tree stays the caller's: the engine neither deletes nor
+        donates it. The ENGINE's weights (``self.params``, what every
+        compiled program takes) hold one tree a layer instead, cut from
+        the stack once, a leaf at a time, at the first dispatch
+        (:meth:`_cut_weights`; until then ``self.params`` is still the
+        stack); a model of layer kinds hands over that form already. A
+        caller whose weights fill the device drops its own reference
+        once the constructor has returned (``dcp-serve`` does, and keeps
+        a fleet's copy on the host): the engine is then each stacked
+        leaf's last owner and the conversion never holds more than the
+        weights and their largest leaf; a caller that keeps its tree on
+        the engine's device keeps both forms there.
       slots: cache rows decoding concurrently (the static batch). Under
         a mesh it must divide over the batch axes
         (``data * fsdp | slots``).
@@ -606,7 +631,6 @@ class ContinuousBatcher:
         self._cancel_mu = threading.Lock()
         self._cancelled: set[int] = set()
         self.model = model
-        self.params = params
         self.B = slots
         self.Tb = prompt_buf
         self.S = segment
@@ -720,10 +744,8 @@ class ContinuousBatcher:
             self._dp = dp
         else:
             self._dp = 1
-        n_layers = (len(self._layer_blocks)
-                    if self._layer_blocks is not None else
-                    int(jax.tree_util.tree_leaves(
-                        params["blocks"])[0].shape[0]))
+        self._hold(params)
+        n_layers = self._n_layers
         # compute dtype == the dtype most floating parameter elements are
         # in (bf16 serving params -> bf16 activations, whatever the f32
         # norm scales say; int8-quantized trees surface their float
@@ -803,7 +825,6 @@ class ContinuousBatcher:
                 f"(eviction frees only refcount-0 blocks)")
         # blocks shard over the batch axes: keep the axis divisible
         pool_blocks = -(-pool_blocks // self._dp) * self._dp
-        self._n_layers = n_layers
 
         # off-mesh the engine lives where its parameters live: a replica
         # whose params were placed on local device i (dcp-serve
@@ -1570,6 +1591,7 @@ class ContinuousBatcher:
         n = len(toks)
         if n == 0:
             return np.zeros((0, 0), np.float32)
+        self._cut_weights()
         nbp = -(-n // self.bt)
         scratch = [{name: jnp.zeros(
                         (1,) + tuple(leaf.shape[1:]) if name == "tail" else
@@ -1730,11 +1752,61 @@ class ContinuousBatcher:
                     for what, why in map(cls._LAYER_KIND_REFUSALS.get,
                                          present)))
 
+    def _hold(self, params):
+        """Take ``params`` as the engine's weights, in the form the caller
+        has them. A model of layer kinds already keeps one tree a layer
+        (``params["layers"]``). Every other family hands over
+        ``params["blocks"]`` STACKED, each leaf with a leading layer axis,
+        and stays so until :meth:`_cut_weights`; the top-level dict is
+        the engine's own from here (the cut swaps ``"blocks"`` in it,
+        never in the caller's)."""
+        if self._layer_blocks is not None:
+            self.params = params
+            self._n_layers = len(self._layer_blocks)
+            return
+        self.params = dict(params)
+        self._n_layers = int(
+            jax.tree.leaves(params["blocks"])[0].shape[0])
+
+    def _cut_weights(self):
+        """Put the weights into the form the compiled programs take: for
+        a stacked family ``params["blocks"]`` becomes a tuple of
+        per-layer trees, cut ONCE here. A tick that cut its layers out
+        of a stack copied every q, k and v weight once more, every tick
+        (PERF.md, PR 39). Every entry point that dispatches a program
+        calls this first (:meth:`_run`, :meth:`prewarm_widths`,
+        :meth:`logit_probe`) and so does :meth:`reload_weights`; after
+        the first it returns at once.
+
+        The stack is cut a leaf at a time and the engine lets go of each
+        stacked leaf once its slices exist, so beside the weights there
+        is never more than the largest leaf again (never a second copy
+        of the tree), provided the caller holds no reference of its own
+        by now. That is why the cut waits for the first dispatch and
+        does not run in the constructor: a caller at full memory drops
+        its tree once the constructor has returned. The caller's arrays
+        are neither deleted nor donated: one that keeps them keeps both
+        forms."""
+        if self._layer_blocks is not None or isinstance(
+                self.params["blocks"], tuple):
+            return
+        leaves, treedef = jax.tree.flatten(self.params.pop("blocks"))
+        for j in range(len(leaves)):
+            at = leaves[j].sharding
+            # the slices exist before the stacked leaf goes (output
+            # buffers are allotted at dispatch: unawaited, every leaf's
+            # slices would stand beside every stacked leaf)
+            leaves[j] = jax.block_until_ready(_unstack(
+                leaves[j], NamedSharding(at.mesh, P(*at.spec[1:]))
+                if isinstance(at, NamedSharding) else None))
+        self.params["blocks"] = tuple(
+            treedef.unflatten([cut[i] for cut in leaves])
+            for i in range(self._n_layers))
+
     def _layer(self, params, i: int):
         """(block, parameters) of layer ``i``."""
         if self._layer_blocks is None:
-            return self._block, jax.tree.map(lambda a: a[i],
-                                             params["blocks"])
+            return self._block, params["blocks"][i]
         return self._layer_blocks[i], self.model.layer_params(params, i)
 
     @staticmethod
@@ -1847,9 +1919,11 @@ class ContinuousBatcher:
             weights_version = self.weights_version + 1
         old = self.weights_version
         # off-mesh the new weights follow the engine to ITS device (a
-        # no-op when the caller already placed them there)
-        self.params = (params if self._device is None
-                       else jax.device_put(params, self._device))
+        # no-op when the caller already placed them there; a copy of the
+        # engine's own when they come from the host or another device)
+        self._hold(params if self._device is None
+                   else jax.device_put(params, self._device))
+        self._cut_weights()
         self.weights_version = int(weights_version)
         self.reset()
         if self._radix is not None:
@@ -2413,6 +2487,7 @@ class ContinuousBatcher:
         indistinguishable from a fresh one. Returns the number of
         rungs dispatched (== programs compiled on a cold jit cache);
         counted in ``serve.width.prewarmed_programs``."""
+        self._cut_weights()
         for w in self._width_ladder:
             tables = np.full((self.B, w), BlockPool.TRASH, np.int32)
             with span("prewarm_width", blocks=int(w)), self._mesh_ctx():
@@ -2716,6 +2791,7 @@ class ContinuousBatcher:
         (module docstring) with the request lifecycle, drain protocol,
         fault recovery and block accounting threaded through its
         host-side decision points."""
+        self._cut_weights()
         t0 = time.monotonic()
         with self._cancel_mu:
             self._cancelled.clear()

@@ -169,7 +169,13 @@ class ElasticFleetController:
 
     ``params``/``weights_version`` are the fleet's CURRENT weights —
     every scale-up and replacement is built from them, and
-    :meth:`upgrade` advances them."""
+    :meth:`upgrade` advances them. The controller holds them for its
+    life, and an engine cuts what it is handed into a form of its own
+    (``ContinuousBatcher._cut_weights``): where the weights fill a
+    device, hand over a HOST tree (``jax.device_get``; ``dcp-serve``
+    does) and let the factory and ``reload_weights`` place a copy a
+    replica, so that no device holds the fleet's tree beside an
+    engine's."""
 
     def __init__(self, router, build_replica, *, params,
                  weights_version: int = 0,

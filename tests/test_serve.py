@@ -581,6 +581,7 @@ def test_moe_admission_capacity_matches_standalone_when_binding():
     Tb = 16
     cb = ContinuousBatcher(model, params, slots=2, t_max=128,
                            prompt_buf=Tb, segment=3)
+    cb._cut_weights()            # the programs take the engine's tree
     # one admission wave's arrays, built the way _prefill_wave does —
     # the paged layout lands the head at logical slots 0..nn-1, mapped
     # through an explicit block table into pool blocks 1..nb

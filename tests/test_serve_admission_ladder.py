@@ -173,6 +173,7 @@ def test_a_null_dispatch_writes_nothing(family):
     cb = ContinuousBatcher(model, params, slots=3, t_max=64, prompt_buf=32)
     cb._caches = jax.tree.map(
         lambda a: jnp.full(a.shape, 3, a.dtype), cb._caches)
+    cb._cut_weights()            # what a serve call does before it warms
     cb._warm_ladder()
     assert cb._admit_c._cache_size() >= len(ladder_shapes(cb._admit_ladder))
     for leaf in jax.tree.leaves(cb._caches):
