@@ -4,7 +4,8 @@ feed-forward) pair chosen by ``layer_types[i]`` and ``mlp_layer_types[i]``
 
 Mixers: ``"full_attention"`` (causal over the whole context),
 ``"sliding_attention"`` (causal over the last ``sliding_window`` tokens,
-the token itself counted) and ``"latent_attention"`` (below).
+the token itself counted), ``"latent_attention"``, ``"cca_attention"``,
+``"linear_attention"`` and ``"sparse_latent_attention"`` (all below).
 Feed-forwards: ``"dense"`` (SwiGLU) and
 ``"sparse"`` (routed experts with a shared expert, of which this chip
 holds ``experts_held``: ``models/moe.py::HeldExperts``). The rest is the
@@ -53,6 +54,45 @@ kind takes from the block below and returns. ``scale_residual_merge``
 makes every residual add the affine merge ``sr (h + br) + sy (f + by)``;
 ``tie_embeddings`` reads out through the embedding.
 
+LINEAR attention (KDA, the Kimi Linear recipe; mixer
+``"linear_attention"``): ``kda_heads`` heads of ``kda_head_dim`` (keys and
+values alike); ``q^, k^, v^ = W x`` each through a depth-wise causal
+convolution over ``kda_conv`` tokens and SiLU, ``q`` and ``k`` scaled to
+unit length (``q`` times ``dk ** -0.5``); a log-decay a CHANNEL ``g =
+kda_gate_lower_bound * sigmoid(exp(A_h) (W_f2 W_f1 x + b_dt))``, ``beta =
+sigmoid(W_b x)`` a head; the delta rule ``S_t = (I - beta k k^T)
+Diag(e^g) S_{t-1} + beta k v^T``, ``o_t = S_t^T q_t`` on a float32 state a
+head; ``y = W_o (RMSNorm_head(o) * sigmoid(W_g2 W_g1 x))``. The cache keeps
+NO tokens (cache kind ``"state"``): per row the state ``[H, dk, dv]`` and a
+TAIL of the last ``kda_conv - 1`` tokens' ``[q^, k^, v^]``. ``apply``
+(prefill) runs ``KDA_CHUNK`` tokens at a time (``ops/attention.py::
+kda_chunk``; the convolutions inside the scan, behind the carried tail);
+``decode_step`` one step (``kda_step``). A pad token leaves the state as it
+was, so what prefill hands decode is the state after each row's last REAL
+token.
+
+SPARSE LATENT attention (the DeepSeek-V3.2 recipe over NoPE latent
+attention; mixer ``"sparse_latent_attention"``): the latent mixer with
+``qk_rope_head_dim`` 0 behind an INDEXER: ``index_heads`` index queries a
+token from the low-rank query, one index key a token (LayerNorm; both with
+their first ``index_rope_dim`` channels rotated), the mean key of every
+group of ``index_pool`` tokens; a token scores the complete groups before
+its own (``sum_j w_j ReLU(q_j . K_g)``), attends the tokens of the
+``index_topk / index_pool`` best and always its own group up to itself.
+Cache kind ``"latent+index"``: the latent pool, the pooled keys on the same
+block table, and per row the keys of its own group not yet pooled.
+``apply`` computes it as dense attention under the selection's mask,
+``decode_step`` gathers the chosen tokens; both call the latent mixer's
+projections, pool write and absorbed products.
+
+HYPER-CONNECTIONS (``hc_mult`` > 0): a token carries ``hc_mult`` streams
+``[.., n, d]`` between layers; round each sublayer three maps read off the
+token's normed ``n d`` channels (``H_pre``, ``H_post``, and ``H_res`` made
+doubly stochastic by Sinkhorn rounds): the sublayer is fed ``RMSNorm(H_pre
+x)`` and merged back as ``H_res x + H_post^T f`` (``_enter`` / ``_leave``).
+``embed`` copies the embedding into every stream, ``readout`` sums them.
+``swiglu_limit`` clamps every SwiGLU's gate and up.
+
 The layers differ in shape, so the parameters are a per-layer list
 (``params["layers"][i]``), not one stacked tree, and the serving layer
 asks for the block and the parameters of layer ``i`` (``layer_block`` /
@@ -84,7 +124,13 @@ from distributed_compute_pytorch_tpu.ops.rotary import (
     apply_rope, apply_rope_interleaved)
 
 MIXERS = ("full_attention", "sliding_attention", "latent_attention",
-          "cca_attention")
+          "cca_attention", "linear_attention", "sparse_latent_attention")
+# tokens of a sub-chunk of the chunked KDA form: the decays of a sub-chunk
+# are divided out in float32, so 16 x |kda_gate_lower_bound| has to stay
+# under its largest exponent (88)
+KDA_SUB = 16
+# tokens of a chunk of the prefill form: whole sub-chunks
+KDA_CHUNK = 64
 MLPS = ("dense", "sparse", "sparse_top1")
 
 
@@ -136,6 +182,31 @@ class HybridConfig:
     scale_residual_merge: bool = False
     # the head is the embedding, read out transposed
     tie_embeddings: bool = False
+    # the linear_attention (KDA) layers: heads and their width (key and
+    # value alike), taps of the three convolutions, the rank of the two
+    # low-rank gates, and the floor of a token's log-decay
+    kda_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv: int = 4
+    kda_gate_rank: int = 0
+    kda_gate_lower_bound: float = -5.0
+    # the sparse_latent_attention layers' indexer: heads and their width,
+    # tokens a query attends (index_topk, in groups of index_pool tokens
+    # with one pooled key a group), the channels of an index head that
+    # rotate and at what base
+    index_heads: int = 0
+    index_head_dim: int = 0
+    index_topk: int = 0
+    index_pool: int = 4
+    index_rope_dim: int = 0
+    index_rope_theta: float = 10000.0
+    # hyper-connections: what a token carries between layers is hc_mult
+    # streams of d_model channels (0: the one residual vector)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    # every SwiGLU is silu(min(gate, l)) * clip(up, -l, l) (0: unclamped)
+    swiglu_limit: float = 0.0
 
     def __post_init__(self):
         if len(self.layer_types) != len(self.mlp_layer_types):
@@ -151,12 +222,36 @@ class HybridConfig:
             raise ValueError(
                 f"norm_placement {self.norm_placement!r} is neither 'post' "
                 f"nor 'pre'")
-        if "latent_attention" in self.layer_types and not all((
-                self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
-                self.qk_rope_head_dim, self.v_head_dim)):
+        if {"latent_attention", "sparse_latent_attention"} & set(
+                self.layer_types) and not all((
+                    self.q_lora_rank, self.kv_lora_rank,
+                    self.qk_nope_head_dim, self.v_head_dim)):
             raise ValueError(
                 "a latent_attention layer needs q_lora_rank, kv_lora_rank, "
-                "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+                "qk_nope_head_dim and v_head_dim (qk_rope_head_dim 0: no "
+                "rotary key)")
+        if "sparse_latent_attention" in self.layer_types and not (
+                self.index_heads and self.index_head_dim and self.index_topk
+                and self.index_topk % self.index_pool == 0
+                and self.index_rope_dim % 2 == 0):
+            raise ValueError(
+                "a sparse_latent_attention layer needs index_heads, "
+                "index_head_dim and an index_topk of whole groups of "
+                "index_pool tokens")
+        if "linear_attention" in self.layer_types and not (
+                self.kda_heads and self.kda_head_dim and self.kda_gate_rank
+                and self.kda_conv >= 2
+                and -80.0 <= KDA_SUB * self.kda_gate_lower_bound < 0):
+            raise ValueError(
+                "a linear_attention layer needs kda_heads, kda_head_dim, "
+                "kda_gate_rank, kda_conv >= 2 and a kda_gate_lower_bound "
+                f"in [-{80 // KDA_SUB}, 0)")
+        if self.hc_mult and (self.hc_mult < 2 or self.norm_placement != "pre"
+                             or self.scale_residual_merge):
+            raise ValueError(
+                "hyper-connections carry hc_mult >= 2 streams, norm the one "
+                "vector a sublayer is fed (norm_placement 'pre') and are "
+                "the residual merge themselves (no scale_residual_merge)")
         if self.scale_residual_merge and self.norm_placement != "pre":
             raise ValueError(
                 "scale_residual_merge merges a sublayer's output into the "
@@ -197,6 +292,28 @@ class HybridConfig:
         """Channels of a CCA layer's per-row tail: ``u`` of the last two
         tokens and the half of the value heads the last leaves the next."""
         return 2 * self.cca_width + self.num_kv_heads * self.head_dim // 2
+
+    @property
+    def kda_width(self) -> int:
+        """Channels of a KDA layer's projected q (k and v alike)."""
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def kda_tail_width(self) -> int:
+        """Channels of a KDA layer's per-row tail: the projected q, k and v
+        of the last ``kda_conv - 1`` tokens, the earliest first."""
+        return (self.kda_conv - 1) * 3 * self.kda_width
+
+    @property
+    def index_groups(self) -> int:
+        """Groups of ``index_pool`` tokens a query attends at most."""
+        return self.index_topk // self.index_pool
+
+    @property
+    def index_tail_width(self) -> int:
+        """Channels of a sparse latent layer's per-row tail: the index keys
+        of the row's own group that no pooled key holds yet."""
+        return (self.index_pool - 1) * self.index_head_dim
 
     @classmethod
     def tiny(cls) -> "HybridConfig":
@@ -240,11 +357,26 @@ class HybridBlock:
         return self.mixer == "cca_attention"
 
     @property
+    def kda(self) -> bool:
+        return self.mixer == "linear_attention"
+
+    @property
+    def selects(self) -> bool:
+        """Does this block attend a learned selection of its cache? Then
+        ``decode_step`` takes ``select_sink`` and hands it the tick's
+        ``[attended, in context]`` token counts of the rows in the plan."""
+        return self.mixer == "sparse_latent_attention"
+
+    @property
     def cache_kind(self) -> str:
         if self.latent:
             return "latent"
         if self.cca:
             return "paged+tail"
+        if self.kda:
+            return "state"
+        if self.selects:
+            return "latent+index"
         return "ring" if self.window else "paged"
 
     @property
@@ -272,17 +404,38 @@ class HybridBlock:
                            shared_d_ff=c.shared_d_ff,
                            routed_scale=c.routed_scale,
                            norm_topk_prob=c.norm_topk_prob,
+                           swiglu_limit=c.swiglu_limit,
                            param_dtype=c.param_dtype)
+
+    def _hc_init(self, key):
+        """One sublayer's hyper-connection: the three maps in the
+        parameters' type, gains and biases in float32, drawn so that
+        ``H_pre`` starts near ``1 / n``, ``H_post`` at 1 and ``H_res`` near
+        the identity."""
+        c = self.config
+        n, nd = c.hc_mult, c.hc_mult * c.d_model
+        ks = jax.random.split(key, 3)
+        phi = lambda k, o: jax.random.uniform(
+            k, (nd, o), c.param_dtype, -nd ** -0.5, nd ** -0.5)
+        gain = lambda: jnp.full((), 0.1, jnp.float32)
+        return {"phi_pre": phi(ks[0], n), "phi_post": phi(ks[1], n),
+                "phi_res": phi(ks[2], n * n),
+                "a_pre": gain(), "a_post": gain(), "a_res": gain(),
+                "b_pre": jnp.full((n,), -jnp.log(n - 1.0), jnp.float32),
+                "b_post": jnp.zeros((n,), jnp.float32),
+                "b_res": 2.5 * jnp.eye(n, dtype=jnp.float32)}
 
     def init(self, key):
         c = self.config
-        ks = iter(jax.random.split(key, 8))
+        # (the kinds that were here first keep the keys they drew)
+        ks = iter(jax.random.split(
+            key, 24 if self.kda or self.selects or c.hc_mult else 8))
         d, hd = c.d_model, c.head_dim
         dense = lambda din, dout: L.Dense(din, dout, use_bias=False,
                                           param_dtype=c.param_dtype)
         norms = (("pre_attn_norm", "pre_mlp_norm") if self._pre
                  else ("post_attn_norm", "post_mlp_norm"))
-        if self.latent:
+        if self.latent or self.selects:
             H, n, r = c.num_heads, c.qk_nope_head_dim, c.qk_rope_head_dim
             p = {"q_down": dense(d, c.q_lora_rank).init(next(ks)),
                  "q_norm": L.RMSNorm(c.q_lora_rank, c.rms_eps).init(None),
@@ -292,6 +445,35 @@ class HybridBlock:
                  "kv_up": dense(c.kv_lora_rank,
                                 H * (n + c.v_head_dim)).init(next(ks)),
                  "o": dense(H * c.v_head_dim, d).init(next(ks))}
+            if self.selects:
+                Hi, di = c.index_heads, c.index_head_dim
+                p.update(
+                    idx_q=dense(c.q_lora_rank, Hi * di).init(next(ks)),
+                    idx_k=dense(d, di).init(next(ks)),
+                    idx_k_norm=L.LayerNorm(di, c.rms_eps).init(None),
+                    idx_w=dense(d, Hi).init(next(ks)))
+        elif self.kda:
+            H, C, R = c.kda_heads, c.kda_width, c.kda_gate_rank
+            conv = lambda k: {"kernel": 0.5 * jax.random.normal(
+                k, (c.kda_conv, C), c.param_dtype)}
+            p = {"q": dense(d, C).init(next(ks)),
+                 "k": dense(d, C).init(next(ks)),
+                 "v": dense(d, C).init(next(ks)),
+                 # taps over the sequence, the earliest token first
+                 "conv_q": conv(next(ks)), "conv_k": conv(next(ks)),
+                 "conv_v": conv(next(ks)),
+                 # the decay gate (low-rank, a bias, a head's rate) ...
+                 "f_down": dense(d, R).init(next(ks)),
+                 "f_up": dense(R, C).init(next(ks)),
+                 "dt_bias": 3.0 * jax.random.normal(next(ks), (C,),
+                                                    jnp.float32),
+                 "A_log": jnp.zeros((H,), jnp.float32),
+                 "beta": dense(d, H).init(next(ks)),
+                 # ... and the output gate
+                 "g_down": dense(d, R).init(next(ks)),
+                 "g_up": dense(R, C).init(next(ks)),
+                 "o_norm": L.RMSNorm(c.kda_head_dim, c.rms_eps).init(None),
+                 "o": dense(C, d).init(next(ks))}
         elif self.cca:
             hq, hk, C, pd = c.num_heads, c.num_kv_heads, c.cca_width, \
                 c.param_dtype
@@ -322,7 +504,11 @@ class HybridBlock:
             for name in ("attn_merge", "mlp_merge"):
                 p[name] = {k: jnp.zeros((d,), c.param_dtype) for k in (
                     "res_scale", "res_bias", "out_scale", "out_bias")}
-        if c.qk_norm and not (self.latent or self.cca):
+        if c.hc_mult:
+            p["attn_hc"] = self._hc_init(next(ks))
+            p["mlp_hc"] = self._hc_init(next(ks))
+        if c.qk_norm and not (self.latent or self.cca or self.kda
+                              or self.selects):
             p["q_norm"] = L.RMSNorm(hd, c.rms_eps).init(None)
             p["k_norm"] = L.RMSNorm(hd, c.rms_eps).init(None)
         if self.mlp == "dense":
@@ -357,67 +543,428 @@ class HybridBlock:
             k = apply_rope(k, positions, c.rope_theta)
         return q, k, v
 
-    def _latent_q_and_token(self, params, x, positions):
-        """A latent layer's queries ``[B, H, T, nope + rope]`` (the rope
-        part rotated) and what its cache keeps of each token ``[B, T,
-        latent_width]``: the normed compressed channels, then the rotated
-        rotary key all heads share."""
+    def _latent_cq(self, params, x):
+        """The normed low-rank query of each token ``[B, T, q_lora_rank]``
+        (an indexer reads it too)."""
         c = self.config
-        d, r, kvl = c.d_model, c.qk_rope_head_dim, c.kv_lora_rank
-        qw = c.num_heads * (c.qk_nope_head_dim + r)
-        cq = L.RMSNorm(c.q_lora_rank, c.rms_eps).apply(
+        return L.RMSNorm(c.q_lora_rank, c.rms_eps).apply(
             params["q_norm"],
-            _dense(d, c.q_lora_rank).apply(params["q_down"], x))
-        q = A.split_heads(_dense(c.q_lora_rank, qw).apply(params["q_up"], cq),
-                          c.num_heads)
-        q = apply_rope_interleaved(q, positions, c.rope_theta, rotary_dim=r)
-        ckv = _dense(d, c.latent_width).apply(params["kv_down"], x)
+            _dense(c.d_model, c.q_lora_rank).apply(params["q_down"], x))
+
+    def _latent_q(self, params, cq, positions, heads=None):
+        """A latent layer's queries ``[B, H, T, nope + rope]`` (the rope
+        part rotated) from the low-rank query (``heads = (first, end)``:
+        those heads only)."""
+        c = self.config
+        H, hw = c.num_heads, c.qk_nope_head_dim + c.qk_rope_head_dim
+        up = params["q_up"]
+        if heads is not None:
+            h0, h1 = heads
+            H = h1 - h0
+            up = {"kernel": up["kernel"][:, h0 * hw:h1 * hw]}
+        q = A.split_heads(_dense(c.q_lora_rank, H * hw).apply(up, cq), H)
+        if not c.qk_rope_head_dim:
+            return q
+        return apply_rope_interleaved(q, positions, c.rope_theta,
+                                      rotary_dim=c.qk_rope_head_dim)
+
+    def _latent_token(self, params, x, positions):
+        """What a latent layer's cache keeps of each token ``[B, T,
+        latent_width]``: the normed compressed channels, then the rotated
+        rotary key all heads share (none where ``qk_rope_head_dim`` is
+        0)."""
+        c = self.config
+        kvl = c.kv_lora_rank
+        ckv = _dense(c.d_model, c.latent_width).apply(params["kv_down"], x)
         comp = L.RMSNorm(kvl, c.rms_eps).apply(params["kv_norm"],
                                                ckv[..., :kvl])
+        if not c.qk_rope_head_dim:
+            return comp
         k_rope = apply_rope_interleaved(ckv[:, None, :, kvl:], positions,
                                         c.rope_theta)[:, 0]
-        return q, jnp.concatenate([comp, k_rope], axis=-1)
+        return jnp.concatenate([comp, k_rope], axis=-1)
+
+    def _latent_q_and_token(self, params, x, positions):
+        """Every head's queries and the tokens' cached vectors."""
+        return (self._latent_q(params, self._latent_cq(params, x), positions),
+                self._latent_token(params, x, positions))
+
+    def _latent_expand(self, params, token, heads=None):
+        """The EXPANDED keys and values of cached vectors ``token [B, T,
+        latent_width]``: every head's ``k = [k_nope, k_rope]`` and ``v``
+        through the up-projection (``heads = (first, end)``: those heads
+        only)."""
+        c = self.config
+        H, n, kvl = c.num_heads, c.qk_nope_head_dim, c.kv_lora_rank
+        up = params["kv_up"]
+        if heads is not None:
+            h0, h1 = heads
+            H = h1 - h0
+            up = {"kernel": up["kernel"][
+                :, h0 * (n + c.v_head_dim):h1 * (n + c.v_head_dim)]}
+        with scope("latent_absorb"):
+            kv = A.split_heads(
+                _dense(kvl, H * (n + c.v_head_dim)).apply(
+                    up, token[..., :kvl]), H)
+        if not c.qk_rope_head_dim:
+            return kv[..., :n], kv[..., n:]
+        k_rope = jnp.broadcast_to(
+            token[:, None, :, kvl:],
+            kv.shape[:3] + (c.qk_rope_head_dim,))
+        return jnp.concatenate([kv[..., :n], k_rope], axis=-1), kv[..., n:]
 
     def _latent_prefill(self, params, x, positions, kv_mask, kv_sink):
         """The EXPANDED form: every head's ``k_nope`` and ``v`` from the
         compressed channels, ordinary causal attention at q/k width
         ``nope + rope`` and v width ``v_head_dim``."""
-        c = self.config
-        H, n, kvl = c.num_heads, c.qk_nope_head_dim, c.kv_lora_rank
         q, token = self._latent_q_and_token(params, x, positions)
         if kv_sink is not None:
             kv_sink.append((token,))
-        with scope("latent_absorb"):
-            kv = A.split_heads(
-                _dense(kvl, H * (n + c.v_head_dim)).apply(
-                    params["kv_up"], token[..., :kvl]), H)
-        k_rope = jnp.broadcast_to(
-            token[:, None, :, kvl:],
-            kv.shape[:3] + (c.qk_rope_head_dim,))
-        k = jnp.concatenate([kv[..., :n], k_rope], axis=-1)
+        k, v = self._latent_expand(params, token)
         # the default scale is q's head width ** -0.5: (nope + rope)
-        return dispatch_attention(q, k, kv[..., n:], causal=True,
-                                  kv_mask=kv_mask)
+        return dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask)
 
-    def _latent_decode(self, params, x, cache, pos):
-        """The ABSORBED form: ``W_uk`` folded into the query and ``W_uv``
-        into the output, so the heads attend the cached vectors
-        themselves; equal to :meth:`_latent_prefill` in exact
-        arithmetic."""
+    def _latent_absorbed(self, params, q, attend):
+        """The ABSORBED form round a read ``attend(q_abs [B, H, W]) -> o_lat
+        [B, H, kv_lora_rank]`` of cached vectors: ``W_uk`` folded into the
+        one-token queries ``q [B, H, 1, nope + rope]`` and ``W_uv`` into
+        the output, so the heads attend the cached vectors themselves.
+        Returns ``(o [B, H, 1, v_head_dim], what attend returned beside
+        o_lat)``."""
         c = self.config
         H, n, kvl = c.num_heads, c.qk_nope_head_dim, c.kv_lora_rank
-        q, token = self._latent_q_and_token(params, x, pos[:, None])
-        w = params["kv_up"]["kernel"].astype(x.dtype).reshape(
+        w = params["kv_up"]["kernel"].astype(q.dtype).reshape(
             kvl, H, n + c.v_head_dim)
         with scope("latent_absorb"):
             q_abs = jnp.einsum("bhn,chn->bhc", q[:, :, 0, :n], w[:, :, :n])
-        o_lat, cache = A.latent_write_and_attend(
-            jnp.concatenate([q_abs, q[:, :, 0, n:]], axis=-1), token[:, 0],
-            cache, pos, v_width=kvl,
-            scale=(n + c.qk_rope_head_dim) ** -0.5)
+        o_lat, *rest = attend(
+            jnp.concatenate([q_abs, q[:, :, 0, n:]], axis=-1))
         with scope("latent_absorb"):
             o = jnp.einsum("bhc,chv->bhv", o_lat, w[:, :, n:])
-        return o[:, :, None, :], cache
+        return (o[:, :, None, :], *rest)
+
+    @property
+    def _latent_scale(self) -> float:
+        c = self.config
+        return (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
+
+    def _latent_decode(self, params, x, cache, pos):
+        """The absorbed form over the whole context; equal to
+        :meth:`_latent_prefill` in exact arithmetic."""
+        q, token = self._latent_q_and_token(params, x, pos[:, None])
+        return self._latent_absorbed(
+            params, q, lambda q_abs: A.latent_write_and_attend(
+                q_abs, token[:, 0], cache, pos,
+                v_width=self.config.kv_lora_rank, scale=self._latent_scale))
+
+    # ---- the sparse latent mixer: the latent mixer behind an indexer ----
+
+    def _index_parts(self, params, x, cq, positions):
+        """The indexer's queries ``[B, T, Hi, di]`` and key ``[B, T, di]``
+        of each token (both with their first ``index_rope_dim`` channels
+        rotated as interleaved pairs) and its head weights ``[B, T, Hi]``
+        (float32, the scale ``(Hi di) ** -0.5`` in them)."""
+        c = self.config
+        Hi, di, rd = c.index_heads, c.index_head_dim, c.index_rope_dim
+
+        def rot(t):                              # [B, heads, T, di]
+            if not rd:
+                return t
+            return jnp.concatenate([apply_rope_interleaved(
+                t[..., :rd], positions, c.index_rope_theta), t[..., rd:]], -1)
+
+        qi = rot(A.split_heads(
+            _dense(c.q_lora_rank, Hi * di).apply(params["idx_q"], cq), Hi))
+        ki = _dense(c.d_model, di).apply(params["idx_k"], x)
+        ki = L.LayerNorm(di, c.rms_eps).apply(      # statistics in float32
+            params["idx_k_norm"], ki.astype(jnp.float32)).astype(x.dtype)
+        ki = rot(ki[:, None])[:, 0]
+        w = jnp.dot(x, params["idx_w"]["kernel"].astype(x.dtype),
+                    preferred_element_type=jnp.float32) * (Hi * di) ** -0.5
+        return qi.transpose(0, 2, 1, 3), ki, w
+
+    def _sparse_prefill(self, params, x, positions, kv_mask, kv_sink):
+        """The whole-window form: the latent mixer's expanded form as dense
+        causal attention under the SELECTION's mask (the same mathematics
+        as the gathered read of :meth:`_sparse_decode`): token ``t`` attends
+        the tokens of its ``index_groups`` best-scored earlier groups and
+        its own group up to itself. ``kv_sink`` is handed
+        ``(token, pooled, idx_tail)``: the latent vectors, the pooled index
+        key of every group of the window, and per row the index keys of
+        its last real token's group that no pooled key holds yet."""
+        c = self.config
+        B, T = x.shape[:2]
+        P_, G = c.index_pool, -(-x.shape[1] // c.index_pool)
+        cq = self._latent_cq(params, x)
+        token = self._latent_token(params, x, positions)
+        with scope("index_select"):
+            qi, ki, w = self._index_parts(params, x, cq, positions)
+            kp = jnp.pad(ki, ((0, 0), (0, G * P_ - T), (0, 0)))
+            pooled = jnp.mean(kp.reshape(B, G, P_, -1).astype(jnp.float32),
+                              axis=2).astype(x.dtype)
+            if kv_sink is not None:
+                n_tok = (jnp.full((B,), T, jnp.int32) if kv_mask is None
+                         else jnp.sum(kv_mask > 0.5, axis=1).astype(
+                             jnp.int32))
+                at = n_tok[:, None] // P_ * P_ + jnp.arange(P_ - 1)[None, :]
+                tail = jnp.take_along_axis(
+                    ki, jnp.minimum(at, T - 1)[:, :, None], axis=1)
+                tail = jnp.where((at < n_tok[:, None])[:, :, None], tail, 0)
+                kv_sink.append((token, pooled, tail.reshape(B, -1)))
+            # whom each query may attend, a block of queries at a time
+            bq = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
+                      if T % b == 0)
+            nblk = T // bq
+            blocks = lambda t: t.reshape(
+                (B, nblk, bq) + t.shape[2:]).swapaxes(0, 1)
+            Gc, t_all = T // P_, jnp.arange(T)       # the complete groups
+
+            def select(args):
+                qib, wb, t0 = args
+                t = t0 + jnp.arange(bq)                            # [bq]
+                score = A.index_scores(qib, wb, pooled[:, :Gc])
+                seen = jnp.arange(Gc)[None, :] < (t // P_)[:, None]
+                chosen = A.topk_mask(jnp.where(seen[None], score, -jnp.inf),
+                                     c.index_groups) & seen[None]
+                see = jnp.pad(jnp.repeat(chosen, P_, axis=2),
+                              ((0, 0), (0, 0), (0, T - Gc * P_)))
+                own = ((t_all[None, :] // P_ == (t // P_)[:, None])
+                       & (t_all[None, :] <= t[:, None]))
+                return see | own[None]
+
+            see = jax.lax.map(select, (blocks(qi), blocks(w),
+                                       jnp.arange(nblk) * bq))
+        # attention a group of heads at a time (their expanded keys and
+        # values are 8 KB a token), each band of query blocks against the
+        # keys up to its end
+        H = c.num_heads
+        hg = next(g for g in (16, 8, 4, 2, 1) if H % g == 0)
+        bands = next(n for n in (4, 2, 1) if nblk % n == 0)
+        per = nblk // bands                            # blocks to a band
+        out = []
+        for h0 in range(0, H, hg):
+            q = self._latent_q(params, cq, positions, (h0, h0 + hg))
+            qb = q.reshape(B, hg, nblk, bq, -1).transpose(2, 0, 1, 3, 4)
+            k, v = self._latent_expand(params, token, (h0, h0 + hg))
+            o_h = []
+            for i in range(bands):
+                Tk = (i + 1) * per * bq
+                attend = lambda a, Tk=Tk: A.masked_attention(
+                    a[0], k[:, :, :Tk], v[:, :, :Tk],
+                    a[1][:, None, :, :Tk], self._latent_scale)
+                o = jax.lax.map(attend, (
+                    qb[i * per:(i + 1) * per],
+                    see[i * per:(i + 1) * per]))     # [per, B, hg, bq, dv]
+                o_h.append(o.transpose(1, 2, 0, 3, 4).reshape(
+                    B, hg, per * bq, -1))
+            out.append(jnp.concatenate(o_h, axis=2))
+        return jnp.concatenate(out, axis=1)
+
+    def _sparse_decode(self, params, x, cache, pos, live, select_sink):
+        """One token a row: the latent mixer's projections, pool write and
+        absorbed products round a read of the CHOSEN tokens only. The
+        row's index key joins its group's tail; the group's running mean
+        goes to the pooled-key pool at the group's slot every tick (a group
+        is scored only once it is complete, so what an incomplete slot
+        holds is never read); the index queries score every complete group
+        before the row's own through the block table, and the
+        ``index_groups`` best are gathered with the row's own group from
+        the latent pool. ``select_sink`` is handed ``[attended, in
+        context]``: the tokens the rows in the plan attended and could
+        have."""
+        c = self.config
+        B = x.shape[0]
+        P_, di = c.index_pool, c.index_head_dim
+        table = cache["table"]
+        cq = self._latent_cq(params, x)
+        q = self._latent_q(params, cq, pos[:, None])
+        token = self._latent_token(params, x, pos[:, None])
+        kv = A.latent_write(token[:, 0], cache, pos)
+        with scope("index_select"):
+            qi, ki, w = self._index_parts(params, x, cq, pos[:, None])
+            r = pos % P_                              # place in its group
+            old = cache["idx_tail"].reshape(B, P_ - 1, di)
+            slot = jnp.arange(P_ - 1)[None, :, None]
+            held = jnp.where(slot < r[:, None, None], old, 0)
+            mean = ((jnp.sum(held.astype(jnp.float32), 1)
+                     + ki[:, 0].astype(jnp.float32)) / P_).astype(x.dtype)
+            new = jnp.where(slot == r[:, None, None], ki, held)
+            if live is not None:
+                new = jnp.where(live[:, None, None] > 0.5, new, old)
+            idx = cache["idx"]
+            bt = kv.shape[3]
+            blk = jnp.take_along_axis(table, (pos // bt)[:, None], 1)[:, 0]
+            with scope("kv_write"):
+                idx = idx.reshape(-1, di).at[
+                    blk * (bt // P_) + (pos % bt) // P_].set(
+                        mean.astype(idx.dtype)).reshape(idx.shape)
+            with scope("kv_gather"):
+                pooled = A.gather_kv_blocks(idx, table)[0, :, 0]  # [B, G, di]
+            score = A.index_scores(qi, w, pooled)[:, 0]
+            seen = (jnp.arange(pooled.shape[1])[None, :]
+                    < (pos // P_)[:, None])
+            kk = min(c.index_groups, pooled.shape[1])
+            _, groups = jax.lax.top_k(jnp.where(seen, score, -jnp.inf), kk)
+            groups_ok = jnp.take_along_axis(seen, groups, axis=1)
+
+        def attend(q_abs):
+            return A.selected_latent_attention(
+                A.pad_channels(q_abs, kv.shape[-1]), kv, table, pos, groups,
+                groups_ok, group_tokens=P_, v_width=c.kv_lora_rank,
+                scale=self._latent_scale)
+
+        o, attended = self._latent_absorbed(params, q, attend)
+        if select_sink is not None:
+            on = (jnp.ones((B,), jnp.int32) if live is None
+                  else (live > 0.5).astype(jnp.int32))
+            select_sink.append(jnp.stack(
+                [jnp.sum(attended * on), jnp.sum((pos + 1) * on)]).astype(
+                    jnp.int32))
+        return o, {"kv": kv, "idx": idx, "idx_tail": new.reshape(B, -1),
+                   "table": table}
+
+    # ---- the KDA mixer: the delta rule on a per-slot state ----
+
+    def _kda_project(self, params, x):
+        """``(W_q x, W_k x, W_v x)`` of each token, before the
+        convolutions: what the next ``kda_conv - 1`` tokens need of it."""
+        c = self.config
+        return [_dense(c.d_model, c.kda_width).apply(params[n], x)
+                for n in ("q", "k", "v")]
+
+    def _kda_conv(self, params, name, window):
+        """One of the three depth-wise convolutions (``name``: ``"q"``,
+        ``"k"``, ``"v"``) and SiLU: ``window [..., K, C]`` (a token's own
+        projection last) -> ``[..., C]``, summed in float32 tap by tap in
+        BOTH forms and rounded to the activations' type, so a tail kept in
+        that type rounds nothing more."""
+        taps = params["conv_" + name]["kernel"].astype(jnp.float32)
+        a = sum(taps[i] * window[..., i, :].astype(jnp.float32)
+                for i in range(self.config.kda_conv))
+        return jax.nn.silu(a).astype(window.dtype)
+
+    def _kda_heads(self, params, a, f_low):
+        """From the convolved ``a = (a_q, a_k, a_v)`` (``[..., C]`` each)
+        and the decay gate's low-rank part ``f_low [..., R]``: ``q`` (unit
+        length times ``dk ** -0.5``), ``k`` (unit length), ``v`` and the
+        log-decay ``g`` (all ``[..., H, dk]`` float32)."""
+        c = self.config
+        H, dk = c.kda_heads, c.kda_head_dim
+        f32 = lambda t: t.astype(jnp.float32)
+        heads = lambda t: f32(t).reshape(t.shape[:-1] + (H, dk))
+        unit = lambda t: t * jax.lax.rsqrt(
+            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        q, k, v = (heads(t) for t in a)
+        f = jnp.dot(f_low, params["f_up"]["kernel"].astype(f_low.dtype),
+                    preferred_element_type=jnp.float32) + params["dt_bias"]
+        g = c.kda_gate_lower_bound * jax.nn.sigmoid(
+            jnp.exp(f32(params["A_log"]))[:, None] * heads(f))
+        return unit(q) * dk ** -0.5, unit(k), v, g
+
+    def _kda_gates(self, params, x):
+        """The decay gate's low-rank part ``[..., R]`` and ``beta [...,
+        H]`` (float32)."""
+        c = self.config
+        f_low = _dense(c.d_model, c.kda_gate_rank).apply(params["f_down"], x)
+        beta = jax.nn.sigmoid(jnp.dot(
+            x, params["beta"]["kernel"].astype(x.dtype),
+            preferred_element_type=jnp.float32))
+        return f_low, beta
+
+    def _kda_out(self, params, x, o):
+        """``o [..., H, dv]`` float32 -> the mixer's output before ``W_o``,
+        ``[..., C]``: each head normed, times the sigmoid of the output
+        gate."""
+        c = self.config
+        o = L.RMSNorm(c.kda_head_dim, c.rms_eps).apply(
+            params["o_norm"], o).astype(x.dtype)
+        gate = jax.nn.sigmoid(_dense(c.kda_gate_rank, c.kda_width).apply(
+            params["g_up"], _dense(c.d_model, c.kda_gate_rank).apply(
+                params["g_down"], x)))
+        return o.reshape(o.shape[:-2] + (c.kda_width,)) * gate
+
+    def _kda_prefill(self, params, x, kv_mask, kv_sink):
+        """The whole-window form, ``KDA_CHUNK`` tokens at a time
+        (``ops/attention.py::kda_chunk``). A pad token (``kv_mask`` 0) gets
+        ``beta = 0`` and no decay, so the state the scan ends with is the
+        one after each row's LAST REAL token. ``kv_sink`` is handed
+        ``(state [B, H, dk, dv] float32, tail [B, kda_tail_width])``: that
+        state and, token by token, ``[q^, k^, v^]`` of the row's last
+        ``kda_conv - 1`` real tokens (zero where it has fewer)."""
+        c = self.config
+        B, T = x.shape[:2]
+        K, C, H, dk = c.kda_conv, KDA_CHUNK, c.kda_heads, c.kda_head_dim
+        u = self._kda_project(params, x)
+        with scope("linear_scan"):
+            f_low, beta = self._kda_gates(params, x)
+            real = (jnp.ones((B, T), jnp.float32) if kv_mask is None
+                    else (kv_mask > 0.5).astype(jnp.float32))
+            nC = -(-T // C)
+            pad = lambda t: t if nC * C == T else jnp.pad(
+                t, ((0, 0), (0, nC * C - T)) + ((0, 0),) * (t.ndim - 2))
+            chunks = lambda t: pad(t).reshape(
+                (B, nC, C) + t.shape[2:]).swapaxes(0, 1)
+
+            def step(carry, xs):
+                # the convolutions run here, a chunk at a time, on the
+                # chunk's projections behind the tails of the chunks before
+                # it: the projections of the row's last K - 1 REAL tokens
+                # (pads only trail, so a real token's are the tokens
+                # before it), which is also what the slot is handed
+                S, tails = carry
+                u_c, f_c, beta_c, real_c, x_c = xs
+                at = (jnp.sum(real_c, axis=1).astype(jnp.int32)[:, None, None]
+                      + jnp.arange(K - 1)[None, :, None])
+                a_c, new_tails = [], []
+                for name, tail, u_i in zip("qkv", tails, u_c):
+                    seq = jnp.concatenate([tail, u_i], axis=1)  # [B, K-1+C, .]
+                    a_c.append(self._kda_conv(params, name, jnp.stack(
+                        [seq[:, i:i + C] for i in range(K)], axis=2)))
+                    new_tails.append(jnp.take_along_axis(seq, at, axis=1))
+                q, k, v, g = self._kda_heads(params, a_c, f_c)
+                o, S = A.kda_chunk(S, q, k, v, g * real_c[..., None, None],
+                                   beta_c * real_c[..., None], KDA_SUB)
+                return (S, new_tails), self._kda_out(params, x_c, o)
+
+            (S, tails), o = jax.lax.scan(
+                step, (jnp.zeros((B, H, dk, dk), jnp.float32),
+                       [jnp.zeros((B, K - 1, t.shape[-1]), t.dtype)
+                        for t in u]),
+                ([chunks(t) for t in u], chunks(f_low), chunks(beta),
+                 chunks(real), chunks(x)))
+            o = o.swapaxes(0, 1).reshape(B, nC * C, -1)[:, :T]
+            if kv_sink is not None:
+                kv_sink.append((S, jnp.concatenate(tails, -1).reshape(B, -1)))
+        return o
+
+    def _kda_decode(self, params, x, cache, live):
+        """One token a row against the row's state and tail (the last
+        ``kda_conv - 1`` tokens' projections): one step of the delta rule.
+        Both are rewritten for the rows in the plan (``live``; a parked
+        row's do not advance). Equal to the prefill form token for
+        token."""
+        c = self.config
+        B = x.shape[0]
+        K = c.kda_conv
+        C = c.kda_width
+        u = self._kda_project(params, x)                    # 3 x [B, 1, C]
+        with scope("linear_scan"):
+            tail = cache["tail"]
+            window = jnp.concatenate(
+                [tail.astype(x.dtype).reshape(B, K - 1, -1),
+                 jnp.concatenate(u, axis=-1)], axis=1)      # [B, K, 3C]
+            a = [self._kda_conv(params, name, window[..., i * C:(i + 1) * C])
+                 for i, name in enumerate("qkv")]
+            f_low, beta = self._kda_gates(params, x[:, 0])
+            q, k, v, g = self._kda_heads(params, a, f_low)
+            o, S = A.kda_step(cache["state"], q, k, v, g, beta)
+            new_tail = window[:, 1:].reshape(B, -1).astype(tail.dtype)
+            if live is not None:
+                on = live > 0.5
+                S = jnp.where(on[:, None, None, None], S, cache["state"])
+                new_tail = jnp.where(on[:, None], new_tail, tail)
+            o = self._kda_out(params, x[:, 0], o)[:, None]
+        return o, {"state": S, "tail": new_tail}
 
     def _cca_project(self, params, x):
         """The down-projections of a CCA layer: ``u = [W_q x; W_k x]``,
@@ -543,25 +1090,78 @@ class HybridBlock:
             pos, slot_mask=slot_mask)
         return o, {**pool, "tail": new}
 
-    def _merge(self, params, name, x, f):
-        """The residual merge of a sublayer's output ``f`` into the stream
+    def _enter(self, params, which, x):
+        """What sublayer ``which`` (``"attn"`` or ``"mlp"``) is fed, and
+        what :meth:`_leave` needs to merge its output back. The plain
+        residual: ``x`` (normed where the norms are on the inputs), and
+        nothing. Hyper-connections (``x [..., n, d]``): ``RMSNorm_in(H_pre
+        x)``, one vector a token, and ``(H_post, H_res)``; the maps are read
+        off the normed ``n d`` channels of the token in float32, ``H_res``
+        through ``hc_sinkhorn_iters`` rounds of row then column
+        normalisation."""
+        c = self.config
+        if not c.hc_mult:
+            return (self._norm(params, f"pre_{which}_norm", x) if self._pre
+                    else x), None
+        hp, n = params[f"{which}_hc"], c.hc_mult
+        f32 = lambda t: t.astype(jnp.float32)
+        with scope("hyper_mix"):
+            # a stream at a time, so that no float32 copy of all the
+            # streams stands beside them while the sublayer runs
+            xs = [x[..., i, :] for i in range(n)]
+            inv = jax.lax.rsqrt(sum(
+                jnp.sum(jnp.square(f32(xi)), -1, keepdims=True)
+                for xi in xs) / (n * c.d_model) + c.rms_eps)
+            flat = jnp.concatenate(
+                [(f32(xi) * inv).astype(x.dtype) for xi in xs], axis=-1)
+            mm = lambda w: jnp.dot(flat, w.astype(x.dtype),
+                                   preferred_element_type=jnp.float32)
+            pre = jax.nn.sigmoid(hp["a_pre"] * mm(hp["phi_pre"])
+                                 + hp["b_pre"])
+            post = 2.0 * jax.nn.sigmoid(hp["a_post"] * mm(hp["phi_post"])
+                                        + hp["b_post"])
+            res = jnp.exp(hp["a_res"] * mm(hp["phi_res"]).reshape(
+                flat.shape[:-1] + (n, n)) + hp["b_res"])
+            for _ in range(c.hc_sinkhorn_iters):
+                res = res / (jnp.sum(res, -1, keepdims=True) + c.hc_eps)
+                res = res / (jnp.sum(res, -2, keepdims=True) + c.hc_eps)
+            u = sum(pre[..., i, None] * f32(xs[i])
+                    for i in range(n)).astype(x.dtype)
+        return self._norm(params, f"pre_{which}_norm", u), (post, res)
+
+    def _leave(self, params, which, x, f, hc):
+        """The merge of sublayer ``which``'s output ``f`` into the stream
         ``x``: the plain add, or ``sr (x + br) + sy (f + by)`` in float32
-        (the scales stored as offsets from 1)."""
+        (the scales stored as offsets from 1), or the hyper-connection's
+        ``H_res x + H_post^T f``."""
+        if hc is not None:
+            post, res = hc
+            n = self.config.hc_mult
+            f32 = lambda t: t.astype(jnp.float32)
+            with scope("hyper_mix"):
+                return jnp.stack([
+                    (sum(res[..., i, j, None] * f32(x[..., j, :])
+                         for j in range(n))
+                     + post[..., i, None] * f32(f)).astype(x.dtype)
+                    for i in range(n)], axis=-2)
+        if not self._pre:
+            f = self._norm(params, f"post_{which}_norm", f)
         if not self.config.scale_residual_merge:
             return x + f
-        p = jax.tree.map(lambda t: t.astype(jnp.float32), params[name])
+        p = jax.tree.map(lambda t: t.astype(jnp.float32),
+                         params[f"{which}_merge"])
         x32, f32 = x.astype(jnp.float32), f.astype(jnp.float32)
         return ((1.0 + p["res_scale"]) * (x32 + p["res_bias"])
                 + (1.0 + p["out_scale"]) * (f32 + p["out_bias"])).astype(
                     x.dtype)
 
-    def _attn_out(self, params, x, o):
-        c = self.config
-        o = A.merge_heads(o)
-        a = _dense(o.shape[-1], c.d_model).apply(params["o"], o)
-        return self._merge(params, "attn_merge", x,
-                           a if self._pre
-                           else self._norm(params, "post_attn_norm", a))
+    def _attn_out(self, params, x, o, hc=None, merged: bool = False):
+        """``o`` (heads ``[B, H, T, hd]``, or already ``merged [B, T, H
+        hd]``) through the output projection and into the stream."""
+        if not merged:
+            o = A.merge_heads(o)
+        a = _dense(o.shape[-1], self.config.d_model).apply(params["o"], o)
+        return self._leave(params, "attn", x, a, hc)
 
     def _mlp(self, params, x, token_mask=None, counts_sink=None,
              carry=None):
@@ -570,13 +1170,13 @@ class HybridBlock:
         the first layer)."""
         c = self.config
         with scope("mlp"):
-            y = self._norm(params, "pre_mlp_norm", x) if self._pre else x
+            y, hc = self._enter(params, "mlp", x)
             if self.mlp == "dense":
-                g = jax.nn.silu(_dense(c.d_model, c.d_ff).apply(
-                    params["gate"], y))
                 m = _dense(c.d_ff, c.d_model).apply(
-                    params["down"],
-                    g * _dense(c.d_model, c.d_ff).apply(params["up"], y))
+                    params["down"], L.clamped_swiglu(
+                        _dense(c.d_model, c.d_ff).apply(params["gate"], y),
+                        lambda: _dense(c.d_model, c.d_ff).apply(
+                            params["up"], y), c.swiglu_limit))
             elif self.carries:
                 m, carry = self.experts().apply_with_state(
                     params["moe"], y, carry, token_mask=token_mask,
@@ -585,9 +1185,7 @@ class HybridBlock:
                 m = self.experts().apply(params["moe"], y,
                                          token_mask=token_mask,
                                          counts_sink=counts_sink)
-            x = self._merge(params, "mlp_merge", x,
-                            m if self._pre
-                            else self._norm(params, "post_mlp_norm", m))
+            x = self._leave(params, "mlp", x, m, hc)
             return (x, carry) if self.carries else x
 
     def apply(self, params, x, *, kv_mask=None, kv_sink=None,
@@ -595,22 +1193,32 @@ class HybridBlock:
         """The whole-sequence forward of one layer (prefill). ``kv_sink``
         captures what a cache stores: the K/V pair (after QK-norm and
         rotation, at kv-head width), or a latent layer's one token vector
-        ``(token,)``, or a CCA layer's ``(k, v, tail)``; ``kv_mask`` (``[B,
-        T]``, 1 = real) hides pad keys and keeps pad tokens out of the
-        experts. A block that :attr:`carries` takes ``carry`` and returns
-        ``(x, carry)``."""
+        ``(token,)``, or a CCA layer's ``(k, v, tail)``, or a KDA layer's
+        ``(state, tail)``, or a sparse latent layer's ``(token, pooled,
+        idx_tail)``; ``kv_mask`` (``[B, T]``, 1 = real) hides pad keys and
+        keeps pad tokens out of the experts and the state. A block that
+        :attr:`carries` takes ``carry`` and returns ``(x, carry)``. With
+        hyper-connections ``x`` is ``[B, T, n, d]``."""
         T = x.shape[1]
         with scope("attn"):
             pos = jnp.arange(T) if positions is None else positions
-            y = self._norm(params, "pre_attn_norm", x) if self._pre else x
+            y, hc = self._enter(params, "attn", x)
             if self.latent:
                 with scope("attn_latent"):
                     x = self._attn_out(params, x, self._latent_prefill(
-                        params, y, pos, kv_mask, kv_sink))
+                        params, y, pos, kv_mask, kv_sink), hc)
             elif self.cca:
                 with scope("attn_cca"):
                     x = self._attn_out(params, x, self._cca_prefill(
-                        params, y, pos, kv_mask, kv_sink))
+                        params, y, pos, kv_mask, kv_sink), hc)
+            elif self.kda:
+                with scope("attn_linear"):
+                    x = self._attn_out(params, x, self._kda_prefill(
+                        params, y, kv_mask, kv_sink), hc, merged=True)
+            elif self.selects:
+                with scope("attn_sparse"):
+                    x = self._attn_out(params, x, self._sparse_prefill(
+                        params, y, pos, kv_mask, kv_sink), hc)
             else:
                 q, k, v = self._qkv(params, y, pos)
                 if kv_sink is not None:
@@ -622,33 +1230,48 @@ class HybridBlock:
                 else:
                     o = dispatch_attention(q, k, v, causal=True,
                                            kv_mask=kv_mask)
-                x = self._attn_out(params, x, o)
+                x = self._attn_out(params, x, o, hc)
         return self._mlp(params, x, token_mask=kv_mask,
                          counts_sink=counts_sink, carry=carry)
 
     def decode_step(self, params, x, cache, pos, slot_mask=None,
-                    counts_sink=None, live=None, carry=None):
+                    counts_sink=None, live=None, carry=None,
+                    select_sink=None):
         """One cached decode tick, ``x [B, 1, d]`` at per-row slots ``pos
         [B]``. ``cache`` is this layer's kind: the paged pool with its
         table (K/V pairs, or a latent layer's token vectors), or a ring
         ``{"kv": [2, B, hk, R, hd]}``, or a CCA layer's pool with the
-        rows' ``"tail" [B, tail_width]`` beside it. ``live`` (``[B]``, 1 =
-        a row in the plan) keeps parked rows out of the experts and their
-        tails where they are. A block that :attr:`carries` takes ``carry``
-        and returns ``(x, cache, carry)``."""
+        rows' ``"tail" [B, tail_width]`` beside it, or a KDA layer's
+        ``{"state" [B, H, dk, dv], "tail" [B, kda_tail_width]}`` and
+        nothing else, or a sparse latent layer's latent pool with the
+        pooled index keys ``"idx"`` on the same table and the rows'
+        ``"idx_tail"``. ``live`` (``[B]``, 1 = a row in the plan) keeps
+        parked rows out of the experts and their per-slot leaves where
+        they are. A block that :attr:`carries` takes ``carry`` and returns
+        ``(x, cache, carry)``; one that :attr:`selects` takes
+        ``select_sink``. With hyper-connections ``x`` is ``[B, 1, n,
+        d]``."""
         with scope("attn"):
-            y = self._norm(params, "pre_attn_norm", x) if self._pre else x
+            y, hc = self._enter(params, "attn", x)
+            rows = lambda: jnp.broadcast_to(jnp.atleast_1d(pos), x.shape[:1])
             if self.latent:
                 with scope("attn_latent"):
-                    o, cache = self._latent_decode(
-                        params, y, cache,
-                        jnp.broadcast_to(jnp.atleast_1d(pos), x.shape[:1]))
-                    x = self._attn_out(params, x, o)
+                    o, cache = self._latent_decode(params, y, cache, rows())
+                    x = self._attn_out(params, x, o, hc)
             elif self.cca:
                 with scope("attn_cca"):
                     o, cache = self._cca_decode(params, y, cache, pos,
                                                 slot_mask, live)
-                    x = self._attn_out(params, x, o)
+                    x = self._attn_out(params, x, o, hc)
+            elif self.kda:
+                with scope("attn_linear"):
+                    o, cache = self._kda_decode(params, y, cache, live)
+                    x = self._attn_out(params, x, o, hc, merged=True)
+            elif self.selects:
+                with scope("attn_sparse"):
+                    o, cache = self._sparse_decode(params, y, cache, rows(),
+                                                   live, select_sink)
+                    x = self._attn_out(params, x, o, hc)
             else:
                 rope_pos = (pos[:, None] if jnp.ndim(pos) == 1
                             else jnp.atleast_1d(pos))
@@ -660,7 +1283,7 @@ class HybridBlock:
                 else:
                     o, cache = A.cache_write_and_attend(
                         q, k, v, cache, pos, slot_mask=slot_mask)
-                x = self._attn_out(params, x, o)
+                x = self._attn_out(params, x, o, hc)
         # a parked row (live 0) routes nowhere: its token is garbage, and
         # the experts' counts are of the rows in the plan
         out = self._mlp(params, x, token_mask=live,
@@ -733,8 +1356,36 @@ class HybridLM:
         38% of its memory roofline where K-EXAONE's pool reaches 77%
         (PERF.md, PR 36); 32 tokens make a block the 32 KB of a GQA block
         of 8 tokens at 8 KV heads. None = the batcher's default."""
-        short = {"latent_attention", "cca_attention"}
+        short = {"latent_attention", "cca_attention",
+                 "sparse_latent_attention"}
         return 32 if short & set(self.config.layer_types) else None
+
+    def slot_leaves(self, kind: str, slots: int, dtype) -> dict:
+        """The leaves a layer of cache kind ``kind`` keeps by SLOT beside
+        (or instead of) its pool, as ``{name: (shape, dtype)}``: a CCA
+        layer's tail; a KDA layer's float32 state and its tail of projected
+        tokens; a sparse latent layer's tail of index keys. ``dtype``: the
+        compute type."""
+        c = self.config
+        if kind == "paged+tail":
+            return {"tail": ((slots, c.tail_width), dtype)}
+        if kind == "state":
+            return {"state": ((slots, c.kda_heads, c.kda_head_dim,
+                               c.kda_head_dim), jnp.float32),
+                    "tail": ((slots, c.kda_tail_width), dtype)}
+        if kind == "latent+index":
+            return {"idx_tail": ((slots, c.index_tail_width), dtype)}
+        return {}
+
+    def index_pool_shape(self, blocks: int, block_tokens: int) -> tuple:
+        """The pooled index keys of a sparse latent layer, on the latent
+        pool's block table: one key to ``index_pool`` tokens."""
+        c = self.config
+        if block_tokens % c.index_pool:
+            raise ValueError(
+                f"a pool block of {block_tokens} tokens does not hold whole "
+                f"groups of index_pool={c.index_pool}")
+        return (1, blocks, 1, block_tokens // c.index_pool, c.index_head_dim)
 
     def init(self, key):
         c = self.config
@@ -765,11 +1416,18 @@ class HybridLM:
                                  params["embed_merge"])
                 x = ((1.0 + e["scale"]) * (x.astype(jnp.float32)
                                            + e["bias"])).astype(x.dtype)
+            if c.hc_mult:
+                # the token's embedding into every stream
+                x = jnp.broadcast_to(x[..., None, :], x.shape[:-1] + (
+                    c.hc_mult, c.d_model))
             return x
 
     def readout(self, params, x):
         c = self.config
         with scope("head"):
+            if c.hc_mult:
+                # the streams fold by their sum
+                x = jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
             x = L.RMSNorm(c.d_model, c.rms_eps).apply(params["norm_f"], x)
             if c.tie_embeddings:
                 return jnp.einsum(

@@ -366,6 +366,15 @@ class Embedding:
         return x @ t.astype(x.dtype).T
 
 
+def clamped_swiglu(gate, up, limit: float = 0.0):
+    """``silu(min(gate, limit)) * clip(up(), -limit, limit)`` (``limit`` 0:
+    unclamped). ``up`` is a thunk: its product is traced after the gate's
+    SiLU, where an unclamped SwiGLU has always had it."""
+    g = jax.nn.silu(jnp.minimum(gate, limit) if limit else gate)
+    u = up()
+    return g * (jnp.clip(u, -limit, limit) if limit else u)
+
+
 def log_softmax(x, axis: int = -1):
     """``F.log_softmax`` equivalent (reference ``main.py:44``)."""
     return jax.nn.log_softmax(x, axis=axis)

@@ -485,6 +485,8 @@ class HeldExperts:
     param_dtype: jnp.dtype = jnp.float32
     router: object = None           # None = SigmoidRouter of the fields above
     skip_index: int | None = None   # a choice of the router no chip holds
+    # every SwiGLU is silu(min(gate, l)) * clip(up, -l, l) (0: unclamped)
+    swiglu_limit: float = 0.0
 
     def _router(self):
         return self.router or SigmoidRouter(
@@ -550,8 +552,8 @@ class HeldExperts:
         xe = jnp.broadcast_to(x[None], (n,) + x.shape)
         mm = lambda a, b: jnp.einsum("enk,ekf->enf", a, b.astype(a.dtype),
                                      preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(mm(xe, ex["gate"])) * mm(xe, ex["up"])).astype(
-            x.dtype)
+        h = L.clamped_swiglu(mm(xe, ex["gate"]), lambda: mm(xe, ex["up"]),
+                             self.swiglu_limit).astype(x.dtype)
         y = mm(h, ex["down"])                                    # [n, N, d]
         return jnp.einsum("end,ne->nd", y, we).astype(x.dtype)
 
@@ -587,8 +589,9 @@ class HeldExperts:
                 g = (jnp.clip(ends - s, 0, rows)
                      - jnp.clip(ends - sizes - s, 0, rows)).astype(jnp.int32)
                 xg = x[t]
-                h = (jax.nn.silu(rd(xg, ex["gate"], g))
-                     * rd(xg, ex["up"], g)).astype(x.dtype)
+                h = L.clamped_swiglu(
+                    rd(xg, ex["gate"], g), lambda: rd(xg, ex["up"], g),
+                    self.swiglu_limit).astype(x.dtype)
                 y = rd(h, ex["down"], g) * jnp.where(live, wt, 0.0)[:, None]
                 # rows past the window's groups are unspecified by
                 # ragged_dot
@@ -647,8 +650,9 @@ class HeldExperts:
             with scope("shared_expert"):
                 sp = params["shared"]
                 mm = lambda a, b: jnp.dot(a, b["kernel"].astype(a.dtype))
-                y = y + mm(jax.nn.silu(mm(x, sp["gate"])) * mm(x, sp["up"]),
-                           sp["down"])
+                y = y + mm(L.clamped_swiglu(
+                    mm(x, sp["gate"]), lambda: mm(x, sp["up"]),
+                    self.swiglu_limit), sp["down"])
         if state is not None:
             state = state.reshape(shape[:-1] + state.shape[-1:])
         return y.reshape(shape), state

@@ -68,13 +68,23 @@ from distributed_compute_pytorch_tpu.obs import flight, metrics
 # ``cca_mix`` inside it (what it adds round the projections and the
 # attention product: the two convolutions over the sequence, the query-key
 # mean, the norms and the temperature, the rotation, the value shift, the
-# tail's read and write). The benchmark's scope metrics
-# (``perfbench/layer_metrics``) name these and nothing else.
+# tail's read and write); ``attn_linear`` in ``attn`` (everything a KDA
+# linear-attention mixer does) with ``linear_scan`` inside it (the
+# convolutions, the decays, the chunked recurrence or the one step, the
+# state's read and write); ``attn_sparse`` in ``attn`` (everything a sparse
+# latent mixer does) with ``index_select`` inside it (index queries and
+# keys, pooling, scores, top-k, the pooled keys' read and write) and
+# ``latent_absorb`` where a latent mixer has it; ``hyper_mix`` under
+# ``attn`` and ``mlp`` alike (a hyper-connection's maps, Sinkhorn and stream
+# products). The benchmark's scope metrics (``perfbench/layer_metrics``)
+# name these and nothing else.
 SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "optimizer", "grad_reduce",
           "admit", "decode", "kv_gather", "kv_write", "sample",
           "router", "experts", "shared_expert", "attn_local",
-          "attn_latent", "latent_absorb", "attn_cca", "cca_mix")
+          "attn_latent", "latent_absorb", "attn_cca", "cca_mix",
+          "attn_linear", "linear_scan", "attn_sparse", "index_select",
+          "hyper_mix")
 
 
 def scope(name: str):

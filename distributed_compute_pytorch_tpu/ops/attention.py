@@ -501,7 +501,27 @@ def latent_pool_width(width: int) -> int:
 
 def pad_channels(x, width: int):
     """``x [..., w]`` zero-padded on its last axis to ``width``."""
+    if x.shape[-1] == width:
+        return x
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+def latent_write(latent, cache, pos):
+    """The write half of a latent layer's decode tick: row ``b``'s token
+    vector ``latent [B, W]`` (zero-padded to the pool's width) goes to the
+    (block, offset) its table maps slot ``pos[b]`` to. ``cache`` holds the
+    pool's ``"kv"`` leaf and the ``"table"`` (other leaves pass through
+    untouched). Returns the pool's new ``"kv"`` leaf."""
+    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
+        kv_pool_insert_all)
+    table = cache["table"]
+    bt, Wp = cache["kv"].shape[3:]
+    latent = pad_channels(latent, Wp)
+    blk = jnp.take_along_axis(table, (pos // bt)[:, None], axis=1)[:, 0]
+    with scope("kv_write"):
+        return kv_pool_insert_all(
+            {"kv": cache["kv"]}, {"kv": latent[None, :, None, None, :]},
+            blk, pos % bt)["kv"]
 
 
 def latent_write_and_attend(q, latent, cache, pos, *, v_width: int,
@@ -510,26 +530,20 @@ def latent_write_and_attend(q, latent, cache, pos, *, v_width: int,
     ``{"kv": [1, P, 1, bt, Wp], "table": int32 [B, nb]}``: row ``b`` writes
     its token's vector ``latent [B, W]`` (compressed K/V, then the rotary
     key; zero-padded to the pool's ``Wp = latent_pool_width(W)``, as the
-    queries are) at the (block, offset) its table maps slot ``pos[b]`` to,
-    then its ``H`` absorbed queries ``q [B, H, W]`` attend slots ``0 ..
-    pos[b]``: the stored vector is every head's key and its first
-    ``v_width`` channels are the value. In place through the table where
-    :func:`latent_read_path` says ``kernel``; otherwise (CPU, a mesh) over
-    the gathered view, which is also the kernel's test oracle. The same
-    block table, write kernel, width rules and parked rows as
+    queries are) at the (block, offset) its table maps slot ``pos[b]`` to
+    (:func:`latent_write`), then its ``H`` absorbed queries ``q [B, H, W]``
+    attend slots ``0 .. pos[b]``: the stored vector is every head's key and
+    its first ``v_width`` channels are the value. In place through the
+    table where :func:`latent_read_path` says ``kernel``; otherwise (CPU, a
+    mesh) over the gathered view, which is also the kernel's test oracle.
+    The same block table, write kernel, width rules and parked rows as
     :func:`_paged_write_and_attend`. Returns ``(o [B, H, v_width],
     new_cache)``."""
-    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
-        kv_pool_insert_all)
     table = cache["table"]
-    pool = {n: leaf for n, leaf in cache.items() if n != "table"}
-    bt, Wp = pool["kv"].shape[3:]
+    Wp = cache["kv"].shape[-1]
     q, latent = pad_channels(q, Wp), pad_channels(latent, Wp)
     pos = jnp.broadcast_to(jnp.atleast_1d(pos), (q.shape[0],))
-    blk = jnp.take_along_axis(table, (pos // bt)[:, None], axis=1)[:, 0]
-    with scope("kv_write"):
-        pool = kv_pool_insert_all(
-            pool, {"kv": latent[None, :, None, None, :]}, blk, pos % bt)
+    pool = {"kv": latent_write(latent, cache, pos)}
     if latent_read_path(pool) == "kernel":
         from distributed_compute_pytorch_tpu.ops.pallas import (
             decode_attention)
@@ -552,6 +566,155 @@ def latent_attention_gathered(q, pool_kv, table, pos, *, v_width: int,
     out = dot_product_attention(q[:, None], lat, lat[..., :v_width],
                                 mask=valid, scale=scale)
     return out[:, 0]
+
+
+def masked_attention(q, k, v, mask, scale: float):
+    """Softmax attention of a block of queries under an arbitrary mask:
+    ``q [B, H, Tq, d]``, ``k [B, H, Tk, d]``, ``v [B, H, Tk, dv]``, ``mask``
+    broadcastable to ``[B, H, Tq, Tk]`` (True = attend; a row that attends
+    nothing gives finite garbage, as :func:`dot_product_attention`'s
+    does). The row maximum is a reduction of its own behind a barrier: left
+    to fuse with the subtraction that broadcasts it back, the v5e's
+    compiler turns the pair into a window reduction of ``2 Tk - 1`` taps an
+    element for ``Tk`` of 4096 and 8192 (11.7 ms a block of 256 queries
+    where its products take 0.6: PERF.md, PR 41). The weights are
+    normalised after the product with ``v``, on ``dv`` channels and not
+    ``Tk``."""
+    logits = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    logits = jnp.where(mask, logits, -1e30)
+    top = lax.optimization_barrier(jnp.max(logits, -1, keepdims=True))
+    p = jnp.exp(logits - top)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p.astype(q.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return (out / jnp.sum(p, -1, keepdims=True)).astype(q.dtype)
+
+
+def topk_mask(score, k: int):
+    """``score [..., n]`` -> bool ``[..., n]``: the ``k`` largest entries
+    of each row, ties to the lower index (what ``lax.top_k`` picks, as a
+    mask and without a scatter): everything above the ``k``-th value, and
+    of the entries equal to it the first that fill the count."""
+    k = min(k, score.shape[-1])
+    kth = lax.top_k(score, k)[0][..., -1:]
+    above = score > kth
+    tie = score == kth
+    room = k - jnp.sum(above, -1, keepdims=True)
+    return above | (tie & (jnp.cumsum(tie, -1) <= room))
+
+
+def index_scores(qi, w, pooled):
+    """The indexer's score of every pooled key for every query: ``qi [B,
+    T, Hi, di]``, head weights ``w [B, T, Hi]`` (float32), ``pooled [B, G,
+    di]`` -> ``sum_j w_j ReLU(q_j . K_g)`` ``[B, T, G]`` float32."""
+    s = jnp.einsum("btjd,bgd->btjg", qi, pooled.astype(qi.dtype),
+                   preferred_element_type=jnp.float32)
+    return jnp.einsum("btj,btjg->btg", w, jax.nn.relu(s))
+
+
+def selected_latent_attention(q, pool_kv, table, pos, groups, groups_ok, *,
+                              group_tokens: int, v_width: int, scale: float):
+    """The read of a latent pool that touches only what was CHOSEN: row
+    ``b``'s ``H`` absorbed queries ``q [B, H, Wp]`` attend the tokens of
+    its chosen groups ``groups [B, k]`` (``groups_ok``: which entries count;
+    group ``g`` is tokens ``g * group_tokens ..``) and its own group as far
+    as it has come (``group_tokens * (pos // group_tokens) .. pos``). The
+    tokens' vectors are gathered from ``pool_kv [1, P, 1, bt, Wp]`` by
+    (block, offset) through ``table``; what was not chosen is never read.
+    Returns ``(o [B, H, v_width], attended [B])``: the tokens each row
+    attended."""
+    P_ = group_tokens
+    bt, Wp = pool_kv.shape[3:]
+    B = q.shape[0]
+    own = (pos // P_ * P_)[:, None] + jnp.arange(P_)[None, :]       # [B, P]
+    tok = jnp.concatenate(
+        [(groups[:, :, None] * P_ + jnp.arange(P_)).reshape(B, -1), own], 1)
+    ok = jnp.concatenate(
+        [jnp.repeat(groups_ok, P_, axis=1), own <= pos[:, None]], 1)
+    tok = jnp.where(ok, tok, 0)
+    with scope("kv_gather"):
+        blk = jnp.take_along_axis(table, tok // bt, axis=1)
+        lat = pool_kv.reshape(-1, Wp)[blk * bt + tok % bt]     # [B, S, Wp]
+    out = dot_product_attention(q[:, None], lat[:, None],
+                                lat[:, None, :, :v_width],
+                                mask=ok[:, None, None, :], scale=scale)
+    return out[:, 0], jnp.sum(ok, axis=1)
+
+
+def _decay_gram(x, y, b, sub: int):
+    """``G[t, j] = sum_c x[t, c] y[j, c] exp(b[t, c] - b[j, c])`` for ``j <=
+    t`` within one chunk (entries with ``j > t`` are unspecified but
+    finite): ``x, y, b [B, C, H, d]`` float32, ``b`` the running sum of the
+    log-decays (non-increasing along ``C``) -> ``[B, H, C, C]``. The
+    exponent is split at the start of ``t``'s SUB-CHUNK of ``sub`` tokens,
+    so that one factor never exceeds 1 and the other ``exp(sub * max
+    |log-decay|)``: both stay in float32's range where the plain split
+    ``(x e^b) (y e^-b)`` overflows after a few dozen tokens."""
+    B, C, H, d = x.shape
+    P_ = C // sub
+    # b at the token before each sub-chunk's first (0 before the chunk's)
+    ref = jnp.concatenate([jnp.zeros_like(b[:, :1]),
+                           b[:, sub - 1:C - 1:sub]], 1)         # [B, P, H, d]
+    xl = (x * jnp.exp(b - jnp.repeat(ref, sub, axis=1))).reshape(
+        B, P_, sub, H, d)
+    # for the sub-chunk p of t: y[j] e^(ref_p - b_j), nothing for a j of a
+    # later sub-chunk (its exponent has no bound)
+    later = (jnp.arange(C)[None, :] // sub > jnp.arange(P_)[:, None])
+    expo = jnp.where(later[None, :, :, None, None], -jnp.inf,
+                     ref[:, :, None] - b[:, None])            # [B, P, C, H, d]
+    yr = y[:, None] * jnp.exp(expo)
+    g = jnp.einsum("bpihc,bpjhc->bhpij", xl, yr,
+                   precision=lax.Precision.HIGHEST)
+    return g.reshape(B, H, C, C)
+
+
+def kda_chunk(S, q, k, v, g, beta, sub: int):
+    """One chunk of the delta rule with a per-channel decay, all its tokens
+    at once: state ``S [B, H, dk, dv]`` before the chunk, ``q, k, g [B, C,
+    H, dk]`` (``g`` the tokens' log-decays, ``<= 0``), ``v [B, C, H, dv]``,
+    ``beta [B, C, H]``, all float32 -> ``(o [B, C, H, dv], S')``. Equal to
+    ``C`` steps of :func:`kda_step` in exact arithmetic: with ``b`` the
+    running sum of ``g``, the tokens' corrections ``Delta`` solve the unit
+    lower-triangular system ``(I + Diag(beta) A) Delta = Diag(beta) (V - (K
+    e^b) S)``, ``A[t, j] = (k_t e^{b_t}) . (k_j e^{-b_j})`` for ``j < t``;
+    its inverse is the product ``(I + M)(I + M^2)(I + M^4)...`` of the
+    nilpotent ``M = -Diag(beta) A``. A token with ``beta = 0`` and ``g = 0``
+    leaves the state as it was."""
+    hi = lax.Precision.HIGHEST
+    C = q.shape[1]
+    b = jnp.cumsum(g, axis=1)
+    A = jnp.tril(_decay_gram(k, k, b, sub), -1)
+    Bm = jnp.tril(_decay_gram(q, k, b, sub))
+    bh = beta.transpose(0, 2, 1)[..., None]                   # [B, H, C, 1]
+    M = -bh * A
+    inv = jnp.eye(C, dtype=M.dtype) + M
+    for _ in range((C - 1).bit_length() - 1):
+        M = jnp.matmul(M, M, precision=hi)
+        inv = inv + jnp.matmul(inv, M, precision=hi)
+    heads = lambda t: t.transpose(0, 2, 1, 3)                 # [B, H, C, .]
+    eb = jnp.exp(b)
+    rhs = jnp.matmul(inv, bh * jnp.concatenate(
+        [heads(v), heads(k * eb)], -1), precision=hi)
+    dv = v.shape[-1]
+    delta = rhs[..., :dv] - jnp.matmul(rhs[..., dv:], S, precision=hi)
+    o = (jnp.matmul(heads(q * eb), S, precision=hi)
+         + jnp.matmul(Bm, delta, precision=hi))
+    k_end = heads(k * jnp.exp(b[:, -1:] - b))
+    S = (heads(eb[:, -1:])[:, :, 0, :, None] * S
+         + jnp.einsum("bhck,bhcv->bhkv", k_end, delta, precision=hi))
+    return o.transpose(0, 2, 1, 3), S
+
+
+def kda_step(S, q, k, v, g, beta):
+    """One token of the delta rule with a per-channel decay: ``S [B, H, dk,
+    dv]``, ``q, k, g [B, H, dk]``, ``v [B, H, dv]``, ``beta [B, H]``, all
+    float32 -> ``(o [B, H, dv], S')``: ``S' = (I - beta k k^T) Diag(e^g) S +
+    beta k v^T``, ``o = S'^T q``."""
+    hi = lax.Precision.HIGHEST
+    S = S * jnp.exp(g)[..., None]
+    w = jnp.einsum("bhk,bhkv->bhv", k, S, precision=hi)
+    S = S + k[..., None] * (beta[..., None] * (v - w))[:, :, None, :]
+    return jnp.einsum("bhk,bhkv->bhv", q, S, precision=hi), S
 
 
 def cache_verify_and_attend(q, k, v, cache, positions, *, slot_mask=None):

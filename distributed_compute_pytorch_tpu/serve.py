@@ -97,7 +97,7 @@ instead, with everything the TPU touches remaining static-shaped:
   collective the two layouts imply — the portable-redistribution move
   (arXiv:2112.01075) that resharded admission K/V in the dense design
   now reshards attached blocks.
-- **Caches of four kinds behind one block table**: a model of layer
+- **Caches of six kinds behind one block table**: a model of layer
   kinds (``models/hybrid.py``) says per layer what it keeps: ``paged``
   (the pool of K/V pairs above), ``ring`` (a window layer's last tokens a
   slot, no table), ``latent`` (a latent-attention layer: a paged pool on
@@ -107,7 +107,17 @@ instead, with everything the TPU touches remaining static-shaped:
   pool in every respect AND a fixed-size tail a slot, ``{"tail": [slots,
   tail_width]}``, that admission writes from the last real tokens of each
   row's window and every tick reads and rewrites for the rows in the
-  plan: state beside the pool that is handed from prefill to decode).
+  plan: state beside the pool that is handed from prefill to decode),
+  ``state`` (a linear-attention layer: NO tokens at all, per slot
+  ``{"state": [slots, H, dk, dv] float32, "tail": [slots, (taps - 1) x 3
+  H dk]}``: a function of the whole prefix, off the block table as a ring
+  is, written whole by admission at each row's last REAL token and
+  rewritten by a tick for the rows in its plan) or ``latent+index`` (a
+  sparse latent layer: the ``latent`` pool and beside it, ON THE SAME
+  table and free list, ``{"idx": [1, P, 1, bt / pool, di]}``, one pooled
+  index key to ``pool`` tokens, with a per-slot ``"idx_tail"`` of the
+  index keys no pooled key holds yet; a tick scores the pooled keys of
+  the row's context and reads only the tokens it chose).
   ``stats_snapshot()["cache_kinds"]`` / ``["cache_bytes_per_token"]`` say
   which and at what cost; what such a model cannot be served with yet is
   refused at construction (``_refuse_for_layer_kinds``).
@@ -264,6 +274,14 @@ _PROGRAM_CACHE_LOCK = threading.Lock()
 # compute-bound on any chip: two waves cost the device what one of their
 # sum would. Not tuned.
 _WAVE_TOKENS = 32768
+
+# The cache kinds of a model of layer kinds (``models/hybrid.py``) that
+# keep nothing on the block table, and the names of the leaves an entry
+# keeps by SLOT, which admission writes whole and a tick rewrites for the
+# rows in its plan (every other leaf of an entry is a block pool's, or a
+# ring; which kind keeps which is the model's to say: ``slot_leaves``).
+_OFF_TABLE = ("ring", "state")
+_SLOT_LEAVES = frozenset(("tail", "state", "idx_tail"))
 
 
 # The buckets of the gap between two deliveries of one request
@@ -874,69 +892,101 @@ class ContinuousBatcher:
         # the pool; admission writes it for the slots it fills, a tick for
         # the rows in its plan. self._cache_kinds says which layer is
         # which; self._pool_of(c) is an entry's block-pool leaves.
+        # A FIFTH, "state" (a linear-attention layer), keeps NO tokens:
+        # {"state": [slots, H, dk, dv] float32, "tail": [slots, ...]}, off
+        # the block table as a ring is. A SIXTH, "latent+index" (a sparse
+        # latent layer), is a "latent" entry with two more leaves: "idx"
+        # [1, P, 1, bt / pool, di], one pooled index key to `pool` tokens
+        # on the SAME table and free list, and the per-slot "idx_tail" of
+        # the index keys not yet pooled. The model says which per-slot
+        # leaves a kind keeps (model.slot_leaves).
         self._cache_kinds = (("paged",) * n_layers
                              if self._layer_blocks is None else
                              tuple(b.cache_kind for b in self._layer_blocks))
         on_table = [i for i, kind in enumerate(self._cache_kinds)
-                    if kind != "ring"]
+                    if kind not in _OFF_TABLE]
         if not on_table:
             raise ValueError(
-                "a model of window layers only is not served: the "
-                "scheduler's block accounting needs one paged layer")
+                "a model of window and state layers only is not served: "
+                "the scheduler's block accounting needs one paged layer")
         # the layer the block accounting and the engine report look at
         self._paged0 = on_table[0]
+        def pool_leaves(kind):
+            if kind in ("paged", "paged+tail"):
+                return {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
+                                    _POOL_SPEC),
+                        **({"scale": zeros((2, pool_blocks, hk, self.bt, 1),
+                                           jnp.float32, _POOL_SPEC)}
+                           if kv_dtype == "int8" else {})}
+            if kind in ("latent", "latent+index"):
+                return {"kv": zeros((1, pool_blocks, 1, self.bt,
+                                     latent_pool_width(model.latent_width)),
+                                    dtype, _POOL_SPEC),
+                        **({"idx": zeros(model.index_pool_shape(
+                            pool_blocks, self.bt), dtype, _POOL_SPEC)}
+                           if kind == "latent+index" else {})}
+            if kind == "ring":
+                return {"kv": zeros((2, slots, hk, model.ring_tokens, hd),
+                                    dtype, None)}
+            return {}                                      # "state"
+
         self._caches = [
-            {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
-                         _POOL_SPEC),
-             **({"scale": zeros((2, pool_blocks, hk, self.bt, 1),
-                                jnp.float32, _POOL_SPEC)}
-                if kv_dtype == "int8" else {})}
-            if kind == "paged" else
-            {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
-                         _POOL_SPEC),
-             "tail": zeros((slots, model.tail_width), self._cdtype, None)}
-            if kind == "paged+tail" else
-            {"kv": zeros((1, pool_blocks, 1, self.bt,
-                          latent_pool_width(model.latent_width)),
-                         dtype, _POOL_SPEC)}
-            if kind == "latent" else
-            {"kv": zeros((2, slots, hk, model.ring_tokens, hd), dtype,
-                         None)}
+            {**pool_leaves(kind),
+             **({name: zeros(shape, dt, None) for name, (shape, dt)
+                 in model.slot_leaves(kind, slots, self._cdtype).items()}
+                if self._layer_blocks is not None else {})}
             for kind in self._cache_kinds]
-        # does any layer keep state by SLOT (a ring, a tail)? Then an
-        # admission dispatch is told which slot each of its rows fills
-        self._slot_state = bool(
-            {"ring", "paged+tail"} & set(self._cache_kinds))
-        self._n_tail = self._cache_kinds.count("paged+tail")
+        # does any layer keep state by SLOT (a ring, a tail, a state)? Then
+        # an admission dispatch is told which slot each of its rows fills
+        # layers whose per-slot leaves admission has to overwrite even for
+        # a row that prefills nothing
+        self._n_tail = sum(bool(_SLOT_LEAVES & set(c)) for c in self._caches)
+        self._slot_state = bool(self._n_tail
+                                or "ring" in self._cache_kinds)
         # bytes one layer of each kind keeps of one cached token, as
-        # allocated (a latent token's 576 channels in 640 lanes: 1280)
+        # allocated (a latent token's 576 channels in 640 lanes: 1280; a
+        # pooled index key is shared by the tokens of its group; a state
+        # layer keeps none)
         self._cache_bytes_per_token = {
-            kind: sum(leaf.nbytes // (leaf.shape[1] * leaf.shape[3])
-                      for leaf in self._pool_of(c).values())
+            kind: sum(leaf.nbytes // (leaf.shape[1] * (
+                          self.bt if name == "idx" else leaf.shape[3]))
+                      for name, leaf in self._pool_of(c).items())
             for c, kind in zip(self._caches, self._cache_kinds)}
         # bytes one layer of a kind keeps of a SLOT beside what grows with
         # its tokens, as allocated (kinds that keep none are left out)
         self._state_bytes_per_slot = {
-            kind: c["tail"].nbytes // slots
+            kind: sum(leaf.nbytes for name, leaf in c.items()
+                      if name in _SLOT_LEAVES) // slots
             for c, kind in zip(self._caches, self._cache_kinds)
-            if "tail" in c}
+            if _SLOT_LEAVES & set(c)}
         # the stats that each entry of a decode tick's count vector adds
         # to (none for a model without held experts)
         held = (model.counted_experts()
                 if hasattr(model, "counted_experts") else 0)
+        # ... and, last, the two counts of a layer that attends a learned
+        # SELECTION of its cache: the tokens the rows in the plan attended
+        # and the tokens they had in context, summed over ticks and layers
+        self._select_keys = (
+            ("sparse_tokens_attended", "sparse_tokens_in_context")
+            if any(getattr(b, "selects", False)
+                   for b in self._layer_blocks or ()) else ())
         self._count_keys = (
             ("expert_assignments", "expert_assignments_held")
             # assignments on a choice that no chip holds (a skip)
             + (("expert_assignments_skipped",)
                if getattr(model, "counts_skips", False) else ())
             + tuple(f"expert_load_{e}" for e in range(held))
-            if held else ())
+            if held else ()) + self._select_keys
         # which engine writes the pool each tick is decided by where the
         # pool lives: the Pallas window write off-mesh on TPU, the XLA
         # scatter under a mesh (a Mosaic call cannot be partitioned) and
         # on CPU. Off-mesh on TPU there is no second choice to fall to.
+        # (the pooled index keys, an eighth of a block's rows, go through
+        # a row scatter of their own: models/hybrid.py)
         self._pallas_write = mesh is None and _pallas_ok(
-            self._pool_of(self._caches[self._paged0]), axis=3)
+            {name: leaf for name, leaf in self._pool_of(
+                self._caches[self._paged0]).items() if name != "idx"},
+            axis=3)
         if (jax.default_backend() == "tpu" and mesh is None
                 and not self._pallas_write):
             raise ValueError(
@@ -950,14 +1000,18 @@ class ContinuousBatcher:
         # Asked here and not noted by the trace: engines of one shape
         # family share their jitted programs (_PROGRAM_CACHE), so a
         # trace belongs to whichever engine dispatched first.
+        # "selected": a sparse latent layer gathers the tokens it chose,
+        # whatever the table's width
         with self._mesh_ctx():
+            kind0 = self._cache_kinds[self._paged0]
             self._paged_read = (
+                "selected" if kind0 == "latent+index" else
                 latent_read_path(self._caches[self._paged0])
-                if self._cache_kinds[self._paged0] == "latent" else
+                if kind0 == "latent" else
                 paged_read_path(
                     self._pool_of(self._caches[self._paged0]), 1))
         if (self._layer_blocks is not None and decode_width_buckets is None
-                and self._paged_read == "kernel"):
+                and self._paged_read in ("kernel", "selected")):
             # the kernel's traffic follows each row's position whatever
             # rung the table was shipped at (PERF.md, PR 25), so for a
             # pool read in place the ladder buys nothing and costs a
@@ -1342,11 +1396,14 @@ class ContinuousBatcher:
             # static: the pool read the decode tick was compiled with
             "paged_read": self._paged_read,
             # static: per layer, "paged" (the block pool of K/V pairs),
-            # "latent" (the block pool of token vectors) or "ring"
+            # "latent" (the block pool of token vectors), "ring",
+            # "paged+tail", "state" (no tokens: a per-slot state) or
+            # "latent+index" (token vectors and pooled index keys)
             "cache_kinds": list(self._cache_kinds),
             # static: per kind, the bytes a layer keeps of a cached token
             "cache_bytes_per_token": dict(self._cache_bytes_per_token),
-            # static: per kind that keeps one, the bytes of a slot's tail
+            # static: per kind that keeps any, the bytes of a slot's tail
+            # and state
             "state_bytes_per_slot": dict(self._state_bytes_per_slot),
             **({"expert_load_max_over_mean": self._expert_load_spread()}
                if self._count_keys else {}),
@@ -1594,7 +1651,8 @@ class ContinuousBatcher:
         self._cut_weights()
         nbp = -(-n // self.bt)
         scratch = [{name: jnp.zeros(
-                        (1,) + tuple(leaf.shape[1:]) if name == "tail" else
+                        (1,) + tuple(leaf.shape[1:])
+                        if name in _SLOT_LEAVES else
                         (leaf.shape[0], 1 if kind == "ring" else nbp)
                         + tuple(leaf.shape[2:]), leaf.dtype)
                     for name, leaf in c.items()}
@@ -1725,6 +1783,44 @@ class ContinuousBatcher:
             "prefill_chunk_tokens": "a chunk would need the tail of the "
                                     "chunk before it",
         }),
+        "state": ("linear-attention layers, whose state is a function of "
+                  "the whole prefix", {
+            "prefix_cache": "a cached prefix holds pool blocks only: the "
+                            "state at the prefix's end is not kept (no "
+                            "snapshot at block boundaries), so the suffix "
+                            "would start from nothing",
+            "speculate": "a rejected draft would have advanced the state "
+                         "past the accepted tokens, and a state cannot be "
+                         "rolled back",
+            "host_cache_mb/host_cache_blocks/disk_cache_dir":
+                "KV tiers demote and promote pool blocks; a state is none",
+            "kv_dtype='int8'": "the state is float32 and has no scale leaf",
+            "mesh": "the state is indexed by slot where the pool is "
+                    "sharded by block, and the held experts' grouped "
+                    "products are single-device programs",
+            "prefill_chunk_tokens": "a chunk would have to take the state "
+                                    "and the tail of the chunk before it",
+        }),
+        "latent+index": ("sparse latent layers behind an indexer", {
+            "prefix_cache": "an attached prefix is gathered as K/V pairs "
+                            "at head width; a latent pool holds neither, "
+                            "and the index tail at the prefix's end is "
+                            "not kept",
+            "speculate": "a sparse latent layer has no verify window: its "
+                         "decode form selects for one query a row, and a "
+                         "rejected draft would have advanced the index "
+                         "tail",
+            "host_cache_mb/host_cache_blocks/disk_cache_dir":
+                "the host and disk tiers are laid out for K/V pairs at "
+                "head width, and the pooled index keys have no tier",
+            "kv_dtype='int8'": "neither the latent pool nor the pooled "
+                               "index keys have a scale leaf",
+            "mesh": "the selected read, the pool writes and the held "
+                    "experts' grouped products are single-device programs",
+            "prefill_chunk_tokens": "a chunk would have to score and "
+                                    "attend the latent vectors and pooled "
+                                    "keys of the chunks before it",
+        }),
     }
 
     @classmethod
@@ -1812,30 +1908,35 @@ class ContinuousBatcher:
     @staticmethod
     def _pool_of(cache: dict) -> dict:
         """The block-pool leaves of a layer's cache entry (all of them but
-        a per-slot tail)."""
-        return {name: leaf for name, leaf in cache.items() if name != "tail"}
+        the per-slot ones: a tail, a state)."""
+        return {name: leaf for name, leaf in cache.items()
+                if name not in _SLOT_LEAVES}
 
     def _decode_layer(self, i: int, params, x, cache, tables, pos,
-                      live=None, counts=None, pin: bool = True, carry=None):
+                      live=None, counts=None, pin: bool = True, carry=None,
+                      selected=None):
         """One layer's decode tick against its own kind of cache; returns
         ``(x, new_cache, carry)``, the pool's leaves pinned to their
         layout unless ``pin`` is off (a scratch pool has none). ``carry``
         is what a block that carries took from the block below and hands
-        the next (None for every other block)."""
+        the next (None for every other block); ``selected`` the sink of a
+        block that attends a selection of its cache."""
         block, p_l = self._layer(params, i)
         kw = ({} if self._layer_blocks is None
               else {"live": live, "counts_sink": counts})
         carries = getattr(block, "carries", False)
         if carries:
             kw["carry"] = carry
-        paged = self._cache_kinds[i] != "ring"
+        if getattr(block, "selects", False):
+            kw["select_sink"] = selected
+        paged = self._cache_kinds[i] not in _OFF_TABLE
         out = block.decode_step(
             p_l, x, {**cache, "table": tables} if paged else cache, pos,
             **kw)
         x, c2, carry = out if carries else (*out, None)
         if paged:
             c2 = {name: constrain(leaf, _POOL_SPEC)
-                  if pin and name != "tail" else leaf
+                  if pin and name not in _SLOT_LEAVES else leaf
                   for name, leaf in c2.items() if name != "table"}
         return x, c2, carry
 
@@ -2063,6 +2164,37 @@ class ContinuousBatcher:
                             "tail": caches[i]["tail"].at[rows].set(
                                 tail.astype(caches[i]["tail"].dtype),
                                 mode="drop")})
+                    elif self._cache_kinds[i] == "state":
+                        # no tokens: the state after each row's last real
+                        # token and the tail of its last projections, whole
+                        # into the slots the rows fill (a pad row's dropped)
+                        state, tail = kept
+                        rows = (jnp.arange(state.shape[0])
+                                if ring_rows is None else ring_rows)
+                        new_caches.append({
+                            "state": caches[i]["state"].at[rows].set(
+                                state, mode="drop"),
+                            "tail": caches[i]["tail"].at[rows].set(
+                                tail.astype(caches[i]["tail"].dtype),
+                                mode="drop")})
+                    elif self._cache_kinds[i] == "latent+index":
+                        # never attached, never chunked: the latent vectors
+                        # and the pooled index keys in whole blocks of the
+                        # one table, the index tails into the rows' slots
+                        token, pooled, tail = kept
+                        rows = (jnp.arange(token.shape[0])
+                                if ring_rows is None else ring_rows)
+                        new_caches.append({
+                            **self._admit_blocks(
+                                caches[i], pad_channels(
+                                    token, caches[i]["kv"].shape[-1])[
+                                        None, :, None], tables, pmask),
+                            **self._admit_blocks(
+                                caches[i], pooled[None, :, None], tables,
+                                pmask, leaf="idx"),
+                            "idx_tail": caches[i]["idx_tail"].at[rows].set(
+                                tail.astype(caches[i]["idx_tail"].dtype),
+                                mode="drop")})
                     elif Lp == 0 and "scale" not in caches[i]:
                         # every window starts at position 0 (static)
                         new_caches.append(self._admit_blocks(
@@ -2108,10 +2240,12 @@ class ContinuousBatcher:
             ring_from_prefill(k, v, n_tok, ring.shape[3]).astype(ring.dtype),
             mode="drop")}
 
-    def _admit_blocks(self, cache, kv, tables, pmask):
+    def _admit_blocks(self, cache, kv, tables, pmask, leaf="kv"):
         """One layer's admission write in WHOLE BLOCKS (``kv [s, K, hk,
         ws, hd]``: the K/V planes, or a latent layer's one plane of token
-        vectors with one "head"; every window of
+        vectors with one "head", or a sparse latent layer's pooled index
+        keys for its pool ``leaf`` ``"idx"``, a block of which holds fewer
+        rows than tokens; every window of
         the dispatch starts at position 0: nothing attached, no chunk
         extension): block ``j`` of wave row ``r`` goes to pool block
         ``tables[r, j]`` if it holds a real token, else nowhere. One index
@@ -2122,14 +2256,15 @@ class ContinuousBatcher:
         the pad tokens' K/V: past the row's live position, never attended,
         and overwritten by the ticks that reach it."""
         s, K, hk, ws, hd = kv.shape
-        nbw = ws // self.bt
-        kv = kv.reshape(s, K, hk, nbw, self.bt, hd).transpose(
-            0, 1, 3, 2, 4, 5).reshape(s, K * nbw, hk, self.bt, hd)
+        pool = cache[leaf]
+        bt = pool.shape[3]                       # rows to a pool block
+        nbw = ws // bt
+        kv = kv.reshape(s, K, hk, nbw, bt, hd).transpose(
+            0, 1, 3, 2, 4, 5).reshape(s, K * nbw, hk, bt, hd)
         n_tok = jnp.sum(pmask > 0.5, axis=1)
         real = (jnp.arange(nbw) * self.bt)[None, :] < n_tok[:, None]
-        pool = cache["kv"]
         ids = jnp.where(real, tables[:, :nbw], pool.shape[1]).reshape(-1)
-        return {"kv": constrain(
+        return {leaf: constrain(
             pool.at[:, ids].set(kv.astype(pool.dtype), mode="drop"),
             _POOL_SPEC)}
 
@@ -2142,10 +2277,10 @@ class ContinuousBatcher:
         writes its own suffix."""
         out = []
         for c, kind in zip(caches, self._cache_kinds):
-            # rings and tails belong to slots, not to blocks (and nothing
-            # that copies a block is served with a tail)
+            # rings, tails and states belong to slots, not to blocks (and
+            # nothing that copies a block is served with any of them)
             out.append(c if kind == "ring" else {
-                name: leaf if name == "tail" else constrain(
+                name: leaf if name in _SLOT_LEAVES else constrain(
                     leaf.at[:, dst].set(leaf[:, src]), _POOL_SPEC)
                 for name, leaf in c.items()})
         return out
@@ -2215,10 +2350,11 @@ class ContinuousBatcher:
                     P(("data", "fsdp"), None, None))
                 new_caches, lcarry = [], None
                 counts: list | None = [] if counted else None
+                selected: list = []
                 for li in range(self._n_layers):
                     x, c2, lcarry = self._decode_layer(
                         li, params, x, caches[li], tables, p, live, counts,
-                        carry=lcarry)
+                        carry=lcarry, selected=selected)
                     new_caches.append(c2)
                 logits = model.readout(params, x)[:, -1]
                 with scope("sample"):
@@ -2227,7 +2363,10 @@ class ContinuousBatcher:
                     else:
                         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if counted:
-                    xc = [xc[0] + sum(counts)]
+                    # the experts' vector, then the selection's two
+                    xc = [xc[0] + jnp.concatenate(
+                        ([sum(counts)] if counts else [])
+                        + ([sum(selected)] if selected else []))]
                 return (nxt, new_caches, n_log + 1, *xc), nxt
 
         xc0 = ([jnp.zeros((len(self._count_keys),), jnp.int32)]

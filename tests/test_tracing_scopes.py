@@ -238,6 +238,59 @@ def test_cca_layers_carry_the_cca_scopes():
     assert _has(seg, "attn_cca", "kv_write")         # the tick's pool write
 
 
+def test_linear_and_sparse_latent_layers_carry_their_scopes():
+    """A KDA linear-attention layer (``models/hybrid.py``): everything its
+    mixer does as ``attn_linear`` inside ``attn``, the convolutions, decays
+    and recurrence as ``linear_scan`` inside that. A sparse latent layer:
+    ``attn_sparse`` inside ``attn``, the indexer, its pooled keys and the
+    top-k as ``index_select`` inside that, the up-projection's products as
+    ``latent_absorb`` where a latent layer has them, the pools' writes and
+    the selected read under ``kv_write`` / ``kv_gather``. The
+    hyper-connections as ``hyper_mix`` under ``attn`` and ``mlp`` alike. In
+    both serve programs."""
+    from distributed_compute_pytorch_tpu.serve import Request
+    model = build_model(
+        "hybrid", vocab_size=256, max_seq_len=64,
+        layer_types=("linear_attention", "sparse_latent_attention",
+                     "linear_attention"),
+        mlp_layer_types=("dense", "sparse", "sparse"), num_heads=4,
+        d_model=64, d_ff=128, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=0, v_head_dim=16,
+        norm_placement="pre", qk_norm=False, kda_heads=4, kda_head_dim=16,
+        kda_gate_rank=8, index_heads=2, index_head_dim=16, index_topk=8,
+        index_rope_dim=8, hc_mult=4, swiglu_limit=10.0, num_experts=8,
+        experts_held=(0, 4), top_k=2, moe_d_ff=32, shared_d_ff=32)
+    params, _ = model.init(jax.random.key(0))
+    cb = ContinuousBatcher(model, params, slots=2, t_max=64, prompt_buf=32,
+                           segment=4)
+    out = cb.serve([Request(tokens=list(range(1, 21)), max_new=3)])
+    assert len(out[0]) == 3
+    for program, outer in (("segment", "decode"), ("admit", "admit")):
+        fn, args, kwargs = cb._program_sigs[program]
+        locs = _locations(fn.lower(*args, **kwargs))
+        for path in ((outer, "attn", "attn_linear"),
+                     ("attn_linear", "linear_scan"),
+                     (outer, "attn", "attn_sparse"),
+                     ("attn_sparse", "index_select"),
+                     ("attn_sparse", "latent_absorb"),
+                     (outer, "attn", "hyper_mix"), (outer, "mlp", "hyper_mix"),
+                     (outer, "mlp", "experts")):
+            assert _has(locs, *path), (program, path)
+        assert not _has(locs, "attn_latent") and not _has(locs, "attn_cca")
+        assert not _has(locs, "mlp", "attn_linear")
+        assert not _has(locs, "attn_linear", "index_select")
+        assert not _has(locs, "linear_scan", "hyper_mix")
+    seg = _locations(cb._program_sigs["segment"][0].lower(
+        *cb._program_sigs["segment"][1], **cb._program_sigs["segment"][2]))
+    assert _has(seg, "attn_sparse", "kv_write")      # the tick's one vector
+    assert _has(seg, "index_select", "kv_write")     # and its pooled key
+    assert _has(seg, "index_select", "kv_gather")    # the pooled keys' read
+    assert _has(seg, "attn_sparse", "kv_gather")     # the chosen tokens'
+    adm = _locations(cb._program_sigs["admit"][0].lower(
+        *cb._program_sigs["admit"][1], **cb._program_sigs["admit"][2]))
+    assert _has(adm, "admit", "kv_write")            # blocks, state, tails
+
+
 def test_admission_prefix_gather_is_a_kv_gather():
     """With the prefix cache on, a second request sharing a block-aligned
     prefix attaches it: the admission program gathers the cached K/V."""
@@ -295,6 +348,16 @@ def test_every_name_a_benchmark_metric_reads_is_emitted():
             for s in [spec["numerator"], *spec["denominator"]]:
                 assert s in spans, (f.name, s)
     assert read >= 17
+    # the scopes ISSUE 41 declared are each read by a metric's file (and a
+    # file that reads none of its own leaves them out with the rest of the
+    # vocabulary, as below)
+    new = {"attn_linear", "linear_scan", "attn_sparse", "index_select",
+           "hyper_mix"}
+    assert new <= set(tracing.SCOPES)
+    named = set()
+    for f in (ROOT / "perfbench" / "layer_metrics").glob("*.json"):
+        named |= set(json.loads(f.read_text()).get("scope", []))
+    assert new <= named
     # an "unscoped" share leaves out the program's whole vocabulary but
     # the scopes that wrap its program (``wraps``): the reader takes
     # ``obs.tracing.SCOPES`` from the run (``perfbench/readers/
