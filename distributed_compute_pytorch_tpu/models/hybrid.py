@@ -65,9 +65,10 @@ Diag(e^g) S_{t-1} + beta k v^T``, ``o_t = S_t^T q_t`` on a float32 state a
 head; ``y = W_o (RMSNorm_head(o) * sigmoid(W_g2 W_g1 x))``. The cache keeps
 NO tokens (cache kind ``"state"``): per row the state ``[H, dk, dv]`` and a
 TAIL of the last ``kda_conv - 1`` tokens' ``[q^, k^, v^]``. ``apply``
-(prefill) runs ``KDA_CHUNK`` tokens at a time (``ops/attention.py::
-kda_chunk``; the convolutions inside the scan, behind the carried tail);
-``decode_step`` one step (``kda_step``). A pad token leaves the state as it
+(prefill) convolves the window, then runs the recurrence ``KDA_CHUNK``
+tokens at a time (``ops/attention.py::kda_window``: one Pallas kernel on a
+TPU, a scan of ``kda_chunk`` elsewhere); ``decode_step`` one step
+(``kda_step``). A pad token leaves the state as it
 was, so what prefill hands decode is the state after each row's last REAL
 token.
 
@@ -850,16 +851,15 @@ class HybridBlock:
         log-decay ``g`` (all ``[..., H, dk]`` float32)."""
         c = self.config
         H, dk = c.kda_heads, c.kda_head_dim
-        f32 = lambda t: t.astype(jnp.float32)
-        heads = lambda t: f32(t).reshape(t.shape[:-1] + (H, dk))
-        unit = lambda t: t * jax.lax.rsqrt(
-            jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+        # (in the order the tick's program has had these operations since
+        # PR 41: its lowered text, and so its compiled program, is unchanged)
+        heads = lambda t: t.astype(jnp.float32).reshape(
+            t.shape[:-1] + (H, dk))
         q, k, v = (heads(t) for t in a)
         f = jnp.dot(f_low, params["f_up"]["kernel"].astype(f_low.dtype),
                     preferred_element_type=jnp.float32) + params["dt_bias"]
-        g = c.kda_gate_lower_bound * jax.nn.sigmoid(
-            jnp.exp(f32(params["A_log"]))[:, None] * heads(f))
-        return unit(q) * dk ** -0.5, unit(k), v, g
+        rate = jnp.exp(params["A_log"].astype(jnp.float32))[:, None]
+        return A.kda_heads(q, k, v, heads(f), rate, c.kda_gate_lower_bound)
 
     def _kda_gates(self, params, x):
         """The decay gate's low-rank part ``[..., R]`` and ``beta [...,
@@ -884,55 +884,43 @@ class HybridBlock:
         return o.reshape(o.shape[:-2] + (c.kda_width,)) * gate
 
     def _kda_prefill(self, params, x, kv_mask, kv_sink):
-        """The whole-window form, ``KDA_CHUNK`` tokens at a time
-        (``ops/attention.py::kda_chunk``). A pad token (``kv_mask`` 0) gets
-        ``beta = 0`` and no decay, so the state the scan ends with is the
-        one after each row's LAST REAL token. ``kv_sink`` is handed
-        ``(state [B, H, dk, dv] float32, tail [B, kda_tail_width])``: that
-        state and, token by token, ``[q^, k^, v^]`` of the row's last
-        ``kda_conv - 1`` real tokens (zero where it has fewer)."""
+        """The whole-window form: the three convolutions over the window,
+        then the recurrence ``KDA_CHUNK`` tokens at a time
+        (``ops/attention.py::kda_window``: one kernel on a TPU, a scan of
+        ``kda_chunk`` elsewhere). A pad token (``kv_mask`` 0) gets ``beta =
+        0`` and no decay, so the state the window ends with is the one
+        after each row's LAST REAL token. ``kv_sink`` is handed ``(state
+        [B, H, dk, dv] float32, tail [B, kda_tail_width])``: that state
+        and, token by token, ``[q^, k^, v^]`` of the row's last ``kda_conv
+        - 1`` real tokens (zero where it has fewer)."""
         c = self.config
         B, T = x.shape[:2]
-        K, C, H, dk = c.kda_conv, KDA_CHUNK, c.kda_heads, c.kda_head_dim
+        K = c.kda_conv
         u = self._kda_project(params, x)
         with scope("linear_scan"):
             f_low, beta = self._kda_gates(params, x)
             real = (jnp.ones((B, T), jnp.float32) if kv_mask is None
                     else (kv_mask > 0.5).astype(jnp.float32))
-            nC = -(-T // C)
-            pad = lambda t: t if nC * C == T else jnp.pad(
-                t, ((0, 0), (0, nC * C - T)) + ((0, 0),) * (t.ndim - 2))
-            chunks = lambda t: pad(t).reshape(
-                (B, nC, C) + t.shape[2:]).swapaxes(0, 1)
-
-            def step(carry, xs):
-                # the convolutions run here, a chunk at a time, on the
-                # chunk's projections behind the tails of the chunks before
-                # it: the projections of the row's last K - 1 REAL tokens
-                # (pads only trail, so a real token's are the tokens
-                # before it), which is also what the slot is handed
-                S, tails = carry
-                u_c, f_c, beta_c, real_c, x_c = xs
-                at = (jnp.sum(real_c, axis=1).astype(jnp.int32)[:, None, None]
-                      + jnp.arange(K - 1)[None, :, None])
-                a_c, new_tails = [], []
-                for name, tail, u_i in zip("qkv", tails, u_c):
-                    seq = jnp.concatenate([tail, u_i], axis=1)  # [B, K-1+C, .]
-                    a_c.append(self._kda_conv(params, name, jnp.stack(
-                        [seq[:, i:i + C] for i in range(K)], axis=2)))
-                    new_tails.append(jnp.take_along_axis(seq, at, axis=1))
-                q, k, v, g = self._kda_heads(params, a_c, f_c)
-                o, S = A.kda_chunk(S, q, k, v, g * real_c[..., None, None],
-                                   beta_c * real_c[..., None], KDA_SUB)
-                return (S, new_tails), self._kda_out(params, x_c, o)
-
-            (S, tails), o = jax.lax.scan(
-                step, (jnp.zeros((B, H, dk, dk), jnp.float32),
-                       [jnp.zeros((B, K - 1, t.shape[-1]), t.dtype)
-                        for t in u]),
-                ([chunks(t) for t in u], chunks(f_low), chunks(beta),
-                 chunks(real), chunks(x)))
-            o = o.swapaxes(0, 1).reshape(B, nC * C, -1)[:, :T]
+            # the projections of the row's last K - 1 REAL tokens (pads
+            # only trail, so a real token's are the tokens before it),
+            # zero where it has fewer; gathered from the projections
+            # themselves, so that the padded copy below feeds the
+            # convolution alone and is fused into it
+            at = (jnp.sum(real, axis=1).astype(jnp.int32)[:, None, None]
+                  + jnp.arange(1 - K, 0)[None, :, None])
+            a, tails = [], []
+            for name, u_i in zip("qkv", u):
+                seq = jnp.pad(u_i, ((0, 0), (K - 1, 0), (0, 0)))
+                a.append(self._kda_conv(params, name, jnp.stack(
+                    [seq[:, i:i + T] for i in range(K)], axis=2)))
+                tails.append(jnp.where(at >= 0, jnp.take_along_axis(
+                    u_i, jnp.maximum(at, 0), axis=1), 0))
+            o, S = A.kda_window(
+                *a, f_low, params["f_up"]["kernel"], params["dt_bias"],
+                jnp.exp(params["A_log"].astype(jnp.float32)), beta, real,
+                lower_bound=c.kda_gate_lower_bound, chunk=KDA_CHUNK,
+                sub=KDA_SUB)
+            o = self._kda_out(params, x, o)
             if kv_sink is not None:
                 kv_sink.append((S, jnp.concatenate(tails, -1).reshape(B, -1)))
         return o

@@ -668,6 +668,19 @@ def _decay_gram(x, y, b, sub: int):
     return g.reshape(B, H, C, C)
 
 
+def kda_heads(aq, ak, av, f, a, lower_bound: float):
+    """A KDA layer's heads from their convolved projections, ``[..., dk]``
+    a head: ``q`` (unit length times ``dk ** -0.5``), ``k`` (unit length),
+    ``v`` and the log-decay ``g = lower_bound * sigmoid(a * f)`` (``f`` the
+    decay gate's logits ``[..., dk]``, ``a`` the head's rate, broadcast
+    against it), all float32. The one definition: the models call it on a
+    window or a token, ``ops/pallas/kda_scan.py`` on a block."""
+    f32 = lambda t: t.astype(jnp.float32)
+    unit = lambda t: t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
+    g = lower_bound * jax.nn.sigmoid(f32(a) * f32(f))
+    return unit(f32(aq)) * aq.shape[-1] ** -0.5, unit(f32(ak)), f32(av), g
+
+
 def kda_chunk(S, q, k, v, g, beta, sub: int):
     """One chunk of the delta rule with a per-channel decay, all its tokens
     at once: state ``S [B, H, dk, dv]`` before the chunk, ``q, k, g [B, C,
@@ -703,6 +716,64 @@ def kda_chunk(S, q, k, v, g, beta, sub: int):
     S = (heads(eb[:, -1:])[:, :, 0, :, None] * S
          + jnp.einsum("bhck,bhcv->bhkv", k_end, delta, precision=hi))
     return o.transpose(0, 2, 1, 3), S
+
+
+def whole_chunks(t, chunk: int):
+    """``t [B, T, ...]`` zero-padded along ``T`` to whole chunks."""
+    short = -t.shape[1] % chunk
+    return t if not short else jnp.pad(
+        t, ((0, 0), (0, short)) + ((0, 0),) * (t.ndim - 2))
+
+
+def _kda_kernel_ok(head_dim: int) -> bool:
+    """Whether a window's recurrence runs as the Pallas kernel: one TPU
+    (no mesh context, as the pool's kernels: ``cache_update._pallas_ok``)
+    and heads a whole number of lane tiles wide."""
+    from distributed_compute_pytorch_tpu.core.mesh import current_mesh
+    return (jax.default_backend() == "tpu" and current_mesh() is None
+            and head_dim % 128 == 0)
+
+
+def kda_window(aq, ak, av, f_low, f_up, dt_bias, rate, beta, real, *,
+               lower_bound: float, chunk: int, sub: int):
+    """The delta rule with a per-channel decay over whole windows, from the
+    zero state, ``chunk`` tokens at a time: ``aq, ak, av [B, T, H * dk]``
+    the CONVOLVED projections, ``f_low [B, T, R]`` and ``f_up [R, H * dk]``
+    the decay gate's two factors, ``dt_bias [H * dk]``, ``rate [H]``
+    (``exp(A_log)``), ``beta [B, T, H]`` float32, ``real [B, T]`` (1 at a
+    token, 0 at a pad: a pad gets ``beta = 0`` and no decay) -> ``(o [B, T,
+    H, dk] float32, S [B, H, dk, dk] float32)``, ``S`` the state after each
+    row's last real token. One algorithm, two executions chosen by what
+    can be observed (:func:`_kda_kernel_ok`): ONE kernel with a head's
+    state in VMEM (``ops/pallas/kda_scan.py``), or a ``lax.scan`` of
+    :func:`kda_chunk`, the portable form the kernel is tested against."""
+    B, T, _ = aq.shape
+    H = beta.shape[-1]
+    dk = aq.shape[-1] // H
+    if _kda_kernel_ok(dk):
+        from distributed_compute_pytorch_tpu.ops.pallas.kda_scan import (
+            kda_chunk_scan)
+        return kda_chunk_scan(aq, ak, av, f_low, f_up, dt_bias, rate, beta,
+                              real, lower_bound=lower_bound, chunk=chunk,
+                              sub=sub)
+    nC = -(-T // chunk)
+    chunks = lambda t: whole_chunks(t, chunk).reshape(
+        (B, nC, chunk) + t.shape[2:]).swapaxes(0, 1)
+    heads = lambda t: t.reshape(t.shape[:-1] + (H, dk))
+
+    def step(S, xs):
+        aq_c, ak_c, av_c, f_c, beta_c, real_c = xs
+        f = jnp.dot(f_c, f_up.astype(f_c.dtype),
+                    preferred_element_type=jnp.float32) + dt_bias
+        q, k, v, g = kda_heads(heads(aq_c), heads(ak_c), heads(av_c),
+                               heads(f), rate[:, None], lower_bound)
+        o, S = kda_chunk(S, q, k, v, g * real_c[..., None, None],
+                         beta_c * real_c[..., None], sub)
+        return S, o
+
+    S, o = lax.scan(step, jnp.zeros((B, H, dk, dk), jnp.float32),
+                    tuple(chunks(t) for t in (aq, ak, av, f_low, beta, real)))
+    return o.swapaxes(0, 1).reshape(B, nC * chunk, H, dk)[:, :T], S
 
 
 def kda_step(S, q, k, v, g, beta):

@@ -3,6 +3,18 @@
 Kernels follow the playbook in the TPU Pallas guide: VMEM-resident blocks,
 MXU-aligned tiles (128), sequential grid with scratch accumulators, and
 interpret mode on CPU so the same kernels run in the test mesh.
+
+The kernels, by the names a profile shows:
+
+- ``flash_attention.py``: ``dcp_flash_fwd``, ``dcp_flash_fwd_band``,
+  ``dcp_flash_bwd_dq``, ``dcp_flash_bwd_dkv``;
+- ``cache_update.py``: ``dcp_cache_write``, ``dcp_kv_write``,
+  ``dcp_kv_rows_write``, ``dcp_kv_pool_write``;
+- ``decode_attention.py``: ``dcp_paged_decode_attn``,
+  ``dcp_paged_latent_decode_attn``;
+- ``kda_scan.py``: ``dcp_kda_chunk_scan`` (the KDA recurrence over a
+  window, a head's state in VMEM from chunk to chunk);
+- ``fused_adamw.py``: the fused AdamW update.
 """
 
 from distributed_compute_pytorch_tpu.ops.pallas.flash_attention import (

@@ -250,13 +250,18 @@ def test_the_chunked_delta_rule_equals_the_recurrence(C):
 @pytest.mark.parametrize("T,real", [(64, 64), (128, 128), (37, 37),
                                     (100, 100), (128, 71), (64, 3),
                                     (96, 0)])
-def test_the_kda_window_form_equals_its_token_form_and_hands_over(T, real):
+@pytest.mark.parametrize("path", ["scan", "kernel"])
+def test_the_kda_window_form_equals_its_token_form_and_hands_over(
+        T, real, path, monkeypatch):
     """ONE KDA layer: its whole-window ``apply`` (chunks of 64 tokens;
     windows that are and are not whole chunks, and windows padded past
     their last real token) against ``decode_step`` token by token; and
     what it hands the slot (the state after the row's LAST REAL token, the
     projections of its last three real tokens) against what the ticks
-    arrive at. float32: 2e-5 on activations of size ~1."""
+    arrive at. float32: 2e-5 on activations of size ~1. Both executions of
+    the recurrence: the scan of chunks, which the CPU takes, and the
+    kernel a TPU takes (``ops/pallas/kda_scan.py``, interpreted here)."""
+    monkeypatch.setattr(A, "_kda_kernel_ok", lambda dk: path == "kernel")
     cfg = one_layer("linear_attention", "dense")
     model, params = build(cfg=cfg)
     block, p = model.layer_block(0), params["layers"][0]
@@ -430,10 +435,15 @@ def test_a_lower_precision_control_fails_the_tolerance():
     assert float(np.max(np.abs(np.asarray(low) - want))) > 10 * TOL_F32
 
 
-def test_rows_of_different_lengths_in_one_wave_hand_their_own_state_over():
+@pytest.mark.parametrize("path", ["scan", "kernel"])
+def test_rows_of_different_lengths_in_one_wave_hand_their_own_state_over(
+        path, monkeypatch):
     """One admission dispatch of three rows (40, 9 and 1 tokens, and a pad
     row) into slots 0, 2 and 3, then ticks: each row goes on from ITS last
-    real token (state, both tails, pooled keys), against the reference."""
+    real token (state, both tails, pooled keys), against the reference;
+    with the KDA layers' recurrence as the scan of chunks and as the
+    kernel."""
+    monkeypatch.setattr(A, "_kda_kernel_ok", lambda dk: path == "kernel")
     model, params = build()
     cb = engine(model, params)
     rng = np.random.default_rng(4)

@@ -254,6 +254,37 @@ def test_latent_kernel_compiles_for_a_described_v5e(one_v5e_chip):
     assert "dcp_paged_latent_decode_attn" in compiled.as_text()
 
 
+@on_cpu
+@pytest.mark.parametrize("rows,window", [(1, 16384), (4, 2048)])
+def test_kda_scan_kernel_compiles_for_a_described_v5e(
+        rows, window, one_v5e_chip, monkeypatch):
+    """Mosaic takes ``dcp_kda_chunk_scan`` (``ops/pallas/kda_scan.py``) at
+    the long-context cell's shapes, the longest and the widest program of
+    its admission ladder: 64 heads of 128 in bf16, a gate of rank 128. (In
+    this file because one process at a time may load the TPU's library:
+    the fixture is this module's. Interpret mode passes forms that Mosaic
+    aborts on: PERF.md section 7, "After PR 43" (4).)"""
+    from distributed_compute_pytorch_tpu.ops.pallas import kda_scan
+    monkeypatch.setattr(kda_scan, "_use_interpret", lambda: False)
+    # the choice is read while tracing: no trace of the other kind, before
+    # or after
+    kda_scan.kda_chunk_scan.clear_cache()
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+    wide = 64 * 128
+    compiled = kda_scan.kda_chunk_scan.lower(
+        arg((rows, window, wide)), arg((rows, window, wide)),
+        arg((rows, window, wide)), arg((rows, window, 128)),
+        arg((128, wide)), arg((wide,), jnp.float32), arg((64,), jnp.float32),
+        arg((rows, window, 64), jnp.float32),
+        arg((rows, window), jnp.float32),
+        lower_bound=-5.0, chunk=64, sub=16).compile()
+    kda_scan.kda_chunk_scan.clear_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "dcp_kda_chunk_scan" in compiled.as_text()
+
+
 @on_tpu
 @pytest.mark.parametrize("shape", ["llama2_7b_mha_bf16", "f32_pool_hd256"])
 def test_compiled_kernel_at_budget_bound_chunks(shape):
