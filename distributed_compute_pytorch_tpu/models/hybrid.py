@@ -10,12 +10,15 @@ Feed-forwards: ``"dense"`` (SwiGLU) and
 ``"sparse"`` (routed experts with a shared expert, of which this chip
 holds ``experts_held``: ``models/moe.py::HeldExperts``). The rest is the
 Llama recipe (``models/llama.py``: bias-free q/k/v/o at GQA width,
-half-split RoPE, RMSNorm, untied head) with three switches a published
+half-split RoPE, RMSNorm, untied head) with four switches a published
 family sets: ``qk_norm`` (an RMSNorm over each head's channels of q and
 of k, before any rotation), ``rope_sliding_only`` (full layers rotate
-nothing) and ``norm_placement``: ``"post"`` norms each sublayer's OUTPUT
-(``h = h + norm(sublayer(h))``, no norm on its input), ``"pre"`` its
-INPUT (``h = h + sublayer(norm(h))``, the DeepSeek-V3 recipe).
+nothing), ``attn_gate`` (a full layer's heads, side by side, times the
+sigmoid of a projection ``gate`` of the layer's input, one gate a channel,
+before ``W_o``: arXiv:2505.06708) and ``norm_placement``: ``"post"`` norms
+each sublayer's OUTPUT (``h = h + norm(sublayer(h))``, no norm on its
+input), ``"pre"`` its INPUT (``h = h + sublayer(norm(h))``, the
+DeepSeek-V3 recipe).
 
 LATENT attention (MLA, the DeepSeek-V2/V3 recipe): queries through a
 low-rank bottleneck with its own norm (``q_down``, ``q_norm``, ``q_up``);
@@ -58,11 +61,16 @@ LINEAR attention (KDA, the Kimi Linear recipe; mixer
 ``"linear_attention"``): ``kda_heads`` heads of ``kda_head_dim`` (keys and
 values alike); ``q^, k^, v^ = W x`` each through a depth-wise causal
 convolution over ``kda_conv`` tokens and SiLU, ``q`` and ``k`` scaled to
-unit length (``q`` times ``dk ** -0.5``); a log-decay a CHANNEL ``g =
-kda_gate_lower_bound * sigmoid(exp(A_h) (W_f2 W_f1 x + b_dt))``, ``beta =
-sigmoid(W_b x)`` a head; the delta rule ``S_t = (I - beta k k^T)
-Diag(e^g) S_{t-1} + beta k v^T``, ``o_t = S_t^T q_t`` on a float32 state a
-head; ``y = W_o (RMSNorm_head(o) * sigmoid(W_g2 W_g1 x))``. The cache keeps
+unit length (``q`` times ``dk ** -0.5``); a log-decay a CHANNEL in one of
+two forms: BOUNDED by a floor, ``g = kda_gate_lower_bound * sigmoid(exp(A_h)
+(W_f2 W_f1 x + b_dt))``, or (``kda_gate_lower_bound`` None) Kimi Linear's
+own with none, ``g = -exp(A_h) softplus(W_f2 W_f1 x + b_dt)``; a step size
+a head, ``beta = sigmoid(W_b x)`` or (``kda_allow_neg_eigval``) ``2
+sigmoid(W_b x)``, under which the transition ``I - beta k k^T`` has the
+eigenvalue ``1 - beta`` in (-1, 1) along ``k`` (arXiv:2411.12537); the
+delta rule ``S_t = (I - beta k k^T) Diag(e^g) S_{t-1} + beta k v^T``, ``o_t
+= S_t^T q_t`` on a float32 state a head; ``y = W_o (RMSNorm_head(o) *
+sigmoid(W_g2 W_g1 x))``. The cache keeps
 NO tokens (cache kind ``"state"``): per row the state ``[H, dk, dv]`` and a
 TAIL of the last ``kda_conv - 1`` tokens' ``[q^, k^, v^]``. ``apply``
 (prefill) convolves the window, then runs the recurrence ``KDA_CHUNK``
@@ -70,7 +78,11 @@ tokens at a time (``ops/attention.py::kda_window``: one Pallas kernel on a
 TPU, a scan of ``kda_chunk`` elsewhere); ``decode_step`` one step
 (``kda_step``). A pad token leaves the state as it
 was, so what prefill hands decode is the state after each row's last REAL
-token.
+token. The chunked form divides a sub-chunk's decays out only under a
+floor; without one its decay grams take the form no ``g <= 0`` can
+overflow (``ops/attention.py::_decay_gram``), a static choice by the
+configuration. A model may pair state layers with any pool kind: the
+paged K/V pool of a full layer and the per-slot state stand side by side.
 
 SPARSE LATENT attention (the DeepSeek-V3.2 recipe over NoPE latent
 attention; mixer ``"sparse_latent_attention"``): the latent mixer with
@@ -126,9 +138,9 @@ from distributed_compute_pytorch_tpu.ops.rotary import (
 
 MIXERS = ("full_attention", "sliding_attention", "latent_attention",
           "cca_attention", "linear_attention", "sparse_latent_attention")
-# tokens of a sub-chunk of the chunked KDA form: the decays of a sub-chunk
-# are divided out in float32, so 16 x |kda_gate_lower_bound| has to stay
-# under its largest exponent (88)
+# tokens of a sub-chunk of the chunked KDA form: under a floor the decays of
+# a sub-chunk are divided out in float32, so 16 x |kda_gate_lower_bound| has
+# to stay under its largest exponent (88)
 KDA_SUB = 16
 # tokens of a chunk of the prefill form: whole sub-chunks
 KDA_CHUNK = 64
@@ -149,6 +161,9 @@ class HybridConfig:
     d_ff: int = 1024               # the dense layers' SwiGLU width
     qk_norm: bool = True
     rope_sliding_only: bool = True
+    # a full_attention layer's output gate: sigmoid(W_g x) a channel of the
+    # merged heads, before W_o
+    attn_gate: bool = False
     rope_theta: float = 10000.0
     rms_eps: float = 1e-6
     # the sparse layers' experts (models/moe.py::HeldExperts)
@@ -185,12 +200,14 @@ class HybridConfig:
     tie_embeddings: bool = False
     # the linear_attention (KDA) layers: heads and their width (key and
     # value alike), taps of the three convolutions, the rank of the two
-    # low-rank gates, and the floor of a token's log-decay
+    # low-rank gates, the floor of a token's log-decay (None: the softplus
+    # gate, which has none), and whether beta runs to 2 and not to 1
     kda_heads: int = 0
     kda_head_dim: int = 0
     kda_conv: int = 4
     kda_gate_rank: int = 0
-    kda_gate_lower_bound: float = -5.0
+    kda_gate_lower_bound: float | None = -5.0
+    kda_allow_neg_eigval: bool = False
     # the sparse_latent_attention layers' indexer: heads and their width,
     # tokens a query attends (index_topk, in groups of index_pool tokens
     # with one pooled key a group), the channels of an index head that
@@ -242,11 +259,12 @@ class HybridConfig:
         if "linear_attention" in self.layer_types and not (
                 self.kda_heads and self.kda_head_dim and self.kda_gate_rank
                 and self.kda_conv >= 2
-                and -80.0 <= KDA_SUB * self.kda_gate_lower_bound < 0):
+                and (self.kda_gate_lower_bound is None
+                     or -80.0 <= KDA_SUB * self.kda_gate_lower_bound < 0)):
             raise ValueError(
                 "a linear_attention layer needs kda_heads, kda_head_dim, "
                 "kda_gate_rank, kda_conv >= 2 and a kda_gate_lower_bound "
-                f"in [-{80 // KDA_SUB}, 0)")
+                f"in [-{80 // KDA_SUB}, 0) or None (a gate with no floor)")
         if self.hc_mult and (self.hc_mult < 2 or self.norm_placement != "pre"
                              or self.scale_residual_merge):
             raise ValueError(
@@ -360,6 +378,11 @@ class HybridBlock:
     @property
     def kda(self) -> bool:
         return self.mixer == "linear_attention"
+
+    @property
+    def gated(self) -> bool:
+        """A full layer whose merged heads pass an output gate."""
+        return self.config.attn_gate and self.mixer == "full_attention"
 
     @property
     def selects(self) -> bool:
@@ -498,6 +521,8 @@ class HybridBlock:
                  "k": dense(d, c.num_kv_heads * hd).init(next(ks)),
                  "v": dense(d, c.num_kv_heads * hd).init(next(ks)),
                  "o": dense(c.num_heads * hd, d).init(next(ks))}
+            if self.gated:
+                p["gate"] = dense(d, c.num_heads * hd).init(next(ks))
         for name in norms:
             p[name] = L.RMSNorm(d, c.rms_eps).init(None)
         if c.scale_residual_merge:
@@ -863,13 +888,14 @@ class HybridBlock:
 
     def _kda_gates(self, params, x):
         """The decay gate's low-rank part ``[..., R]`` and ``beta [...,
-        H]`` (float32)."""
+        H]`` (float32; in (0, 1), or (0, 2) under
+        ``kda_allow_neg_eigval``)."""
         c = self.config
         f_low = _dense(c.d_model, c.kda_gate_rank).apply(params["f_down"], x)
         beta = jax.nn.sigmoid(jnp.dot(
             x, params["beta"]["kernel"].astype(x.dtype),
             preferred_element_type=jnp.float32))
-        return f_low, beta
+        return f_low, 2.0 * beta if c.kda_allow_neg_eigval else beta
 
     def _kda_out(self, params, x, o):
         """``o [..., H, dv]`` float32 -> the mixer's output before ``W_o``,
@@ -1143,11 +1169,19 @@ class HybridBlock:
                 + (1.0 + p["out_scale"]) * (f32 + p["out_bias"])).astype(
                     x.dtype)
 
-    def _attn_out(self, params, x, o, hc=None, merged: bool = False):
+    def _attn_out(self, params, x, o, hc=None, merged: bool = False,
+                  gate_in=None):
         """``o`` (heads ``[B, H, T, hd]``, or already ``merged [B, T, H
-        hd]``) through the output projection and into the stream."""
+        hd]``) through the output projection and into the stream; a
+        :attr:`gated` layer's heads first times the sigmoid of the gate's
+        projection of ``gate_in``, the mixer's input."""
         if not merged:
             o = A.merge_heads(o)
+        if gate_in is not None:
+            with scope("attn_gate"):
+                o = o * jax.nn.sigmoid(_dense(
+                    self.config.d_model, o.shape[-1]).apply(
+                        params["gate"], gate_in))
         a = _dense(o.shape[-1], self.config.d_model).apply(params["o"], o)
         return self._leave(params, "attn", x, a, hc)
 
@@ -1218,7 +1252,8 @@ class HybridBlock:
                 else:
                     o = dispatch_attention(q, k, v, causal=True,
                                            kv_mask=kv_mask)
-                x = self._attn_out(params, x, o, hc)
+                x = self._attn_out(params, x, o, hc,
+                                   gate_in=y if self.gated else None)
         return self._mlp(params, x, token_mask=kv_mask,
                          counts_sink=counts_sink, carry=carry)
 
@@ -1271,7 +1306,8 @@ class HybridBlock:
                 else:
                     o, cache = A.cache_write_and_attend(
                         q, k, v, cache, pos, slot_mask=slot_mask)
-                x = self._attn_out(params, x, o, hc)
+                x = self._attn_out(params, x, o, hc,
+                                   gate_in=y if self.gated else None)
         # a parked row (live 0) routes nowhere: its token is garbage, and
         # the experts' counts are of the rows in the plan
         out = self._mlp(params, x, token_mask=live,

@@ -76,7 +76,8 @@ from distributed_compute_pytorch_tpu.obs import flight, metrics
 # keys, pooling, scores, top-k, the pooled keys' read and write) and
 # ``latent_absorb`` where a latent mixer has it; ``hyper_mix`` under
 # ``attn`` and ``mlp`` alike (a hyper-connection's maps, Sinkhorn and stream
-# products). The benchmark's scope metrics (``perfbench/layer_metrics``)
+# products); ``attn_gate`` in ``attn`` (a full layer's output gate: its
+# projection, the sigmoid and the product with the heads). The benchmark's scope metrics (``perfbench/layer_metrics``)
 # name these and nothing else.
 SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "optimizer", "grad_reduce",
@@ -84,7 +85,7 @@ SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "router", "experts", "shared_expert", "attn_local",
           "attn_latent", "latent_absorb", "attn_cca", "cca_mix",
           "attn_linear", "linear_scan", "attn_sparse", "index_select",
-          "hyper_mix")
+          "hyper_mix", "attn_gate")
 
 
 def scope(name: str):

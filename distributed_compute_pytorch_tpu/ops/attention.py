@@ -641,7 +641,7 @@ def selected_latent_attention(q, pool_kv, table, pos, groups, groups_ok, *,
     return out[:, 0], jnp.sum(ok, axis=1)
 
 
-def _decay_gram(x, y, b, sub: int):
+def _decay_gram(x, y, b, sub: int, floor: bool = True):
     """``G[t, j] = sum_c x[t, c] y[j, c] exp(b[t, c] - b[j, c])`` for ``j <=
     t`` within one chunk (entries with ``j > t`` are unspecified but
     finite): ``x, y, b [B, C, H, d]`` float32, ``b`` the running sum of the
@@ -649,43 +649,70 @@ def _decay_gram(x, y, b, sub: int):
     exponent is split at the start of ``t``'s SUB-CHUNK of ``sub`` tokens,
     so that one factor never exceeds 1 and the other ``exp(sub * max
     |log-decay|)``: both stay in float32's range where the plain split
-    ``(x e^b) (y e^-b)`` overflows after a few dozen tokens."""
+    ``(x e^b) (y e^-b)`` overflows after a few dozen tokens. That holds
+    while the log-decay has a ``floor``. Without one no split inside a
+    sub-chunk is safe (the secondary chunking of arXiv:2312.06635, section
+    4): the split then serves the ``j`` of EARLIER sub-chunks alone, where
+    both factors, ``e^(b_t - ref)`` and ``e^(ref - b_j)``, are at most 1,
+    and INSIDE a sub-chunk the exponent is the difference ``b_t - b_j <=
+    0`` itself, a channel at a time: no ``g <= 0`` can overflow, and what
+    underflows to zero is zero to float32."""
     B, C, H, d = x.shape
     P_ = C // sub
+    hi = lax.Precision.HIGHEST
+    subs = lambda t: t.reshape(B, P_, sub, H, d)
     # b at the token before each sub-chunk's first (0 before the chunk's)
     ref = jnp.concatenate([jnp.zeros_like(b[:, :1]),
                            b[:, sub - 1:C - 1:sub]], 1)         # [B, P, H, d]
-    xl = (x * jnp.exp(b - jnp.repeat(ref, sub, axis=1))).reshape(
-        B, P_, sub, H, d)
+    xl = subs(x * jnp.exp(b - jnp.repeat(ref, sub, axis=1)))
     # for the sub-chunk p of t: y[j] e^(ref_p - b_j), nothing for a j of a
-    # later sub-chunk (its exponent has no bound)
-    later = (jnp.arange(C)[None, :] // sub > jnp.arange(P_)[:, None])
-    expo = jnp.where(later[None, :, :, None, None], -jnp.inf,
+    # later sub-chunk (its exponent has no bound) nor, without a floor, of
+    # p itself
+    sub_of, p = jnp.arange(C)[None, :] // sub, jnp.arange(P_)[:, None]
+    unseen = sub_of > p if floor else sub_of >= p
+    expo = jnp.where(unseen[None, :, :, None, None], -jnp.inf,
                      ref[:, :, None] - b[:, None])            # [B, P, C, H, d]
     yr = y[:, None] * jnp.exp(expo)
-    g = jnp.einsum("bpihc,bpjhc->bhpij", xl, yr,
-                   precision=lax.Precision.HIGHEST)
+    g = jnp.einsum("bpihc,bpjhc->bhpij", xl, yr, precision=hi)
+    if not floor:
+        bs = subs(b)
+        within = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+        e = jnp.exp(jnp.where(within[:, :, None, None],
+                              bs[:, :, :, None] - bs[:, :, None], -jnp.inf))
+        own = jnp.einsum("bpihc,bpjhc,bpijhc->bhpij", subs(x), subs(y), e,
+                         precision=hi)                     # [B, H, P, sub, sub]
+        # each sub-chunk's own block onto the diagonal
+        g = g + (own[:, :, :, :, None, :] * jnp.eye(P_, dtype=own.dtype)[
+            :, None, :, None]).reshape(g.shape)
     return g.reshape(B, H, C, C)
 
 
-def kda_heads(aq, ak, av, f, a, lower_bound: float):
+def kda_heads(aq, ak, av, f, a, lower_bound: float | None):
     """A KDA layer's heads from their convolved projections, ``[..., dk]``
     a head: ``q`` (unit length times ``dk ** -0.5``), ``k`` (unit length),
-    ``v`` and the log-decay ``g = lower_bound * sigmoid(a * f)`` (``f`` the
-    decay gate's logits ``[..., dk]``, ``a`` the head's rate, broadcast
-    against it), all float32. The one definition: the models call it on a
-    window or a token, ``ops/pallas/kda_scan.py`` on a block."""
+    ``v`` and the log-decay ``g`` (``f`` the decay gate's logits ``[...,
+    dk]``, ``a`` the head's rate, broadcast against it), all float32. The
+    gate has two forms, the layer's configuration says which: BOUNDED by a
+    floor, ``g = lower_bound * sigmoid(a * f)`` in ``[lower_bound, 0)``, or
+    (``lower_bound`` None) Kimi Linear's own with no floor, ``g = -a *
+    softplus(f)``. The one definition: the models call it on a window or a
+    token, ``ops/pallas/kda_scan.py`` on a block."""
     f32 = lambda t: t.astype(jnp.float32)
     unit = lambda t: t * lax.rsqrt(jnp.sum(t * t, -1, keepdims=True) + 1e-6)
-    g = lower_bound * jax.nn.sigmoid(f32(a) * f32(f))
+    if lower_bound is None:
+        g = -f32(a) * jax.nn.softplus(f32(f))
+    else:
+        g = lower_bound * jax.nn.sigmoid(f32(a) * f32(f))
     return unit(f32(aq)) * aq.shape[-1] ** -0.5, unit(f32(ak)), f32(av), g
 
 
-def kda_chunk(S, q, k, v, g, beta, sub: int):
+def kda_chunk(S, q, k, v, g, beta, sub: int, floor: bool = True):
     """One chunk of the delta rule with a per-channel decay, all its tokens
     at once: state ``S [B, H, dk, dv]`` before the chunk, ``q, k, g [B, C,
-    H, dk]`` (``g`` the tokens' log-decays, ``<= 0``), ``v [B, C, H, dv]``,
-    ``beta [B, C, H]``, all float32 -> ``(o [B, C, H, dv], S')``. Equal to
+    H, dk]`` (``g`` the tokens' log-decays, ``<= 0``; ``floor``: no lower
+    than ``-80 / sub``, else the decay grams take the form that needs no
+    floor), ``v [B, C, H, dv]``, ``beta [B, C, H]`` (in ``[0, 2)``), all
+    float32 -> ``(o [B, C, H, dv], S')``. Equal to
     ``C`` steps of :func:`kda_step` in exact arithmetic: with ``b`` the
     running sum of ``g``, the tokens' corrections ``Delta`` solve the unit
     lower-triangular system ``(I + Diag(beta) A) Delta = Diag(beta) (V - (K
@@ -696,8 +723,8 @@ def kda_chunk(S, q, k, v, g, beta, sub: int):
     hi = lax.Precision.HIGHEST
     C = q.shape[1]
     b = jnp.cumsum(g, axis=1)
-    A = jnp.tril(_decay_gram(k, k, b, sub), -1)
-    Bm = jnp.tril(_decay_gram(q, k, b, sub))
+    A = jnp.tril(_decay_gram(k, k, b, sub, floor), -1)
+    Bm = jnp.tril(_decay_gram(q, k, b, sub, floor))
     bh = beta.transpose(0, 2, 1)[..., None]                   # [B, H, C, 1]
     M = -bh * A
     inv = jnp.eye(C, dtype=M.dtype) + M
@@ -735,13 +762,14 @@ def _kda_kernel_ok(head_dim: int) -> bool:
 
 
 def kda_window(aq, ak, av, f_low, f_up, dt_bias, rate, beta, real, *,
-               lower_bound: float, chunk: int, sub: int):
+               lower_bound: float | None, chunk: int, sub: int):
     """The delta rule with a per-channel decay over whole windows, from the
     zero state, ``chunk`` tokens at a time: ``aq, ak, av [B, T, H * dk]``
     the CONVOLVED projections, ``f_low [B, T, R]`` and ``f_up [R, H * dk]``
     the decay gate's two factors, ``dt_bias [H * dk]``, ``rate [H]``
     (``exp(A_log)``), ``beta [B, T, H]`` float32, ``real [B, T]`` (1 at a
-    token, 0 at a pad: a pad gets ``beta = 0`` and no decay) -> ``(o [B, T,
+    token, 0 at a pad: a pad gets ``beta = 0`` and no decay),
+    ``lower_bound`` the gate's floor or None (:func:`kda_heads`) -> ``(o [B, T,
     H, dk] float32, S [B, H, dk, dk] float32)``, ``S`` the state after each
     row's last real token. One algorithm, two executions chosen by what
     can be observed (:func:`_kda_kernel_ok`): ONE kernel with a head's
@@ -768,7 +796,8 @@ def kda_window(aq, ak, av, f_low, f_up, dt_bias, rate, beta, real, *,
         q, k, v, g = kda_heads(heads(aq_c), heads(ak_c), heads(av_c),
                                heads(f), rate[:, None], lower_bound)
         o, S = kda_chunk(S, q, k, v, g * real_c[..., None, None],
-                         beta_c * real_c[..., None], sub)
+                         beta_c * real_c[..., None], sub,
+                         floor=lower_bound is not None)
         return S, o
 
     S, o = lax.scan(step, jnp.zeros((B, H, dk, dk), jnp.float32),

@@ -128,19 +128,40 @@ def _scan_kernel(aq_ref, ak_ref, av_ref, fl_ref, fu_ref, bias_ref, rate_ref,
     # the decay grams A[t, j] = (k_t e^{b_t}) . (k_j e^{-b_j}) and B_m (q for
     # the first k), sub-chunk by sub-chunk of t: the exponent is split at
     # the sub-chunk's first token, so e^{b_t - b_0} <= 1 and, for the j it
-    # may see, e^{b_0 - b_j} <= e^{sub * |lower_bound|}
+    # may see, e^{b_0 - b_j} <= e^{sub * |lower_bound|}. A gate with no
+    # floor (lower_bound None) has no such bound: the split then serves the
+    # j of EARLIER sub-chunks alone (both factors at most 1), and inside
+    # the sub-chunk the exponent is b_t - b_j itself, a column j at a time
+    # (ops/attention.py::_decay_gram)
     token = lax.broadcasted_iota(jnp.int32, (C, dk), 0)
+    floor = lower_bound is not None
 
     def gram(p, _):
         at = pl.ds(pl.multiple_of(p * sub, sub), sub)
         b_p = b_scr[:, at, :]
         first = b_p[:, :1, :]
         w = jnp.exp(b_p - first)
-        seen = jnp.where(token >= (p + 1) * sub, 0.0,
+        seen = jnp.where(token >= (p + int(floor)) * sub, 0.0,
                          k_scr[...] * jnp.exp(first - b_scr[...]))
         G = _dot(jnp.concatenate([k_scr[:, at, :] * w, q_scr[:, at, :] * w],
                                  axis=1), seen, _NT)            # [hb, 2 sub, C]
-        a_scr[:, at, :], bm_scr[:, at, :] = G[:, :sub], G[:, sub:]
+        Ga, Gb = G[:, :sub], G[:, sub:]
+        if not floor:
+            k_p, q_p = k_scr[:, at, :], q_scr[:, at, :]
+            t_own = lax.broadcasted_iota(jnp.int32, (sub, dk), 0)
+            column_of = lax.broadcasted_iota(jnp.int32, (sub, C), 1)
+
+            def column(j, acc):
+                one = pl.ds(p * sub + j, 1)
+                e = k_scr[:, one, :] * jnp.exp(jnp.where(
+                    t_own >= j, b_p - b_scr[:, one, :], -jnp.inf))
+                here = column_of == p * sub + j
+                return tuple(
+                    jnp.where(here, jnp.sum(x * e, -1, keepdims=True), a)
+                    for x, a in zip((k_p, q_p), acc))
+
+            Ga, Gb = lax.fori_loop(0, sub, column, (Ga, Gb))
+        a_scr[:, at, :], bm_scr[:, at, :] = Ga, Gb
         return 0
 
     lax.fori_loop(0, C // sub, gram, 0)
@@ -183,13 +204,15 @@ def _scan_kernel(aq_ref, ak_ref, av_ref, fl_ref, fu_ref, bias_ref, rate_ref,
 # it are traced once a signature a process and lowered once a program.
 @functools.partial(jax.jit, static_argnames=("lower_bound", "chunk", "sub"))
 def kda_chunk_scan(aq, ak, av, f_low, f_up, dt_bias, rate, beta, real, *,
-                   lower_bound: float, chunk: int, sub: int):
+                   lower_bound: float | None, chunk: int, sub: int):
     """The delta rule with a per-channel decay over whole windows, from the
     zero state: ``aq, ak, av [B, T, H * dk]`` the CONVOLVED projections
     (the activations' type), ``f_low [B, T, R]`` and ``f_up [R, H * dk]``
     the decay gate's two factors, ``dt_bias [H * dk]`` and ``rate [H]``
     (``exp(A_log)``) float32, ``beta [B, T, H]`` float32, ``real [B, T]``
-    (1 at a token, 0 at a pad: a pad gets ``beta = 0`` and no decay) ->
+    (1 at a token, 0 at a pad: a pad gets ``beta = 0`` and no decay),
+    ``lower_bound`` the decay gate's floor or None for the gate that has
+    none (:func:`ops.attention.kda_heads`) ->
     ``(o [B, T, H, dk] float32, S [B, H, dk, dk] float32)``, ``S`` the
     state after each row's last real token. Equal to a ``lax.scan`` of
     :func:`ops.attention.kda_chunk` over chunks of ``chunk`` tokens."""

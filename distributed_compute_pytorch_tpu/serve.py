@@ -1209,6 +1209,10 @@ class ContinuousBatcher:
             # the plan: ``waste``'s two parked counts together), for which
             # the paged decode kernels attend nothing
             "decode_rows_parked": 0,
+            # slot-ticks of the rows IN a plan of a model with state layers:
+            # the rows whose per-slot state each such layer reads and
+            # rewrites, summed over ticks (0 for a model without one)
+            "state_rows_advanced": 0,
             # deliveries that closed a gap (every harvest handing a request
             # new tokens, but the request's first), split by whether the
             # device ran admission between the two segments that delivered
@@ -3464,6 +3468,8 @@ class ContinuousBatcher:
                 table[b].remaining -= take
                 ticks_charged[ri] += take
                 self.waste["planned_ticks"] += self.S
+            if "state" in self._cache_kinds:
+                self.stats["state_rows_advanced"] += len(plan) * self.S
             if chaos is not None and chaos.on_segment is not None:
                 # host observation hook: drills flip drain flags /
                 # cancel requests at a deterministic segment

@@ -33,30 +33,34 @@ KW = dict(lower_bound=LOW, chunk=KDA_CHUNK, sub=KDA_SUB)
 TOL = 2e-5
 
 
-def inputs(B, T, seed=0, at_bound=False):
+def inputs(B, T, seed=0, at_bound=False, deep=False, beta_max=1.0):
     """Convolved projections, the gate's factors, ``beta``: the arguments
     of ``kda_window`` but ``real``. ``dt_bias`` at the family's std of 3
     (decays from fast to slow), or ``at_bound`` 40 in every channel: every
-    decay AT the gate's lower bound."""
+    decay AT the gate's lower bound, or ``deep`` spread evenly from -6 to
+    40: under the gate with no floor, log-decays from -0.002 down to -40 a
+    token and past it. ``beta`` in ``(0, beta_max)``."""
     ks = jax.random.split(jax.random.key(seed), 8)
     aq, ak, av = (jax.nn.silu(jax.random.normal(k, (B, T, H * DK)))
                   for k in ks[:3])
     f_low = jax.random.normal(ks[3], (B, T, R))
     f_up = jax.random.normal(ks[4], (R, H * DK)) * R ** -0.5
-    dt_bias = (jnp.full((H * DK,), 40.0) if at_bound
-               else 3.0 * jax.random.normal(ks[5], (H * DK,)))
+    dt_bias = (jnp.full((H * DK,), 40.0) if at_bound else
+               jax.random.uniform(ks[5], (H * DK,), minval=-6.0, maxval=40.0)
+               if deep else 3.0 * jax.random.normal(ks[5], (H * DK,)))
     rate = jnp.exp(0.3 * jax.random.normal(ks[6], (H,)))
-    beta = jax.nn.sigmoid(jax.random.normal(ks[7], (B, T, H)))
+    beta = beta_max * jax.nn.sigmoid(jax.random.normal(ks[7], (B, T, H)))
     return aq, ak, av, f_low, f_up, dt_bias, rate, beta
 
 
-def token_steps(aq, ak, av, f_low, f_up, dt_bias, rate, beta, real):
+def token_steps(aq, ak, av, f_low, f_up, dt_bias, rate, beta, real,
+                lower_bound=LOW):
     """``T`` steps of ``kda_step``; a pad leaves the state as it was."""
     B, T, _ = aq.shape
     heads = lambda t: t.reshape(t.shape[:-1] + (H, DK))
     f = jnp.dot(f_low, f_up, preferred_element_type=jnp.float32) + dt_bias
     q, k, v, g = A.kda_heads(heads(aq), heads(ak), heads(av), heads(f),
-                             rate[:, None], LOW)
+                             rate[:, None], lower_bound)
 
     def step(S, xs):
         q, k, v, g, beta, real = xs
@@ -120,6 +124,53 @@ def test_decays_at_the_gates_lower_bound_stay_in_range(B, T):
     assert worst(o * on, o_step * on) < TOL and worst(S, S_step) < TOL
 
 
+# the decay gate's two forms (a floor of -5, or the softplus gate with none),
+# each at decays it may reach, and both ranges of beta
+GATES = {"floor": (LOW, False), "no_floor": (None, False),
+         "no_floor_deep": (None, True)}
+
+
+@pytest.mark.parametrize("B,T", [(1, 128), (3, 100)])
+@pytest.mark.parametrize("beta_max", [1.0, 2.0])
+@pytest.mark.parametrize("gate", list(GATES))
+def test_chunk_form_and_kernel_equal_the_token_steps_whatever_the_gate(
+        gate, beta_max, B, T):
+    """Both forms of the gate and both ranges of ``beta`` (GLM-5.3-Flash's
+    is ``floor`` at 1, Solar-Open2's ``no_floor`` at 2): the scan of
+    ``kda_chunk`` and the kernel against ``T`` steps of ``kda_step``,
+    finite everywhere, at the tolerance the bounded form has always had.
+    ``no_floor_deep``: log-decays down to -40 a token and past it, -640 over
+    a sub-chunk, where any form that divides a sub-chunk's decays out
+    overflows float32 (the control at the end); the form without a floor
+    takes differences only, so a decay that deep just underflows to the
+    zero it is. ``beta`` up to 2 doubles the entries of the triangular
+    system and changes nothing else."""
+    lower_bound, deep = GATES[gate]
+    args = inputs(B, T, seed=T + B, deep=deep, beta_max=beta_max)
+    real, _ = rows_of(B, T)
+    kw = dict(KW, lower_bound=lower_bound)
+    o_step, S_step = token_steps(*args, real, lower_bound=lower_bound)
+    on = real[..., None, None]
+    assert float(jnp.max(jnp.abs(o_step * on))) > 100 * TOL
+    for form in (kda_scan.kda_chunk_scan, A.kda_window):
+        o, S = form(*args, real, **kw)
+        assert np.isfinite(np.asarray(o)).all(), form
+        assert np.isfinite(np.asarray(S)).all(), form
+        assert worst(o * on, o_step * on) < TOL, form
+        assert worst(S, S_step) < TOL, form
+    if deep:
+        heads = lambda t: t.reshape(t.shape[:-1] + (H, DK))
+        f = jnp.dot(args[3], args[4]) + args[5]
+        q, k, v, g = A.kda_heads(*(heads(t) for t in args[:3]), heads(f),
+                                 args[6][:, None], None)
+        assert float(g.min()) < -40 and float(g.max()) > -0.01
+        # the control: the form that needs a floor, on these decays
+        o_floor, _ = A.kda_chunk(jnp.zeros((B, H, DK, DK)), q[:, :64],
+                                 k[:, :64], v[:, :64], g[:, :64],
+                                 args[7][:, :64], KDA_SUB, floor=True)
+        assert not np.isfinite(np.asarray(o_floor)).all()
+
+
 def test_products_in_bfloat16_fail_the_tolerance(monkeypatch):
     """The control: the same kernel with the operands of its float32
     products rounded to bfloat16 (one pass of the MXU in place of
@@ -148,9 +199,14 @@ def test_products_in_bfloat16_fail_the_tolerance(monkeypatch):
 # doubling at each of the inverse's two levels and two between them, the
 # state's read, delta, the output's, the state's update.
 _BODY_AT_MOST = (286, 13)
+# the form for a gate with no floor (PR 44): the same thirteen products and
+# one more loop of one body, a sub-chunk's sixteen columns (49 equations)
+_BODY_AT_MOST_NO_FLOOR = (335, 13)
 
 
-def test_kernel_body_does_not_grow_with_rows_or_window():
+@pytest.mark.parametrize("lower_bound,at_most", [
+    (LOW, _BODY_AT_MOST), (None, _BODY_AT_MOST_NO_FLOOR)])
+def test_kernel_body_does_not_grow_with_rows_or_window(lower_bound, at_most):
     """The build-cost guard (the form of ``tests/test_flash_attention.py::
     test_kernel_body_does_not_grow_with_block_offset_or_window``): the
     kernel is traced and lowered in every set-up, once a program of the
@@ -164,11 +220,12 @@ def test_kernel_body_does_not_grow_with_rows_or_window():
                 z(B, T, 128), z(128, 64 * 128), jnp.zeros((64 * 128,)),
                 jnp.ones((64,)), jnp.zeros((B, T, 64)), jnp.ones((B, T)))
         sizes.add(_kernel_sizes(
-            lambda *a: kda_scan.kda_chunk_scan(*a, **KW),
+            lambda *a: kda_scan.kda_chunk_scan(
+                *a, **dict(KW, lower_bound=lower_bound)),
             *args)["dcp_kda_chunk_scan"])
     assert len(sizes) == 1, sizes
     (eqns, dots), = sizes
-    assert dots == _BODY_AT_MOST[1] and eqns <= _BODY_AT_MOST[0], (eqns, dots)
+    assert dots == at_most[1] and eqns <= at_most[0], (eqns, dots)
 
 
 def _pjit_calls(jaxpr, name, out):

@@ -255,12 +255,15 @@ def test_latent_kernel_compiles_for_a_described_v5e(one_v5e_chip):
 
 
 @on_cpu
-@pytest.mark.parametrize("rows,window", [(1, 16384), (4, 2048)])
+@pytest.mark.parametrize("rows,window,lower_bound", [
+    (1, 16384, -5.0), (4, 2048, -5.0), (1, 2048, None), (4, 256, None)])
 def test_kda_scan_kernel_compiles_for_a_described_v5e(
-        rows, window, one_v5e_chip, monkeypatch):
+        rows, window, lower_bound, one_v5e_chip, monkeypatch):
     """Mosaic takes ``dcp_kda_chunk_scan`` (``ops/pallas/kda_scan.py``) at
     the long-context cell's shapes, the longest and the widest program of
-    its admission ladder: 64 heads of 128 in bf16, a gate of rank 128. (In
+    its admission ladder, and, in the form for a gate with no floor
+    (``lower_bound`` None), at the long-generation cell's: 64 heads of 128
+    in bf16, a gate of rank 128. (In
     this file because one process at a time may load the TPU's library:
     the fixture is this module's. Interpret mode passes forms that Mosaic
     aborts on: PERF.md section 7, "After PR 43" (4).)"""
@@ -279,7 +282,7 @@ def test_kda_scan_kernel_compiles_for_a_described_v5e(
         arg((128, wide)), arg((wide,), jnp.float32), arg((64,), jnp.float32),
         arg((rows, window, 64), jnp.float32),
         arg((rows, window), jnp.float32),
-        lower_bound=-5.0, chunk=64, sub=16).compile()
+        lower_bound=lower_bound, chunk=64, sub=16).compile()
     kda_scan.kda_chunk_scan.clear_cache()
     assert "tpu_custom_call" in compiled.as_text()
     assert "dcp_kda_chunk_scan" in compiled.as_text()
