@@ -75,8 +75,10 @@ NO tokens (cache kind ``"state"``): per row the state ``[H, dk, dv]`` and a
 TAIL of the last ``kda_conv - 1`` tokens' ``[q^, k^, v^]``. ``apply``
 (prefill) convolves the window, then runs the recurrence ``KDA_CHUNK``
 tokens at a time (``ops/attention.py::kda_window``: one Pallas kernel on a
-TPU, a scan of ``kda_chunk`` elsewhere); ``decode_step`` one step
-(``kda_step``). A pad token leaves the state as it
+TPU, a scan of ``kda_chunk`` elsewhere); ``decode_step`` one step for the
+rows in the plan (``kda_step_live``: one Pallas kernel on a TPU that moves
+a live row's state once each way and no parked row's, ``kda_step`` and a
+select elsewhere). A pad token leaves the state as it
 was, so what prefill hands decode is the state after each row's last REAL
 token. The chunked form divides a sub-chunk's decays out only under a
 floor; without one its decay grams take the form no ``g <= 0`` can
@@ -971,12 +973,10 @@ class HybridBlock:
                  for i, name in enumerate("qkv")]
             f_low, beta = self._kda_gates(params, x[:, 0])
             q, k, v, g = self._kda_heads(params, a, f_low)
-            o, S = A.kda_step(cache["state"], q, k, v, g, beta)
+            o, S = A.kda_step_live(cache["state"], q, k, v, g, beta, live)
             new_tail = window[:, 1:].reshape(B, -1).astype(tail.dtype)
             if live is not None:
-                on = live > 0.5
-                S = jnp.where(on[:, None, None, None], S, cache["state"])
-                new_tail = jnp.where(on[:, None], new_tail, tail)
+                new_tail = jnp.where((live > 0.5)[:, None], new_tail, tail)
             o = self._kda_out(params, x[:, 0], o)[:, None]
         return o, {"state": S, "tail": new_tail}
 

@@ -753,7 +753,8 @@ def whole_chunks(t, chunk: int):
 
 
 def _kda_kernel_ok(head_dim: int) -> bool:
-    """Whether a window's recurrence runs as the Pallas kernel: one TPU
+    """Whether a window's recurrence (and a token's step:
+    :func:`kda_step_live`) runs as the Pallas kernel: one TPU
     (no mesh context, as the pool's kernels: ``cache_update._pallas_ok``)
     and heads a whole number of lane tiles wide."""
     from distributed_compute_pytorch_tpu.core.mesh import current_mesh
@@ -815,6 +816,25 @@ def kda_step(S, q, k, v, g, beta):
     w = jnp.einsum("bhk,bhkv->bhv", k, S, precision=hi)
     S = S + k[..., None] * (beta[..., None] * (v - w))[:, :, None, :]
     return jnp.einsum("bhk,bhkv->bhv", q, S, precision=hi), S
+
+
+def kda_step_live(S, q, k, v, g, beta, live=None):
+    """:func:`kda_step` for the rows in the plan: ``live [B]`` (above 0.5:
+    the row advances; None: every row does) -> ``(o [B, H, dv], S')``, a
+    parked row's state as it was and its ``o`` of no use to anyone. One
+    algorithm, two executions chosen by what can be observed
+    (:func:`_kda_kernel_ok`): ONE kernel that moves a live row's state in
+    and out once and nothing of a parked row's
+    (``ops/pallas/kda_step.py``), or :func:`kda_step` and a select, the
+    portable form the kernel is tested against."""
+    if _kda_kernel_ok(S.shape[-2]) and S.shape[-2] == S.shape[-1]:
+        from distributed_compute_pytorch_tpu.ops.pallas.kda_step import (
+            kda_step_rows)
+        return kda_step_rows(S, q, k, v, g, beta, live)
+    o, new = kda_step(S, q, k, v, g, beta)
+    if live is not None:
+        new = jnp.where((live > 0.5)[:, None, None, None], new, S)
+    return o, new
 
 
 def cache_verify_and_attend(q, k, v, cache, positions, *, slot_mask=None):

@@ -14,6 +14,10 @@ The kernels, by the names a profile shows:
   ``dcp_paged_latent_decode_attn``;
 - ``kda_scan.py``: ``dcp_kda_chunk_scan`` (the KDA recurrence over a
   window, a head's state in VMEM from chunk to chunk);
+- ``kda_step.py``: ``dcp_kda_step`` (the KDA state's one-token step: a
+  live row's state read once and written once, a parked row's not at all;
+  chosen by ``ops/attention.py::kda_step_live`` where
+  ``_kda_kernel_ok`` holds: one TPU, no mesh, heads of whole lane tiles);
 - ``fused_adamw.py``: the fused AdamW update.
 """
 

@@ -561,6 +561,20 @@ def test_serving_is_greedy_equal_to_the_full_forward_and_counts():
     assert again.status == "ok" and cb.stats["prefill_rows"] > 0
 
 
+def test_with_the_step_kernel_chosen_the_engine_serves_the_same_tokens(
+        monkeypatch):
+    """The one-token step as the kernel (``ops/pallas/kda_step.py``,
+    interpreted) through the scheduler with slots parked: the tokens are
+    the portable form's and ``state_rows_advanced`` counts the same rows."""
+    from tests.test_kda_step import serve_portable_then_with_the_kernel
+    (want, portable), (got, kernel), traced = (
+        serve_portable_then_with_the_kernel(build, monkeypatch))
+    assert traced and got == want
+    assert (kernel["state_rows_advanced"] == portable["state_rows_advanced"]
+            > 0)
+    assert kernel["decode_rows_parked"] == portable["decode_rows_parked"] > 0
+
+
 def test_a_reconstruction_rebuilds_state_tails_and_pooled_keys():
     """A device fault mid-stream: every leaf is zeroed and the rows are
     re-prefilled from their tokens (state, tails and pooled keys with

@@ -205,6 +205,20 @@ def test_serving_is_greedy_equal_to_the_full_forward_and_counts():
     assert st["expert_assignments"] > st["expert_assignments_held"] > 0
 
 
+def test_with_the_step_kernel_chosen_the_engine_serves_the_same_tokens(
+        monkeypatch):
+    """The one-token step as the kernel (``ops/pallas/kda_step.py``,
+    interpreted) through the scheduler with slots parked: the tokens are
+    the portable form's and ``state_rows_advanced`` counts the same rows."""
+    from tests.test_kda_step import serve_portable_then_with_the_kernel
+    (want, portable), (got, kernel), traced = (
+        serve_portable_then_with_the_kernel(build, monkeypatch))
+    assert traced and got == want
+    assert (kernel["state_rows_advanced"] == portable["state_rows_advanced"]
+            > 0)
+    assert kernel["decode_rows_parked"] == portable["decode_rows_parked"] > 0
+
+
 def test_a_model_without_state_layers_advances_no_state_rows():
     """The counter is of models with state layers: the tiny preset (rings
     and a pool) keeps it at 0."""

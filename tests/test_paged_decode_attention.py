@@ -288,6 +288,29 @@ def test_kda_scan_kernel_compiles_for_a_described_v5e(
     assert "dcp_kda_chunk_scan" in compiled.as_text()
 
 
+@on_cpu
+@pytest.mark.parametrize("rows", [160, 32])
+def test_kda_step_kernel_compiles_for_a_described_v5e(
+        rows, one_v5e_chip, monkeypatch):
+    """Mosaic takes ``dcp_kda_step`` (``ops/pallas/kda_step.py``) at the
+    long-generation cell's shape and the long-context cell's: 160 and 32
+    slots of 64 heads of 128 x 128 float32, the vectors turned inside the
+    kernel. (In this file for the fixture's sake, as the test above.)"""
+    from distributed_compute_pytorch_tpu.ops.pallas import kda_step
+    monkeypatch.setattr(kda_step, "_use_interpret", lambda: False)
+    kda_step.kda_step_rows.clear_cache()
+
+    def arg(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_v5e_chip)
+    vec = arg(rows, 64, 128)
+    compiled = kda_step.kda_step_rows.lower(
+        arg(rows, 64, 128, 128), vec, vec, vec, vec, arg(rows, 64),
+        arg(rows)).compile()
+    kda_step.kda_step_rows.clear_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "dcp_kda_step" in compiled.as_text()
+
+
 @on_tpu
 @pytest.mark.parametrize("shape", ["llama2_7b_mha_bf16", "f32_pool_hd256"])
 def test_compiled_kernel_at_budget_bound_chunks(shape):
