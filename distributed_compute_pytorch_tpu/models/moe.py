@@ -342,6 +342,29 @@ class MoELayer:
         return y.reshape(B, T, d), aux
 
 
+# What ``dcp_held_experts`` (``ops/pallas/held_experts.py``) takes to stream
+# the weights of EVERY held expert over what ``HeldExperts._dense`` takes, so
+# the kernel is the faster form where a call's rows are expected to choose a
+# smaller share of the experts than this. Alone on a v5e, every expert
+# chosen, bfloat16: 36 experts of 4096 x 2048 over 32 rows 2.46-2.54 ms
+# against 2.51 (0.98-1.01 over blocks of 1, 2 and 4 MB), 16 of 2048 x 2048
+# over 20 rows 0.566 against 0.564 (1.00), 32 of 2048 x 768 over 64 rows
+# 0.427 against 0.454 (0.94); with half chosen 0.52, 0.53, 0.50 of
+# ``_dense`` (PERF.md, PR 46). Set at the low end of those readings: above
+# it a tick's rows choose all but a few percent of the experts, and there is
+# a reading's own spread to lose and nothing to win.
+CHOSEN_STREAM_RATIO = 0.95
+
+
+def _chosen_kernel_ok(d_model: int, d_ff: int) -> bool:
+    """Whether a tick's held experts may run as the Pallas kernel over the
+    chosen experts: one TPU (no mesh context, as the pool's kernels and the
+    KDA kernels: ``ops/attention.py::_kda_kernel_ok``) and both widths a
+    whole number of lane tiles."""
+    return (jax.default_backend() == "tpu" and current_mesh() is None
+            and d_model % 128 == 0 and d_ff % 128 == 0)
+
+
 @dataclass(frozen=True)
 class SigmoidRouter:
     """The router of :class:`HeldExperts` that is ONE matrix (the
@@ -460,17 +483,25 @@ class HeldExperts:
     already means) so that the counts can tell it from an expert held
     elsewhere.
 
-    Two static shapes, two forms (both read every held expert's weights
-    once): up to ``dense_max_tokens`` tokens (a decode tick: a handful of
-    tokens an expert, bound by the weight stream) every held expert runs
-    over every token under its weight, zero where the token did not pick
-    it; above (an admission wave) the held assignments are sorted by
-    expert and go through grouped matrix products (``lax.ragged_dot``) in
-    windows of a static number of rows: one window of ``window_rows`` when
-    the load is near uniform, and what it leaves, under skew, in windows
-    of ``tail_rows`` (an eighth of it), so the cost follows the
-    assignments and never the worst case. ``token_mask`` (1 = real) keeps
-    pad tokens out: they route nowhere.
+    One algorithm, three executions chosen by what is static at trace time
+    (:meth:`form`: the tokens of the call, the router's shape, the
+    backend; no option chooses). Up to ``dense_max_tokens`` tokens (a
+    decode tick: a handful of tokens an expert, bound by the weight
+    stream) every held expert runs over every token under its weight, zero
+    where the token did not pick it: a batched product that reads every
+    held expert's weights once (``_dense``), or, where the rows are
+    expected to choose few enough of the held experts
+    (:meth:`expected_chosen_share` under ``CHOSEN_STREAM_RATIO``) and a
+    kernel may run (:func:`_chosen_kernel_ok`), ONE kernel that reads the
+    weights of the experts some unmasked row chose and of no other
+    (``_chosen``: ``ops/pallas/held_experts.py``). Above (an admission
+    wave) the held assignments are sorted by expert and go through grouped
+    matrix products (``lax.ragged_dot``) in windows of a static number of
+    rows: one window of ``window_rows`` when the load is near uniform, and
+    what it leaves, under skew, in windows of ``tail_rows`` (an eighth of
+    it), so the cost follows the assignments and never the worst case
+    (``_sorted``). ``token_mask`` (1 = real) keeps pad tokens and parked
+    rows out: they route nowhere and choose no expert.
     """
 
     d_model: int
@@ -557,6 +588,32 @@ class HeldExperts:
         y = mm(h, ex["down"])                                    # [n, N, d]
         return jnp.einsum("end,ne->nd", y, we).astype(x.dtype)
 
+    def _chosen(self, ex, x, local, w):
+        """``_dense`` from the weights of the experts some assignment fell
+        on, and of no other: one kernel over their compacted list."""
+        from distributed_compute_pytorch_tpu.ops.pallas.held_experts import (
+            held_experts_chosen)
+        return held_experts_chosen(ex["gate"], ex["up"], ex["down"], x,
+                                   local, w, swiglu_limit=self.swiglu_limit)
+
+    def expected_chosen_share(self, n_tokens: int) -> float:
+        """Share of the experts that ``n_tokens`` tokens send at least one
+        assignment to under a uniform router: ``1 - (1 - k / E) ^ N``."""
+        return 1.0 - (1.0 - self.top_k / self.num_experts) ** n_tokens
+
+    def form(self, n_tokens: int) -> str:
+        """The execution a call of ``n_tokens`` tokens takes: ``"sorted"``
+        above ``dense_max_tokens``; below, ``"chosen"`` where the tokens
+        are expected to choose a smaller share of the experts than the
+        kernel's stream costs over ``_dense``'s and the kernel may run,
+        else ``"dense"``."""
+        if n_tokens > self.dense_max_tokens:
+            return "sorted"
+        if (self.expected_chosen_share(n_tokens) < CHOSEN_STREAM_RATIO
+                and _chosen_kernel_ok(self.d_model, self.d_ff)):
+            return "chosen"
+        return "dense"
+
     def _sorted(self, ex, x, local, w):
         """Held assignments sorted by expert, grouped products over one
         window of ``window_rows`` sorted rows and, for what it leaves,
@@ -616,11 +673,13 @@ class HeldExperts:
         """``x [..., d]``, the router state ``[..., R]`` of the layer below
         (None: none, or the first layer) -> (this chip's partial ``m``, the
         state this layer's router hands up).
-        ``counts_sink`` (a list) is handed one int32 vector ``[2 +
+        ``counts_sink`` (a list) is handed one int32 vector ``[4 +
         count]``: the assignments of the unmasked tokens, those among
-        them that fell on held experts, and the held experts' loads; with
-        a ``skip_index``, ``[3 + count]``: those that fell on the skip
-        choice come third."""
+        them that fell on held experts, the held experts at least one of
+        them fell on, the held experts (``count``: summed over layers and
+        ticks, what the chosen are a share of), and the held experts'
+        loads; with a ``skip_index``, ``[5 + count]``: those that fell on
+        the skip choice come third."""
         first, n = self.held
         shape = x.shape
         x = x.reshape(-1, shape[-1])
@@ -640,11 +699,11 @@ class HeldExperts:
             if self.skip_index is not None:
                 head.append(jnp.sum((idx == self.skip_index)
                                     * live[:, None]))
+            head += [jnp.sum(load > 0), n]
             counts_sink.append(jnp.concatenate(
                 [jnp.stack(head), load]).astype(jnp.int32))
         with scope("experts"):
-            form = (self._dense if x.shape[0] <= self.dense_max_tokens
-                    else self._sorted)
+            form = getattr(self, "_" + self.form(x.shape[0]))
             y = form(params["experts"], x, local, w)
         if self.shared_d_ff:
             with scope("shared_expert"):

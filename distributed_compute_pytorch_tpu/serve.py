@@ -975,6 +975,9 @@ class ContinuousBatcher:
             # assignments on a choice that no chip holds (a skip)
             + (("expert_assignments_skipped",)
                if getattr(model, "counts_skips", False) else ())
+            # the held experts some row in the plan chose, and the held
+            # experts, a layer a tick: what a tick needs of their weights
+            + ("experts_chosen", "experts_held_ticks")
             + tuple(f"expert_load_{e}" for e in range(held))
             if held else ()) + self._select_keys
         # which engine writes the pool each tick is decided by where the

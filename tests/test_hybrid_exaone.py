@@ -294,9 +294,11 @@ def test_a_skewed_router_drops_nothing(form):
                                            got - shared_only)))) < 1e-5
     counts = np.asarray(sink[0])
     n_live = int(mask.sum())
-    assert counts[0] == 2 * n_live and counts[2] == n_live   # expert 0: all
-    assert counts[3] == 0                                    # expert 1: none
-    assert counts[1] == counts[2:].sum() > layer.window_rows(600)
+    # the head: assignments, held, held experts chosen, held experts
+    assert counts[0] == 2 * n_live and counts[4] == n_live   # expert 0: all
+    assert counts[5] == 0                                    # expert 1: none
+    assert counts[1] == counts[4:].sum() > layer.window_rows(600)
+    assert counts[2] == (counts[4:] > 0).sum() < counts[3] == 4
 
 
 @pytest.mark.parametrize("held_rows", [0, 700, 1024, 1100, 1200])

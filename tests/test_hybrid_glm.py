@@ -575,6 +575,24 @@ def test_with_the_step_kernel_chosen_the_engine_serves_the_same_tokens(
     assert kernel["decode_rows_parked"] == portable["decode_rows_parked"] > 0
 
 
+def test_with_the_experts_kernel_chosen_the_engine_serves_the_same_tokens(
+        monkeypatch):
+    """The held experts' decode form as the kernel over the chosen experts
+    (``ops/pallas/held_experts.py``, interpreted) through the scheduler with
+    slots parked (four sparse layers of top-2 of 16, four held, the clamp,
+    the shared expert): the tokens are the dense form's, so are the
+    experts' counts, and the chosen experts are a share of the held ones."""
+    from tests.test_held_experts_kernel import serve_dense_then_chosen
+    (want, dense), (got, chosen), traced = serve_dense_then_chosen(
+        build, monkeypatch)
+    assert traced and got == want
+    keys = [k for k in dense if k.startswith("expert")]
+    assert "experts_chosen" in keys and "expert_load_0" in keys
+    assert {k: chosen[k] for k in keys} == {k: dense[k] for k in keys}
+    assert 0 < chosen["experts_chosen"] < chosen["experts_held_ticks"]
+    assert chosen["decode_rows_parked"] == dense["decode_rows_parked"] > 0
+
+
 def test_a_reconstruction_rebuilds_state_tails_and_pooled_keys():
     """A device fault mid-stream: every leaf is zeroed and the rows are
     re-prefilled from their tokens (state, tails and pooled keys with

@@ -367,7 +367,11 @@ def test_with_every_expert_held_the_layer_is_the_uncut_references(form):
         state.reshape(-1, 16) - want_state))) < 1e-5
     total, held, skipped = (int(c) for c in counts[0][:3])
     assert total == 150 and held + skipped == total and skipped > 0
-    assert int(counts[0][3:].sum()) == held
+    # after the head: the held experts chosen, the held experts, the loads
+    chosen, of = (int(c) for c in counts[0][3:5])
+    assert 1 <= chosen <= of == 4
+    assert int(counts[0][5:].sum()) == held
+    assert int((counts[0][5:] > 0).sum()) == chosen
 
 
 def test_partial_rotation_turns_the_first_channels_only():
@@ -478,3 +482,21 @@ def test_the_training_path_and_half_named_kinds_refuse():
     with pytest.raises(ValueError, match="norm_placement"):
         HybridConfig(scale_residual_merge=True)
     assert isinstance(model, HybridLM)
+
+
+def test_with_the_experts_kernel_chosen_the_engine_serves_the_same_tokens(
+        monkeypatch):
+    """The held experts' decode form as the kernel over the chosen experts
+    (``ops/pallas/held_experts.py``, interpreted) through the scheduler with
+    slots parked (top-1 with the skip choice, the router's state carried
+    from layer to layer): the tokens are the dense form's, so are the
+    experts' counts, and the chosen experts are a share of the held ones."""
+    from tests.test_held_experts_kernel import serve_dense_then_chosen
+    (want, dense), (got, chosen), traced = serve_dense_then_chosen(
+        build, monkeypatch)
+    assert traced and got == want
+    keys = [k for k in dense if k.startswith("expert")]
+    assert "experts_chosen" in keys and "expert_load_0" in keys
+    assert {k: chosen[k] for k in keys} == {k: dense[k] for k in keys}
+    assert 0 < chosen["experts_chosen"] < chosen["experts_held_ticks"]
+    assert chosen["decode_rows_parked"] == dense["decode_rows_parked"] > 0

@@ -172,29 +172,32 @@ def test_the_state_is_aliased_and_the_entry_does_not_grow_with_rows():
     assert dots == _BODY_AT_MOST[1] and eqns <= _BODY_AT_MOST[0], (eqns, dots)
 
 
-def serve_portable_then_with_the_kernel(build, monkeypatch):
-    """For the families' engine tests (``tests/test_hybrid_solar.py``,
-    ``tests/test_hybrid_glm.py``): three requests through four slots, one
-    ending early, so a slot is parked from the first segment and another
-    from the second; first in the portable form, then with the kernel chosen
-    (interpreted; the choice is by backend and width, the caller's test
-    makes it) -> ``((tokens, stats) portable, (tokens, stats) kernel, the
-    state blocks the kernel was traced on)``."""
+def serve_with_slots_parked(build, monkeypatch):
+    """Three requests through four slots, one ending early, so a slot is
+    parked from the first segment and another from the second, by an engine
+    with programs of its own (engines of one configuration share theirs,
+    and a choice of execution is read while a program is traced) ->
+    ``(tokens, stats)``."""
     rng = np.random.default_rng(8)
     reqs = [Request(tokens=[int(t) for t in rng.integers(1, 512, n)],
                     max_new=m) for n, m in ((17, 4), (30, 12), (9, 12))]
+    monkeypatch.setattr(serve_module, "_PROGRAM_CACHE", {})
+    model, params = build()
+    cb = ContinuousBatcher(model, params, slots=4, t_max=128,
+                           prompt_buf=64, segment=4)
+    out = cb.serve_detailed(reqs)
+    assert all(r.status == "ok" for r in out)
+    return [list(r.tokens) for r in out], cb.stats_snapshot()["stats"]
 
-    def serve():
-        # programs of its own (engines of one configuration share theirs):
-        # the choice is read while a program is traced
-        monkeypatch.setattr(serve_module, "_PROGRAM_CACHE", {})
-        model, params = build()
-        cb = ContinuousBatcher(model, params, slots=4, t_max=128,
-                               prompt_buf=64, segment=4)
-        out = cb.serve_detailed(reqs)
-        assert all(r.status == "ok" for r in out)
-        return [list(r.tokens) for r in out], cb.stats_snapshot()["stats"]
 
+def serve_portable_then_with_the_kernel(build, monkeypatch):
+    """For the families' engine tests (``tests/test_hybrid_solar.py``,
+    ``tests/test_hybrid_glm.py``): :func:`serve_with_slots_parked` first in
+    the portable form, then with the kernel chosen (interpreted; the choice
+    is by backend and width, the caller's test makes it) -> ``((tokens,
+    stats) portable, (tokens, stats) kernel, the state blocks the kernel
+    was traced on)``."""
+    serve = lambda: serve_with_slots_parked(build, monkeypatch)
     portable = serve()
     monkeypatch.setattr(A, "_kda_kernel_ok", lambda head_dim: True)
     traced, body = [], kda_step._step_kernel

@@ -311,6 +311,33 @@ def test_kda_step_kernel_compiles_for_a_described_v5e(
     assert "dcp_kda_step" in compiled.as_text()
 
 
+@on_cpu
+@pytest.mark.parametrize("n,d,f,rows,k,limit", [
+    (36, 4096, 2048, 32, 8, 10.0), (16, 2048, 2048, 20, 1, 0.0),
+    (32, 2048, 768, 64, 8, 0.0)],
+    ids=["glm53flash", "zaya1", "joyai"])
+def test_held_experts_kernel_compiles_for_a_described_v5e(
+        n, d, f, rows, k, limit, one_v5e_chip, monkeypatch):
+    """Mosaic takes ``dcp_held_experts`` (``ops/pallas/held_experts.py``) at
+    the ticks of the three cells whose rows choose few enough of their held
+    experts: bfloat16 blocks of 2 MB (1.5 where ``f`` is 768) in two buffers
+    each beside the rows and the float32 result, inside the VMEM the call
+    asks for. (In this file for the fixture's sake, as the tests above.)"""
+    from distributed_compute_pytorch_tpu.ops.pallas import held_experts
+    monkeypatch.setattr(held_experts, "_use_interpret", lambda: False)
+    held_experts.held_experts_chosen.clear_cache()
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+    compiled = held_experts.held_experts_chosen.lower(
+        arg((n, d, f)), arg((n, d, f)), arg((n, f, d)), arg((rows, d)),
+        arg((rows, k), jnp.int32), arg((rows, k), jnp.float32),
+        swiglu_limit=limit).compile()
+    held_experts.held_experts_chosen.clear_cache()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert "dcp_held_experts" in compiled.as_text()
+
+
 @on_tpu
 @pytest.mark.parametrize("shape", ["llama2_7b_mha_bf16", "f32_pool_hd256"])
 def test_compiled_kernel_at_budget_bound_chunks(shape):
