@@ -111,9 +111,12 @@ x)`` and merged back as ``H_res x + H_post^T f`` (``_enter`` / ``_leave``).
 The layers differ in shape, so the parameters are a per-layer list
 (``params["layers"][i]``), not one stacked tree, and the serving layer
 asks for the block and the parameters of layer ``i`` (``layer_block`` /
-``layer_params``) and the block for its ``cache_kind``: a full layer
-keeps the paged pool, a sliding layer a ring of ``ring_tokens`` slots a
-row that does not grow with the horizon (``ops/attention.py::
+``layer_params``) and the block for what it keeps (``cache_leaves``: the
+one declaration of a layer's cache format, leaf by leaf, each keyed by
+block or by slot; ``apply`` hands admission the same leaves by name in
+``kv_sink``, ``decode_step`` takes and returns them): a full layer keeps
+the paged pool, a sliding layer a ring of ``ring_tokens`` slots a row
+that does not grow with the horizon (``ops/attention.py::
 ring_write_and_attend``).
 
 Served path only: ``apply`` (the whole forward, what the tests compare
@@ -354,6 +357,14 @@ def _dense(din, dout):
     return L.Dense(din, dout, use_bias=False)
 
 
+def _real_tokens(kv_mask, B: int, T: int):
+    """Real tokens a row of the window ``[B, T]`` holds (``kv_mask`` 1 =
+    real, pads only trail; None: all of them), int32 ``[B]``."""
+    if kv_mask is None:
+        return jnp.full((B,), T, jnp.int32)
+    return jnp.sum(kv_mask > 0.5, axis=1).astype(jnp.int32)
+
+
 @dataclass(frozen=True)
 class HybridBlock:
     """One layer: its mixer and its feed-forward, each with its RMSNorm:
@@ -404,6 +415,80 @@ class HybridBlock:
         if self.selects:
             return "latent+index"
         return "ring" if self.window else "paged"
+
+    @property
+    def ring_tokens(self) -> int:
+        """Slots of a sliding layer's ring: the window, in whole cache
+        write windows (``ops/pallas/cache_update.py``)."""
+        return -(-self.config.sliding_window // 16) * 16
+
+    def cache_leaves(self, slots: int, pool_blocks: int, block_tokens: int,
+                     dtype, kv_dtype: str = "bf16") -> dict:
+        """What this layer keeps, for an engine of ``slots`` rows over a
+        pool of ``pool_blocks`` blocks of ``block_tokens`` tokens: ``{name:
+        CacheLeaf}`` in the compute ``dtype``, each leaf keyed by block or
+        by slot (``ops/attention.py::CacheLeaf``). The ONE place a layer's
+        cache format is written: ``serve.py`` allocates, writes, copies and
+        accounts by it, :meth:`apply` hands ``kv_sink`` the same names,
+        :meth:`decode_step` takes and returns them (:attr:`cache_kind` is
+        the label of the combination, for reports and refusals).
+
+        - a full layer: the paged K/V pool (``kv_pool_leaves``);
+        - a sliding layer: ``"kv" [2, slots, hk, ring_tokens, hd]`` by slot
+          (axis 1), the last tokens of each row at ``pos % ring_tokens``;
+        - a latent layer: ``"kv" [1, P, 1, bt, Wp]`` by block, a token one
+          vector of ``latent_width`` channels in whole lane tiles (576 ->
+          640), the pool's axes kept so that block writes and copies treat
+          it as a K/V pool;
+        - a CCA layer: the paged K/V pool and ``"tail" [slots,
+          tail_width]`` by slot, what the next token needs of the last two
+          (zero: a slot that holds nothing yet);
+        - a KDA layer: no tokens; ``"state" [slots, H, dk, dv]`` float32
+          and ``"tail" [slots, kda_tail_width]`` by slot;
+        - a sparse latent layer: the latent pool, ``"idx" [1, P, 1, bt /
+          index_pool, di]`` by block on the same table (one pooled index
+          key to ``index_pool`` tokens), and ``"idx_tail" [slots,
+          index_tail_width]`` by slot, the keys of the row's own group
+          that no pooled key holds yet."""
+        c, bt, Leaf = self.config, block_tokens, A.CacheLeaf
+        if self.kda:
+            return {"state": Leaf((slots, c.kda_heads, c.kda_head_dim,
+                                   c.kda_head_dim), jnp.float32, 0),
+                    "tail": Leaf((slots, c.kda_tail_width), dtype, 0)}
+        if self.window:
+            return {"kv": Leaf((2, slots, c.num_kv_heads, self.ring_tokens,
+                                c.head_dim), dtype, 1, self.ring_tokens)}
+        if self.latent or self.selects:
+            leaves = {"kv": Leaf((1, pool_blocks, 1, bt, A.latent_pool_width(
+                c.latent_width)), dtype, tokens=bt)}
+            if self.selects:
+                if bt % c.index_pool:
+                    raise ValueError(
+                        f"a pool block of {bt} tokens does not hold whole "
+                        f"groups of index_pool={c.index_pool}")
+                leaves["idx"] = Leaf((1, pool_blocks, 1, bt // c.index_pool,
+                                      c.index_head_dim), dtype, tokens=bt)
+                leaves["idx_tail"] = Leaf((slots, c.index_tail_width),
+                                          dtype, 0)
+            return leaves
+        leaves = A.kv_pool_leaves(pool_blocks, c.num_kv_heads, bt,
+                                  c.head_dim, dtype, kv_dtype)
+        if self.cca:
+            leaves["tail"] = Leaf((slots, c.tail_width), dtype, 0)
+        return leaves
+
+    def read_path(self, cache: dict) -> str:
+        """Which engine reads this layer's block leaves in a decode tick
+        (``stats_snapshot()["paged_read"]``): ``"selected"`` (a sparse
+        latent layer gathers the tokens it chose, whatever the table's
+        width), else the latent or the paged pool's own answer
+        (``"kernel"`` / ``"gather"``)."""
+        if self.selects:
+            return "selected"
+        if self.latent:
+            return A.latent_read_path(cache)
+        return A.paged_read_path(
+            {n: leaf for n, leaf in cache.items() if n != "tail"}, 1)
 
     @property
     def carries(self) -> bool:
@@ -617,6 +702,14 @@ class HybridBlock:
         return (self._latent_q(params, self._latent_cq(params, x), positions),
                 self._latent_token(params, x, positions))
 
+    def _latent_planes(self, token):
+        """Cached vectors ``token [K, T, latent_width]`` as admission
+        writes them into the latent pool's blocks: ``[1, K, 1, T, Wp]``,
+        zero-padded to the pool's lane width."""
+        with scope("kv_write"):
+            return A.pad_channels(token, A.latent_pool_width(
+                self.config.latent_width))[None, :, None]
+
     def _latent_expand(self, params, token, heads=None):
         """The EXPANDED keys and values of cached vectors ``token [B, T,
         latent_width]``: every head's ``k = [k_nope, k_rope]`` and ``v``
@@ -647,7 +740,7 @@ class HybridBlock:
         ``nope + rope`` and v width ``v_head_dim``."""
         q, token = self._latent_q_and_token(params, x, positions)
         if kv_sink is not None:
-            kv_sink.append((token,))
+            kv_sink.append({"kv": self._latent_planes(token)})
         k, v = self._latent_expand(params, token)
         # the default scale is q's head width ** -0.5: (nope + rope)
         return dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask)
@@ -716,10 +809,10 @@ class HybridBlock:
         causal attention under the SELECTION's mask (the same mathematics
         as the gathered read of :meth:`_sparse_decode`): token ``t`` attends
         the tokens of its ``index_groups`` best-scored earlier groups and
-        its own group up to itself. ``kv_sink`` is handed
-        ``(token, pooled, idx_tail)``: the latent vectors, the pooled index
-        key of every group of the window, and per row the index keys of
-        its last real token's group that no pooled key holds yet."""
+        its own group up to itself. ``kv_sink`` is handed ``"kv"`` (the
+        latent vectors), ``"idx"`` (the pooled index key of every group of
+        the window) and ``"idx_tail"`` (per row the index keys of its last
+        real token's group that no pooled key holds yet)."""
         c = self.config
         B, T = x.shape[:2]
         P_, G = c.index_pool, -(-x.shape[1] // c.index_pool)
@@ -731,14 +824,14 @@ class HybridBlock:
             pooled = jnp.mean(kp.reshape(B, G, P_, -1).astype(jnp.float32),
                               axis=2).astype(x.dtype)
             if kv_sink is not None:
-                n_tok = (jnp.full((B,), T, jnp.int32) if kv_mask is None
-                         else jnp.sum(kv_mask > 0.5, axis=1).astype(
-                             jnp.int32))
+                n_tok = _real_tokens(kv_mask, B, T)
                 at = n_tok[:, None] // P_ * P_ + jnp.arange(P_ - 1)[None, :]
                 tail = jnp.take_along_axis(
                     ki, jnp.minimum(at, T - 1)[:, :, None], axis=1)
                 tail = jnp.where((at < n_tok[:, None])[:, :, None], tail, 0)
-                kv_sink.append((token, pooled, tail.reshape(B, -1)))
+                kv_sink.append({"kv": self._latent_planes(token),
+                                "idx": pooled[None, :, None],
+                                "idx_tail": tail.reshape(B, -1)})
             # whom each query may attend, a block of queries at a time
             bq = next(b for b in (256, 128, 64, 32, 16, 8, 4, 2, 1)
                       if T % b == 0)
@@ -917,10 +1010,10 @@ class HybridBlock:
         (``ops/attention.py::kda_window``: one kernel on a TPU, a scan of
         ``kda_chunk`` elsewhere). A pad token (``kv_mask`` 0) gets ``beta =
         0`` and no decay, so the state the window ends with is the one
-        after each row's LAST REAL token. ``kv_sink`` is handed ``(state
-        [B, H, dk, dv] float32, tail [B, kda_tail_width])``: that state
-        and, token by token, ``[q^, k^, v^]`` of the row's last ``kda_conv
-        - 1`` real tokens (zero where it has fewer)."""
+        after each row's LAST REAL token. ``kv_sink`` is handed ``"state"
+        [B, H, dk, dv]`` float32 (that state) and ``"tail" [B,
+        kda_tail_width]``: token by token, ``[q^, k^, v^]`` of the row's
+        last ``kda_conv - 1`` real tokens (zero where it has fewer)."""
         c = self.config
         B, T = x.shape[:2]
         K = c.kda_conv
@@ -950,7 +1043,8 @@ class HybridBlock:
                 sub=KDA_SUB)
             o = self._kda_out(params, x, o)
             if kv_sink is not None:
-                kv_sink.append((S, jnp.concatenate(tails, -1).reshape(B, -1)))
+                kv_sink.append({"state": S, "tail": jnp.concatenate(
+                    tails, -1).reshape(B, -1)})
         return o
 
     def _kda_decode(self, params, x, cache, live):
@@ -1038,11 +1132,11 @@ class HybridBlock:
 
     def _cca_prefill(self, params, x, positions, kv_mask, kv_sink):
         """The whole-window form: the convolutions and the value shift as
-        shifts along the sequence. ``kv_sink`` is handed ``(k, v, tail)``:
-        the pool's pair and, per row, the tail at its LAST REAL token
-        (``kv_mask``): ``u`` of that token and of the one before it, and
-        the value half it leaves the next (zero where the row has no such
-        token: a zero tail is a row that holds nothing yet)."""
+        shifts along the sequence. ``kv_sink`` is handed ``"kv"`` (the
+        pool's pair) and ``"tail"``: per row, the tail at its LAST REAL
+        token (``kv_mask``): ``u`` of that token and of the one before it,
+        and the value half it leaves the next (zero where the row has no
+        such token: a zero tail is a row that holds nothing yet)."""
         c = self.config
         B, T = x.shape[:2]
         u, v_cur, v_next = self._cca_project(params, x)
@@ -1059,9 +1153,7 @@ class HybridBlock:
                 jnp.concatenate([v_cur, shift(v_next)], axis=-1),
                 c.num_kv_heads)
             if kv_sink is not None:
-                n_tok = (jnp.full((B,), T, jnp.int32) if kv_mask is None
-                         else jnp.sum(kv_mask > 0.5, axis=1).astype(
-                             jnp.int32))
+                n_tok = _real_tokens(kv_mask, B, T)
 
                 def at(t, back):
                     i = n_tok - back
@@ -1069,8 +1161,10 @@ class HybridBlock:
                         t, jnp.maximum(i, 0)[:, None, None], axis=1)[:, 0]
                     return jnp.where((i >= 0)[:, None], got, 0)
 
-                kv_sink.append((k, v, jnp.concatenate(
-                    [at(u, 1), at(u, 2), at(v_next, 1)], axis=-1)))
+                with scope("kv_write"):
+                    kv = jnp.stack([k, v])
+                kv_sink.append({"kv": kv, "tail": jnp.concatenate(
+                    [at(u, 1), at(u, 2), at(v_next, 1)], axis=-1)})
         return dispatch_attention(q, k, v, causal=True, kv_mask=kv_mask)
 
     def _cca_decode(self, params, x, cache, pos, slot_mask, live):
@@ -1213,11 +1307,16 @@ class HybridBlock:
     def apply(self, params, x, *, kv_mask=None, kv_sink=None,
               positions=None, counts_sink=None, carry=None):
         """The whole-sequence forward of one layer (prefill). ``kv_sink``
-        captures what a cache stores: the K/V pair (after QK-norm and
-        rotation, at kv-head width), or a latent layer's one token vector
-        ``(token,)``, or a CCA layer's ``(k, v, tail)``, or a KDA layer's
-        ``(state, tail)``, or a sparse latent layer's ``(token, pooled,
-        idx_tail)``; ``kv_mask`` (``[B, T]``, 1 = real) hides pad keys and
+        is handed what the window leaves in the layer's cache: ONE mapping
+        with the names of :meth:`cache_leaves`, each leaf's content in the
+        form admission writes it. A leaf by block: the planes of its
+        blocks, ``[s, B, heads, T, width]`` (the K/V pair after QK-norm and
+        rotation, stacked; a latent token padded to the pool's lane width;
+        the pooled index keys with as many rows as the window's blocks hold
+        of them). A leaf by slot: the rows' entries where the declared
+        leaf has its slots (a tail or a state ``[B, ...]``; a sliding
+        layer's ring as the ticks will read it, ``[2, B, hk, ring_tokens,
+        hd]``). ``kv_mask`` (``[B, T]``, 1 = real) hides pad keys and
         keeps pad tokens out of the experts and the state. A block that
         :attr:`carries` takes ``carry`` and returns ``(x, carry)``. With
         hyper-connections ``x`` is ``[B, T, n, d]``."""
@@ -1244,7 +1343,11 @@ class HybridBlock:
             else:
                 q, k, v = self._qkv(params, y, pos)
                 if kv_sink is not None:
-                    kv_sink.append((k, v))
+                    with scope("kv_write"):
+                        kv_sink.append({"kv": A.ring_from_prefill(
+                            k, v, _real_tokens(kv_mask, *x.shape[:2]),
+                            self.ring_tokens) if self.window
+                            else jnp.stack([k, v])})
                 if self.window:
                     with scope("attn_local"):
                         o = A.attention(q, k, v, causal=True,
@@ -1261,14 +1364,9 @@ class HybridBlock:
                     counts_sink=None, live=None, carry=None,
                     select_sink=None):
         """One cached decode tick, ``x [B, 1, d]`` at per-row slots ``pos
-        [B]``. ``cache`` is this layer's kind: the paged pool with its
-        table (K/V pairs, or a latent layer's token vectors), or a ring
-        ``{"kv": [2, B, hk, R, hd]}``, or a CCA layer's pool with the
-        rows' ``"tail" [B, tail_width]`` beside it, or a KDA layer's
-        ``{"state" [B, H, dk, dv], "tail" [B, kda_tail_width]}`` and
-        nothing else, or a sparse latent layer's latent pool with the
-        pooled index keys ``"idx"`` on the same table and the rows'
-        ``"idx_tail"``. ``live`` (``[B]``, 1 = a row in the plan) keeps
+        [B]``. ``cache`` holds this layer's leaves (:meth:`cache_leaves`)
+        and, where any of them is keyed by block, the rows' block
+        ``"table"``. ``live`` (``[B]``, 1 = a row in the plan) keeps
         parked rows out of the experts and their per-slot leaves where
         they are. A block that :attr:`carries` takes ``carry`` and returns
         ``(x, cache, carry)``; one that :attr:`selects` takes
@@ -1332,12 +1430,6 @@ class HybridLM:
     def layer_params(self, params, i: int):
         return params["layers"][i]
 
-    @property
-    def ring_tokens(self) -> int:
-        """Slots of a sliding layer's ring: the window, in whole cache
-        write windows (``ops/pallas/cache_update.py``)."""
-        return -(-self.config.sliding_window // 16) * 16
-
     def counted_experts(self) -> int:
         """Held experts a sparse layer counts loads for (0: no sparse
         layer, no counters)."""
@@ -1353,16 +1445,12 @@ class HybridLM:
 
     @property
     def tail_width(self) -> int:
-        """Channels of a CCA layer's per-slot tail."""
+        """Channels of a CCA layer's per-slot tail (the benchmark's family
+        test reads it off the model)."""
         return self.config.tail_width
 
     def kv_cache_spec(self):
         return self.config.num_kv_heads, self.config.head_dim
-
-    @property
-    def latent_width(self) -> int:
-        """Channels a token takes in a latent layer's pool."""
-        return self.config.latent_width
 
     @property
     def cache_block_tokens(self) -> int | None:
@@ -1383,33 +1471,6 @@ class HybridLM:
         short = {"latent_attention", "cca_attention",
                  "sparse_latent_attention"}
         return 32 if short & set(self.config.layer_types) else None
-
-    def slot_leaves(self, kind: str, slots: int, dtype) -> dict:
-        """The leaves a layer of cache kind ``kind`` keeps by SLOT beside
-        (or instead of) its pool, as ``{name: (shape, dtype)}``: a CCA
-        layer's tail; a KDA layer's float32 state and its tail of projected
-        tokens; a sparse latent layer's tail of index keys. ``dtype``: the
-        compute type."""
-        c = self.config
-        if kind == "paged+tail":
-            return {"tail": ((slots, c.tail_width), dtype)}
-        if kind == "state":
-            return {"state": ((slots, c.kda_heads, c.kda_head_dim,
-                               c.kda_head_dim), jnp.float32),
-                    "tail": ((slots, c.kda_tail_width), dtype)}
-        if kind == "latent+index":
-            return {"idx_tail": ((slots, c.index_tail_width), dtype)}
-        return {}
-
-    def index_pool_shape(self, blocks: int, block_tokens: int) -> tuple:
-        """The pooled index keys of a sparse latent layer, on the latent
-        pool's block table: one key to ``index_pool`` tokens."""
-        c = self.config
-        if block_tokens % c.index_pool:
-            raise ValueError(
-                f"a pool block of {block_tokens} tokens does not hold whole "
-                f"groups of index_pool={c.index_pool}")
-        return (1, blocks, 1, block_tokens // c.index_pool, c.index_head_dim)
 
     def init(self, key):
         c = self.config

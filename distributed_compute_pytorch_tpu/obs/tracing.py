@@ -56,7 +56,8 @@ from distributed_compute_pytorch_tpu.obs import flight, metrics
 # The device-side vocabulary, one name per layer boundary of PERF.md
 # section 3. ``dropout`` nests (``attn/dropout``, ``mlp/dropout``,
 # ``embed/dropout``); ``kv_gather``/``kv_write``/``sample`` nest under
-# ``admit``/``decode``; ``router``/``experts``/``shared_expert`` nest in
+# ``admit``/``decode`` (``kv_write`` also inside a mixer of
+# ``models/hybrid.py``, round what lays a leaf out for its write); ``router``/``experts``/``shared_expert`` nest in
 # ``mlp`` (routed-expert layers, ``models/moe.py::HeldExperts``) and
 # ``attn_local`` in ``attn`` (a window layer's attention: the banded
 # prefill and the ring read); ``attn_latent`` in ``attn`` too (everything

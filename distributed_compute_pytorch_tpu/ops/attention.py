@@ -13,6 +13,8 @@ stable (max-subtracted softmax in float32) regardless of compute dtype.
 
 from __future__ import annotations
 
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -345,6 +347,48 @@ def cached_attention_q8(q, cache, pos, *, scale: float | None = None,
         pv, v_q, dimension_numbers=(((3,), (2,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.float32).astype(q.dtype)
     return out.reshape(B, H, q_len, hd) if grouped else out
+
+
+class CacheLeaf(NamedTuple):
+    """One leaf of a layer's serving cache, as the layer declares it
+    (``models/hybrid.py::HybridBlock.cache_leaves``) and as ``serve.py``
+    allocates, writes, copies and accounts it. A leaf is keyed BY BLOCK
+    (``slot_axis`` None): ``[s, P, heads, rows, width]`` with axis 1 the
+    pool's block axis, so on the block table and the free list, written
+    by admission in whole blocks, copied by copy-on-write and pinned to
+    the pool's sharding. Or BY SLOT (``slot_axis``: the axis with one
+    entry a slot): never on the table, written by admission at the
+    wave's slots, never copied. ``tokens``: how many tokens one entry (a
+    block, a slot) holds, which is what bytes a cached token are counted
+    from; 0 for what is a function of the whole prefix (a state, a
+    tail), counted by the slot."""
+
+    shape: tuple
+    dtype: object
+    slot_axis: int | None = None
+    tokens: int = 0
+
+    @property
+    def by_block(self) -> bool:
+        return self.slot_axis is None
+
+
+def kv_pool_leaves(blocks: int, heads: int, block_tokens: int,
+                   head_dim: int, dtype, kv_dtype: str = "bf16") -> dict:
+    """The paged K/V pool of one layer as declared leaves: ``{"kv": [2,
+    P, hk, bt, hd]}`` in the compute ``dtype``, or for ``kv_dtype``
+    ``"int8"`` int8 blocks with a ``"scale"`` leaf ``[2, P, hk, bt, 1]``
+    (float32, one scale a position and head) beside them, sharded alike
+    (the last two axes are unsharded in ``infer._POOL_SPEC``)."""
+    int8 = kv_dtype == "int8"
+    shape = (2, blocks, heads, block_tokens)
+    leaves = {"kv": CacheLeaf(shape + (head_dim,),
+                              jnp.int8 if int8 else dtype,
+                              tokens=block_tokens)}
+    if int8:
+        leaves["scale"] = CacheLeaf(shape + (1,), jnp.float32,
+                                    tokens=block_tokens)
+    return leaves
 
 
 def gather_kv_blocks(pool_leaf, table):

@@ -97,30 +97,22 @@ instead, with everything the TPU touches remaining static-shaped:
   collective the two layouts imply — the portable-redistribution move
   (arXiv:2112.01075) that resharded admission K/V in the dense design
   now reshards attached blocks.
-- **Caches of six kinds behind one block table**: a model of layer
-  kinds (``models/hybrid.py``) says per layer what it keeps: ``paged``
-  (the pool of K/V pairs above), ``ring`` (a window layer's last tokens a
-  slot, no table), ``latent`` (a latent-attention layer: a paged pool on
-  the SAME table, free list and block writes whose token is one vector
-  with no heads and no K/V pair, read by its own decode kernel) or
-  ``paged+tail`` (a compressed-convolutional-attention layer: a ``paged``
-  pool in every respect AND a fixed-size tail a slot, ``{"tail": [slots,
-  tail_width]}``, that admission writes from the last real tokens of each
-  row's window and every tick reads and rewrites for the rows in the
-  plan: state beside the pool that is handed from prefill to decode),
-  ``state`` (a linear-attention layer: NO tokens at all, per slot
-  ``{"state": [slots, H, dk, dv] float32, "tail": [slots, (taps - 1) x 3
-  H dk]}``: a function of the whole prefix, off the block table as a ring
-  is, written whole by admission at each row's last REAL token and
-  rewritten by a tick for the rows in its plan) or ``latent+index`` (a
-  sparse latent layer: the ``latent`` pool and beside it, ON THE SAME
-  table and free list, ``{"idx": [1, P, 1, bt / pool, di]}``, one pooled
-  index key to ``pool`` tokens, with a per-slot ``"idx_tail"`` of the
-  index keys no pooled key holds yet; a tick scores the pooled keys of
-  the row's context and reads only the tokens it chose).
-  ``stats_snapshot()["cache_kinds"]`` / ``["cache_bytes_per_token"]`` say
-  which and at what cost; what such a model cannot be served with yet is
-  refused at construction (``_refuse_for_layer_kinds``).
+- **A layer says what it keeps**: a model of layer kinds
+  (``models/hybrid.py``) declares each layer's cache leaf by leaf
+  (``HybridBlock.cache_leaves``, where each mixer documents its own), and
+  every leaf has one of two PLACEMENTS (``ops/attention.py::CacheLeaf``).
+  Keyed BY BLOCK: axis 1 is the pool's block axis, so the leaf is on the
+  block table and the free list, written by admission in whole blocks,
+  copied by copy-on-write and pinned to the pool's sharding (the K/V pool
+  above, a latent layer's token vectors, pooled index keys). Keyed BY
+  SLOT: one entry a slot, never on the table, written by admission at the
+  wave's slots and by a tick for the rows in its plan, never copied (a
+  window layer's ring, a tail, a linear-attention layer's state). The
+  engine allocates, writes, copies and accounts by placement and compares
+  no kind; ``cache_kind`` is a layer's label in
+  ``stats_snapshot()["cache_kinds"]`` / ``["cache_bytes_per_token"]`` and
+  in what such a model cannot be served with yet, refused at
+  construction (``_refuse_for_layer_kinds``).
 - **Overlapped host scheduler**: a plain queue, with the single
   device->host fetch per segment (the token harvest) OVERLAPPED with
   the next segment's execution: segment N+1 is dispatched BEFORE
@@ -274,15 +266,6 @@ _PROGRAM_CACHE_LOCK = threading.Lock()
 # compute-bound on any chip: two waves cost the device what one of their
 # sum would. Not tuned.
 _WAVE_TOKENS = 32768
-
-# The cache kinds of a model of layer kinds (``models/hybrid.py``) that
-# keep nothing on the block table, and the names of the leaves an entry
-# keeps by SLOT, which admission writes whole and a tick rewrites for the
-# rows in its plan (every other leaf of an entry is a block pool's, or a
-# ring; which kind keeps which is the model's to say: ``slot_leaves``).
-_OFF_TABLE = ("ring", "state")
-_SLOT_LEAVES = frozenset(("tail", "state", "idx_tail"))
-
 
 # The buckets of the gap between two deliveries of one request
 # (``_run::count_gaps``): their upper edges in seconds, spaced as
@@ -591,7 +574,7 @@ class ContinuousBatcher:
         from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
             _pallas_ok, _window)
         from distributed_compute_pytorch_tpu.ops.attention import (
-            latent_pool_width, latent_read_path, paged_read_path)
+            paged_read_path)
         if prompt_buf > t_max:
             raise ValueError(f"prompt_buf {prompt_buf} > t_max {t_max}")
         if admit_policy not in ("fifo", "skip_fit"):
@@ -858,107 +841,58 @@ class ContinuousBatcher:
             return jax.device_put(jnp.zeros(shape, dtype),
                                   named_sharding(mesh, spec))
 
-        # per-layer block POOLS [2(k/v), P, hk, bt, hd]: each tick's
-        # write is one window DMA per row through the block table
-        # (ops/pallas/cache_update.py::kv_pool_insert_rows_pallas).
-        # int8 pools carry a "scale" leaf [2, P, hk, bt, 1] beside
-        # "kv", sharded identically (the last two axes are unsharded in
-        # _POOL_SPEC, so the narrower leaf reuses the spec) — every
-        # consumer of the pool dict (attention ops, COW copies,
-        # reset/reconstruct zeroing) treats the leaves generically.
-        #
-        # TWO KINDS of cache in this one list: a layer whose kind is
-        # "ring" (a window layer of models/hybrid.py) keeps, instead of
-        # a pool, {"kv": [2, slots, hk, R, hd]} (the per-row contiguous
-        # cache format, no table): the last R >= window tokens of every
-        # slot, written at pos % R and read whole under a position mask
-        # (ops/attention.py::ring_write_and_attend). Its bytes a slot do
-        # not grow with t_max, and it takes no part in the block table,
-        # the radix cache or copy-on-write. A THIRD kind, "latent" (a
-        # latent-attention layer), is a paged pool on the same block
-        # table, free list and block writes as "paged", whose token is
-        # ONE vector of model.latent_width channels with no K/V pair and
-        # no heads: {"kv": [1, P, 1, bt, Wp]} (Wp: the width in whole
-        # lane tiles, 576 -> 640), the pool's axes kept so that the block
-        # write, the copies and the zeroing below treat it as they treat a
-        # K/V pool. A FOURTH, "paged+tail" (a compressed-convolutional-
-        # attention layer), is a "paged" entry in every respect (table,
-        # free list, whole-block admission write, the decode kernel, the
-        # window write) with one more leaf beside the pool, {"tail":
-        # [slots, model.tail_width]} in the compute dtype: what the NEXT
-        # token of each slot needs of the last two (models/hybrid.py says
-        # what, and why that type rounds nothing more). Zero = a slot that
-        # holds nothing yet, so reset() and reconstruction zero it with
-        # the pool; admission writes it for the slots it fills, a tick for
-        # the rows in its plan. self._cache_kinds says which layer is
-        # which; self._pool_of(c) is an entry's block-pool leaves.
-        # A FIFTH, "state" (a linear-attention layer), keeps NO tokens:
-        # {"state": [slots, H, dk, dv] float32, "tail": [slots, ...]}, off
-        # the block table as a ring is. A SIXTH, "latent+index" (a sparse
-        # latent layer), is a "latent" entry with two more leaves: "idx"
-        # [1, P, 1, bt / pool, di], one pooled index key to `pool` tokens
-        # on the SAME table and free list, and the per-slot "idx_tail" of
-        # the index keys not yet pooled. The model says which per-slot
-        # leaves a kind keeps (model.slot_leaves).
+        # one entry a layer, of the leaves the layer declares (_declared):
+        # a dense family's is the paged K/V pool [2(k/v), P, hk, bt, hd],
+        # each tick's write one window DMA per row through the block table
+        # (ops/pallas/cache_update.py::kv_pool_insert_rows_pallas), with
+        # the int8 "scale" leaf beside it; a layer of kinds says its own
+        # (models/hybrid.py::HybridBlock.cache_leaves). A leaf keyed by
+        # BLOCK is pinned to the pool's sharding, on the block table and
+        # the free list; one keyed by SLOT is none of these. Every consumer
+        # (attention ops, COW copies, reset/reconstruct zeroing: zero = a
+        # slot or a block that holds nothing yet) goes by that placement.
         self._cache_kinds = (("paged",) * n_layers
                              if self._layer_blocks is None else
                              tuple(b.cache_kind for b in self._layer_blocks))
-        on_table = [i for i, kind in enumerate(self._cache_kinds)
-                    if kind not in _OFF_TABLE]
-        if not on_table:
+        self._leaves = self._declared(slots, pool_blocks)
+        # which layers keep anything on the block table
+        self._on_table = [any(l.by_block for l in leaves.values())
+                          for leaves in self._leaves]
+        if not any(self._on_table):
             raise ValueError(
                 "a model of window and state layers only is not served: "
                 "the scheduler's block accounting needs one paged layer")
         # the layer the block accounting and the engine report look at
-        self._paged0 = on_table[0]
-        def pool_leaves(kind):
-            if kind in ("paged", "paged+tail"):
-                return {"kv": zeros((2, pool_blocks, hk, self.bt, hd), dtype,
-                                    _POOL_SPEC),
-                        **({"scale": zeros((2, pool_blocks, hk, self.bt, 1),
-                                           jnp.float32, _POOL_SPEC)}
-                           if kv_dtype == "int8" else {})}
-            if kind in ("latent", "latent+index"):
-                return {"kv": zeros((1, pool_blocks, 1, self.bt,
-                                     latent_pool_width(model.latent_width)),
-                                    dtype, _POOL_SPEC),
-                        **({"idx": zeros(model.index_pool_shape(
-                            pool_blocks, self.bt), dtype, _POOL_SPEC)}
-                           if kind == "latent+index" else {})}
-            if kind == "ring":
-                return {"kv": zeros((2, slots, hk, model.ring_tokens, hd),
-                                    dtype, None)}
-            return {}                                      # "state"
-
+        self._paged0 = self._on_table.index(True)
         self._caches = [
-            {**pool_leaves(kind),
-             **({name: zeros(shape, dt, None) for name, (shape, dt)
-                 in model.slot_leaves(kind, slots, self._cdtype).items()}
-                if self._layer_blocks is not None else {})}
-            for kind in self._cache_kinds]
-        # does any layer keep state by SLOT (a ring, a tail, a state)? Then
-        # an admission dispatch is told which slot each of its rows fills
-        # layers whose per-slot leaves admission has to overwrite even for
-        # a row that prefills nothing
-        self._n_tail = sum(bool(_SLOT_LEAVES & set(c)) for c in self._caches)
-        self._slot_state = bool(self._n_tail
-                                or "ring" in self._cache_kinds)
+            {name: zeros(l.shape, l.dtype, _POOL_SPEC if l.by_block else None)
+             for name, l in leaves.items()} for leaves in self._leaves]
+        # does any layer keep a leaf by SLOT? Then an admission dispatch is
+        # told which slot each of its rows fills, and a row that prefills
+        # nothing is dispatched all the same (_prefill_wave)
+        self._slot_state = any(not l.by_block for leaves in self._leaves
+                               for l in leaves.values())
+        # does any layer keep no tokens at all (a state a slot)?
+        self._state_layers = any(
+            not any(l.tokens for l in leaves.values())
+            for leaves in self._leaves)
         # bytes one layer of each kind keeps of one cached token, as
         # allocated (a latent token's 576 channels in 640 lanes: 1280; a
         # pooled index key is shared by the tokens of its group; a state
-        # layer keeps none)
+        # layer keeps none) ...
+        layers = list(zip(self._caches, self._leaves, self._cache_kinds))
         self._cache_bytes_per_token = {
-            kind: sum(leaf.nbytes // (leaf.shape[1] * (
-                          self.bt if name == "idx" else leaf.shape[3]))
-                      for name, leaf in self._pool_of(c).items())
-            for c, kind in zip(self._caches, self._cache_kinds)}
-        # bytes one layer of a kind keeps of a SLOT beside what grows with
-        # its tokens, as allocated (kinds that keep none are left out)
+            kind: sum(c[name].nbytes // (l.tokens * l.shape[
+                          1 if l.by_block else l.slot_axis])
+                      for name, l in leaves.items() if l.tokens)
+            for c, leaves, kind in layers}
+        # ... and of a SLOT beside what grows with its tokens (kinds that
+        # keep none are left out)
         self._state_bytes_per_slot = {
-            kind: sum(leaf.nbytes for name, leaf in c.items()
-                      if name in _SLOT_LEAVES) // slots
-            for c, kind in zip(self._caches, self._cache_kinds)
-            if _SLOT_LEAVES & set(c)}
+            kind: sum(c[name].nbytes for name, l in leaves.items()
+                      if not l.tokens) // slots
+            for c, leaves, kind in layers
+            if not all(l.tokens for l in leaves.values())}
         # the stats that each entry of a decode tick's count vector adds
         # to (none for a model without held experts)
         held = (model.counted_experts()
@@ -984,12 +918,12 @@ class ContinuousBatcher:
         # pool lives: the Pallas window write off-mesh on TPU, the XLA
         # scatter under a mesh (a Mosaic call cannot be partitioned) and
         # on CPU. Off-mesh on TPU there is no second choice to fall to.
-        # (the pooled index keys, an eighth of a block's rows, go through
-        # a row scatter of their own: models/hybrid.py)
+        # (asked of the leaves that hold a row a token: one with fewer,
+        # as pooled index keys, goes through its layer's own row scatter)
+        pool0 = self._pool0()
         self._pallas_write = mesh is None and _pallas_ok(
-            {name: leaf for name, leaf in self._pool_of(
-                self._caches[self._paged0]).items() if name != "idx"},
-            axis=3)
+            {name: leaf for name, leaf in pool0.items()
+             if leaf.shape[3] == self.bt}, axis=3)
         if (jax.default_backend() == "tpu" and mesh is None
                 and not self._pallas_write):
             raise ValueError(
@@ -1003,16 +937,12 @@ class ContinuousBatcher:
         # Asked here and not noted by the trace: engines of one shape
         # family share their jitted programs (_PROGRAM_CACHE), so a
         # trace belongs to whichever engine dispatched first.
-        # "selected": a sparse latent layer gathers the tokens it chose,
-        # whatever the table's width
+        # A layer of kinds answers for its own leaves (read_path)
         with self._mesh_ctx():
-            kind0 = self._cache_kinds[self._paged0]
             self._paged_read = (
-                "selected" if kind0 == "latent+index" else
-                latent_read_path(self._caches[self._paged0])
-                if kind0 == "latent" else
-                paged_read_path(
-                    self._pool_of(self._caches[self._paged0]), 1))
+                paged_read_path(pool0, 1) if self._layer_blocks is None else
+                self._layer_blocks[self._paged0].read_path(
+                    self._caches[self._paged0]))
         if (self._layer_blocks is not None and decode_width_buckets is None
                 and self._paged_read in ("kernel", "selected")):
             # the kernel's traffic follows each row's position whatever
@@ -1029,8 +959,7 @@ class ContinuousBatcher:
         # rides along when present) — the unit behind
         # serve.width.bytes_saved_vs_full
         self._gather_block_bytes = sum(
-            leaf.nbytes // leaf.shape[1]
-            for leaf in self._pool_of(self._caches[self._paged0]).values())
+            leaf.nbytes // leaf.shape[1] for leaf in pool0.values())
         row_spec = P(("data", "fsdp"))
         self._cur_tok = zeros((slots,), jnp.int32, row_spec)
         self._n_logical = zeros((slots,), jnp.int32, row_spec)
@@ -1303,14 +1232,11 @@ class ContinuousBatcher:
         # the pool, dispatches that dequantized a gathered read, bytes
         # the int8 layout saved against the bf16 one (HBM computed once
         # from the actual cache geometry; D2H/handoff accumulated per
-        # move), greedy mismatches harvested by the bf16-vs-int8 A/B
-        # (record_greedy_mismatch — the relaxed parity contract's
-        # forensic counter), and handoffs declined for a dtype mismatch
+        # move), and handoffs declined for a dtype mismatch
         self.kvq = obs_metrics.MetricDict(self.obs, "serve.kvq.", {
             "quantized_blocks": 0, "dequant_reads": 0,
             "bytes_saved_hbm": 0, "bytes_saved_d2h": 0,
-            "bytes_saved_handoff": 0, "greedy_mismatches": 0,
-            "handoff_dtype_declined": 0})
+            "bytes_saved_handoff": 0, "handoff_dtype_declined": 0})
         if getattr(self, "kv_dtype", "bf16") == "int8":
             saved = 0
             for c in self._caches:
@@ -1402,10 +1328,8 @@ class ContinuousBatcher:
             "ticks": self.ticks,
             # static: the pool read the decode tick was compiled with
             "paged_read": self._paged_read,
-            # static: per layer, "paged" (the block pool of K/V pairs),
-            # "latent" (the block pool of token vectors), "ring",
-            # "paged+tail", "state" (no tokens: a per-slot state) or
-            # "latent+index" (token vectors and pooled index keys)
+            # static: per layer, the label of what it keeps ("paged" for a
+            # dense family; a layer of kinds says its cache_kind)
             "cache_kinds": list(self._cache_kinds),
             # static: per kind, the bytes a layer keeps of a cached token
             "cache_bytes_per_token": dict(self._cache_bytes_per_token),
@@ -1435,7 +1359,7 @@ class ContinuousBatcher:
         """Where and in what dtype this engine runs, as IT sees it: a
         bf16 checkpoint must show bf16 weights and pool here, a replica
         its own device, and the pool write the engine that performs it."""
-        pool = self._caches[self._paged0]["kv"]
+        pool = next(iter(self._pool0().values()))
         return {"param_dtype": str(self._cdtype),
                 "pool_dtype": str(pool.dtype),
                 "platform": jax.default_backend(),
@@ -1657,13 +1581,9 @@ class ContinuousBatcher:
             return np.zeros((0, 0), np.float32)
         self._cut_weights()
         nbp = -(-n // self.bt)
-        scratch = [{name: jnp.zeros(
-                        (1,) + tuple(leaf.shape[1:])
-                        if name in _SLOT_LEAVES else
-                        (leaf.shape[0], 1 if kind == "ring" else nbp)
-                        + tuple(leaf.shape[2:]), leaf.dtype)
-                    for name, leaf in c.items()}
-                   for c, kind in zip(self._caches, self._cache_kinds)]
+        scratch = [{name: jnp.zeros(l.shape, l.dtype)
+                    for name, l in leaves.items()}
+                   for leaves in self._declared(1, nbp)]
         table = jnp.arange(nbp, dtype=jnp.int32)[None, :]
         model = self.model
         if prefill:
@@ -1703,22 +1623,6 @@ class ContinuousBatcher:
                     jnp.asarray([i], jnp.int32))
                 out.append(np.asarray(logits[0], jnp.float32))
         return np.stack(out)
-
-    def record_greedy_mismatch(self, position: int, expected: int,
-                               got: int, stream: str = "") -> None:
-        """A/B hook: one bf16-vs-int8 greedy divergence at
-        ``position`` of ``stream``. Bumps
-        ``serve.kvq.greedy_mismatches`` and drops a flight-recorder
-        instant so every mismatch harvested during the A/B is
-        post-mortem visible (ISSUE 16 satellite) — the parity gate is
-        rate-based (>=99% match), so individual mismatches are
-        expected, recorded, and bounded, not fatal."""
-        self.kvq["greedy_mismatches"] += 1
-        instant("kvq_greedy_mismatch", position=int(position),
-                expected=int(expected), got=int(got), stream=str(stream))
-        flight.record("kvq_greedy_mismatch", position=int(position),
-                      expected=int(expected), got=int(got),
-                      stream=str(stream))
 
     def profile_next(self, segments: int, profile_dir: str) -> None:
         """Arm ON-DEMAND XLA profiling: the next ``segments``
@@ -1912,19 +1816,35 @@ class ContinuousBatcher:
             return self._block, params["blocks"][i]
         return self._layer_blocks[i], self.model.layer_params(params, i)
 
-    @staticmethod
-    def _pool_of(cache: dict) -> dict:
-        """The block-pool leaves of a layer's cache entry (all of them but
-        the per-slot ones: a tail, a state)."""
-        return {name: leaf for name, leaf in cache.items()
-                if name not in _SLOT_LEAVES}
+    def _declared(self, slots: int, pool_blocks: int) -> list:
+        """Per layer, the leaves of its cache for ``slots`` rows over
+        ``pool_blocks`` blocks, ``{name: CacheLeaf}``: what a layer of
+        kinds declares, the paged K/V pool for every layer of a dense
+        family."""
+        from distributed_compute_pytorch_tpu.ops.attention import (
+            kv_pool_leaves)
+        if self._layer_blocks is not None:
+            return [b.cache_leaves(slots, pool_blocks, self.bt, self._cdtype,
+                                   self.kv_dtype)
+                    for b in self._layer_blocks]
+        hk, hd = self.model.kv_cache_spec()
+        return [kv_pool_leaves(pool_blocks, hk, self.bt, hd, self._cdtype,
+                               self.kv_dtype)] * self._n_layers
+
+    def _pool0(self) -> dict:
+        """The leaves keyed by block of the layer the block accounting
+        and the engine report look at."""
+        return {name: leaf
+                for name, leaf in self._caches[self._paged0].items()
+                if self._leaves[self._paged0][name].by_block}
 
     def _decode_layer(self, i: int, params, x, cache, tables, pos,
                       live=None, counts=None, pin: bool = True, carry=None,
                       selected=None):
-        """One layer's decode tick against its own kind of cache; returns
-        ``(x, new_cache, carry)``, the pool's leaves pinned to their
-        layout unless ``pin`` is off (a scratch pool has none). ``carry``
+        """One layer's decode tick against its own leaves (the table
+        beside them where any is keyed by block); returns ``(x, new_cache,
+        carry)``, the leaves keyed by block pinned to the pool's layout
+        unless ``pin`` is off (a scratch pool has none). ``carry``
         is what a block that carries took from the block below and hands
         the next (None for every other block); ``selected`` the sink of a
         block that attends a selection of its cache."""
@@ -1936,15 +1856,13 @@ class ContinuousBatcher:
             kw["carry"] = carry
         if getattr(block, "selects", False):
             kw["select_sink"] = selected
-        paged = self._cache_kinds[i] not in _OFF_TABLE
         out = block.decode_step(
-            p_l, x, {**cache, "table": tables} if paged else cache, pos,
-            **kw)
+            p_l, x, {**cache, "table": tables} if self._on_table[i]
+            else cache, pos, **kw)
         x, c2, carry = out if carries else (*out, None)
-        if paged:
-            c2 = {name: constrain(leaf, _POOL_SPEC)
-                  if pin and name not in _SLOT_LEAVES else leaf
-                  for name, leaf in c2.items() if name != "table"}
+        c2 = {name: constrain(c2[name], _POOL_SPEC)
+              if pin and l.by_block else c2[name]
+              for name, l in self._leaves[i].items()}
         return x, c2, carry
 
     def _mesh_ctx(self):
@@ -2080,13 +1998,13 @@ class ContinuousBatcher:
         the whole pool for it, twice a layer, whatever the dispatch holds
         (33 ms of a Mistral-7B dispatch on a v5e: PERF.md, PR 29).
 
-        A layer whose cache is a RING takes the last tokens of each row's
-        head instead (``ops/attention.py::ring_from_prefill``), written
-        whole into the ring rows of the slots ``ring_rows [K]`` (a slot
-        id out of range = a pad row, dropped; None = wave row ``j`` is
-        slot ``j``, a wave over the first ``K`` slots). A layer that keeps
-        a per-slot TAIL beside its pool writes the tail its block captured
-        at each row's last real token into the same slots.
+        A layer of kinds hands over its leaves by name, each in the form
+        it is written (``models/hybrid.py::HybridBlock.apply``), and the
+        write goes by the leaf's placement: one keyed by block in whole
+        blocks (such a model is never attached, never chunked: refused at
+        construction), one keyed by slot whole into the slots ``ring_rows
+        [K]`` the wave's rows fill (a slot id out of range = a pad row,
+        dropped; passed whenever a layer keeps such a leaf).
 
         Each request's LAST prompt token is deliberately NOT prefilled:
         the host sets it as the row's current token and the next
@@ -2096,7 +2014,7 @@ class ContinuousBatcher:
         (no device->host read).
         """
         from distributed_compute_pytorch_tpu.ops.attention import (
-            gather_kv_blocks, pad_channels)
+            gather_kv_blocks)
         with scope("admit"):
             model = self.model
             Lp = prefix_mask.shape[1]
@@ -2144,68 +2062,27 @@ class ContinuousBatcher:
                     x, carry = x
                 elif isinstance(x, tuple):   # MoE blocks return (x, aux)
                     x = x[0]
-                # (k, v) [K, hk, ws, hd], (token,) [K, ws, W], or
-                # (k, v, tail [K, tail_width])
+                # a dense family's pair (k, v) [K, hk, ws, hd], or a layer
+                # of kinds' {name: content}
                 kept, = sink
                 with scope("kv_write"):
-                    if self._cache_kinds[i] == "ring":
-                        new_caches.append(self._admit_ring(
-                            caches[i], *kept, pmask, ring_rows))
-                    elif self._cache_kinds[i] == "latent":
-                        # never attached, never chunked (refused at
-                        # construction): every window starts at 0
-                        new_caches.append(self._admit_blocks(
-                            caches[i], pad_channels(
-                                kept[0], caches[i]["kv"].shape[-1])[
-                                    None, :, None], tables, pmask))
-                    elif self._cache_kinds[i] == "paged+tail":
-                        # never attached, never chunked (refused at
-                        # construction): whole blocks, and the rows' tails
-                        # into the slots they fill (a pad row's is dropped)
-                        k, v, tail = kept
-                        rows = (jnp.arange(k.shape[0]) if ring_rows is None
-                                else ring_rows)
-                        new_caches.append({
-                            **self._admit_blocks(
-                                caches[i], jnp.stack([k, v]), tables, pmask),
-                            "tail": caches[i]["tail"].at[rows].set(
-                                tail.astype(caches[i]["tail"].dtype),
-                                mode="drop")})
-                    elif self._cache_kinds[i] == "state":
-                        # no tokens: the state after each row's last real
-                        # token and the tail of its last projections, whole
-                        # into the slots the rows fill (a pad row's dropped)
-                        state, tail = kept
-                        rows = (jnp.arange(state.shape[0])
-                                if ring_rows is None else ring_rows)
-                        new_caches.append({
-                            "state": caches[i]["state"].at[rows].set(
-                                state, mode="drop"),
-                            "tail": caches[i]["tail"].at[rows].set(
-                                tail.astype(caches[i]["tail"].dtype),
-                                mode="drop")})
-                    elif self._cache_kinds[i] == "latent+index":
-                        # never attached, never chunked: the latent vectors
-                        # and the pooled index keys in whole blocks of the
-                        # one table, the index tails into the rows' slots
-                        token, pooled, tail = kept
-                        rows = (jnp.arange(token.shape[0])
-                                if ring_rows is None else ring_rows)
-                        new_caches.append({
-                            **self._admit_blocks(
-                                caches[i], pad_channels(
-                                    token, caches[i]["kv"].shape[-1])[
-                                        None, :, None], tables, pmask),
-                            **self._admit_blocks(
-                                caches[i], pooled[None, :, None], tables,
-                                pmask, leaf="idx"),
-                            "idx_tail": caches[i]["idx_tail"].at[rows].set(
-                                tail.astype(caches[i]["idx_tail"].dtype),
-                                mode="drop")})
+                    if self._layer_blocks is not None:
+                        new = {}
+                        for name, content in kept.items():
+                            leaf, l = caches[i][name], self._leaves[i][name]
+                            # by block: whole blocks; by slot: whole into
+                            # the rows' slots (a pad row's is dropped)
+                            new[name] = self._admit_blocks(
+                                leaf, content, tables, pmask
+                            ) if l.by_block else leaf.at[
+                                (slice(None),) * l.slot_axis + (ring_rows,)
+                            ].set(content.astype(leaf.dtype), mode="drop")
+                        new_caches.append(new)
                     elif Lp == 0 and "scale" not in caches[i]:
                         # every window starts at position 0 (static)
-                        new_caches.append(self._admit_blocks(
-                            caches[i], jnp.stack(kept), tables, pmask))
+                        new_caches.append({"kv": self._admit_blocks(
+                            caches[i]["kv"], jnp.stack(kept), tables,
+                            pmask)})
                     else:
                         new_caches.append(self._admit_scatter(
                             caches[i], *kept, blk_idx, off_idx))
@@ -2232,28 +2109,12 @@ class ContinuousBatcher:
                     "scale": scatter(cache["scale"], jnp.stack([ks, vs]))}
         return {"kv": scatter(cache["kv"], jnp.stack([k, v]))}
 
-    @staticmethod
-    def _admit_ring(cache, k, v, pmask, ring_rows):
-        """One WINDOW layer's admission write: the last tokens of each
-        row's head, laid out as the ring holds them
-        (``ops/attention.py::ring_from_prefill``), written whole into the
-        ring rows of the slots ``ring_rows``."""
-        from distributed_compute_pytorch_tpu.ops.attention import (
-            ring_from_prefill)
-        ring = cache["kv"]
-        n_tok = jnp.sum(pmask > 0.5, axis=1).astype(jnp.int32)
-        rows = jnp.arange(k.shape[0]) if ring_rows is None else ring_rows
-        return {"kv": ring.at[:, rows].set(
-            ring_from_prefill(k, v, n_tok, ring.shape[3]).astype(ring.dtype),
-            mode="drop")}
-
-    def _admit_blocks(self, cache, kv, tables, pmask, leaf="kv"):
-        """One layer's admission write in WHOLE BLOCKS (``kv [s, K, hk,
-        ws, hd]``: the K/V planes, or a latent layer's one plane of token
-        vectors with one "head", or a sparse latent layer's pooled index
-        keys for its pool ``leaf`` ``"idx"``, a block of which holds fewer
-        rows than tokens; every window of
-        the dispatch starts at position 0: nothing attached, no chunk
+    def _admit_blocks(self, pool, kv, tables, pmask):
+        """The admission write of one leaf keyed by BLOCK, in WHOLE BLOCKS
+        (``kv [s, K, hk, ws, hd]``: the K/V planes, or one plane of token
+        vectors with one "head", or the planes of a leaf a block of which
+        holds fewer rows than tokens, as pooled index keys; every window
+        of the dispatch starts at position 0: nothing attached, no chunk
         extension): block ``j`` of wave row ``r`` goes to pool block
         ``tables[r, j]`` if it holds a real token, else nowhere. One index
         on the pool's block axis, so the pool is updated in place; the
@@ -2263,7 +2124,6 @@ class ContinuousBatcher:
         the pad tokens' K/V: past the row's live position, never attended,
         and overwritten by the ticks that reach it."""
         s, K, hk, ws, hd = kv.shape
-        pool = cache[leaf]
         bt = pool.shape[3]                       # rows to a pool block
         nbw = ws // bt
         kv = kv.reshape(s, K, hk, nbw, bt, hd).transpose(
@@ -2271,9 +2131,9 @@ class ContinuousBatcher:
         n_tok = jnp.sum(pmask > 0.5, axis=1)
         real = (jnp.arange(nbw) * self.bt)[None, :] < n_tok[:, None]
         ids = jnp.where(real, tables[:, :nbw], pool.shape[1]).reshape(-1)
-        return {leaf: constrain(
+        return constrain(
             pool.at[:, ids].set(kv.astype(pool.dtype), mode="drop"),
-            _POOL_SPEC)}
+            _POOL_SPEC)
 
     def _copy_impl(self, caches, src, dst):
         """Copy-on-write block copies: pool blocks ``src [M]`` duplicated
@@ -2282,15 +2142,13 @@ class ContinuousBatcher:
         donor's (divergent) K/V — never attended (the per-row position
         mask stops at the live position) and overwritten as the attacher
         writes its own suffix."""
-        out = []
-        for c, kind in zip(caches, self._cache_kinds):
-            # rings, tails and states belong to slots, not to blocks (and
-            # nothing that copies a block is served with any of them)
-            out.append(c if kind == "ring" else {
-                name: leaf if name in _SLOT_LEAVES else constrain(
-                    leaf.at[:, dst].set(leaf[:, src]), _POOL_SPEC)
-                for name, leaf in c.items()})
-        return out
+        # a leaf keyed by slot belongs to no block (and nothing that copies
+        # a block is served with one)
+        return [{name: constrain(leaf.at[:, dst].set(leaf[:, src]),
+                                 _POOL_SPEC)
+                 if leaves[name].by_block else leaf
+                 for name, leaf in c.items()}
+                for c, leaves in zip(caches, self._leaves)]
 
     def _promote_impl(self, caches, dst, payload):
         """Hierarchical-KV promotion: host-tier K/V ``payload`` — a
@@ -3471,7 +3329,7 @@ class ContinuousBatcher:
                 table[b].remaining -= take
                 ticks_charged[ri] += take
                 self.waste["planned_ticks"] += self.S
-            if "state" in self._cache_kinds:
+            if self._state_layers:
                 self.stats["state_rows_advanced"] += len(plan) * self.S
             if chaos is not None and chaos.on_segment is not None:
                 # host observation hook: drills flip drain flags /
@@ -4049,9 +3907,9 @@ class ContinuousBatcher:
                        entries)]
         Lp = 0 if max_m == 0 else self._bucket_width(max_m) * self.bt
         for w, r, group in groups:
-            # a row that prefills nothing still takes its slot's tail over
-            # from the former tenant: it has to be written (as zero)
-            if self._n_tail or any(upto > m for _, _, m, upto in group):
+            # a row that prefills nothing still takes its slot's leaves
+            # over from the former tenant: they have to be written
+            if self._slot_state or any(upto > m for _, _, m, upto in group):
                 self._dispatch_prefill(group, r, w, Lp)
         final = [(b, known) for b, known, _m, upto in entries
                  if upto >= len(known) - 1]
@@ -4121,7 +3979,7 @@ class ContinuousBatcher:
                 caps.append(self._block.prefill_capacity(len(known)))
         kw = {}
         if self._slot_state:
-            # the slot each wave row's ring or tail is; pad rows out of range
+            # the slot each wave row fills; pad rows out of range
             kw["ring_rows"] = jnp.asarray(
                 [b for b, *_ in entries] + [self.B] * (R - K), jnp.int32)
         if caps:

@@ -157,8 +157,12 @@ def test_defaults_build_the_other_families_trees_unchanged(family):
     want = jax.tree.map(lambda s: s[0], ref_mod.param_spec(cfg),
                         is_leaf=weights._is_leaf)
     assert have == want
-    assert not any(model.slot_leaves(kind, 4, jnp.float32)
-                   for kind in ("paged", "latent", "ring"))
+    by_slot = {b.cache_kind: [n for n, l in b.cache_leaves(
+        4, 9, 32, jnp.float32).items() if not l.by_block]
+               for b in map(model.layer_block, range(model.num_layers))}
+    assert by_slot == {"zaya": {"paged+tail": ["tail"]},
+                       "joyai_llm_flash": {"latent": []},
+                       "exaone_moe": {"ring": ["kv"], "paged": []}}[family]
 
 
 def test_full_forward_matches_the_reference_on_logits():
@@ -269,9 +273,10 @@ def test_the_kda_window_form_equals_its_token_form_and_hands_over(
     mask = (jnp.arange(T) < real)[None].astype(jnp.float32)
     sink: list = []
     want = block.apply(p, x, kv_mask=mask, kv_sink=sink)
-    (state, tail), = sink
-    cache = {k: jnp.zeros(s, d) for k, (s, d) in model.slot_leaves(
-        "state", 1, jnp.float32).items()}
+    state, tail = sink[0]["state"], sink[0]["tail"]
+    cache = {k: jnp.zeros(l.shape, l.dtype) for k, l in block.cache_leaves(
+        1, 0, 32, jnp.float32).items()}
+    assert list(sink[0]) == list(cache)
     assert state.shape == cache["state"].shape == (1, 4, 16, 16)
     assert tail.shape == cache["tail"].shape == (1, 3 * 3 * 64)
     step = jax.jit(block.decode_step)
@@ -316,14 +321,17 @@ def test_within_its_selection_the_sparse_layer_is_the_dense_latent_layer():
     s_sink, d_sink = [], []
     ys = sparse.apply(p, x, kv_sink=s_sink)
     yd = dense.apply(p, x, kv_sink=d_sink)
-    np.testing.assert_array_equal(np.asarray(s_sink[0][0]),
-                                  np.asarray(d_sink[0][0]))
+    np.testing.assert_array_equal(np.asarray(s_sink[0]["kv"]),
+                                  np.asarray(d_sink[0]["kv"]))
     assert float(jnp.max(jnp.abs(ys - yd))) < 2e-5
     nb = -(-T // bt)
     table = (jnp.arange(nb, dtype=jnp.int32) + 1)[None]
     pool = lambda: jnp.zeros((1, nb + 1, 1, bt, 128))
-    cs = {"kv": pool(), "idx": jnp.zeros(model.index_pool_shape(nb + 1, bt)),
-          "idx_tail": jnp.zeros((1, 3 * 16)), "table": table}
+    cs = {**{k: jnp.zeros(l.shape, l.dtype) for k, l in sparse.cache_leaves(
+        1, nb + 1, bt, jnp.float32).items()}, "table": table}
+    assert {k: a.shape for k, a in cs.items()} == {
+        "kv": (1, nb + 1, 1, bt, 128), "idx": (1, nb + 1, 1, bt // 4, 16),
+        "idx_tail": (1, 3 * 16), "table": (1, nb)}
     cd = {"kv": pool(), "table": table}
     s_step, d_step = jax.jit(sparse.decode_step), jax.jit(dense.decode_step)
     counts: list = []
@@ -350,7 +358,9 @@ def test_past_its_selection_the_sparse_layer_attends_what_it_chose():
     x = jax.random.normal(jax.random.key(5), (1, T, 4, 64))
     sink: list = []
     ys = sparse.apply(p, x, kv_sink=sink)
-    token, pooled, idx_tail = sink[0]
+    assert list(sink[0]) == ["kv", "idx", "idx_tail"]
+    pooled, idx_tail = sink[0]["idx"][0, :, 0], sink[0]["idx_tail"]
+    assert sink[0]["kv"].shape == (1, 1, 1, T, 128)  # 64 channels in a tile
     assert pooled.shape == (1, 25, 16) and idx_tail.shape == (1, 3 * 16)
     assert not idx_tail.any()                  # 100 tokens: whole groups
     assert float(jnp.max(jnp.abs(ys - dense.apply(p, x)))) > 1e-2
@@ -363,7 +373,7 @@ def test_past_its_selection_the_sparse_layer_attends_what_it_chose():
     assert float(jnp.max(jnp.abs(got[0] - want))) < 2e-5
     nb = -(-T // bt)
     cs = {"kv": jnp.zeros((1, nb + 1, 1, bt, 128)),
-          "idx": jnp.zeros(model.index_pool_shape(nb + 1, bt)),
+          "idx": jnp.zeros((1, nb + 1, 1, bt // 4, 16)),
           "idx_tail": jnp.zeros((1, 3 * 16)),
           "table": (jnp.arange(nb, dtype=jnp.int32) + 1)[None]}
     step = jax.jit(lambda p, x, c, pos: (lambda s: sparse.decode_step(
@@ -712,4 +722,4 @@ def test_half_named_layers_and_the_training_path_refuse():
     with pytest.raises(ValueError, match="hc_mult"):
         HybridConfig(hc_mult=4)               # norms after the sublayers
     with pytest.raises(ValueError, match="whole groups"):
-        model.index_pool_shape(8, 6)
+        model.layer_block(1).cache_leaves(4, 8, 6, jnp.float32)

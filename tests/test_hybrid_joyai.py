@@ -147,9 +147,13 @@ def test_the_absorbed_decode_form_equals_the_expanded_form():
     x = jax.random.normal(jax.random.key(3), (1, T, 64))
     sink: list = []
     want = block.apply(p, x, kv_sink=sink)
-    (captured,), = sink
+    W = model.config.latent_width
     nb = -(-T // bt)
-    Wp = A.latent_pool_width(model.latent_width)
+    Wp = A.latent_pool_width(W)
+    # handed over as the planes of the pool's blocks, in whole lane tiles
+    assert list(sink[0]) == ["kv"] and sink[0]["kv"].shape == (1, 1, 1, T, Wp)
+    assert not sink[0]["kv"][..., W:].any()
+    captured = sink[0]["kv"][0, :, 0, :, :W]
     cache = {"kv": jnp.zeros((1, nb + 1, 1, bt, Wp)),
              "table": (jnp.arange(nb, dtype=jnp.int32) + 1)[None]}
     step = jax.jit(block.decode_step)
@@ -157,9 +161,8 @@ def test_the_absorbed_decode_form_equals_the_expanded_form():
         y, cache = step(p, x[:, t:t + 1], cache, jnp.asarray([t]))
         assert float(jnp.max(jnp.abs(y - want[:, t:t + 1]))) < 2e-5, t
     held = cache["kv"][0, 1:, 0].reshape(nb * bt, Wp)[:T]
-    assert float(jnp.max(jnp.abs(held[:, :model.latent_width]
-                                 - captured[0]))) < 2e-5
-    assert not held[:, model.latent_width:].any()      # the lane padding
+    assert float(jnp.max(jnp.abs(held[:, :W] - captured[0]))) < 2e-5
+    assert not held[:, W:].any()                       # the lane padding
     normed = ref._rms(x[0], p["pre_attn_norm"]["scale"], 1e-6)
     assert float(jnp.max(jnp.abs(
         captured[0] - ref.latent_of(normed, p, CFG)))) < 2e-5

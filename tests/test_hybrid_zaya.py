@@ -214,7 +214,9 @@ def test_the_decode_form_equals_the_prefill_form_token_for_token(lengths):
         y, _ = block.apply(p, x, kv_mask=mask, kv_sink=sink)
         return y, sink[0]
 
-    _, (k, v, tail) = prefill(p, x[:, :T], mask)
+    _, kept = prefill(p, x[:, :T], mask)
+    (k, v), tail = kept["kv"], kept["tail"]
+    assert list(kept) == list(block.cache_leaves(B, 4, 16, jnp.float32))
     assert tail.shape == (B, model.tail_width)
     for b, real in enumerate(lengths):
         if real <= 1:                # no token before the first: zeros
@@ -233,7 +235,8 @@ def test_the_decode_form_equals_the_prefill_form_token_for_token(lengths):
         got.append(y[:, 0])
     for b in range(B):
         nb = int(n[b])
-        want, (wk, wv, _) = prefill(p, x[b:b + 1, :nb + more])
+        want, kept = prefill(p, x[b:b + 1, :nb + more])
+        wk, wv = kept["kv"]
         for t in range(more):
             assert float(jnp.max(jnp.abs(
                 got[t][b] - want[0, nb + t]))) < 1e-5, (b, t)

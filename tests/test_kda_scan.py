@@ -255,6 +255,9 @@ def test_four_kda_layers_share_one_trace_under_their_scopes(monkeypatch):
     params, _ = model.init(jax.random.key(0))
     cb = ContinuousBatcher(model, params, slots=2, t_max=64, prompt_buf=32,
                            segment=4)
+    # (the entry's own traces of another test's engine at these shapes,
+    # in this process, would leave nothing to trace here)
+    kda_scan.kda_chunk_scan.clear_cache()
     traced, body = [], kda_scan._scan_kernel
     monkeypatch.setattr(kda_scan, "_scan_kernel", lambda *refs, **kw: (
         traced.append(refs[0].shape), body(*refs, **kw))[1])
