@@ -287,6 +287,14 @@ def make_generate_fn(model, max_new_tokens: int, *, t_max: int | None = None,
     """
     if max_new_tokens < 0:
         raise ValueError(f"max_new_tokens must be >= 0, got {max_new_tokens}")
+    if getattr(model, "block_generation", None) is not None:
+        # (dcp-generate comes through here too)
+        raise ValueError(
+            "this model generates by block diffusion (block_length "
+            f"{model.block_generation[0]}): a loop of one causal token a "
+            "tick would print text the model never wrote; serve it through "
+            "serve.ContinuousBatcher, whose block pass follows its mask and "
+            "its denoising schedule")
     if mesh is not None:
         tp = dict(mesh.shape).get("tensor", 1)
         hk, _ = model.kv_cache_spec()

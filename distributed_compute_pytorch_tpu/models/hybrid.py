@@ -108,6 +108,24 @@ x)`` and merged back as ``H_res x + H_post^T f`` (``_enter`` / ``_leave``).
 ``embed`` copies the embedding into every stream, ``readout`` sums them.
 ``swiglu_limit`` clamps every SwiGLU's gate and up.
 
+BLOCK DIFFUSION (``block_length`` > 0; the SDAR recipe, full-attention
+layers only): positions are cut into blocks of ``block_length`` from
+position 0, prompt included, and position ``i`` attends ``j`` iff ``j //
+block_length <= i // block_length``: every earlier block and ALL of its
+own. ``apply`` runs a whole sequence under that mask
+(``dispatch_attention(mask_block=)``); :meth:`HybridBlock.block_step` is the
+cached form beside ``decode_step``: a block's positions of a row write their
+K/V and all read slots ``0 .. block_end - 1`` of the pool, one range a row.
+Position ``i``'s logits are token ``i``'s (no shift). How such a model
+generates (a block's unknown positions hold ``mask_token_id`` and are
+unmasked ``block_length / denoising_steps`` a pass by the rule
+``remasking``) is ``HybridLM.block_generation``, which the serving layer
+reads; the loop itself is ``serve.py``'s (``_row_passes``,
+``_block_segment_impl``). The sparse layers' router is chosen by ``router``:
+``"sigmoid"`` (the aux-loss-free form with a selection bias) or
+``"softmax"`` (a softmax over all experts, its top-k renormalised:
+``models/moe.py::SoftmaxRouter``).
+
 The layers differ in shape, so the parameters are a per-layer list
 (``params["layers"][i]``), not one stacked tree, and the serving layer
 asks for the block and the parameters of layer ``i`` (``layer_block`` /
@@ -133,7 +151,7 @@ import jax.numpy as jnp
 
 from distributed_compute_pytorch_tpu.models import layers as L
 from distributed_compute_pytorch_tpu.models.moe import (
-    HeldExperts, MLPRouter)
+    HeldExperts, MLPRouter, SoftmaxRouter)
 from distributed_compute_pytorch_tpu.models.transformer import (
     dispatch_attention)
 from distributed_compute_pytorch_tpu.obs.tracing import scope
@@ -150,6 +168,10 @@ KDA_SUB = 16
 # tokens of a chunk of the prefill form: whole sub-chunks
 KDA_CHUNK = 64
 MLPS = ("dense", "sparse", "sparse_top1")
+ROUTERS = ("sigmoid", "softmax")
+# the rules by which a block-diffusion pass chooses which masked positions
+# take their token; the last makes the yield depend on the data
+REMASKINGS = ("sequential", "low_confidence_static", "low_confidence_dynamic")
 
 
 @dataclass(frozen=True)
@@ -179,7 +201,19 @@ class HybridConfig:
     shared_d_ff: int = 256         # 0 = no shared expert
     routed_scale: float = 1.0
     norm_topk_prob: bool = True
+    # the sparse layers' router: "sigmoid" (scores a sigmoid, a selection
+    # bias) or "softmax" (a softmax over all experts, its top-k renormalised)
+    router: str = "sigmoid"
     param_dtype: jnp.dtype = jnp.float32
+    # generation by BLOCK DIFFUSION (0: causal, one token a pass): positions
+    # are cut into blocks of block_length from position 0 and a position
+    # attends every earlier block and ALL of its own; a block's unknown
+    # positions hold mask_token_id and are unmasked block_length /
+    # denoising_steps a pass, chosen by the rule `remasking`
+    block_length: int = 0
+    denoising_steps: int = 1
+    remasking: str = "sequential"
+    mask_token_id: int = 0
     # "post": norm each sublayer's output; "pre": its input
     norm_placement: str = "post"
     # the latent_attention layers' widths (num_heads heads; head_dim and
@@ -296,6 +330,20 @@ class HybridConfig:
             raise ValueError(
                 f"num_heads={self.num_heads} must be a multiple of "
                 f"num_kv_heads={self.num_kv_heads}")
+        if self.router not in ROUTERS:
+            raise ValueError(f"router {self.router!r} is none of {ROUTERS}")
+        b = self.block_length
+        if b and not (b >= 2 and b & (b - 1) == 0
+                      and set(self.layer_types) == {"full_attention"}
+                      and self.denoising_steps >= 1
+                      and b % self.denoising_steps == 0
+                      and self.remasking in REMASKINGS
+                      and 0 <= self.mask_token_id < self.vocab_size):
+            raise ValueError(
+                "a block-diffusion model has a block_length that is a power "
+                "of two, full_attention layers only (the block mask is "
+                "theirs), denoising_steps dividing block_length, a remasking "
+                f"of {REMASKINGS} and a mask_token_id inside the vocabulary")
 
     @property
     def num_layers(self) -> int:
@@ -487,8 +535,11 @@ class HybridBlock:
             return "selected"
         if self.latent:
             return A.latent_read_path(cache)
+        # (a block-diffusion model's pass: a block's queries, one range)
+        b = self.config.block_length
         return A.paged_read_path(
-            {n: leaf for n, leaf in cache.items() if n != "tail"}, 1)
+            {n: leaf for n, leaf in cache.items() if n != "tail"}, b or 1,
+            one_range=bool(b))
 
     @property
     def carries(self) -> bool:
@@ -516,7 +567,12 @@ class HybridBlock:
                            routed_scale=c.routed_scale,
                            norm_topk_prob=c.norm_topk_prob,
                            swiglu_limit=c.swiglu_limit,
-                           param_dtype=c.param_dtype)
+                           param_dtype=c.param_dtype,
+                           router=SoftmaxRouter(
+                               c.d_model, c.num_experts, c.top_k,
+                               c.routed_scale, c.norm_topk_prob,
+                               c.param_dtype)
+                           if c.router == "softmax" else None)
 
     def _hc_init(self, key):
         """One sublayer's hyper-connection: the three maps in the
@@ -1353,12 +1409,39 @@ class HybridBlock:
                         o = A.attention(q, k, v, causal=True,
                                         kv_mask=kv_mask, window=self.window)
                 else:
-                    o = dispatch_attention(q, k, v, causal=True,
-                                           kv_mask=kv_mask)
+                    # (a block-diffusion model: under the block mask)
+                    o = dispatch_attention(
+                        q, k, v, causal=True, kv_mask=kv_mask,
+                        **({"mask_block": self.config.block_length}
+                           if self.config.block_length else {}))
                 x = self._attn_out(params, x, o, hc,
                                    gate_in=y if self.gated else None)
         return self._mlp(params, x, token_mask=kv_mask,
                          counts_sink=counts_sink, carry=carry)
+
+    def block_step(self, params, x, cache, pos0, live=None,
+                   counts_sink=None):
+        """One cached pass over a BLOCK of a block-diffusion model: ``x [B,
+        L, d]``, the ``L = block_length`` positions ``pos0[b] .. pos0[b] + L
+        - 1`` of each row (``pos0`` a multiple of ``L``). The block's K/V
+        are written at its slots through the table (``cache`` as
+        :meth:`decode_step` takes it), then every query of the block
+        attends slots ``0 .. pos0 + L - 1``: the earlier blocks and all of
+        its own, one range a row (``ops/attention.py::
+        block_write_and_attend``). ``live`` as in :meth:`decode_step`.
+        Returns ``(x, cache)``."""
+        L_blk = x.shape[1]
+        with scope("attn"):
+            y, hc = self._enter(params, "attn", x)
+            q, k, v = self._qkv(params, y,
+                                pos0[:, None] + jnp.arange(L_blk)[None, :])
+            o, cache = A.block_write_and_attend(q, k, v, cache, pos0)
+            x = self._attn_out(params, x, o, hc,
+                               gate_in=y if self.gated else None)
+        mask = None if live is None else jnp.broadcast_to(
+            live[:, None], x.shape[:2])
+        return self._mlp(params, x, token_mask=mask,
+                         counts_sink=counts_sink), cache
 
     def decode_step(self, params, x, cache, pos, slot_mask=None,
                     counts_sink=None, live=None, carry=None,
@@ -1451,6 +1534,18 @@ class HybridLM:
 
     def kv_cache_spec(self):
         return self.config.num_kv_heads, self.config.head_dim
+
+    @property
+    def block_generation(self):
+        """How this model generates, where it is by block diffusion:
+        ``(block_length, denoising_steps, remasking, mask_token_id)``; None
+        for a causal model (one token a row a pass). What
+        ``serve.ContinuousBatcher`` reads to choose the pass it compiles and
+        the step contract it plans by; ``infer.generate`` refuses such a
+        model."""
+        c = self.config
+        return ((c.block_length, c.denoising_steps, c.remasking,
+                 c.mask_token_id) if c.block_length else None)
 
     @property
     def cache_block_tokens(self) -> int | None:

@@ -401,6 +401,36 @@ class SigmoidRouter:
 
 
 @dataclass(frozen=True)
+class SoftmaxRouter:
+    """The router of :class:`HeldExperts` that is one matrix behind a
+    SOFTMAX (the Qwen3-MoE form): ``p = softmax(W_r h)`` in float32 over
+    ALL experts, the ``top_k`` largest, ``w_e = p_e / sum_{e' in T} p_e'``
+    when ``norm_topk_prob`` (else the probabilities as they are), times
+    ``routed_scale``. No selection bias, no state."""
+
+    d_model: int
+    num_experts: int
+    top_k: int
+    routed_scale: float = 1.0
+    norm_topk_prob: bool = True
+    param_dtype: jnp.dtype = jnp.float32
+
+    def init(self, key):
+        d = self.d_model
+        return {"router": {"kernel": jax.random.uniform(
+            key, (d, self.num_experts), self.param_dtype,
+            -d ** -0.5, d ** -0.5)}}
+
+    def route(self, params, x, state=None):
+        logits = jnp.dot(x, params["router"]["kernel"].astype(x.dtype),
+                         preferred_element_type=jnp.float32)
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), self.top_k)
+        if self.norm_topk_prob:
+            w = w / jnp.sum(w, -1, keepdims=True)
+        return idx.astype(jnp.int32), w * self.routed_scale, state
+
+
+@dataclass(frozen=True)
 class MLPRouter:
     """A router that is a small NETWORK with state carried from layer to
     layer (the ZAYA1 recipe): ``rs = W_d h + c_d + gamma * rs_prev``
@@ -476,8 +506,10 @@ class HeldExperts:
     dropped whatever the skew. That router is the default
     (:class:`SigmoidRouter`, built from the fields below); ``router`` takes
     another part with its own parameters and its own ``route(params, x,
-    state) -> (idx, w, state)`` (:class:`MLPRouter`: a network with state
-    carried from layer to layer, which :meth:`apply_with_state` threads).
+    state) -> (idx, w, state)`` (:class:`SoftmaxRouter`: a softmax over all
+    experts, its top-k renormalised; :class:`MLPRouter`: a network with
+    state carried from layer to layer, which :meth:`apply_with_state`
+    threads).
     ``skip_index`` names a choice of the router that NO chip holds (a
     token sent there gets a zero output at no cost, which is what ``held``
     already means) so that the counts can tell it from an expert held

@@ -28,7 +28,8 @@ from distributed_compute_pytorch_tpu.ops import attention as A
 
 def dispatch_attention(q, k, v, *, causal: bool = False,
                        seq_axis: str = "seq", attn_impl: str = "auto",
-                       kv_mask=None, manual_axes: tuple = ()):
+                       kv_mask=None, manual_axes: tuple = (),
+                       mask_block: int = 0):
     """Route split-head ``[B, H, T, hd]`` attention to the right engine.
 
     One dispatcher for every model family: the Pallas flash kernel (or
@@ -42,6 +43,12 @@ def dispatch_attention(q, k, v, *, causal: bool = False,
     narrow K/V directly — rotating pre-repeated heads would move ``G x``
     the bytes over ICI — while the flash/dense kernels get an explicit
     head repeat.
+
+    ``mask_block`` (static, with ``causal``): the BLOCK mask of a
+    block-diffusion model in place of the causal one: row ``i`` sees key
+    ``j`` iff ``j // mask_block <= i // mask_block`` (every earlier block
+    and all of its own). Flash and dense engines only: a ``seq`` mesh axis
+    refuses it.
     """
     from distributed_compute_pytorch_tpu.core.mesh import current_mesh
     from distributed_compute_pytorch_tpu.parallel.ring_attention import (
@@ -50,6 +57,9 @@ def dispatch_attention(q, k, v, *, causal: bool = False,
     mesh = current_mesh()
     seq_sharded = (mesh is not None and seq_axis in mesh.axis_names
                    and mesh.shape[seq_axis] > 1)
+    if mask_block and (seq_sharded or not causal):
+        raise ValueError("mask_block is the flash and dense engines' form "
+                         "of causal self-attention: no seq axis")
     if seq_sharded and seq_axis in manual_axes:
         return ring_attention_manual(q, k, v, seq_axis,
                                      mesh.shape[seq_axis], causal=causal,
@@ -62,7 +72,8 @@ def dispatch_attention(q, k, v, *, causal: bool = False,
         k = jnp.repeat(k, rep, axis=1)
         v = jnp.repeat(v, rep, axis=1)
     return A.attention(q, k, v, causal=causal, impl=attn_impl,
-                       kv_mask=kv_mask)
+                       kv_mask=kv_mask,
+                       **({"mask_block": mask_block} if mask_block else {}))
 
 
 def attention_sublayer(params, x, *, num_heads: int, causal: bool = False,

@@ -86,7 +86,7 @@ SCOPES = ("embed", "attn", "mlp", "dropout", "head", "loss",
           "router", "experts", "shared_expert", "attn_local",
           "attn_latent", "latent_absorb", "attn_cca", "cca_mix",
           "attn_linear", "linear_scan", "attn_sparse", "index_select",
-          "hyper_mix", "attn_gate")
+          "hyper_mix", "attn_gate", "block_pass", "unmask")
 
 
 def scope(name: str):

@@ -79,7 +79,8 @@ def _pick_block(t: int) -> int | None:
 
 def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
               kv_mask=None, impl: str = "auto", block_q: int | None = None,
-              block_k: int | None = None, window: int | None = None):
+              block_k: int | None = None, window: int | None = None,
+              mask_block: int = 0):
     """Attention dispatcher: the Pallas flash kernel on TPU when shapes
     allow, the fused-by-XLA dense path otherwise.
 
@@ -94,6 +95,11 @@ def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
     ``i`` sees ``j`` with ``i - window < j <= i``); ``k``/``v`` may then
     stay at their own (fewer) heads. The banded flash forward skips what
     lies outside the band; the dense path masks it.
+
+    ``mask_block``: causal SELF-attention (``q_len == kv_len``) under the
+    block mask of a block-diffusion model: row ``i`` sees ``j`` iff ``j //
+    mask_block <= i // mask_block``. The flash forward masks its diagonal
+    tiles so (forward only); the dense path takes the mask whole.
     """
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown attention impl {impl!r}")
@@ -113,11 +119,20 @@ def attention(q, k, v, *, causal: bool = False, scale: float | None = None,
         raise ValueError(
             f"impl='pallas' forced but causal q_len {t} > kv_len {tk} "
             f"is not a meaningful attention shape")
+    if mask_block and not (causal and t == tk):
+        raise ValueError(f"mask_block is causal self-attention's: causal "
+                         f"{causal}, q_len {t}, kv_len {tk}")
     if impl == "pallas" or (impl == "auto" and eligible
                             and jax.default_backend() == "tpu"):
-        return _flash_per_shard(q, k, v, kv_mask, causal=causal,
-                                scale=scale, block_q=bq, block_k=bk)
+        return _flash_per_shard(
+            q, k, v, kv_mask, causal=causal, scale=scale, block_q=bq,
+            block_k=bk, **({"mask_block": mask_block} if mask_block else {}))
     mask = None if kv_mask is None else kv_mask[:, None, None, :].astype(bool)
+    if mask_block:
+        seen = (jnp.arange(tk)[None, :] // mask_block
+                <= jnp.arange(t)[:, None] // mask_block)[None, None]
+        return dot_product_attention(
+            q, k, v, scale=scale, mask=seen if mask is None else seen & mask)
     return dot_product_attention(q, k, v, causal=causal, scale=scale,
                                  mask=mask)
 
@@ -437,14 +452,17 @@ def _paged_view(pool, table) -> dict:
         return view
 
 
-def paged_read_path(pool: dict, q_len: int, slot_mask=None) -> str:
+def paged_read_path(pool: dict, q_len: int, slot_mask=None,
+                    one_range: bool = False) -> str:
     """Which engine reads a paged pool for attention: ``"kernel"`` (the
     block-table Pallas kernel, the pool read in place) or ``"gather"``
     (:func:`_paged_view` + the dense cached attention). Decided from the
     operands alone: the repo's one policy for Pallas dispatchers
     (``cache_update._pallas_ok``: TPU backend, no mesh context, window-
     aligned blocks), a float pool (no int8 ``scale`` leaf), ONE query
-    position, no ``slot_mask``, heads of whole 128-lane tiles, and a
+    position (or ``one_range``: a row's ``q_len`` queries all attend the
+    same slots, as the positions of a block-diffusion block do), no
+    ``slot_mask``, heads of whole 128-lane tiles, and a
     block of all KV heads small enough for the kernel's VMEM scratch.
     Everything else (CPU, a mesh, hd 64, the int8 pool, verify windows)
     reads through the gather, exactly as before the kernel existed."""
@@ -453,7 +471,8 @@ def paged_read_path(pool: dict, q_len: int, slot_mask=None) -> str:
     from distributed_compute_pytorch_tpu.ops.pallas.decode_attention import (
         _chunk_blocks)
     kv = pool["kv"]
-    eligible = (q_len == 1 and slot_mask is None and "scale" not in pool
+    eligible = ((q_len == 1 or one_range) and slot_mask is None
+                and "scale" not in pool
                 and kv.shape[-1] % 128 == 0
                 and _chunk_blocks(kv.shape, kv.dtype.itemsize, 1) == 1
                 and _pallas_ok(pool, axis=3))
@@ -517,6 +536,45 @@ def _paged_write_and_attend(q, k, v, cache, pos, *, slot_mask=None):
             view = _paged_view(pool, table)
             out = cached_attention(q, view["k"], view["v"], pos,
                                    slot_mask=slot_mask)
+    return out, {**pool, "table": table}
+
+
+def block_write_and_attend(q, k, v, cache, pos0):
+    """One pass over a BLOCK of a block-diffusion model against the paged
+    pool (``cache`` as :func:`_paged_write_and_attend` takes it, float
+    pools only): ``q [B, H, L, hd]``, ``k``/``v`` ``[B, hk, L, hd]`` are
+    the ``L`` positions ``pos0[b] .. pos0[b] + L - 1`` of each row, ``pos0``
+    and the pool's block size multiples of ``L``, so a block lies in ONE
+    pool block. The block's K/V are written first (one window a row:
+    ``cache_update.kv_pool_insert_span_all``), then every query attends
+    slots ``0 .. pos0 + L - 1``: ONE range a row for all its queries, so
+    where :func:`paged_read_path` allows the pool is read in place by the
+    block-table kernel with the ``L`` queries beside the head group (``L x
+    G`` query rows to a KV head), else through the gathered view. A parked
+    row (an all-trash table) writes into trash and attends nothing worth
+    reading, as in a tick."""
+    from distributed_compute_pytorch_tpu.ops.pallas.cache_update import (
+        kv_pool_insert_span_all)
+    table = cache["table"]
+    pool = {n: leaf for n, leaf in cache.items() if n != "table"}
+    assert "scale" not in pool, "a block pass has no int8 form"
+    bt, L = pool["kv"].shape[3], q.shape[2]
+    assert bt % L == 0, (bt, L)
+    blk = jnp.take_along_axis(table, (pos0 // bt)[:, None], axis=1)[:, 0]
+    with scope("kv_write"):
+        pool = kv_pool_insert_span_all(pool, {"kv": jnp.stack([k, v])},
+                                       blk, pos0 % bt)
+    end = pos0 + L - 1
+    if paged_read_path(pool, L, one_range=True) == "kernel":
+        from distributed_compute_pytorch_tpu.ops.pallas import (
+            decode_attention)
+        out = decode_attention.paged_decode_attention_pallas(
+            q, pool["kv"], table, end)
+    else:
+        view = _paged_view(pool, table)
+        out = cached_attention(
+            q, view["k"], view["v"],
+            jnp.broadcast_to(end[:, None], (q.shape[0], L)))
     return out, {**pool, "table": table}
 
 

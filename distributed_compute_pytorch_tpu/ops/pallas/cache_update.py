@@ -320,6 +320,87 @@ def kv_pool_insert_all(cache: dict, upd: dict, blocks, offsets) -> dict:
             for k in cache}
 
 
+# ---------------------------------------------------------------------------
+# SPAN insert: ``L`` consecutive slots a row in one write (a block of a
+# block-diffusion model: serve.py's block pass). ``L`` divides the window, so
+# a row's span lies in ONE window of one pool block and the write is the
+# per-row kernel's: one window in, the span's rows replaced, one window out.
+# ---------------------------------------------------------------------------
+
+
+def _pool_span_kernel(n: int, L: int):
+    """As :func:`_pool_rows_kernel`, for a span: update row ``g`` is a whole
+    WINDOW whose slot ``j`` holds the span's token ``j % L`` (the caller
+    tiles it: no sub-tile vector is cut inside the kernel), of which slots
+    ``off[g] % W .. + L - 1`` are taken."""
+    def kernel(blk_ref, off_ref, *refs):
+        del blk_ref                    # consumed by the index maps
+        g = pl.program_id(0)
+        upds, caches, outs = refs[:n], refs[n:2 * n], refs[2 * n:]
+        for u, c, o in zip(upds, caches, outs):
+            r = off_ref[g] % c.shape[3]
+            blk = c[...]
+            slot = lax.broadcasted_iota(jnp.int32, blk.shape, 3)
+            o[...] = jnp.where((slot >= r) & (slot < r + L), u[...], blk)
+    return kernel
+
+
+def kv_pool_insert_span_pallas(cache: dict, upd: dict, blocks, offsets, *,
+                               interpret: bool = False) -> dict:
+    """``L`` consecutive slots a row into a PAGED block pool: ``upd``
+    leaves ``[s, B, hk, L, w]`` land at ``cache[:, blocks[b], :, offsets[b]
+    .. offsets[b] + L - 1, :]``. ``L`` divides the dtype's window and
+    ``offsets`` are multiples of ``L``. Everything else as
+    :func:`kv_pool_insert_rows_pallas`."""
+    names = sorted(cache)
+    n = len(names)
+    B, L = upd[names[0]].shape[1], upd[names[0]].shape[3]
+    in_specs = [None] * (2 * n)
+    out_specs, out_shapes, aliases, tiled = [], [], {}, []
+    for i, name in enumerate(names):
+        c = cache[name]
+        s, p, hk, bt, w = c.shape
+        W = _window(c.dtype)
+        assert bt % W == 0 and W % L == 0, (name, bt, W, L)
+        tiled.append(jnp.tile(upd[name].astype(c.dtype),
+                              (1, 1, 1, W // L, 1)))
+        in_specs[i] = pl.BlockSpec(
+            (s, 1, hk, W, w), lambda g, blk_ref, off_ref: (0, g, 0, 0, 0))
+        window = pl.BlockSpec(
+            (s, 1, hk, W, w),
+            lambda g, blk_ref, off_ref, W=W:
+            (0, blk_ref[g], 0, off_ref[g] // W, 0))
+        in_specs[n + i] = window
+        out_specs.append(window)
+        out_shapes.append(jax.ShapeDtypeStruct(c.shape, c.dtype))
+        aliases[2 + n + i] = i         # 2 scalar-prefetch args lead
+    outs = pl.pallas_call(
+        _pool_span_kernel(n, L),
+        out_shape=out_shapes,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B,),
+            in_specs=in_specs, out_specs=out_specs),
+        input_output_aliases=aliases,
+        name="dcp_kv_pool_write_span",
+        interpret=interpret,
+    )(blocks.astype(jnp.int32), offsets.astype(jnp.int32), *tiled,
+      *[cache[k] for k in names])
+    return dict(zip(names, outs))
+
+
+def kv_pool_insert_span_all(cache: dict, upd: dict, blocks, offsets) -> dict:
+    """Dispatcher for the span write: the Pallas kernel on an unsharded TPU
+    pool, an XLA scatter of the span's ``L`` slots elsewhere."""
+    if _pallas_ok(cache, axis=3):
+        return kv_pool_insert_span_pallas(cache, upd, blocks, offsets)
+    L = next(iter(upd.values())).shape[3]
+    at = offsets[:, None] + jnp.arange(L)[None, :]             # [B, L]
+    # advanced indices at axes (1, 3) land broadcast-first: [B, L, s, hk, w]
+    return {k: cache[k].at[:, blocks[:, None], :, at, :].set(
+        upd[k].transpose(1, 3, 0, 2, 4).astype(cache[k].dtype), mode="drop")
+        for k in cache}
+
+
 def _pair_rows_kernel(n: int):
     """Per-row variant of :func:`_pair_kernel`: grid step ``b`` owns
     batch row ``b``'s window block ([2, 1, hk, W, w], window axis 3) at

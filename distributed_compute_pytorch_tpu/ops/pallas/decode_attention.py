@@ -279,15 +279,20 @@ def _chunk_blocks(pool_shape, itemsize: int, nb_w: int) -> int:
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
                                   interpret: bool = False):
-    """Single-position decode attention over a paged pool, read in place.
+    """Decode attention over a paged pool, read in place: every query of a
+    row over ONE range of its slots.
 
-    ``q [B, H, 1, hd]`` (this tick's queries); ``pool_kv [2, P, hk, bt,
+    ``q [B, H, L, hd]`` (this tick's queries, ``L`` 1; or the ``L``
+    positions of a block-diffusion block, which all attend the same slots:
+    they ride beside the head group, ``L x G`` query rows to a KV head,
+    and the stream of the row's blocks is the one a single query would
+    cost); ``pool_kv [2, P, hk, bt,
     hd]`` (dim 0 = k/v: the serving pool leaf as it is, never copied or
     re-laid); ``table`` int32 ``[B, nb_w]`` (row ``b``'s logical slot
     ``t`` lives in pool block ``table[b, t // bt]`` at offset ``t %
     bt``; a width-rung slice is fine, the cost follows ``pos``); ``pos``
     int32 ``[B]`` (row ``b`` attends slots ``0 .. pos[b]``, its own
-    just-written one included). Returns ``[B, H, 1, hd]`` in ``q``'s
+    just-written ones included). Returns ``[B, H, L, hd]`` in ``q``'s
     dtype: the same mathematics as ``cached_attention`` over
     ``gather_kv_blocks`` (GQA: query head ``h`` reads KV head
     ``h // (H // hk)``). Needs ``hd % 128 == 0`` (one lane tile per
@@ -301,9 +306,9 @@ def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
     rungs cost the steady cell 65 s of set-up before this)."""
     B, H, q_len, hd = q.shape
     _, _, hk, bt, _ = pool_kv.shape
-    assert q_len == 1 and hd % 128 == 0 and H % hk == 0, (q.shape, hk)
+    assert hd % 128 == 0 and H % hk == 0, (q.shape, hk)
     nb_w = table.shape[1]
-    G = H // hk
+    G = H // hk * q_len       # query rows to a KV head: (group, position)
     C = _chunk_blocks(pool_kv.shape, pool_kv.dtype.itemsize, nb_w)
     assert C >= 1, ("a block pair does not fit the scratch", pool_kv.shape)
     row_spec = pl.BlockSpec((1, hk, G, hd), lambda b, p, t, n: (b, 0, 0, 0))
@@ -332,7 +337,7 @@ def paged_decode_attention_pallas(q, pool_kv, table, pos, *,
     )(jnp.broadcast_to(jnp.atleast_1d(pos).astype(jnp.int32), (B,)),
       table.reshape(-1).astype(jnp.int32), _live_from(table),
       q.reshape(B, hk, G, hd), pool_kv)
-    return out.reshape(B, H, 1, hd)
+    return out.reshape(B, H, q_len, hd)
 
 
 # ---------------------------------------------------------------------------

@@ -120,13 +120,18 @@ def _masked(s, keep):
     return jax.lax.select(keep, s, jnp.full_like(s, _NEG_INF))
 
 
-def _edge_mask(s, lead, window=None):
+def _edge_mask(s, lead, window=None, mask_block=0):
     """Mask a score tile that the diagonal (or the band's low edge) crosses.
     ``lead`` = absolute position of the tile's first row less that of its
     first column: row ``i`` sees column ``j`` iff ``j - i <= lead`` (and,
-    in a band, ``i - j + lead < window``)."""
-    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    in a band, ``i - j + lead < window``). ``mask_block`` (a power of two
+    dividing both tile origins): the BLOCK mask in place of the causal one,
+    row ``i`` sees every column of the blocks up to its own: ``j`` counts
+    as the first column of its block."""
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if mask_block:
+        col = col & ~(mask_block - 1)
+    rel = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) - col
     keep = rel >= -lead
     if window is not None:
         keep = keep & (rel < window - lead)
@@ -190,7 +195,7 @@ def _accumulate(step, load, keep, *, run, lead, causal, tiles, block,
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
                 scale, causal, offset, masked, block_q, block_k,
-                window=None, nband=None):
+                window=None, nband=None, mask_block=0):
     if masked:
         mask_ref, o_ref, lse_ref, acc, m_s, l_s = rest
     else:
@@ -230,7 +235,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         v = v_ref[0]                                  # [Bk, Dv]
         s = _dot_tt(q, k) * scale
         if causal:
-            s = _edge_mask(s, offset + qi * block_q - kb * block_k, window)
+            s = _edge_mask(s, offset + qi * block_q - kb * block_k, window,
+                           mask_block)
         if masked:
             # [1, Bk] f32 0/1 key-validity row broadcast down the q rows.
             # _NEG_INF (not -inf) keeps fully-masked rows NaN-free: their
@@ -269,7 +275,7 @@ def _mask_spec(heads, block_k):
 
 
 def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
-               block_q, block_k, window=None, kv_heads=None):
+               block_q, block_k, window=None, kv_heads=None, mask_block=0):
     bh, t, d = q.shape
     tk = k.shape[1]
     # v may have another head width than q and k (latent attention: 192
@@ -279,9 +285,12 @@ def _flash_fwd(q, k, v, kv_mask, heads, scale, causal, offset,
     v_spec = None
     if window is None:
         grid = (bh, t // block_q, tk // block_k)
-        kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                                   offset=offset, masked=masked,
-                                   block_q=block_q, block_k=block_k)
+        kernel = functools.partial(
+            _fwd_kernel, scale=scale, causal=causal, offset=offset,
+            masked=masked, block_q=block_q, block_k=block_k,
+            # (a keyword only where it is set: every other call's kernel is
+            # the partial it always was)
+            **({"mask_block": mask_block} if mask_block else {}))
         kv_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
         if dv != d:
             v_spec = pl.BlockSpec((1, block_k, dv), lambda b, i, j: (b, j, 0))
@@ -671,7 +680,8 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     scale: float | None = None,
                     kv_mask=None,
                     block_q: int = DEFAULT_BLOCK,
-                    block_k: int = DEFAULT_BLOCK):
+                    block_k: int = DEFAULT_BLOCK,
+                    mask_block: int = 0):
     """Fused attention: ``[b, h, t, d]`` in, same out. Differentiable.
     ``v`` may have another head width than ``q`` and ``k`` (``[b, h, tk,
     dv]``: a latent-attention head is 192 wide for q and k, 128 for v);
@@ -691,6 +701,12 @@ def flash_attention(q, k, v, *, causal: bool = False,
     row i attends kv positions ``<= i + kv_len - q_len`` — the masked
     decode-prefill convention, matching the dense path); ``q_len >
     kv_len`` causal is rejected (its top rows would attend nothing).
+
+    ``mask_block`` (static; causal self-attention, a power of two dividing
+    128): the block mask of a block-diffusion model, row ``i`` sees ``j``
+    iff ``j // mask_block <= i // mask_block``. The grid skips the same
+    tiles as under the causal mask (a row's own block ends inside its
+    tile); only the masking of the diagonal tiles differs. Forward only.
     """
     b, h, t, d = q.shape
     tk = k.shape[2]
@@ -701,6 +717,10 @@ def flash_attention(q, k, v, *, causal: bool = False,
             f"first {t - tk} query rows attending nothing")
     offset = (tk - t) if causal else 0
     scale = (d ** -0.5) if scale is None else scale
+    if mask_block and not (causal and offset == 0 and 128 % mask_block == 0):
+        raise ValueError(
+            f"mask_block {mask_block} needs causal self-attention and a "
+            f"power of two dividing 128 (causal {causal}, q {t}, kv {tk})")
 
     pad_q = (-t) % block_q
     pad_k = (-tk) % block_k
@@ -730,7 +750,14 @@ def flash_attention(q, k, v, *, causal: bool = False,
     kf = k.reshape(b * h, tkp, d)
     dv = v.shape[-1]
     vf = v.reshape(b * h, tkp, dv)
-    if kv_mask is None:
+    if mask_block:
+        # padded query rows reach padded keys under the block mask too, and
+        # are sliced off; forward only (served, not trained)
+        o, _ = _flash_fwd(
+            qf, kf, vf, None if kv_mask is None else kv_mask.astype(
+                jnp.float32).reshape(b, 1, tkp), h, scale, True, 0,
+            block_q, block_k, mask_block=mask_block)
+    elif kv_mask is None:
         o = _flash(qf, kf, vf, scale, causal, offset, block_q, block_k)
     else:
         # rank-3 [B, 1, Tk] so the kernels' (1, 1, block_k) mask blocks

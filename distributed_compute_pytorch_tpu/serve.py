@@ -57,6 +57,19 @@ instead, with everything the TPU touches remaining static-shaped:
   per block, in-place pool writes, per-row sampling). Pool/tokens carry
   ACROSS calls as donated buffers, so consecutive segments reuse the
   same compiled program at zero re-trace cost.
+- **Block passes** (a model that generates by BLOCK DIFFUSION,
+  ``model.block_generation``: ``models/hybrid.py``): the segment is
+  ``_block_segment_impl`` instead, ``segment`` PASSES in which a row takes
+  a block of ``block_length`` positions and yields none to
+  ``block_length`` tokens by its phase (denoise: some of the block's
+  masked positions take their token, the position stays; commit: the
+  finished block's K/V are final, its tokens are delivered, the position
+  moves a block). The step contract is stated once, where a segment is
+  planned (``ContinuousBatcher._row_passes``): a causal model is the case
+  "every pass yields one token and moves one slot". Admission prefills the
+  prompt's whole blocks under the block mask and the tail opens the first
+  generated block; everything else below (tables, overlap, recovery from
+  prompt + delivered tokens) is shared.
 - **Per-row positions**: every row advances an INDEPENDENT write
   position (``decode_step`` takes a ``[B]`` position vector); a row's
   prompt head occupies logical slots ``[0, n-1)`` and decode continues
@@ -279,6 +292,18 @@ _GAP_EDGES_S = tuple(1e-2 * 10.0 ** (i / 16) for i in range(1, 49))
 _GAP_COUNTERS = (
     *(f"delivery_gap_upto_{float(f'{1e3 * e:.3g}'):g}" for e in _GAP_EDGES_S),
     "delivery_gap_upto_inf")
+
+
+# The ``stats`` counters of a model that generates by block diffusion
+# (``_run::dispatch_block_segment``): passes dispatched (segments x S); the
+# row-passes of rows in a plan while their request is owed tokens, and of
+# those the two phases; blocks committed; positions
+# generated past a request's ``max_new`` (its last block is denoised whole);
+# prompt tokens that filled no block and opened the first generated one.
+_BLOCK_COUNTERS = ("block_passes", "block_row_passes",
+                   "denoise_row_passes", "commit_row_passes",
+                   "blocks_committed", "block_tokens_cut",
+                   "prompt_tail_tokens")
 
 
 @functools.partial(jax.jit, static_argnums=1)
@@ -645,6 +670,16 @@ class ContinuousBatcher:
         self._layer_blocks = (
             [model.layer_block(i) for i in range(model.num_layers)]
             if hasattr(model, "layer_block") else None)
+        # how the model generates is the model's own: None (causal, a pass
+        # yields one token a row) or block diffusion's (block_length,
+        # denoising_steps, remasking, mask_token_id): the STEP CONTRACT of
+        # _row_passes, the pass of _block_segment_impl
+        self._blockgen = getattr(model, "block_generation", None)
+        if self._blockgen is not None:
+            self._refuse_for_block_generation(
+                self._blockgen[2], prefix_cache=prefix_cache,
+                speculate=speculate, kv_dtype=kv_dtype, mesh=mesh,
+                prefill_chunk_tokens=prefill_chunk_tokens)
         if self._layer_blocks is not None:
             self._refuse_for_layer_kinds(
                 {b.cache_kind for b in self._layer_blocks},
@@ -780,6 +815,11 @@ class ContinuousBatcher:
         bt = kv_block_tokens if kv_block_tokens is not None else (
             implied or align)
         self.bt = -(-bt // align) * align
+        if self._blockgen is not None and self.bt % self._blockgen[0]:
+            raise ValueError(
+                f"kv_block_tokens ({self.bt}) must be a multiple of the "
+                f"model's block_length ({self._blockgen[0]}): a block of "
+                f"positions never straddles two pool blocks")
         self.t_max = -(-t_max // self.bt) * self.bt
         self.nb = self.t_max // self.bt          # table entries per row
         # width-bucket ladder (ISSUE 19): every decode/verify dispatch
@@ -944,14 +984,19 @@ class ContinuousBatcher:
                 self._layer_blocks[self._paged0].read_path(
                     self._caches[self._paged0]))
         if (self._layer_blocks is not None and decode_width_buckets is None
-                and self._paged_read in ("kernel", "selected")):
+                and (self._paged_read in ("kernel", "selected")
+                     or self._blockgen is not None)):
             # the kernel's traffic follows each row's position whatever
             # rung the table was shipped at (PERF.md, PR 25), so for a
             # pool read in place the ladder buys nothing and costs a
             # compiled segment a rung, the top one reached only by a row
             # past half the horizon: mid-traffic. A model of layer kinds
             # starts with the one full-width rung; the families that were
-            # served before it keep their ladder (and their programs).
+            # served before it keep their ladder (and their programs). So
+            # does a model that generates by blocks wherever it runs: its
+            # pass is read in place on the chip, and a row's position moves
+            # by its phases, not a slot a tick, which a warm-up sized for
+            # ticks would not cover rung by rung.
             self._width_ladder = (self.nb,)
             self._cur_width = self.nb
         # HBM bytes ONE gathered block read moves per (row, layer):
@@ -963,6 +1008,20 @@ class ContinuousBatcher:
         row_spec = P(("data", "fsdp"))
         self._cur_tok = zeros((slots,), jnp.int32, row_spec)
         self._n_logical = zeros((slots,), jnp.int32, row_spec)
+        if self._blockgen is not None:
+            # a row's block on the device, carried from segment to segment:
+            # its tokens and WHICH ARE MASKED (state, never a comparison
+            # with the mask id: a prompt may hold it). The host's side of a
+            # row's block (_row_passes): the slot its block starts at
+            # (_row_pos), the positions still masked, the positions the
+            # block generates (all but a first block's prompt tail), and
+            # the slot at which the request's last block ends
+            L = self._blockgen[0]
+            self._blk_tok = zeros((slots, L), jnp.int32, None)
+            self._blk_masked = zeros((slots, L), jnp.bool_, None)
+            self._blk_left = [0] * slots
+            self._blk_gen = [0] * slots
+            self._stop_pos = np.zeros((slots,), np.int32)
         # host-side paged-cache state: the refcounted block pool, the
         # per-row block tables (shipped with every dispatch; trash = 0),
         # and the radix prefix cache
@@ -1097,6 +1156,8 @@ class ContinuousBatcher:
             ref = _PROGRAM_CACHE.get(key) if key is not None else None
             donor = ref() if ref is not None else None
             if donor is not None:
+                if self._blockgen is not None:
+                    self._block_segment_c = donor._block_segment_c
                 self._admit_c = donor._admit_c
                 self._segment_c = donor._segment_c
                 self._copy_c = donor._copy_c
@@ -1115,6 +1176,9 @@ class ContinuousBatcher:
                                          static_argnames=("sampling",))
                 self._promote_c = jax.jit(self._promote_impl,
                                           donate_argnums=(0,))
+                if self._blockgen is not None:
+                    self._block_segment_c = jax.jit(
+                        self._block_segment_impl, donate_argnums=(1,))
                 if key is not None:
                     _PROGRAM_CACHE[key] = weakref.ref(self)
 
@@ -1172,7 +1236,9 @@ class ContinuousBatcher:
             # assignments of the rows in the plan (ticks x top_k), those
             # that fell on the experts this chip holds, and each held
             # expert's load
-            **dict.fromkeys(self._count_keys, 0)})
+            **dict.fromkeys(self._count_keys, 0),
+            **dict.fromkeys(_BLOCK_COUNTERS if self._blockgen is not None
+                            else (), 0)})
         self.last_slot_leaks = 0   # rows still owned at serve() exit
         self.last_block_leaks = 0  # pool refs unaccounted at serve() exit
                                    # (both must be 0 — asserted by tests)
@@ -1575,6 +1641,10 @@ class ContinuousBatcher:
         layer's own kind of cache) and only the rest through decode
         ticks: prefill-then-decode as a request lives it. The logits
         returned are then those of positions ``prefill .. n-1``."""
+        if self._blockgen is not None:
+            raise NotImplementedError(
+                "logit_probe walks a stream a token a tick; a model that "
+                "generates by block diffusion has no such tick")
         toks = [int(t) for t in tokens]
         n = len(toks)
         if n == 0:
@@ -1759,6 +1829,41 @@ class ContinuousBatcher:
                     for what, why in map(cls._LAYER_KIND_REFUSALS.get,
                                          present)))
 
+    @staticmethod
+    def _refuse_for_block_generation(remasking, *, prefix_cache, speculate,
+                                     kv_dtype, mesh, prefill_chunk_tokens):
+        """What a model that generates by block diffusion is not served
+        with, each with its reason (sampled rows are refused a request at a
+        time: :meth:`_validate_one`)."""
+        if remasking == "low_confidence_dynamic":
+            raise ValueError(
+                "remasking 'low_confidence_dynamic' is not served: a "
+                "confidence threshold makes a pass's yield depend on the "
+                "data, so the host could not charge a row's budget at "
+                "dispatch nor dispatch a segment before it fetched the one "
+                "before")
+        for name, on, why in (
+            ("prefix_cache", prefix_cache,
+             "a cached prefix would have to end on a block boundary and "
+             "be attended under the block mask; the radix cache keys on "
+             "prompt heads and the attached-prefix path is causal"),
+            ("speculate", speculate is not None,
+             "a verify window scores causal drafts a position at a time; a "
+             "block pass already yields up to block_length tokens"),
+            ("kv_dtype='int8'", kv_dtype == "int8",
+             "the block pass's span write and one-range read have no "
+             "quantized form"),
+            ("mesh", mesh is not None,
+             "the span write and the block-table read are single-device "
+             "kernels"),
+            ("prefill_chunk_tokens", prefill_chunk_tokens is not None,
+             "a chunk would have to attend the blocks before it through "
+             "the pool under the block mask, and the chunk path is causal"),
+        ):
+            if on:
+                raise ValueError(f"{name} does not compose with generation "
+                                 f"by block diffusion yet: {why}")
+
     def _hold(self, params):
         """Take ``params`` as the engine's weights, in the form the caller
         has them. A model of layer kinds already keeps one tree a layer
@@ -1910,6 +2015,7 @@ class ContinuousBatcher:
         self._cur_tok = jnp.zeros_like(self._cur_tok)
         self._n_logical = jnp.zeros_like(self._n_logical)
         self._row_pos = [0] * self.B
+        self._zero_blocks()
         self._temp[:] = 0.0
         self._topk[:] = 0
         self._topp[:] = 2.0
@@ -1922,6 +2028,16 @@ class ContinuousBatcher:
         self._widths_dispatched.clear()
         self.ticks = 0
         self._zero_stats()
+
+    def _zero_blocks(self) -> None:
+        """Forget every row's block (a model that generates by blocks)."""
+        if self._blockgen is None:
+            return
+        self._blk_tok = jnp.zeros_like(self._blk_tok)
+        self._blk_masked = jnp.zeros_like(self._blk_masked)
+        self._blk_left = [0] * self.B
+        self._blk_gen = [0] * self.B
+        self._stop_pos[:] = 0
 
     def reload_weights(self, params, weights_version: int | None = None):
         """HOT WEIGHT SWAP (ISSUE 20): install ``params`` as this
@@ -2241,6 +2357,101 @@ class ContinuousBatcher:
             (jnp.arange(self.S), tick_keys))
         return (caches, tok, n_logical, toks.transpose(1, 0), *xc)
 
+    def _block_segment_impl(self, params, caches, tables, blk_tok,
+                            blk_masked, pos0, stop):
+        """``S`` passes of a model that generates by BLOCK DIFFUSION. A
+        row carries a block of ``L = block_length`` positions: its tokens
+        ``blk_tok [B, L]``, which of them are masked ``blk_masked [B, L]``
+        (state), and the slot it starts at (``pos0 [B]``, a multiple of
+        ``L``). One pass, every row: embed the block (a masked position
+        embeds the mask token) at its absolute positions; every layer
+        projects the block's q/k/v, WRITES the block's K/V at its slots
+        through the table (every pass writes; a denoise pass's values are
+        overwritten by the next) and attends slots ``0 .. pos + L - 1``,
+        one range a row for all its queries (``HybridBlock.block_step``);
+        read-out at the block's positions (position ``i``'s logits are
+        token ``i``'s), the greedy token a position; then, by the row's
+        phase (the step contract: ``_row_passes``):
+
+        - DENOISE (some position is masked): the rule marks ``L /
+          denoising_steps`` masked positions (all that are left if fewer),
+          which take their token: ``sequential`` the leftmost,
+          ``low_confidence_static`` those whose token has the largest
+          probability, ties to the left. The position stays.
+        - COMMIT (none is masked): the K/V this pass wrote are the block's
+          final ones; the block's tokens are what the pass hands back, the
+          position moves by ``L`` and the next block opens all masked.
+
+        A row past ``stop [B]`` (the slot at which its request's last block
+        ends; a request can end inside a segment) is out of the rest of the
+        segment like a row out of the plan: its table is swapped for the
+        all-trash row pass by pass, so it writes into trash, the kernel
+        passes it by and the experts do not count it.
+
+        Returns the carried state, ``toks [B, S, L]`` (pass ``s``'s block as
+        it went in: final where pass ``s`` committed, which the host knows)
+        and the blocks each row committed ``[B]`` (held against the host's
+        plan at the harvest), then the experts' counts as a tick's."""
+        L, steps, rule, mask_id = self._blockgen
+        per_pass = L // steps
+        model = self.model
+        counted = bool(self._count_keys)
+        trash = jnp.int32(BlockPool.TRASH)
+        at = jnp.arange(L)
+
+        def one_pass(carry, _):
+            with scope("block_pass"):
+                tok, masked, pos, caches, *xc = carry
+                active = pos < stop
+                tables_p = jnp.where(active[:, None], tables, trash)
+                live = (tables_p[:, 0] != trash).astype(jnp.float32)
+                x = model.embed(params, jnp.where(masked, mask_id, tok))
+                counts: list | None = [] if counted else None
+                new_caches = []
+                for li in range(self._n_layers):
+                    block, p_l = self._layer(params, li)
+                    x, c2 = block.block_step(
+                        p_l, x, {**caches[li], "table": tables_p}, pos,
+                        live=live, counts_sink=counts)
+                    new_caches.append(
+                        {name: c2[name] for name in self._leaves[li]})
+                logits = model.readout(params, x)              # [B, L, V]
+                with scope("unmask"):
+                    pred = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    if rule == "sequential":
+                        # a masked position's place among the masked
+                        rank = jnp.cumsum(masked.astype(jnp.int32), 1) - 1
+                    else:
+                        # the greedy token's probability, 1 / sum exp(l -
+                        # max); a position goes after those of larger
+                        # probability and, at a tie, those to its left
+                        lg = logits.astype(jnp.float32)
+                        conf = 1.0 / jnp.sum(jnp.exp(
+                            lg - jnp.max(lg, -1, keepdims=True)), -1)
+                        sc = jnp.where(masked, conf, -1.0)
+                        first = (sc[:, None, :] > sc[:, :, None]) | (
+                            (sc[:, None, :] == sc[:, :, None])
+                            & (at[None, None, :] < at[None, :, None]))
+                        rank = jnp.sum(first, axis=2)
+                    take = masked & (rank < per_pass)
+                    commit = ~jnp.any(masked, axis=1)
+                    moved = commit & active
+                    out = tok
+                    tok = jnp.where(take, pred, tok)
+                    masked = jnp.where(commit[:, None], True, masked & ~take)
+                    pos = jnp.where(moved, pos + L, pos)
+                if counted:
+                    xc = [xc[0] + sum(counts)]
+                return (tok, masked, pos, new_caches, *xc), (out, moved)
+
+        xc0 = ([jnp.zeros((len(self._count_keys),), jnp.int32)]
+               if counted else [])
+        (tok, masked, _, caches, *xc), (toks, moved) = lax.scan(
+            one_pass, (blk_tok, blk_masked, pos0, caches, *xc0), None,
+            length=self.S)
+        return (caches, tok, masked, toks.transpose(1, 0, 2),
+                jnp.sum(moved, axis=0).astype(jnp.int32), *xc)
+
     def _verify_impl(self, params, caches, tables, toks, positions0,
                      n_logical, temp, top_k, top_p, seeds,
                      sampling: bool = False):
@@ -2404,6 +2615,10 @@ class ContinuousBatcher:
         self._tables[b, :] = BlockPool.TRASH
         self._tables[b, :nblocks] = row_blocks
         slot.blocks = row_blocks
+        if self._blockgen is not None:
+            # the slot at which the request's last block ends
+            L = self._blockgen[0]
+            self._stop_pos[b] = -(-(len(known) + remaining) // L) * L
         self.stats["block_pool_occupancy"] = max(
             self.stats["block_pool_occupancy"],
             self._pool.allocated / self._pool.num_blocks)
@@ -2432,7 +2647,64 @@ class ContinuousBatcher:
         the prompt head, strictly inside the extent)."""
         if self._spec is not None:
             return max_new
+        if self._blockgen is not None:
+            # whole blocks from the block the prompt's tail opens: at most
+            # block_length slots past head + max_new. A request that ends
+            # inside a segment writes nothing after it (its stop position)
+            return max_new + self._blockgen[0]
         return -(-max_new // self.S) * self.S
+
+    def _row_passes(self, b: int, remaining: int) -> tuple:
+        """THE STEP CONTRACT, where a segment is planned: what the next
+        ``S`` passes of row ``b`` yield, known to the host at dispatch. A
+        pass has a PHASE, and the phase says how many tokens the pass
+        yields and whether the row's position moves.
+
+        A causal model is the case "every pass yields one token and moves
+        one slot": ``min(remaining, S)`` tokens, as ``dispatch_segment``
+        charges them. A model that generates by block diffusion
+        (``_block_segment_impl``):
+
+        - DENOISE: the row's block holds masked positions; the pass
+          unmasks ``block_length / denoising_steps`` of them (all that are
+          left if fewer: a first block opened by ``r`` prompt tokens has
+          ``block_length - r``). Nothing is delivered, the position stays.
+        - COMMIT: nothing is masked; the pass yields the block's generated
+          tokens (the last block's cut to ``remaining``: a request is
+          served exactly ``max_new``), its K/V are final, the position
+          moves by ``block_length`` and the next block opens all masked.
+
+        Tokens are delivered when their block commits, so every delivered
+        token is in the cache and a reconstruction or a journal replay
+        re-admits prompt + delivered as ever. Advances the host's side of
+        the row's block and returns ``(deliveries, remaining)``:
+        ``deliveries`` one ``(pass, first offset in the block, tokens)`` a
+        commit, ``remaining`` what the request is still owed after them (0:
+        it ends in this segment and sits out what is left of it)."""
+        L, steps = self._blockgen[:2]
+        deliveries, denoised, cut = [], 0, 0
+        for s in range(self.S):
+            if remaining <= 0:
+                break
+            if self._blk_left[b]:
+                self._blk_left[b] -= min(L // steps, self._blk_left[b])
+                denoised += 1
+                continue
+            gen = self._blk_gen[b]
+            n = min(gen, remaining)
+            deliveries.append((s, L - gen, n))
+            remaining -= n
+            cut += gen - n
+            self._row_pos[b] += L
+            self._blk_left[b] = self._blk_gen[b] = L
+        # (once a row, not once a pass: every write is mirrored to a gauge)
+        st, commits = self.stats, len(deliveries)
+        st["block_row_passes"] += denoised + commits
+        st["denoise_row_passes"] += denoised
+        st["commit_row_passes"] += commits
+        st["blocks_committed"] += commits
+        st["block_tokens_cut"] += cut
+        return deliveries, remaining
 
     # ---- width buckets (ISSUE 19) ---------------------------------------
 
@@ -2494,6 +2766,16 @@ class ContinuousBatcher:
         self._cut_weights()
         for w in self._width_ladder:
             tables = np.full((self.B, w), BlockPool.TRASH, np.int32)
+            if self._blockgen is not None:
+                with span("prewarm_width", blocks=int(w)):
+                    (self._caches, self._blk_tok, self._blk_masked, *_
+                     ) = self._block_segment_c(
+                        self.params, self._caches, jnp.asarray(tables),
+                        self._blk_tok, self._blk_masked,
+                        jnp.zeros((self.B,), jnp.int32),
+                        jnp.zeros((self.B,), jnp.int32))
+                self.width["prewarmed_programs"] += 1
+                continue
             with span("prewarm_width", blocks=int(w)), self._mesh_ctx():
                 (self._caches, self._cur_tok, self._n_logical, *_
                  ) = self._segment_c(
@@ -2508,6 +2790,7 @@ class ContinuousBatcher:
         self._caches = jax.tree.map(jnp.zeros_like, self._caches)
         self._cur_tok = jnp.zeros_like(self._cur_tok)
         self._n_logical = jnp.zeros_like(self._n_logical)
+        self._zero_blocks()
         return len(self._width_ladder)
 
     def _width_fraction(self) -> float:
@@ -2534,7 +2817,11 @@ class ContinuousBatcher:
         Either tick count is then weighted by :meth:`_width_fraction`,
         so a replica whose bucket stays small undercuts one already
         gathering a long session's horizon."""
-        if self._spec is None or not self._spec_on:
+        if self._blockgen is not None:
+            # a block is its denoising passes and one commit pass
+            L, steps = self._blockgen[:2]
+            ticks = -(-(-(-max_new // L) * (steps + 1)) // self.S) * self.S
+        elif self._spec is None or not self._spec_on:
             ticks = -(-max_new // self.S) * self.S
         else:
             rate = min(1.0, max(0.0, float(self.spec["acceptance_rate"])))
@@ -2577,6 +2864,11 @@ class ContinuousBatcher:
             return f"max_new must be >= 1, got {r.max_new}"
         if r.temperature < 0.0:
             return f"temperature must be >= 0, got {r.temperature}"
+        if r.temperature > 0.0 and self._blockgen is not None:
+            return ("sampled rows are not served by a model that generates "
+                    "by block diffusion: a pass takes each position's "
+                    "greedy token, and the rules choose among those "
+                    "(temperature must be 0)")
         if r.temperature == 0.0 and (r.top_k is not None
                                      or r.top_p is not None):
             return ("top_k/top_p require temperature > 0 "
@@ -3096,6 +3388,11 @@ class ContinuousBatcher:
                     self.stats["prefill_tokens_saved"] += m
                     head_len = len(req.tokens) - 1
                     upto = head_len
+                    if self._blockgen is not None:
+                        # the prompt's whole blocks; its tail opens the
+                        # first generated block (_open_blocks)
+                        upto = (len(req.tokens) // self._blockgen[0]
+                                * self._blockgen[0])
                     if budget is not None:
                         give = min(head_len - m, budget)
                         budget -= give
@@ -3337,6 +3634,74 @@ class ContinuousBatcher:
                 chaos.on_segment(self.stats["segments"])
             # held experts' counts ride with the tokens to the harvest
             return "plain", (toks, *xc), plan, mark
+
+        def dispatch_block_segment():
+            """:func:`dispatch_segment` for a model that generates by block
+            diffusion: ONE compiled segment of ``S`` passes
+            (``_block_segment_impl``), no fetch. What each row's passes
+            yield is the step contract's (``_row_passes``), host-known: the
+            budget is charged here and segment N+1 goes out before segment
+            N is fetched, as ever. A row's plan entry carries its
+            deliveries in place of a tick count."""
+            rows = [b for b, slot in enumerate(table)
+                    if slot.req_index >= 0 and slot.remaining > 0]
+            if not rows:
+                return None
+            pos0 = np.zeros((self.B,), np.int32)
+            stop = np.zeros((self.B,), np.int32)
+            plan = []
+            for b in rows:
+                slot = table[b]
+                pos0[b], stop[b] = self._row_pos[b], self._stop_pos[b]
+                deliveries, left = self._row_passes(b, slot.remaining)
+                ticks_charged[slot.req_index] += slot.remaining - left
+                slot.remaining = left
+                plan.append((b, slot.req_index, deliveries, left <= 0))
+                self.waste["planned_ticks"] += self.S
+            pending = (bool(queue) if self.admit_policy == "fifo"
+                       else any(self._fits(requests[i]) for i in queue))
+            tables_now = self._tables.copy()
+            for b in set(range(self.B)) - set(rows):
+                tables_now[b, :] = BlockPool.TRASH
+                self._row_pos[b] = 0
+                self.waste["parked_admission_lag" if pending
+                           else "parked_drain"] += self.S
+                self.stats["decode_rows_parked"] += self.S
+            # the segment's passes write and attend nothing past the block a
+            # row ends the segment in (a finished row: past its stop)
+            need = max(min(self._row_pos[b] + self._blockgen[0],
+                           int(self._stop_pos[b])) for b in rows)
+            nb_w = self._bucket_width(need)
+            self._note_width(nb_w, self.S, min(self.nb, -(-need // self.bt)))
+            prof = self._profile_req
+            if prof is not None and not prof["active"]:
+                jax.profiler.start_trace(prof["dir"])
+                prof["active"] = True
+            mark, admitted = mark_segment()
+            with span("dispatch_segment", rows=len(plan),
+                      rids=" ".join(jids[ri] for _, ri, _, _ in plan),
+                      admitted_window_tokens=admitted):
+                args = (self.params, self._caches,
+                        jnp.asarray(tables_now[:, :nb_w]), self._blk_tok,
+                        self._blk_masked, jnp.asarray(pos0),
+                        jnp.asarray(stop))
+                self._note_program("segment", self._block_segment_c, args,
+                                   {})
+                (self._caches, self._blk_tok, self._blk_masked,
+                 *outs) = self._block_segment_c(*args)
+                del args
+            if prof is not None and prof["active"]:
+                prof["remaining"] -= 1
+                if prof["remaining"] <= 0:
+                    jax.block_until_ready(outs[0])
+                    jax.profiler.stop_trace()
+                    self._profile_req = None
+            self.ticks += self.S
+            self.stats["segments"] += 1
+            self.stats["block_passes"] += self.S
+            if chaos is not None and chaos.on_segment is not None:
+                chaos.on_segment(self.stats["segments"])
+            return "block", tuple(outs), plan, mark
 
         def cow_for_write(plan):
             """Speculation rollback-safety guard (ISSUE 12): a verify
@@ -3587,6 +3952,8 @@ class ContinuousBatcher:
             plain segments otherwise."""
             if self._spec is not None and self._spec_on:
                 return dispatch_verify()
+            if self._blockgen is not None:
+                return dispatch_block_segment()
             return dispatch_segment()
 
         def harvest(seg, overlapped: bool):
@@ -3617,6 +3984,17 @@ class ContinuousBatcher:
                         fetch, self.tick_timeout_s, "serve tick harvest")
                 else:
                     toks_h, *xc_h = fetch()
+                if _kind == "block":
+                    # toks_h [B, S, L]; a row's ``take`` is its deliveries
+                    # (_row_passes), and the device committed as planned
+                    commits_h, *xc_h = xc_h
+                    off = [b for b, _, take, _ in plan
+                           if commits_h[b] != len(take)]
+                    if off:
+                        raise RuntimeError(
+                            f"rows {off} committed "
+                            f"{[int(commits_h[b]) for b in off]} blocks "
+                            f"against the host's plan")
                 for key, c in zip(self._count_keys, *xc_h):
                     self.stats[key] += int(c)
                 now = time.monotonic()
@@ -3632,7 +4010,10 @@ class ContinuousBatcher:
                         continue   # row re-admitted after an early free
                     was_empty = not slot.out
                     prev_out = len(slot.out)
-                    slot.out.extend(int(t) for t in toks_h[b, :take])
+                    new = (toks_h[b, :take] if _kind == "plain" else
+                           [t for s, o, k in take
+                            for t in toks_h[b, s, o:o + k]])
+                    slot.out.extend(int(t) for t in new)
                     if (was_empty and slot.out
                             and first_tok_at[ri] is None):
                         # first generated token reached the host: TTFT
@@ -3762,11 +4143,11 @@ class ContinuousBatcher:
         while seg is not None:
             nxt = None
             try:
-                if seg[0] == "plain":
+                if seg[0] != "spec":
                     # overlap (None: nothing live). Verify steps never
                     # overlap: the next window's drafts depend on THIS
                     # harvest's accepted tokens
-                    nxt = dispatch_segment()
+                    nxt = dispatch_next()
                 harvest(seg, overlapped=nxt is not None)
                 fault_state["consecutive"] = 0
             except Exception as e:  # noqa: BLE001 — the fault path:
@@ -3911,6 +4292,9 @@ class ContinuousBatcher:
             # over from the former tenant: they have to be written
             if self._slot_state or any(upto > m for _, _, m, upto in group):
                 self._dispatch_prefill(group, r, w, Lp)
+        if self._blockgen is not None:
+            self._open_blocks([(b, known) for b, known, *_ in entries])
+            return
         final = [(b, known) for b, known, _m, upto in entries
                  if upto >= len(known) - 1]
         if final:
@@ -3926,6 +4310,29 @@ class ContinuousBatcher:
             with self._mesh_ctx():
                 self._cur_tok = jnp.where(sel, lasts, self._cur_tok)
                 self._n_logical = jnp.where(sel, n_log, self._n_logical)
+
+    def _open_blocks(self, rows) -> None:
+        """Open the first generated block of each admitted row ``(b,
+        known)`` (a model that generates by block diffusion; ``known`` the
+        prompt, or prompt + delivered on a reconstruction or a replay): the
+        tail of ``known`` that fills no block holds the block's first
+        positions as known tokens, the rest is masked. The whole blocks
+        before it are what the wave prefilled."""
+        L = self._blockgen[0]
+        sel = np.zeros((self.B,), bool)
+        toks = np.zeros((self.B, L), np.int32)
+        masked = np.zeros((self.B, L), bool)
+        for b, known in rows:
+            start = len(known) // L * L
+            tail = known[start:]
+            sel[b] = True
+            toks[b, :len(tail)] = tail
+            masked[b, len(tail):] = True
+            self._row_pos[b] = start
+            self._blk_left[b] = self._blk_gen[b] = L - len(tail)
+            self.stats["prompt_tail_tokens"] += len(tail)
+        self._blk_tok = jnp.where(sel[:, None], toks, self._blk_tok)
+        self._blk_masked = jnp.where(sel[:, None], masked, self._blk_masked)
 
     def _warm_ladder(self) -> None:
         """Build every shape of the admission ladder, once, before the
@@ -4051,6 +4458,7 @@ class ContinuousBatcher:
         self._cur_tok = jnp.zeros_like(self._cur_tok)
         self._n_logical = jnp.zeros_like(self._n_logical)
         self._row_pos = [0] * self.B
+        self._zero_blocks()
         waves: dict[int, list] = {}
         for b, slot in enumerate(table):
             if slot.req_index < 0:
@@ -4083,8 +4491,11 @@ class ContinuousBatcher:
                 # the radix was cleared, so these allocations are always
                 # fresh blocks (m == 0) — replay never trusts dead K/V
                 self._assign_blocks(b, slot, known, remaining)
+            # (a model that generates by blocks prefills whole blocks)
+            L = self._blockgen[0] if self._blockgen is not None else 0
             self._prefill_wave(
-                [(b, known, 0, len(known) - 1) for b, _, known, _ in rows],
+                [(b, known, 0, len(known) // L * L if L else len(known) - 1)
+                 for b, _, known, _ in rows],
                 None if W == self.Tb else W)
             for b, slot, known, remaining in rows:
                 # host-known truth: the in-flight plan's budget

@@ -338,6 +338,53 @@ def test_held_experts_kernel_compiles_for_a_described_v5e(
     assert "dcp_held_experts" in compiled.as_text()
 
 
+@on_cpu
+def test_block_pass_kernels_compile_for_a_described_v5e(one_v5e_chip):
+    """Mosaic takes what a block-diffusion pass hands the pool at SDAR's
+    widths (96 rows, 32 query heads on 4 KV heads of 128, tables of 336
+    blocks of 8): ``dcp_paged_decode_attn`` with a block's 4 positions
+    beside the head group (32 query rows to a KV head) and
+    ``dcp_kv_pool_write_span`` (4 slots a row in one window). (In this file
+    for the fixture's sake, as the tests above.)"""
+    from distributed_compute_pytorch_tpu.ops.pallas import cache_update
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+    pool = arg((2, 64, 4, BT, 128))
+    read = jax.jit(paged_decode_attention_pallas).lower(
+        arg((96, 32, 4, 128)), pool, arg((96, 336), jnp.int32),
+        arg((96,), jnp.int32)).compile()
+    assert "dcp_paged_decode_attn" in read.as_text()
+    write = jax.jit(cache_update.kv_pool_insert_span_pallas).lower(
+        {"kv": pool}, {"kv": arg((2, 96, 4, 4, 128))},
+        arg((96,), jnp.int32), arg((96,), jnp.int32)).compile()
+    assert "dcp_kv_pool_write_span" in write.as_text()
+
+
+@on_cpu
+@pytest.mark.parametrize("t,masked", [(1536, True), (192, False)])
+def test_block_masked_flash_forward_compiles_for_a_described_v5e(
+        t, masked, one_v5e_chip, monkeypatch):
+    """Mosaic takes the flash forward under the block mask (the columns'
+    low bits cleared on the diagonal tiles) at the admission ladder's widest
+    and narrowest windows, with and without a pad mask."""
+    import importlib
+    flash_attention = importlib.import_module(
+        "distributed_compute_pytorch_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(flash_attention, "_use_interpret", lambda: False)
+
+    def arg(shape, dt=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_v5e_chip)
+    block = 512 if t % 512 == 0 else 128
+    fn = lambda q, k, v, *m: flash_attention.flash_attention(
+        q, k, v, causal=True, kv_mask=m[0] if m else None, block_q=block,
+        block_k=block, mask_block=4)
+    qkv = [arg((1, 32, t, 128))] * 3
+    compiled = jax.jit(fn).lower(
+        *qkv, *([arg((1, t), jnp.float32)] if masked else [])).compile()
+    assert "dcp_flash_fwd" in compiled.as_text()
+
+
 @on_tpu
 @pytest.mark.parametrize("shape", ["llama2_7b_mha_bf16", "f32_pool_hd256"])
 def test_compiled_kernel_at_budget_bound_chunks(shape):

@@ -162,6 +162,37 @@ def test_layer_kinds_carry_the_expert_and_window_scopes():
     assert _has(seg, "attn", "kv_write")             # the pool's
 
 
+def test_a_block_models_pass_carries_its_scopes():
+    """A model that generates by block diffusion: its segment is the block
+    program, wrapped in ``block_pass`` (never ``decode``), with the rule's
+    work under ``unmask`` and the pool's span write under ``attn``."""
+    from distributed_compute_pytorch_tpu.serve import Request
+    model = build_model(
+        "hybrid", vocab_size=256, max_seq_len=32,
+        layer_types=("full_attention",) * 2, mlp_layer_types=("sparse",) * 2,
+        num_heads=4, num_kv_heads=2, head_dim=16, d_model=64, d_ff=128,
+        rope_sliding_only=False, norm_placement="pre", num_experts=8,
+        top_k=2, moe_d_ff=32, shared_d_ff=0, router="softmax",
+        block_length=4, denoising_steps=2, mask_token_id=200)
+    params, _ = model.init(jax.random.key(0))
+    cb = ContinuousBatcher(model, params, slots=2, t_max=32, prompt_buf=16,
+                           segment=4)
+    out = cb.serve([Request(tokens=list(range(1, 11)), max_new=5)])
+    assert len(out[0]) == 5
+    fn, args, kwargs = cb._program_sigs["segment"]
+    seg = _locations(fn.lower(*args, **kwargs))
+    for path in (("block_pass",), ("block_pass", "embed"),
+                 ("block_pass", "attn"), ("attn", "kv_write"),
+                 ("attn", "kv_gather"), ("block_pass", "mlp", "router"),
+                 ("block_pass", "mlp", "experts"), ("block_pass", "head"),
+                 ("block_pass", "unmask")):
+        assert _has(seg, *path), path
+    assert not _has(seg, "decode") and not _has(seg, "sample")
+    fn, args, kwargs = cb._program_sigs["admit"]
+    adm = _locations(fn.lower(*args, **kwargs))
+    assert _has(adm, "admit", "attn") and not _has(adm, "block_pass")
+
+
 def test_latent_layers_carry_the_latent_scopes():
     """A latent-attention layer (``models/hybrid.py``): everything its
     mixer does as ``attn_latent`` inside ``attn``, the products with the
